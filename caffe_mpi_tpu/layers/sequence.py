@@ -107,6 +107,13 @@ class AttentionLayer(Layer):
                 q, k, v, mp.mesh, seq_axis="model", causal=bool(p.causal),
                 batch_axis="data" if mp.mesh.shape.get("data", 1) > 1
                 else None, use_flash=bool(p.use_flash))
+        elif p.use_flash and mp is not None:
+            # the flash kernels are Mosaic calls, which GSPMD cannot
+            # partition: split the batch by hand (attention never mixes
+            # samples)
+            out = mp.per_batch_shard(
+                lambda q, k, v: attention(q, k, v, causal=bool(p.causal),
+                                          use_flash=True), q, k, v)
         else:
             out = attention(q, k, v, causal=bool(p.causal),
                             use_flash=bool(p.use_flash))

@@ -217,8 +217,15 @@ class LRNLayer(Layer):
                       and (knob == "1" or x.dtype == jnp.bfloat16))
         if use_pallas:
             from ..ops.lrn import lrn_across_channels
-            y = lrn_across_channels(x, p.local_size, p.alpha, p.beta, p.k)
-            return [y], state
+
+            def kernel(x):
+                return lrn_across_channels(x, p.local_size, p.alpha,
+                                           p.beta, p.k)
+            if self.mesh_plan is not None:
+                # inside the solver's GSPMD step a Mosaic call must be
+                # partitioned by hand; LRN is per-sample
+                return [self.mesh_plan.per_batch_shard(kernel, x)], state
+            return [kernel(x)], state
         sq = jnp.square(x)
         half = (p.local_size - 1) // 2
         if self.region == "WITHIN_CHANNEL":
@@ -231,13 +238,21 @@ class LRNLayer(Layer):
             )
             scale = p.k + window_sum * (p.alpha / (p.local_size * p.local_size))
         else:
-            # across channels: 1-D window over C
-            window_sum = lax.reduce_window(
-                sq, np.zeros((), np.dtype(x.dtype))[()], lax.add,
-                window_dimensions=(1, p.local_size, 1, 1),
-                window_strides=(1, 1, 1, 1),
-                padding=((0, 0), (half, half), (0, 0), (0, 0)),
-            )
+            # across channels: 1-D window over C, as local_size shifted
+            # adds over a zero-padded copy (the same sum ops/lrn.py's
+            # kernels take). NOT a padded lax.reduce_window over the
+            # channel axis: XLA:TPU (libtpu 0.0.34) refuses the AlexNet
+            # deploy net at batch 1 and 4 with it — "INVALID_ARGUMENT:
+            # during context [post-optimization]: Binary op with
+            # incompatible shapes: f32[55,8,8,96] and f32[55,8,8,92]" —
+            # the window's padding is lost somewhere after it fuses
+            # behind conv1 (batch 10 and 256 compile; an explicit
+            # jnp.pad + VALID window is folded back and fails alike).
+            padded = jnp.pad(sq, ((0, 0), (half, half), (0, 0), (0, 0)))
+            c = x.shape[1]
+            window_sum = padded[:, 0:c]
+            for off in range(1, p.local_size):
+                window_sum = window_sum + padded[:, off:off + c]
             scale = p.k + window_sum * (p.alpha / p.local_size)
         return [x * jnp.power(scale, -p.beta)], state
 

@@ -1,13 +1,16 @@
 """ctypes binding for the native batch transformer.
 
-Loads libcaffe_tpu_native.so (built by build.sh / CMake) and exposes
-`transform_batch`. `available()` gates callers; the Python numpy path in
-data.transformer is the behavioral reference and fallback.
+Loads libcaffe_tpu_native.so (built by build.sh; the .so is not
+committed) and exposes `transform_batch`. `available()` gates callers;
+the Python numpy path in data.transformer is the behavioral reference
+and fallback. A missing or stale library is said once, in the log — the
+PIL/numpy path is several times slower and must not be taken in silence.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 
 import numpy as np
@@ -22,10 +25,18 @@ def _load():
         return _LIB
     _TRIED = True
     path = os.path.join(os.path.dirname(__file__), "libcaffe_tpu_native.so")
+    log = logging.getLogger("caffe_mpi_tpu.native")
     if not os.path.exists(path):
+        log.warning("native library not built (%s missing): decode and "
+                    "transform take the PIL/numpy path; build it with "
+                    "caffe_mpi_tpu/native/build.sh", path)
         return None
     lib = ctypes.CDLL(path)
     if lib.caffe_tpu_native_abi_version() != 1:
+        log.warning("native library %s has ABI version %d, expected 1: "
+                    "ignored (PIL/numpy path); rebuild it with "
+                    "caffe_mpi_tpu/native/build.sh", path,
+                    lib.caffe_tpu_native_abi_version())
         return None
     lib.caffe_tpu_db_open.restype = ctypes.c_void_p
     lib.caffe_tpu_db_open.argtypes = [ctypes.c_char_p]

@@ -1,6 +1,10 @@
 #!/bin/sh
-# Build the native library in place. CMake+ninja when available, plain g++
-# otherwise. Output: libcaffe_tpu_native.so next to this script.
+# Build the native library in place: one g++ invocation over the four
+# sources (about 3 s), no build directory and no cached configuration —
+# a checkout copied to another root (the chip tool's sealed machine)
+# rebuilds the same way. Output: libcaffe_tpu_native.so next to this
+# script, written under a temporary name and renamed so a half-written
+# library is never loadable.
 #
 # The decode plane (decode.cc, ISSUE 10) needs libjpeg + libpng dev
 # headers; when either is missing the library still builds with the
@@ -24,13 +28,9 @@ else
        "building transform-only (PIL decode fallback stays active)" >&2
 fi
 
-if command -v cmake >/dev/null 2>&1 && command -v ninja >/dev/null 2>&1; then
-  cmake -G Ninja -B build -DCMAKE_BUILD_TYPE=Release >/dev/null
-  ninja -C build >/dev/null
-else
-  # shellcheck disable=SC2086 — CODEC_* are intentionally word-split flags
-  g++ -O3 -fPIC -shared -std=c++17 -pthread $CODEC_FLAGS \
-      transform.cc datumdb.cc lmdb_reader.cc decode.cc \
-      -o libcaffe_tpu_native.so $CODEC_LIBS
-fi
+# shellcheck disable=SC2086 — CODEC_* are intentionally word-split flags
+g++ -O3 -fPIC -shared -std=c++17 -pthread -Wall $CODEC_FLAGS \
+    transform.cc datumdb.cc lmdb_reader.cc decode.cc \
+    -o libcaffe_tpu_native.so.tmp $CODEC_LIBS
+mv -f libcaffe_tpu_native.so.tmp libcaffe_tpu_native.so
 echo "built $(pwd)/libcaffe_tpu_native.so"

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..parallel.mesh import axis_size as _axis_size
+from ..parallel.mesh import mark_varying
 
 
 def _block_attn(q, k, v, *, scale, mask=None):
@@ -60,14 +60,11 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     (ops/flash_attention.py) — O(S) memory VMEM-tiled online softmax,
     differentiable (custom_vjp backward kernels); arbitrary sequence
     lengths (uneven lengths are padded to the kernel tile sizes and
-    masked). flash_interpret: None picks interpreter mode when the
-    process default backend is not TPU; pass an explicit bool when
-    executing somewhere other than the default backend (e.g. CPU-pinned
-    under a TPU-default process)."""
+    masked). flash_interpret: None = the interpreter on the cpu
+    platform, Mosaic on any other (ops/pallas_call.py); a bool pins
+    it."""
     if use_flash:
         from .flash_attention import flash_attention
-        if flash_interpret is None:
-            flash_interpret = jax.default_backend() != "tpu"
         return flash_attention(q, k, v, causal=causal,
                                interpret=flash_interpret)
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -93,7 +90,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     valid_len: global key positions >= valid_len are padding (the top-level
     wrapper pads uneven sequence lengths up to a multiple of the ring
     size); they are masked out of every block."""
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     scale = 1.0 / math.sqrt(q.shape[-1])
     block_len = q.shape[1]
@@ -130,8 +127,6 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     # mark the softmax stats as varying over the ring axis so the scan carry
     # types line up under shard_map's per-device type tracking
-    from ..parallel.mesh import mark_varying
-
     m0 = mark_varying(jnp.full((b, h, s), -jnp.inf, q.dtype), like=q)
     l0 = mark_varying(jnp.zeros((b, h, s), q.dtype), like=q)
     (out, m, l, _, _), _ = lax.scan(step, (out0, m0, l0, k, v),
@@ -173,7 +168,7 @@ def _merge_blocks(O, LSE, out_b, lse_b):
 
 
 def _ring_rotate(axis_name, *arrays):
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(j, (j - 1) % n) for j in range(n)]
     return tuple(lax.ppermute(a, axis_name, perm) for a in arrays)
 
@@ -197,9 +192,8 @@ def _block_pred(i, causal, my, src, s_loc, valid_len):
 
 def _ring_flash_loop(q2, k2, v2, axis_name, causal, valid_len, interpret):
     from .flash_attention import flash_block
-    from ..parallel.mesh import mark_varying
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     bh, s, d = q2.shape
     O = mark_varying(jnp.zeros((bh, s, d), jnp.float32), like=q2)
@@ -254,14 +248,13 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, valid_len, interpret):
 
 def _ring_flash_bwd(axis_name, causal, valid_len, interpret, res, dout):
     from .flash_attention import _delta, flash_block_bwd
-    from ..parallel.mesh import mark_varying
 
     q, k, v, out, LSE = res
     b, s, h, d = q.shape
     q2, k2, v2 = _to_heads2(q), _to_heads2(k), _to_heads2(v)
     out2, do2 = _to_heads2(out), _to_heads2(dout)
     delta = _delta(do2, out2)   # global rowsum(dO*O), shared by blocks
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
 
     dq = mark_varying(jnp.zeros(q2.shape, jnp.float32), like=q2)
@@ -307,7 +300,7 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 def ring_flash_attention(q, k, v, *, axis_name: str, causal: bool = False,
                          valid_len: int | None = None,
-                         interpret: bool = False) -> jnp.ndarray:
+                         interpret: bool | None = None) -> jnp.ndarray:
     """ring_attention with flash-kernel blocks: call inside shard_map with
     the LOCAL (B, S/n, H, D) shards. Differentiable (ring-level
     custom_vjp). The local shard length must satisfy the flash tiling
@@ -337,7 +330,6 @@ def sequence_parallel_attention(q, k, v, mesh, *, seq_axis: str = "model",
     per-device memory stays O(S/n) with no (S/n)^2 score materialization.
     Padding then rounds the LOCAL shard up to the flash tile rule."""
     from jax.sharding import PartitionSpec as P
-    from ..parallel.mesh import shard_map  # jax-version shim
 
     n = mesh.shape[seq_axis]
     s = q.shape[1]
@@ -355,20 +347,18 @@ def sequence_parallel_attention(q, k, v, mesh, *, seq_axis: str = "model",
 
     spec = P(batch_axis, seq_axis, None, None)
     if use_flash:
-        if flash_interpret is None:
-            flash_interpret = jax.default_backend() != "tpu"
         inner = functools.partial(ring_flash_attention, axis_name=seq_axis,
                                   causal=causal, valid_len=valid_len,
                                   interpret=flash_interpret)
         # check_vma=False: pallas_call's internal slicing mixes varying
         # and unvarying operands in ways the vma checker rejects (the
         # jnp ring path below keeps full checking)
-        fn = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=False)
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
     else:
         inner = functools.partial(ring_attention, axis_name=seq_axis,
                                   causal=causal, valid_len=valid_len)
-        fn = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec)
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec)
     out = fn(q, k, v)
     return out[:, :s] if pad else out

@@ -19,8 +19,16 @@ where D = rowsum(dO * O). Differentiation is wired through jax.custom_vjp,
 so `jax.grad` through `attention(use_flash=True)` hits these kernels.
 
 Used by ops.attention.attention when `use_flash=True`; the jnp
-implementation remains the numerical reference and the CPU fallback
-(interpret=True runs these same kernels in interpreter mode for tests).
+implementation remains the numerical reference. `interpret=None`
+(every entry's default) means the interpreter on the cpu platform and
+Mosaic on any other — ops/pallas_call.py owns that choice.
+
+VMEM: the forward and dQ kernels keep one head's whole K and V sequence
+resident (the dK/dV kernel: Q and dO), double-buffered by the pipeline.
+`_vmem_params` raises the scoped-VMEM limit to the computed need when it
+passes Mosaic's 16 MiB default, and refuses with FlashVmemError past
+what a v5e core holds — tiling K/V through the grid instead is ROADMAP
+Reach 5.
 """
 
 from __future__ import annotations
@@ -31,9 +39,44 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_call import pallas_call
 
 BQ = 128  # query tile (MXU-aligned)
 BK = 128  # key tile
+
+# Mosaic's default scoped-VMEM limit, and the most this module will ask
+# for: a v5e TensorCore has 128 MiB of VMEM, and the compiler needs room
+# beyond the blocks this module can count
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_MAX = 100 * 2**20
+
+
+class FlashVmemError(ValueError):
+    """The whole-sequence-resident blocks of a flash kernel do not fit
+    the VMEM this module is willing to request."""
+
+
+def _vmem_params(what, seq, d, dtype):
+    """CompilerParams for a kernel holding two (seq, d) blocks of
+    `dtype` resident per grid step — K and V, or Q and dO — each
+    double-buffered by the pipeline; None when the default limit is
+    enough."""
+    resident = 2 * 2 * seq * d * jnp.dtype(dtype).itemsize
+    # tile-sized operands, f32 in-kernel temporaries, compiler scratch
+    need = resident + 8 * 2**20
+    if need <= _VMEM_DEFAULT:
+        return None
+    if need > _VMEM_MAX:
+        raise FlashVmemError(
+            f"flash attention {what}: two resident blocks of "
+            f"({seq}, {d}) {jnp.dtype(dtype).name} need "
+            f"{need / 2**20:.0f} MiB of VMEM, over the "
+            f"{_VMEM_MAX / 2**20:.0f} MiB this kernel may request; shard "
+            f"the sequence (attention_param sequence_parallel) or use "
+            f"bf16")
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid):
@@ -55,6 +98,16 @@ def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid):
         ok = cols < sk_valid
         mask = ok if mask is None else mask & ok
     return mask
+
+
+def _row_slice(i, tile, total):
+    """Slice `tile` entries at tile index `i` along the LANE (last) axis
+    of a stats/bias row. Mosaic must prove a dynamic lane offset is a
+    multiple of 128; it can for `i * 128` but not for `i * 64`. A tile
+    narrower than 128 only occurs when it is the whole row
+    (_check_tiles: tile = min(128, total)), where the offset is the
+    constant 0."""
+    return pl.dslice(0 if tile == total else i * tile, tile)
 
 
 def _n_k_tiles(sk, bk, sk_valid):
@@ -82,7 +135,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
-            s = s + bias_ref[0, pl.dslice(j * bk, bk)].astype(
+            s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
         mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid)
         if mask is not None:
@@ -140,7 +193,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
-            s = s + bias_ref[0, pl.dslice(j * bk, bk)].astype(
+            s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
         p = jnp.exp(s - lse[:, None])          # normalized probabilities
         # the same mask as the forward (see _tile_mask: padded-column p
@@ -177,8 +230,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk, dv = carry
         q = q_ref[0, pl.dslice(i * bq, bq), :].astype(jnp.float32)
         do = do_ref[0, pl.dslice(i * bq, bq), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(i * bq, bq)].astype(jnp.float32)
-        delta = delta_ref[0, 0, pl.dslice(i * bq, bq)].astype(jnp.float32)
+        lse = lse_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
+        delta = delta_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
@@ -233,8 +286,7 @@ def _sds(shape, dtype, like):
     axis set of `like` — under shard_map (ring attention) outputs must
     declare how they vary over mesh axes; outside it the vma set is
     empty/absent and a plain struct is produced."""
-    from ..parallel.mesh import vma as _vma  # jax-version typeof shim
-    axes = _vma(like)
+    axes = jax.typeof(like).vma
     if axes:
         return jax.ShapeDtypeStruct(shape, dtype, vma=axes)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -261,7 +313,7 @@ def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None):
     if has_bias:
         in_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
         args.append(k_bias)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(bh, sq // bq),
         in_specs=in_specs,
@@ -273,6 +325,7 @@ def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None):
             _sds((bh, sq, d), q.dtype, q),
             _sds((bh, 1, sq), jnp.float32, q),
         ],
+        compiler_params=_vmem_params("forward", sk, d, k.dtype),
         interpret=interpret,
     )(*args)
 
@@ -309,7 +362,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
     if has_bias:
         dq_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
         dq_args.append(k_bias)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           sk=sk, bq=bq, bk=bk,
                           sk_valid=sk if sk_valid is None else sk_valid,
@@ -318,6 +371,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), q.dtype, q),
+        compiler_params=_vmem_params("dQ", sk, d, k.dtype),
         interpret=interpret,
     )(*dq_args)
     dkv_specs = [
@@ -332,7 +386,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
     if has_bias:
         dkv_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
         dkv_args.append(k_bias)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           sq=sq, bq=bq, bk=bk, has_bias=has_bias),
         grid=(bh, sk // bk),
@@ -345,6 +399,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
             _sds((bh, sk, d), k.dtype, k),
             _sds((bh, sk, d), v.dtype, v),
         ],
+        compiler_params=_vmem_params("dK/dV", sq, d, q.dtype),
         interpret=interpret,
     )(*dkv_args)
     return dq, dk, dv
@@ -358,14 +413,14 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
 # global probabilities restricted to that block.
 # ---------------------------------------------------------------------------
 
-def flash_block(q, k, v, *, causal=False, k_bias=None, interpret=False):
+def flash_block(q, k, v, *, causal=False, k_bias=None, interpret=None):
     """(B*H, Sq, D) x (B*H, Sk, D) -> (normalized out, lse). k_bias:
     (1, Sk) f32, 0 for live keys / -inf for masked (padded) ones."""
     return _fwd_impl(q, k, v, causal, interpret, k_bias=k_bias)
 
 
 def flash_block_bwd(q, k, v, out, lse, do, *, causal=False, k_bias=None,
-                    interpret=False, delta=None):
+                    interpret=None, delta=None):
     """Per-block backward against the GLOBAL (out, lse): returns
     (dq_partial, dk_block, dv_block). Summing dq_partial over blocks and
     routing each dk/dv block to its owner reconstructs the exact global
@@ -399,7 +454,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = False, interpret: bool = False
+                    causal: bool = False, interpret: bool | None = None
                     ) -> jnp.ndarray:
     """q,k,v: (B, S, H, D) -> (B, S, H, D). Differentiable: jax.grad hits
     the Pallas backward kernels via custom_vjp.

@@ -27,9 +27,10 @@ backward kernel.
 
 Math is f32 in-kernel regardless of the I/O dtype (bf16 under
 `precision: bf16`); outputs cast back at the tile edge. The jnp path in
-vision.py remains the numerical reference, the f32 default, and the CPU
-fallback (interpret=True runs these same kernels in interpreter mode
-for tests — the flash-attention recipe)."""
+vision.py remains the numerical reference and the f32 default. On the
+`cpu` platform the same kernels run in Pallas interpreter mode (the
+CPU test suite); every other platform compiles them through Mosaic or
+fails — ops/pallas_call.py owns that choice."""
 
 from __future__ import annotations
 
@@ -38,6 +39,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .pallas_call import pallas_call
 
 LANE = 128  # spatial tile width (VPU lane count)
 
@@ -93,7 +96,7 @@ def _run(kernel, args, *, size, alpha, beta, k, interpret):
     n, c, sp = args[0].shape
     sp_pad, t = _tile(sp)
     spec = pl.BlockSpec((1, c, t), lambda i, j: (i, 0, j))
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(kernel, size=size, alpha=alpha, beta=beta, k=k),
         grid=(n, sp_pad // t),
         in_specs=[spec] * len(args),
@@ -119,14 +122,6 @@ def _prep(x):
 def _restore(y3, shape_info):
     n, c, h, w, sp = shape_info
     return y3[:, :, :sp].reshape(n, c, h, w)
-
-
-def _auto_interpret(interpret):
-    if interpret is None:
-        # same rule as ops/attention.py: interpreter mode everywhere but
-        # real TPU, so CPU tests execute the identical kernel logic
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
@@ -161,12 +156,13 @@ def lrn_across_channels(x: jnp.ndarray, size: int, alpha: float,
                         interpret: bool | None = None) -> jnp.ndarray:
     """Across-channels LRN over a (N, C, H, W) blob — the AlexNet /
     CaffeNet norm_region=ACROSS_CHANNELS case. Differentiable
-    (custom_vjp -> the Pallas backward kernel). `interpret=None` picks
-    interpreter mode off-TPU."""
+    (custom_vjp -> the Pallas backward kernel). `interpret=None` =
+    interpreter on the cpu platform, Mosaic on any other
+    (ops/pallas_call.py)."""
     if x.ndim != 4:
         raise ValueError(f"lrn_across_channels expects NCHW, got "
                          f"shape {x.shape}")
     if size % 2 != 1:
         raise ValueError("LRN local_size must be odd")
     return _lrn(x, int(size), float(alpha), float(beta), float(k),
-                _auto_interpret(interpret))
+                interpret)

@@ -39,83 +39,21 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 log = logging.getLogger("caffe_mpi_tpu.parallel")
 
 
-def typeof(x):
-    """`jax.typeof` appeared after 0.4.x (this environment pins jax
-    0.4.37); fall back to the abstract value, which carries the same
-    shape/dtype surface and — matching the pre-vma world — no `.vma`.
-    The single version shim every vma-aware call site routes through."""
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    from jax.core import get_aval
-    return get_aval(x)
-
-
-def vma(x) -> frozenset:
-    """The varying-manual-axes set of `x` under shard_map; empty on jax
-    versions without vma tracking (0.4.x), where replication checking
-    is the coarser whole-value `check_rep`."""
-    return frozenset(getattr(typeof(x), "vma", None) or ())
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version shim over the moving shard_map surface: jax 0.4.x ships
-    it as `jax.experimental.shard_map.shard_map(check_rep=...)`, newer
-    jax as top-level `jax.shard_map(check_vma=...)`. Callers use the
-    modern spelling; the shim maps the replication-check kwarg to
-    whatever the installed jax accepts."""
-    import inspect
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    params = inspect.signature(_sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in params:
-        kw["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside shard_map.
-    `lax.axis_size` postdates jax 0.4.x, where `core.axis_frame(name)`
-    returns the size directly (an int)."""
-    from jax import lax
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    from jax.core import axis_frame
-    fr = axis_frame(axis_name)
-    return fr if isinstance(fr, int) else fr.size
-
-
 def mark_varying(x, axis_name: str | None = None, *, like=None):
-    """Mark a value as varying over mesh axes (shard_map per-device type
-    tracking). Shim over the in-flux pcast/pvary jax API — the single
-    definition used by ring attention and the pipeline schedule.
-    Idempotent: axes x already varies over are skipped. On jax versions
-    without vma tracking (0.4.x: no pcast/pvary, avals carry no .vma)
-    this is a no-op — there is no per-axis type to adjust.
+    """Mark a value as varying over mesh axes (shard_map's per-device
+    type tracking) — the single definition used by ring attention and
+    the pipeline schedule. Idempotent: axes x already varies over are
+    skipped.
 
     like: instead of naming an axis, copy the varying-axis set of another
     value — scan carries built from jnp.zeros/full must match the vma of
     the sharded inputs they merge with, whatever axes the enclosing
     shard_map spans (e.g. 'data' x 'model' in a DPxSP step)."""
     from jax import lax
-    if like is not None:
-        axes = tuple(vma(like))
-    else:
-        axes = (axis_name,)
-    missing = tuple(a for a in axes if a and a not in vma(x))
-    if not missing:
-        return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, missing, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, missing)
-    return x  # pre-vma jax: nothing to mark
+    axes = tuple(jax.typeof(like).vma) if like is not None else (axis_name,)
+    have = jax.typeof(x).vma
+    missing = tuple(a for a in axes if a and a not in have)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def resolve_cluster(sp=None, host_id: int | None = None):
@@ -174,7 +112,6 @@ def init_distributed(coordinator: str | None = None,
     suite."""
     if num_processes is None or num_processes <= 1:
         return
-    import inspect
     import os
     import time
 
@@ -183,10 +120,6 @@ def init_distributed(coordinator: str | None = None,
     if timeout_s is None:
         timeout_s = float(os.environ.get("CAFFE_TPU_INIT_TIMEOUT", "60")
                           or 60)
-    kw = {}
-    if "initialization_timeout" in inspect.signature(
-            jax.distributed.initialize).parameters:
-        kw["initialization_timeout"] = int(max(timeout_s, 1))
     delay = base_delay
     last: Exception | None = None
     for attempt in range(max(attempts, 1)):
@@ -194,9 +127,10 @@ def init_distributed(coordinator: str | None = None,
             FAULTS.maybe_raise(
                 "coordinator_down", RuntimeError,
                 f"injected coordinator outage (attempt {attempt + 1})")
-            jax.distributed.initialize(coordinator_address=coordinator,
-                                       num_processes=num_processes,
-                                       process_id=process_id, **kw)
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes, process_id=process_id,
+                initialization_timeout=int(max(timeout_s, 1)))
             log.info("jax.distributed initialized: process %d/%d "
                      "(coordinator %s, attempt %d)", jax.process_index(),
                      jax.process_count(), coordinator, attempt + 1)
@@ -237,15 +171,11 @@ def shutdown_distributed() -> None:
 
 def _cluster_client():
     """The live coordination-service client, or None outside a
-    jax.distributed run. jax 0.4.x exposes it only via the private
-    global_state (the public accessor postdates this pin)."""
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client
-    # lint: ok(typed-failure) — None IS the typed answer here: no
-    # distributed runtime; every caller handles the None branch
-    except Exception:  # noqa: BLE001 — no distributed runtime
-        return None
+    jax.distributed run. jax exposes it only through the private
+    global_state (jax.distributed has initialize/is_initialized/
+    shutdown and no client accessor)."""
+    from jax._src import distributed
+    return distributed.global_state.client
 
 
 def cluster_barrier(name: str, timeout_s: float = 600.0) -> bool:
@@ -526,6 +456,24 @@ class MeshPlan:
                for x in jax.tree.leaves(feeds)):
             return self.shard_feeds(feeds, batch_axis=batch_axis), True
         return self.replicate(feeds), False
+
+    def per_batch_shard(self, fn, *arrays):
+        """Run `fn` on each device's batch shard of `arrays` under
+        shard_map. This is how a Pallas kernel sits inside the
+        GSPMD-partitioned train/eval step: the partitioner refuses Mosaic
+        custom calls ("Mosaic kernels cannot be automatically
+        partitioned"), so per-sample kernels (LRN, single-device flash
+        attention) take the batch split explicitly — exact, since no
+        sample reads another. Operands are replicated over 'model'. A
+        batch the 'data' axis does not divide (an eval batch placed by
+        shard_feeds_or_replicate) runs replicated instead."""
+        divisible = all(a.shape[0] % self.n_data == 0 for a in arrays)
+        spec = P("data") if divisible else P()
+        # check_vma=False: pallas_call's internal slicing mixes varying
+        # and unvarying operands in ways the vma checker rejects
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=(spec,) * len(arrays),
+                             out_specs=spec, check_vma=False)(*arrays)
 
     # -- ZeRO-1 optimizer-state sharding (beyond the reference) ---------
     def zero_slot_sharding(self, shape) -> NamedSharding | None:

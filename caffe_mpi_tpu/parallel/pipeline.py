@@ -187,8 +187,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh, *,
             jnp.arange(n_ticks))
         return out_local
 
-    from .mesh import shard_map  # jax-version shim
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(param_specs, io_spec),  # microbatch I/O sharded over stage
         out_specs=io_spec,

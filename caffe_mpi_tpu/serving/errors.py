@@ -35,7 +35,7 @@ class ShedError(ServingError):
 
 class EngineUnhealthyError(ShedError):
     """The dispatch stall breaker is open (a device call blew past
-    `serve_stall_s`, e.g. a dead tunnel): requests shed immediately
+    `serve_stall_s`): requests shed immediately
     instead of queueing behind a hung dispatch. A recovery probe
     closing the breaker clears this."""
 

@@ -157,7 +157,7 @@ class RequestIngest:
             })
         # process-wide decode-plane counters (shared with the training
         # feeder): which decoder actually ran — the engagement telemetry
-        # `caffe serve -smoke` and tpu_validation's serve stage read
+        # `caffe serve -smoke` and chip_smoke.py's serve leg read
         out["decode_plane"] = decode_mod.STATS.snapshot()
         return out
 

@@ -38,7 +38,7 @@ from __future__ import annotations
 LOCK_ORDER: tuple[tuple[str, str], ...] = (
     # swap_weights commits under the engine lock while holding the
     # model's upload lock (PR 12): a concurrent ensure_resident holding
-    # _upload_lock for a tunnel-length upload only delays the commit,
+    # _upload_lock for a seconds-long upload only delays the commit,
     # never the engine lock. The REVERSE (engine._lock held while
     # waiting on an upload lock) is the PR 11 deadlock shape and is
     # deliberately not declared.

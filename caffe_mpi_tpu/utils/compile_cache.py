@@ -1,19 +1,31 @@
-# lint: ok(reference-citation) — TPU-native: the reference compiles AOT
-# with nvcc and has no JIT compilation step to cache
-"""Persistent XLA compilation cache setup (shared by the CLI and bench).
+"""Persistent XLA compilation cache: one rule for every entry point.
 
-The AlexNet-class training step costs ~20-40s to compile on TPU; a warm
-disk cache turns repeat invocations (and the bench's fresh-process retry)
-into a cache hit. JAX_COMPILATION_CACHE_DIR overrides the default dir;
-setting it to the empty string disables the cache entirely.
+The AlexNet-class training step costs tens of seconds to compile on
+TPU; a warm disk cache turns a repeat invocation into a cache hit. The
+directory is part of the cache key, so it must never move between runs:
+
+- `JAX_COMPILATION_CACHE_DIR` set (to anything, the empty string
+  included): jax reads the variable itself and this program sets no
+  cache directory in code — whoever launched the process owns the
+  placement.
+- unset: `<checkout>/.jax_cache`, computed from where this package
+  lives (gitignored) — never from `~`, a temp name, a pid or the time.
+
+`caffe` (cli.py), the serving engine, bench.py and the tools all call
+`enable_compile_cache()` with no argument, so a bench child and
+`caffe train` share compiles.
+
+The reference has no analogue: it compiles ahead of time with nvcc and
+has no JIT compilation step to cache.
 """
 
 from __future__ import annotations
 
 import os
 
-DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                           "caffe_mpi_tpu_xla")
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def runtime_tag() -> str:
@@ -33,15 +45,11 @@ def runtime_tag() -> str:
             f"/{dev.platform}/{dev.device_kind}")
 
 
-def enable_compile_cache(default_dir: str = DEFAULT_DIR) -> str | None:
-    """Returns the cache dir in use, or None when disabled/unsupported."""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", default_dir)
-    if not cache_dir:
-        return None
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        return None  # older jax: cache flags absent
-    return cache_dir
+def enable_compile_cache() -> str:
+    """Returns the cache dir in use ('' = the launcher disabled it)."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed is not None:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

@@ -58,13 +58,19 @@ PEAK_FLOPS_BY_KIND = {
 
 
 def peak_flops(device) -> float | None:
-    """Peak FLOP/s for a jax device, or None when the kind is unknown."""
-    kind = getattr(device, "device_kind", "")
-    if kind in PEAK_FLOPS_BY_KIND:
-        return PEAK_FLOPS_BY_KIND[kind]
+    """Peak FLOP/s for a jax device. The host CPU has no entry and no
+    peak: None, and callers print no MFU. Any other device missing from
+    the table is an error, not an MFU of None — a utilization against an
+    unknown peak is not a number."""
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind
     # longest prefix wins: 'TPU v5 lite pod' must match 'TPU v5 lite',
     # not 'TPU v5'
     for k in sorted(PEAK_FLOPS_BY_KIND, key=len, reverse=True):
         if kind.startswith(k):
             return PEAK_FLOPS_BY_KIND[k]
-    return None
+    raise ValueError(
+        f"no peak FLOP/s on record for device kind {kind!r} (platform "
+        f"{device.platform!r}); add it to PEAK_FLOPS_BY_KIND with its "
+        f"source")

@@ -283,13 +283,18 @@ class TestEquivalence:
 # ---------------------------------------------------------------------------
 
 class TestMeasurement:
-    def test_bucketed_step_emits_at_least_bucket_count_collectives(
+    def test_bucketed_step_reduces_at_least_bucket_count_buffers(
             self, rng):
+        # jax 0.9.0's CPU pipeline runs XLA's all-reduce combiner: the
+        # three bucket psums (and the loss psum) come out as ONE
+        # tuple-typed all-reduce with one operand per bucket, so the
+        # bucket count is held on operands, not on ops
         data = mlp_data(rng, 1)
         b = make_solver("reduce_overlap: true reduce_buckets: 3",
                         mesh=MeshPlan.data_parallel())
         stats = reduction.collective_stats(b.step_hlo_text(data[0]))
-        assert stats["all_reduces"] >= 3, stats
+        assert stats["all_reduces"] >= 1, stats
+        assert stats["reduced_buffers"] >= 3, stats
 
     def test_collective_stats_counts_hlo_text(self):
         text = "\n".join([
@@ -297,9 +302,12 @@ class TestMeasurement:
             "%ar = f32[8]{0} all-reduce(%x), replica_groups={}",
             "%y = f32[8]{0} add(%ar, %ar)",
             "%ar2 = f32[8]{0} all-reduce-start(%y)",
+            "%g = f32[8]{0} get-tuple-element(%all-reduce), index=0",
+            "%ar3 = (f32[8]{0}, f32[]) all-reduce(%y, %z), channel_id=1",
         ])
         stats = reduction.collective_stats(text)
-        assert stats["all_reduces"] == 2
+        assert stats["all_reduces"] == 3
+        assert stats["reduced_buffers"] == 4
         assert stats["overlap_span"] > 0
 
     def test_reduction_stats_shapes(self, rng):

@@ -701,7 +701,7 @@ class TestFailurePathLiveness:
 
     def test_wait_snapshots_join_is_bounded(self):
         """deadline-discipline fix: a wedged async snapshot writer
-        (dead-tunnel device fetch) must fail wait_snapshots loudly
+        (a device fetch that never returns) must fail wait_snapshots loudly
         within the timeout, not hang the exit path forever."""
         from caffe_mpi_tpu.solver.solver import Solver
 

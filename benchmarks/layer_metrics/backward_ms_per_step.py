@@ -1,0 +1,12 @@
+"""Device time per training iteration in operations under a prototxt
+layer's scope in the backward pass (`transpose(` in the `op_name`: reverse
+mode, rematerialised forward included), averaged over the chips used
+(span_reduce.py). None for a program that writes no layer scopes. Layer:
+Net_layers. Moves train_samples_per_s in every cell."""
+
+import span_reduce
+
+
+def compute(run: dict, trace: dict | None):
+    return span_reduce.layer_ms_per_step(
+        run, trace, lambda row: row["phase"] == "backward")

@@ -48,6 +48,7 @@ from jax import lax
 log = logging.getLogger(__name__)
 
 from ..core.fillers import fill
+from ..utils.spans import layer_scope
 from .base import Layer, ParamDecl, Shape, create_layer, register
 
 
@@ -161,7 +162,9 @@ class PipelineLayer(Layer):
             for il in self.block:
                 lparams = {pn: p_stage[f"{il.name}.{pn}"] for pn in il.params}
                 bottoms = [env[b] for b in il.lp.bottom]
-                tops, _ = il.apply(lparams, {}, bottoms, train=train, rng=None)
+                with layer_scope(il):
+                    tops, _ = il.apply(lparams, {}, bottoms, train=train,
+                                       rng=None)
                 for t, v in zip(il.lp.top, tops):
                     env[t] = v
             return env[self.block_output]

@@ -34,6 +34,7 @@ from .layers.base import Layer, create_layer
 from .layers.data_layers import InputLayerBase
 from .proto.config import NetParameter, NetState
 from .proto.upgrade import filter_net, normalize_net
+from .utils.spans import layer_scope
 
 log = logging.getLogger(__name__)
 
@@ -381,16 +382,18 @@ class Net:
                         for i, b in enumerate(bottoms)
                     ]
             apply_fn = layer.apply
-            if layer.lp.remat and train:
-                # recompute this layer's forward during backward instead of
-                # keeping its activations in HBM (layer-level remat)
-                apply_fn = jax.checkpoint(
-                    lambda p, s, b, layer=layer, lrng=lrng: layer.apply(
-                        p, s, b, train=True, rng=lrng))
-                tops, lstate_new = apply_fn(lparams, lstate, bottoms)
-            else:
-                tops, lstate_new = apply_fn(lparams, lstate, bottoms,
-                                            train=train, rng=lrng)
+            with layer_scope(layer):
+                if layer.lp.remat and train:
+                    # recompute this layer's forward during backward
+                    # instead of keeping its activations in HBM
+                    # (layer-level remat)
+                    apply_fn = jax.checkpoint(
+                        lambda p, s, b, layer=layer, lrng=lrng: layer.apply(
+                            p, s, b, train=True, rng=lrng))
+                    tops, lstate_new = apply_fn(lparams, lstate, bottoms)
+                else:
+                    tops, lstate_new = apply_fn(lparams, lstate, bottoms,
+                                                train=train, rng=lrng)
             if lstate_new is not lstate and lstate_new:
                 new_state[layer.name] = lstate_new
             for t, v in zip(layer.lp.top, tops):

@@ -90,9 +90,10 @@ def _tile(sp: int) -> tuple[int, int]:
     return -(-sp // LANE) * LANE, LANE
 
 
-def _run(kernel, args, *, size, alpha, beta, k, interpret):
+def _run(kernel, name, args, *, size, alpha, beta, k, interpret):
     """Common pallas_call driver: args are (N, C, SP) arrays (already
-    lane-padded), output mirrors args[0]."""
+    lane-padded), output mirrors args[0]. `name` is the kernel's name in
+    the HLO and in a profiler trace (`lrn_fwd.N`, not `branch_0_fun.N`)."""
     n, c, sp = args[0].shape
     sp_pad, t = _tile(sp)
     spec = pl.BlockSpec((1, c, t), lambda i, j: (i, 0, j))
@@ -103,6 +104,7 @@ def _run(kernel, args, *, size, alpha, beta, k, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(args[0].shape, args[0].dtype),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -127,7 +129,7 @@ def _restore(y3, shape_info):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
 def _lrn(x, size, alpha, beta, k, interpret):
     x3, info = _prep(x)
-    y3 = _run(_fwd_kernel, (x3,), size=size, alpha=alpha, beta=beta,
+    y3 = _run(_fwd_kernel, "lrn_fwd", (x3,), size=size, alpha=alpha, beta=beta,
               k=k, interpret=interpret)
     return _restore(y3, info)
 
@@ -143,7 +145,7 @@ def _lrn_bwd(size, alpha, beta, k, interpret, x, dy):
     # so recompute wins)
     x3, info = _prep(x)
     dy3, _ = _prep(dy)
-    dx3 = _run(_bwd_kernel, (x3, dy3), size=size, alpha=alpha,
+    dx3 = _run(_bwd_kernel, "lrn_bwd", (x3, dy3), size=size, alpha=alpha,
                beta=beta, k=k, interpret=interpret)
     return (_restore(dx3, info),)
 

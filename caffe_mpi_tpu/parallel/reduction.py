@@ -388,6 +388,8 @@ def bucketed_value_and_grad(loss_fn, mesh_plan, plan: ReductionPlan):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
+    from ..utils import spans
+
     n = plan.n_data
     axis = plan.axis
 
@@ -398,10 +400,11 @@ def bucketed_value_and_grad(loss_fn, mesh_plan, plan: ReductionPlan):
             loss_fn, has_aux=True)(params, net_state, feeds, rng)
         # idx >= 0 is traced-but-always-true: it gates the bitwise
         # unpack isolation (see psum_buckets), never the values
-        grads = plan.psum_buckets(grads, pred=idx >= 0)
-        if n > 1:
-            scaled = lax.psum(scaled, axis) / n
-            loss = lax.psum(loss, axis) / n
+        with jax.named_scope(spans.REDUCE):
+            grads = plan.psum_buckets(grads, pred=idx >= 0)
+            if n > 1:
+                scaled = lax.psum(scaled, axis) / n
+                loss = lax.psum(loss, axis) / n
         return (scaled, (new_state, loss)), grads
 
     def vg(params, net_state, feeds, rng):

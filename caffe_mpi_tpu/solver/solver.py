@@ -18,6 +18,7 @@ averaging (solver.cpp:439-540), snapshot/restore of weights + solver state
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -35,7 +36,7 @@ from ..net import Net
 from ..parallel.mesh import needs_collective_gather
 from ..proto.config import NetParameter, NetState, SolverParameter, solver_type
 from ..proto.text_format import parse_file
-from ..utils import resilience
+from ..utils import resilience, spans
 from ..utils.resilience import FAULTS
 from . import lr_policy
 from .updates import UPDATE_FNS, Hyper, n_slots
@@ -76,6 +77,12 @@ def _load_net_param(sp: SolverParameter, phase: str, model_dir: str = "",
     if sp.net:
         return NetParameter.from_file(os.path.join(model_dir, sp.net))
     raise ValueError("solver specifies no net")
+
+
+@contextlib.contextmanager
+def _nested(outer, inner):
+    with outer, inner:
+        yield
 
 
 class Solver:
@@ -728,64 +735,67 @@ class Solver:
                 ((grads, total_loss), net_state), _ = jax.lax.scan(
                     micro, ((zero_g, jnp.float32(0.0)), net_state),
                     (feeds_stack, rngs))
-            # normalize: 1/(iter_size * loss scale) (SGDSolver::Normalize
-            # + net.cpp:815-818 loss-scale unwind) — the unwind happens
-            # AFTER the cast to f32, so a dynamically-scaled bf16
-            # gradient re-enters master range without double rounding
-            denom = iter_size * eff_scale
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) / denom, grads)
-            loss_out = total_loss / iter_size
+            with jax.named_scope(spans.UPDATE):
+                # normalize: 1/(iter_size * loss scale) (SGDSolver::Normalize
+                # + net.cpp:815-818 loss-scale unwind) — the unwind happens
+                # AFTER the cast to f32, so a dynamically-scaled bf16
+                # gradient re-enters master range without double rounding
+                denom = iter_size * eff_scale
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / denom, grads)
+                loss_out = total_loss / iter_size
 
-            if grad_transform is not None:
-                grads = grad_transform(grads)
+                if grad_transform is not None:
+                    grads = grad_transform(grads)
 
-            # gradient clipping by global L2 norm (sgd_solver.cpp:110-128)
-            if sp.clip_gradients > 0:
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads)))
-                scale = jnp.where(gnorm > sp.clip_gradients,
-                                  sp.clip_gradients / gnorm, 1.0)
-                grads = jax.tree.map(lambda g: g * scale, grads)
+                # gradient clipping by global L2 norm (sgd_solver.cpp:110-128)
+                if sp.clip_gradients > 0:
+                    gnorm = jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g))
+                        for g in jax.tree.leaves(grads)))
+                    scale = jnp.where(gnorm > sp.clip_gradients,
+                                      sp.clip_gradients / gnorm, 1.0)
+                    grads = jax.tree.map(lambda g: g * scale, grads)
 
-            # iteration-dependent LR/momentum from the (possibly carried)
-            # iteration scalar — the whole schedule lives on device, so a
-            # K-step chunk can cross an lr_policy step boundary mid-scan
-            rate, mom = lr_policy.schedule(sp, it)
-            hyper = Hyper(rate=rate, momentum=mom, momentum2=sp.momentum2,
-                          delta=sp.delta, weight_decay=sp.weight_decay,
-                          reg_l1=(sp.regularization_type == "L1"),
-                          t=it + 1)
+                # iteration-dependent LR/momentum from the (possibly carried)
+                # iteration scalar — the whole schedule lives on device, so a
+                # K-step chunk can cross an lr_policy step boundary mid-scan
+                rate, mom = lr_policy.schedule(sp, it)
+                hyper = Hyper(rate=rate, momentum=mom, momentum2=sp.momentum2,
+                              delta=sp.delta, weight_decay=sp.weight_decay,
+                              reg_l1=(sp.regularization_type == "L1"),
+                              t=it + 1)
 
-            new_params = {}
-            new_opt = {}
-            zero_sh = self._zero_shardings
-            repl = self.mesh.replicated() if zero_sh else None
-            for lname, lparams in params.items():
-                new_params[lname] = {}
-                new_opt[lname] = {}
-                for pname, w in lparams.items():
-                    decl = self._decls[lname][pname]
-                    g = grads[lname][pname]
-                    slots = opt_state[lname][pname]
-                    if decl.lr_mult == 0.0:
-                        new_params[lname][pname] = w
-                        new_opt[lname][pname] = slots
-                        continue
-                    zsh = zero_sh.get((lname, pname))
-                    if zsh is not None:
-                        # ZeRO-1: pin the gradient to the slot partition
-                        # (GSPMD lowers the psum of the batch-sharded
-                        # backward into a reduce-scatter), update 1/N of
-                        # the param on each device, all-gather the result
-                        # back to the replicated param layout.
-                        g = jax.lax.with_sharding_constraint(g, zsh)
-                    w32 = w.astype(jnp.float32)
-                    w2, slots2 = update_fn(w32, g, slots, hyper,
-                                           decl.lr_mult, decl.decay_mult)
-                    if zsh is not None:
-                        w2 = jax.lax.with_sharding_constraint(w2, repl)
-                    new_params[lname][pname] = w2.astype(w.dtype)
-                    new_opt[lname][pname] = slots2
+                new_params = {}
+                new_opt = {}
+                zero_sh = self._zero_shardings
+                repl = self.mesh.replicated() if zero_sh else None
+                for lname, lparams in params.items():
+                    new_params[lname] = {}
+                    new_opt[lname] = {}
+                    for pname, w in lparams.items():
+                        decl = self._decls[lname][pname]
+                        g = grads[lname][pname]
+                        slots = opt_state[lname][pname]
+                        if decl.lr_mult == 0.0:
+                            new_params[lname][pname] = w
+                            new_opt[lname][pname] = slots
+                            continue
+                        zsh = zero_sh.get((lname, pname))
+                        if zsh is not None:
+                            # ZeRO-1: pin the gradient to the slot partition
+                            # (GSPMD lowers the psum of the batch-sharded
+                            # backward into a reduce-scatter), update 1/N of
+                            # the param on each device, all-gather the result
+                            # back to the replicated param layout.
+                            g = jax.lax.with_sharding_constraint(g, zsh)
+                        w32 = w.astype(jnp.float32)
+                        w2, slots2 = update_fn(w32, g, slots, hyper,
+                                               decl.lr_mult, decl.decay_mult)
+                        if zsh is not None:
+                            w2 = jax.lax.with_sharding_constraint(w2, repl)
+                        new_params[lname][pname] = w2.astype(w.dtype)
+                        new_opt[lname][pname] = slots2
             if not guard:
                 return new_params, net_state, new_opt, loss_out, rate
 
@@ -933,10 +943,11 @@ class Solver:
                         jnp.int32)
                 return (oldp, olds, oldo, out_gs)
 
-            new_params, net_state, new_opt, new_gstate = jax.lax.cond(
-                it >= 0, _apply_guard, _all_skip,
-                (loss_out, new_params, new_opt, net_state,
-                 params, opt_state, net_state0, gstate, it))
+            with jax.named_scope(spans.UPDATE):
+                new_params, net_state, new_opt, new_gstate = jax.lax.cond(
+                    it >= 0, _apply_guard, _all_skip,
+                    (loss_out, new_params, new_opt, net_state,
+                     params, opt_state, net_state0, gstate, it))
             return (new_params, net_state, new_opt, loss_out, rate,
                     new_gstate)
 
@@ -987,8 +998,8 @@ class Solver:
             # guard mode: the 5-scalar guard state rides in the scan
             # carry exactly like params — zero extra dispatches, and the
             # per-step skip decision never leaves HBM
-            def multi_g(params, net_state, opt_state, feeds_super, it0,
-                        base_rng, gstate):
+            def multi_step(params, net_state, opt_state, feeds_super, it0,
+                           base_rng, gstate):
                 def scan_body(carry, feeds_stack):
                     p, s, o, it, gs = carry
                     rng = jax.random.fold_in(base_rng, it + 1)
@@ -1002,10 +1013,11 @@ class Solver:
                     feeds_super)
                 return params, net_state, opt_state, losses, rates, gstate
 
-            return jax.jit(multi_g,
+            return jax.jit(multi_step,
                            donate_argnums=self._train_donate_argnums())
 
-        def multi(params, net_state, opt_state, feeds_super, it0, base_rng):
+        def multi_step(params, net_state, opt_state, feeds_super, it0,
+                       base_rng):
             def scan_body(carry, feeds_stack):
                 p, s, o, it = carry
                 rng = jax.random.fold_in(base_rng, it + 1)
@@ -1016,7 +1028,8 @@ class Solver:
                 scan_body, (params, net_state, opt_state, it0), feeds_super)
             return params, net_state, opt_state, losses, rates
 
-        return jax.jit(multi, donate_argnums=self._train_donate_argnums())
+        return jax.jit(multi_step,
+                       donate_argnums=self._train_donate_argnums())
 
     # ------------------------------------------------------------------
     def _chunk_at(self, it: int, n: int, testing: bool = True) -> int:
@@ -1086,8 +1099,8 @@ class Solver:
                 hint = (self.iter + c, c2)
         with self._guard("feed wait"):
             feeds_super = queue.get(self.iter, c, hint=hint)
-        it0 = jnp.int32(self.iter)
         with self._guard("train dispatch"):
+            it0 = jnp.int32(self.iter)
             FAULTS.maybe_stall("dispatch_stall")
             if self._guard_on:
                 (self.params, self.net_state, self.opt_state, losses,
@@ -1116,6 +1129,7 @@ class Solver:
             update_fn = partial(update_fn, rms_decay=sp.rms_decay)
         decls = self._decls
 
+        @jax.named_scope(spans.UPDATE)
         def upd(params_s, grads_s, opt_s, rate, mom, it, gscale):
             hyper = Hyper(rate=rate, momentum=mom, momentum2=sp.momentum2,
                           delta=sp.delta, weight_decay=sp.weight_decay,
@@ -1221,7 +1235,7 @@ class Solver:
     # resilience.EXIT_WATCHDOG so the supervisor (`cli train
     # --max-restarts`) can restart from the newest verified snapshot.
     # Off by default (sp.watchdog_deadline == 0): zero change for
-    # existing solvers, and _guard() is then a shared nullcontext.
+    # existing solvers, and _guard() then opens its profiler span alone.
 
     def _ensure_watchdog(self) -> None:
         if self._watchdog is not None:
@@ -1267,9 +1281,12 @@ class Solver:
                      resilience.EXIT_WATCHDOG)
 
     def _guard(self, label: str):
+        """The one boundary every blocking host section passes: the
+        profiler span `caffe/solver/<label>` (utils/spans.py) and, when
+        the watchdog is armed, its section of the same label."""
+        span = spans.span("solver/" + label)
         wd = self._watchdog
-        return wd.section(label) if wd is not None \
-            else resilience._NULL_SECTION
+        return span if wd is None else _nested(span, wd.section(label))
 
     def _watchdog_journal(self, label: str, elapsed: float) -> None:
         self._journal_run_state(
@@ -1492,158 +1509,168 @@ class Solver:
         imgs_per_iter = self._batch_images() * iter_size \
             * max(self._gpipe_micro, 1)
         while n > 0:
-            # test-only: simulates "the process died mid-run" for the
-            # supervised auto-resume suite (no cost when faults are off)
-            FAULTS.maybe_exit("train_abort", key=self.iter)
-            if (sp.test_interval and self.iter % sp.test_interval == 0
-                    and (self.iter > 0 or sp.test_initialization)
-                    and test_feed_fns):
-                # asynchronous evaluation: drain the previous pass (its
-                # scores are certainly computed by now — its programs
-                # preceded a full test_interval of train chunks), then
-                # dispatch this one and resume training immediately; the
-                # device runs the eval between train chunks
-                self._harvest_eval()
-                self._start_eval(test_feed_fns)
-            c = 1
-            if self.gpipe is not None:
-                with self._guard("train dispatch"):
-                    loss, rate = self._gpipe_iteration(feed_fn)
-                self.dispatch_count += 1
-            else:
-                testing = bool(test_feed_fns)
-                c = self._chunk_at(self.iter, n, testing)
-                if c > 1:
-                    # K-step fused path: one dispatch covers c iterations
-                    losses, rates = self._scan_chunk(feed_fn, c, n, testing)
-                    loss, rate = losses[-1], rates[-1]
-                else:
-                    # feed assembly + host->device transfer are watchdog
-                    # sections too: a hung runtime blocks inside the
-                    # jnp.asarray/shard_feeds C++ transfer exactly like a
-                    # dispatch (the fused path guards queue.get the same
-                    # way)
-                    with self._guard("feed wait"):
-                        micro_feeds = [feed_fn(self.iter * iter_size + k)
-                                       for k in range(iter_size)]
-                        if iter_size == 1:
-                            # view, not copy: the common path skips the
-                            # host-side stack
-                            feeds_stack = jax.tree.map(
-                                lambda x: jnp.asarray(x)[None],
-                                micro_feeds[0])
-                        else:
-                            feeds_stack = jax.tree.map(
-                                lambda *xs: jnp.stack(xs), *micro_feeds)
-                        if self.mesh is not None:
-                            # global batch sharded over the 'data' mesh
-                            # axis (divide_batch_size semantics,
-                            # parallel.cpp:295-348)
-                            feeds_stack = self.mesh.shard_feeds(
-                                feeds_stack, batch_axis=1)
-                    rng = jax.random.fold_in(self.base_rng, self.iter + 1)
-                    it = jnp.int32(self.iter)
+            with spans.iteration(self.iter):
+                # test-only: simulates "the process died mid-run" for the
+                # supervised auto-resume suite (no cost when faults are off)
+                FAULTS.maybe_exit("train_abort", key=self.iter)
+                if (sp.test_interval and self.iter % sp.test_interval == 0
+                        and (self.iter > 0 or sp.test_initialization)
+                        and test_feed_fns):
+                    # asynchronous evaluation: drain the previous pass (its
+                    # scores are certainly computed by now — its programs
+                    # preceded a full test_interval of train chunks), then
+                    # dispatch this one and resume training immediately; the
+                    # device runs the eval between train chunks
+                    self._harvest_eval()
+                    with spans.span("solver/eval dispatch"):
+                        self._start_eval(test_feed_fns)
+                c = 1
+                if self.gpipe is not None:
                     with self._guard("train dispatch"):
-                        FAULTS.maybe_stall("dispatch_stall")
-                        if self._guard_on:
-                            (self.params, self.net_state, self.opt_state,
-                             loss, rate, self._gstate) = self._step_jit(
-                                self.params, self.net_state, self.opt_state,
-                                feeds_stack, it, rng, self._gstate)
-                        else:
-                            (self.params, self.net_state, self.opt_state,
-                             loss, rate) = self._step_jit(
-                                self.params, self.net_state, self.opt_state,
-                                feeds_stack, it, rng)
+                        loss, rate = self._gpipe_iteration(feed_fn)
                     self.dispatch_count += 1
-            # feed any in-flight eval pass the chunks whose super-batches
-            # the worker finished while this train chunk dispatched —
-            # non-blocking, so eval assembly never stalls training
-            self._continue_eval()
-            if self._sync_steps:
-                with self._guard("step sync"):
-                    jax.block_until_ready(loss)
-            # keep the loss ON DEVICE: a float() here would force a host
-            # sync every iteration (the reference pays microseconds over
-            # PCIe; over a remote TPU link it would serialize the pipeline).
-            # Materialize only at display boundaries.
-            last_loss = loss
-            if c == 1:
-                self._loss_window.append(loss)
-            else:
-                # only the slices that can survive the window are worth a
-                # (lazy, async) device gather op
-                w = self._loss_window.maxlen or 1
-                for k in range(max(0, c - w), c):
-                    self._loss_window.append(losses[k])
-            last_iter = self.iter + c - 1  # chunk ends ON display iters
-            if sp.display and last_iter % sp.display == 0 and self.rank == 0:
-                with self._guard("display sync"):
-                    smoothed = float(sum(  # host-sync: ok (display boundary)
-                        jnp.asarray(l) for l in self._loss_window)) / len(
-                            self._loss_window)
-                self.host_sync_count += 1
-                elapsed = time.time() - t0
-                ips = ((last_iter - it0 + 1) * imgs_per_iter / elapsed
-                       if elapsed > 0 else 0.0)
-                log.info("Iteration %d (%.4g iter/s, %.1f img/s), loss = %.6g, "
-                         "lr = %.6g", last_iter,  # host-sync: ok (display)
-                         (last_iter - it0 + 1) / max(elapsed, 1e-9), ips,
-                         smoothed, float(rate))
-            self.iter += c
-            n -= c
-            if self._guard_on:
-                # deferred divergence check: materialize a PREVIOUS
-                # dispatch's guard counters now that this one is in
-                # flight — the read blocks on a program that has almost
-                # certainly retired, so the pipeline stays full. At
-                # K>1 every chunk boundary checks; at K=1 a per-
-                # iteration device_get would cost one host sync per
-                # iteration, so checks rate-limit to every
-                # _GUARD_CHECK_EVERY dispatches — safe, because the
-                # carried counters (skips, consec, monotone max_consec)
-                # lose nothing between checks; only detection latency
-                # is bounded by the interval
-                prev, self._guard_prev = (self._guard_prev,
-                                          (self.iter - 1, self._gstate))
-                self._guard_unchecked += 1
-                if prev is not None and (
-                        c > 1 or self._guard_unchecked
-                        >= self._GUARD_CHECK_EVERY):
-                    self._guard_unchecked = 0
-                    self._check_guard(*prev)
-            if (sp.test_interval and test_feed_fns
-                    and self.iter % sp.test_interval == 0
-                    and (self.iter > 0 or sp.test_initialization)
-                    and (n > 0 or self.iter < sp.max_iter)):
-                # the next loop pass (or next step() call) starts an
-                # eval here: warm its first test super-batch while the
-                # chunk that just dispatched computes. At max_iter no
-                # eval can follow — don't assemble a super-batch nobody
-                # will consume (it would pin HBM until close())
-                self._prefetch_test_feeds(test_feed_fns)
-            if sp.snapshot and self.iter % sp.snapshot == 0:
-                if self._guard_on and self._guard_prev is not None:
-                    # the snapshot at this boundary becomes the rewind
-                    # target: the chunk that just ended must pass its
-                    # divergence check FIRST, or a >=M burst inside it
-                    # gets sealed into a verified snapshot that the
-                    # supervisor then rewinds to — skipping the
-                    # divergent region instead of replaying it
-                    # (iteration-exactness lost). The extra host read
-                    # is snapshot-rate, and snapshot() blocks on this
-                    # state moments later anyway.
-                    prev, self._guard_prev = self._guard_prev, None
-                    self._check_guard(*prev)
-                # interval snapshots don't stall the train loop (the
-                # reference's do: solver.cpp:339-344 writes inline)
-                self.snapshot(block=False)
-                # ISSUE 19: snapshot boundaries are the only points a
-                # degraded cluster may grow back at (the resume target
-                # the re-formed cluster restores is the snapshot just
-                # written). MAIN thread on purpose: the async snapshot
-                # writer swallows raises into _snapshot_error.
-                self._maybe_admit_rejoin()
+                else:
+                    testing = bool(test_feed_fns)
+                    c = self._chunk_at(self.iter, n, testing)
+                    if c > 1:
+                        # K-step fused path: one dispatch covers c iterations
+                        losses, rates = self._scan_chunk(feed_fn, c, n,
+                                                         testing)
+                        loss, rate = losses[-1], rates[-1]
+                    else:
+                        # feed assembly + host->device transfer are watchdog
+                        # sections too: a hung runtime blocks inside the
+                        # jnp.asarray/shard_feeds C++ transfer exactly like a
+                        # dispatch (the fused path guards queue.get the same
+                        # way)
+                        with self._guard("feed wait"):
+                            micro_feeds = [feed_fn(self.iter * iter_size + k)
+                                           for k in range(iter_size)]
+                            if iter_size == 1:
+                                # view, not copy: the common path skips the
+                                # host-side stack
+                                feeds_stack = jax.tree.map(
+                                    lambda x: jnp.asarray(x)[None],
+                                    micro_feeds[0])
+                            else:
+                                feeds_stack = jax.tree.map(
+                                    lambda *xs: jnp.stack(xs), *micro_feeds)
+                            if self.mesh is not None:
+                                # global batch sharded over the 'data' mesh
+                                # axis (divide_batch_size semantics,
+                                # parallel.cpp:295-348)
+                                feeds_stack = self.mesh.shard_feeds(
+                                    feeds_stack, batch_axis=1)
+                        with self._guard("train dispatch"):
+                            # the step's scalar arguments are device
+                            # programs of their own (`fold_in`, a cast):
+                            # launches like the step's, in its section
+                            rng = jax.random.fold_in(self.base_rng,
+                                                     self.iter + 1)
+                            it = jnp.int32(self.iter)
+                            FAULTS.maybe_stall("dispatch_stall")
+                            if self._guard_on:
+                                (self.params, self.net_state, self.opt_state,
+                                 loss, rate, self._gstate) = self._step_jit(
+                                    self.params, self.net_state,
+                                    self.opt_state, feeds_stack, it, rng,
+                                    self._gstate)
+                            else:
+                                (self.params, self.net_state, self.opt_state,
+                                 loss, rate) = self._step_jit(
+                                    self.params, self.net_state,
+                                    self.opt_state, feeds_stack, it, rng)
+                        self.dispatch_count += 1
+                # feed any in-flight eval pass the chunks whose super-batches
+                # the worker finished while this train chunk dispatched —
+                # non-blocking, so eval assembly never stalls training
+                self._continue_eval()
+                if self._sync_steps:
+                    with self._guard("step sync"):
+                        jax.block_until_ready(loss)
+                # keep the loss ON DEVICE: a float() here would force a host
+                # sync every iteration (the reference pays microseconds over
+                # PCIe; over a remote TPU link it would serialize the
+                # pipeline). Materialize only at display boundaries.
+                last_loss = loss
+                if c == 1:
+                    self._loss_window.append(loss)
+                else:
+                    # only the slices that can survive the window are worth a
+                    # (lazy, async) device gather op
+                    w = self._loss_window.maxlen or 1
+                    for k in range(max(0, c - w), c):
+                        self._loss_window.append(losses[k])
+                last_iter = self.iter + c - 1  # chunk ends ON display iters
+                if (sp.display and last_iter % sp.display == 0
+                        and self.rank == 0):
+                    with self._guard("display sync"):
+                        smoothed = float(sum(  # host-sync: ok (display)
+                            jnp.asarray(l) for l in self._loss_window)) / len(
+                                self._loss_window)
+                    self.host_sync_count += 1
+                    elapsed = time.time() - t0
+                    ips = ((last_iter - it0 + 1) * imgs_per_iter / elapsed
+                           if elapsed > 0 else 0.0)
+                    log.info("Iteration %d (%.4g iter/s, %.1f img/s), "
+                             "loss = %.6g, "
+                             "lr = %.6g", last_iter,  # host-sync: ok (display)
+                             (last_iter - it0 + 1) / max(elapsed, 1e-9), ips,
+                             smoothed, float(rate))
+                self.iter += c
+                n -= c
+                if self._guard_on:
+                    # deferred divergence check: materialize a PREVIOUS
+                    # dispatch's guard counters now that this one is in
+                    # flight — the read blocks on a program that has almost
+                    # certainly retired, so the pipeline stays full. At
+                    # K>1 every chunk boundary checks; at K=1 a per-
+                    # iteration device_get would cost one host sync per
+                    # iteration, so checks rate-limit to every
+                    # _GUARD_CHECK_EVERY dispatches — safe, because the
+                    # carried counters (skips, consec, monotone max_consec)
+                    # lose nothing between checks; only detection latency
+                    # is bounded by the interval
+                    prev, self._guard_prev = (self._guard_prev,
+                                              (self.iter - 1, self._gstate))
+                    self._guard_unchecked += 1
+                    if prev is not None and (
+                            c > 1 or self._guard_unchecked
+                            >= self._GUARD_CHECK_EVERY):
+                        self._guard_unchecked = 0
+                        self._check_guard(*prev)
+                if (sp.test_interval and test_feed_fns
+                        and self.iter % sp.test_interval == 0
+                        and (self.iter > 0 or sp.test_initialization)
+                        and (n > 0 or self.iter < sp.max_iter)):
+                    # the next loop pass (or next step() call) starts an
+                    # eval here: warm its first test super-batch while the
+                    # chunk that just dispatched computes. At max_iter no
+                    # eval can follow — don't assemble a super-batch nobody
+                    # will consume (it would pin HBM until close())
+                    self._prefetch_test_feeds(test_feed_fns)
+                if sp.snapshot and self.iter % sp.snapshot == 0:
+                    if self._guard_on and self._guard_prev is not None:
+                        # the snapshot at this boundary becomes the rewind
+                        # target: the chunk that just ended must pass its
+                        # divergence check FIRST, or a >=M burst inside it
+                        # gets sealed into a verified snapshot that the
+                        # supervisor then rewinds to — skipping the
+                        # divergent region instead of replaying it
+                        # (iteration-exactness lost). The extra host read
+                        # is snapshot-rate, and snapshot() blocks on this
+                        # state moments later anyway.
+                        prev, self._guard_prev = self._guard_prev, None
+                        self._check_guard(*prev)
+                    # interval snapshots don't stall the train loop (the
+                    # reference's do: solver.cpp:339-344 writes inline)
+                    self.snapshot(block=False)
+                    # ISSUE 19: snapshot boundaries are the only points a
+                    # degraded cluster may grow back at (the resume target
+                    # the re-formed cluster restores is the snapshot just
+                    # written). MAIN thread on purpose: the async snapshot
+                    # writer swallows raises into _snapshot_error.
+                    self._maybe_admit_rejoin()
         if self._guard_on and self._guard_prev is not None:
             # drain the deferred check so a divergence inside THIS call's
             # final chunk surfaces before step() returns
@@ -1972,7 +1999,8 @@ class Solver:
                         entry["next"],
                         min(entry["T"], entry["iters"] - entry["next"])):
                     break
-                self._dispatch_eval_chunk(entry)
+                with spans.span("solver/eval dispatch"):
+                    self._dispatch_eval_chunk(entry)
         if block:
             self.eval_stall_ms += (time.perf_counter() - t0) * 1e3
 
@@ -2033,7 +2061,8 @@ class Solver:
         drained first (its scores log under their own iteration tag),
         then this pass dispatches and harvests."""
         self._harvest_eval()
-        self._start_eval(test_feed_fns)
+        with spans.span("solver/eval dispatch"):
+            self._start_eval(test_feed_fns)
         return self._harvest_eval()
 
     def _shared_params(self, tnet: Net):
@@ -2121,13 +2150,14 @@ class Solver:
                                    self.opt_state))
         copy = lambda t: jax.tree.map(
             lambda a: jnp.copy(a) if isinstance(a, jax.Array) else a, t)
-        view = (copy(self.params), copy(self.net_state),
-                copy(self.opt_state), self.iter, self._current_step())
-        self.wait_snapshots()  # at most one in flight, writes stay ordered
-        self._snapshot_thread = threading.Thread(
-            target=self._write_snapshot_guarded, args=view, daemon=True,
-            name="snapshot-writer")
-        self._snapshot_thread.start()
+        with spans.span("solver/snapshot handoff"):
+            view = (copy(self.params), copy(self.net_state),
+                    copy(self.opt_state), self.iter, self._current_step())
+            self.wait_snapshots()  # at most one in flight: writes stay ordered
+            self._snapshot_thread = threading.Thread(
+                target=self._write_snapshot_guarded, args=view, daemon=True,
+                name="snapshot-writer")
+            self._snapshot_thread.start()
         return ""
 
     def wait_snapshots(self, timeout: float = 600.0) -> None:

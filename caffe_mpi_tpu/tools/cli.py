@@ -55,8 +55,16 @@ def _parser() -> argparse.ArgumentParser:
         ("phase", dict(default="TEST", choices=["TRAIN", "TEST"])),
         ("synthetic", dict(action="store_true",
                            help="feed random data into Input layers")),
-        ("profile", dict(default="", help="write a JAX/XLA profiler trace "
-                                          "(xplane) to this directory")),
+        ("profile", dict(default="", help="train, time: write a JAX/XLA "
+                         "profiler trace (xplane, for XProf and "
+                         "benchmarks/span_reduce.py) to this directory and "
+                         "print its path. train traces ONE slice of the "
+                         "run, drained before it starts and before it "
+                         "stops: the second display interval, iterations "
+                         "[D, 2D) counted from where this run starts (D = "
+                         "the solver's display, 20 when that is 0); a run "
+                         "shorter than 2D traces its second half. time "
+                         "traces its whole-graph passes")),
         ("max_iter", dict(type=int, default=0,
                           help="override solver max_iter (0 = prototxt)")),
         ("test_iter", dict(type=int, default=0,
@@ -587,6 +595,49 @@ def _supervised_train(args) -> int:
         journal_prefix=journal)
 
 
+class _ProfileSlice:
+    """`caffe train -profile DIR`: the profiler runs over one slice of the
+    run, the rule of the flag's help text. `clip(chunk)` is called before
+    every `solver.step(chunk)`: it starts or stops the session when the
+    run stands on an edge of the slice, with the device drained so that
+    the trace holds whole iterations, and cuts the chunk at the next edge."""
+
+    def __init__(self, directory: str, solver, sp):
+        self.directory, self.solver = directory, solver
+        self.tracing = False
+        n, interval = sp.max_iter - solver.iter, sp.display or 20
+        lo = min(interval, n // 2)
+        self.edges = [solver.iter + lo, solver.iter + min(lo + interval, n)]
+
+    def clip(self, chunk: int) -> int:
+        if not self.directory:
+            return chunk
+        import jax
+        it = self.solver.iter
+        if self.edges and it >= self.edges[0]:
+            self.edges.pop(0)
+            jax.block_until_ready(self.solver.params)
+            if not self.tracing:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0  # spans, not every call
+                jax.profiler.start_trace(self.directory,
+                                         profiler_options=options)
+                self.tracing = True
+            else:
+                self.stop()
+        return min(chunk, self.edges[0] - it) if self.edges else chunk
+
+    def stop(self) -> None:
+        """Idempotent; also the error path's, so a failing run still
+        leaves what it traced."""
+        if not self.tracing:
+            return
+        import jax
+        self.tracing = False
+        jax.profiler.stop_trace()
+        print(f"profiler trace written to {self.directory}", flush=True)
+
+
 def _cluster_exit(prefix: str, rank: int, reason: str, error: str) -> int:
     """Journal a bounded cluster failure (ISSUE 11) and hand exit 87 to
     the supervisor. Rank 0 owns `<prefix>.run.json`; other ranks write
@@ -879,13 +930,15 @@ def cmd_train(args) -> int:
 
     t0 = time.time()
     start_iter = solver.iter
+    profile = _ProfileSlice(args.profile, solver, sp)
     try:
         while solver.iter < sp.max_iter and not state["stop"]:
-            chunk = min(100, sp.max_iter - solver.iter)
+            chunk = profile.clip(min(100, sp.max_iter - solver.iter))
             solver.step(chunk, feed_fn, test_feed_fns)
             if state["snap"]:
                 state["snap"] = False
                 solver.snapshot()
+        profile.clip(0)  # a slice that ends with the run
         if not state["stop"] and test_feed_fns and sp.test_interval:
             # final evaluation after the last iteration. Deliberate
             # deviation: the reference only runs its trailing TestAll when
@@ -930,6 +983,7 @@ def cmd_train(args) -> int:
                   resilience.EXIT_NUMERIC)
         return resilience.EXIT_NUMERIC
     finally:
+        profile.stop()
         # async interval writes must land even when training raises —
         # a half-written checkpoint is worse than a slow exit — and the
         # fused-mode feed queue's worker thread must not outlive the run
@@ -1463,7 +1517,11 @@ def main(argv=None) -> int:
         level=logging.INFO,
         format="%(levelname).1s%(asctime)s %(name)s] %(message)s",
         datefmt="%m%d %H:%M:%S")
-    args = _parser().parse_args(argv)
+    parser = _parser()
+    args = parser.parse_args(argv)
+    if args.profile and args.command not in ("train", "time"):
+        parser.error(f"-profile is read by train and time, not by "
+                     f"{args.command}")
     # the supervisor rebuilds the child command from the ORIGINAL argv
     # (argparse normalization would drop flag spellings)
     args._argv = list(argv) if argv is not None else sys.argv[1:]

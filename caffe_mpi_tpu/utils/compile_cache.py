@@ -13,7 +13,10 @@ directory is part of the cache key, so it must never move between runs:
 
 `caffe` (cli.py), the serving engine, bench.py and the tools all call
 `enable_compile_cache()` with no argument, so a bench child and
-`caffe train` share compiles.
+`caffe train` share compiles. The same call puts the HLO metadata into
+the cache key: the scope names a profiler trace is read by
+(utils/spans.py) live there, and an entry compiled before a name changed
+must not be served after it.
 
 The reference has no analogue: it compiles ahead of time with nvcc and
 has no JIT compilation step to cache.
@@ -47,9 +50,15 @@ def runtime_tag() -> str:
 
 def enable_compile_cache() -> str:
     """Returns the cache dir in use ('' = the launcher disabled it)."""
+    import jax
+    # a profiler trace is read by the scope names in the executable's
+    # metadata (utils/spans.py). jax leaves metadata out of the cache key
+    # by default, so an executable cached by an older source would be
+    # served with the older names (seen on jax 0.9.0: a step compiled
+    # under scope A, then requested under scope B, traces as A)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed is not None:
         return placed
-    import jax
     jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
     return CHECKOUT_CACHE_DIR

@@ -41,7 +41,7 @@ import os
 import sys
 import threading
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 from glob import escape as glob_escape
 
@@ -1120,9 +1120,6 @@ class DispatchWatchdog:
                 logging.shutdown()
                 os._exit(EXIT_WATCHDOG)
             return
-
-
-_NULL_SECTION = nullcontext()
 
 
 # ---------------------------------------------------------------------------

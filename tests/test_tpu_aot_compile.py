@@ -107,6 +107,12 @@ class TestLRNKernels:
             return y, vjp(y)[0]
         text = compile_tpu(fwd_bwd, on_chip(shape, jnp.bfloat16))
         assert mosaic_calls(text) >= 2  # forward + backward kernels
+        # the kernels carry their own names into the HLO, and so into a
+        # profiler trace (they used to read `branch_0_fun.N`)
+        calls = [line.split()[0] for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert sorted(c.split(".")[0].lstrip("%") for c in calls
+                      if c != "ROOT") == ["lrn_bwd", "lrn_fwd"], calls
 
 
 class TestFlashKernels:

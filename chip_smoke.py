@@ -375,14 +375,19 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
             return out, vjp(jnp.ones_like(out))
         return g
 
+    # both operand views of ops/lrn.py: spatial on the lanes (any batch
+    # under 128; the rehearsal shrinks the batch) and batch on the lanes
+    # (a multiple of 128; the rehearsal shrinks the image instead)
     n = max(32 // scale, 2)
-    for tag, shape in (("norm1", (n, 96, 55, 55)), ("norm2", (n, 256, 27, 27))):
-        x = jnp.asarray(rng.randn(*shape).astype(np.float32) * 2,
-                        jnp.bfloat16)
-        results[f"lrn-alexnet-{tag}-bf16"] = _check_kernel(
-            f"lrn {tag}",
-            with_grad(lambda x: lrn_across_channels(x, 5, 1e-4, 0.75, 1.0)),
-            with_grad(lrn_ref), (x,), 1e-2, on_chip)
+    for tag, c, hw in (("norm1", 96, 55), ("norm2", 256, 27)):
+        for shape in ((n, c, hw, hw), (128, c, hw // scale, hw // scale)):
+            x = jnp.asarray(rng.randn(*shape).astype(np.float32) * 2,
+                            jnp.bfloat16)
+            results[f"lrn-alexnet-{tag}-b{shape[0]}-bf16"] = _check_kernel(
+                f"lrn {tag} batch {shape[0]}",
+                with_grad(lambda x: lrn_across_channels(
+                    x, 5, 1e-4, 0.75, 1.0)),
+                with_grad(lrn_ref), (x,), 1e-2, on_chip)
 
     def qkv(b, s, h, d, dtype):
         return tuple(jnp.asarray(rng.randn(b, s, h, d).astype(np.float32),

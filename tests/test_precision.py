@@ -345,13 +345,41 @@ class TestPallasLRN:
         return {"data": jnp.asarray(r.randn(4, 8, 6, 6).astype(np.float32)),
                 "label": jnp.asarray(r.randint(0, 4, 4))}
 
-    def test_kernel_matches_lax_fwd_and_bwd(self):
-        from jax import lax
-        from caffe_mpi_tpu.ops.lrn import lrn_across_channels
-        r = np.random.RandomState(0)
-        x = jnp.asarray(r.randn(2, 16, 7, 9).astype(np.float32)) * 2
+    # (N, C, H, W), local_size, ops/lrn.py _BLOCK_BYTES (None = as
+    # shipped; small values, counted as VMEM tiles the block, force
+    # several blocks with a ragged last one)
+    KERNEL_CASES = {
+        # batch on the lanes: the (H*W, C, N) view
+        "lanes-batch128-c16": ((128, 16, 3, 5), 5, None),
+        "lanes-batch256-c96": ((256, 96, 2, 3), 5, None),
+        "lanes-batch128-c5-size3": ((128, 5, 2, 2), 3, None),
+        "lanes-batch128-c3-below-window": ((128, 3, 2, 2), 5, None),
+        "lanes-batch128-ragged-rows": ((128, 5, 7, 1), 5, 3 * 32 * 128),
+        "lanes-batch256-two-lane-blocks": ((256, 16, 3, 3), 5,
+                                           16 * 128 * 2),
+        # spatial on the lanes: the (N, C, H*W) view
+        "spatial-batch2-c16": ((2, 16, 7, 9), 5, None),
+        "spatial-batch5-c96-size3": ((5, 96, 3, 3), 3, None),
+        "spatial-batch2-c5": ((2, 5, 4, 4), 5, None),
+        "spatial-batch2-c3-below-window": ((2, 3, 4, 4), 5, None),
+        "spatial-batch2-short-last-tile": ((2, 8, 15, 20), 5, None),
+        "spatial-batch5-ragged-rows": ((5, 16, 6, 6), 3, 2 * 16 * 128 * 4),
+        "spatial-batch2-ragged-lanes": ((2, 16, 15, 20), 5, 16 * 128 * 4),
+    }
 
-        def ref(x, size=5, alpha=1e-4, beta=0.75, k=1.0):
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", KERNEL_CASES)
+    def test_kernel_matches_lax_fwd_and_bwd(self, case, dtype, monkeypatch):
+        from jax import lax
+        from caffe_mpi_tpu.ops import lrn
+        shape, size, block_bytes = self.KERNEL_CASES[case]
+        if block_bytes is not None:
+            monkeypatch.setattr(lrn, "_BLOCK_BYTES", block_bytes)
+        r = np.random.RandomState(0)
+        x = jnp.asarray(r.randn(*shape).astype(np.float32) * 2, dtype)
+        dy = jnp.asarray(r.randn(*shape).astype(np.float32), dtype)
+
+        def ref(x, alpha=1e-4, beta=0.75, k=1.0):
             half = (size - 1) // 2
             ws = lax.reduce_window(
                 jnp.square(x), np.zeros((), np.dtype(x.dtype))[()],
@@ -360,13 +388,19 @@ class TestPallasLRN:
                 padding=((0, 0), (half, half), (0, 0), (0, 0)))
             return x * jnp.power(k + ws * (alpha / size), -beta)
 
-        np.testing.assert_allclose(
-            lrn_across_channels(x, 5, 1e-4, 0.75, 1.0), ref(x),
-            rtol=1e-5, atol=1e-6)
-        g_ker = jax.grad(lambda x: jnp.sum(
-            lrn_across_channels(x, 5, 1e-4, 0.75, 1.0) ** 2))(x)
-        g_ref = jax.grad(lambda x: jnp.sum(ref(x) ** 2))(x)
-        np.testing.assert_allclose(g_ker, g_ref, rtol=1e-4, atol=1e-5)
+        y, vjp = jax.vjp(
+            lambda x: lrn.lrn_across_channels(x, size, 1e-4, 0.75, 1.0), x)
+        dx, = vjp(dy)
+        assert y.dtype == dx.dtype == x.dtype
+        # the reference in f32 from the same inputs; bf16 I/O rounds the
+        # kernel's f32 result once at the block's edge
+        y_ref, vjp_ref = jax.vjp(ref, x.astype(jnp.float32))
+        dx_ref, = vjp_ref(dy.astype(jnp.float32))
+        fwd, bwd = ((dict(rtol=1e-5, atol=1e-6), dict(rtol=1e-4, atol=1e-5))
+                    if dtype == "float32" else
+                    (dict(rtol=1e-2, atol=1e-3),) * 2)
+        np.testing.assert_allclose(y.astype(jnp.float32), y_ref, **fwd)
+        np.testing.assert_allclose(dx.astype(jnp.float32), dx_ref, **bwd)
 
     def test_bf16_routes_through_pallas_f32_does_not(self, monkeypatch):
         monkeypatch.delenv("CAFFE_LRN_PALLAS", raising=False)

@@ -16,6 +16,7 @@ chip_smoke.py's `kernels` leg on the chip.
 
 import functools
 import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,15 @@ def mosaic_calls(text: str) -> int:
     return text.count("tpu_custom_call")
 
 
+def kernel_calls(text: str) -> list[tuple[str, list[str]]]:
+    """(name, operand names) of each Mosaic call in a compiled module's
+    text, `%` stripped: `("lrn_fwd.2", ["bitcast.9"])`."""
+    return [(name, re.findall(r"%([\w.-]+)", operands))
+            for name, operands in re.findall(
+                r"%([\w.-]+) = [^\n]*? custom-call\(([^)]*)\), "
+                r'custom_call_target="tpu_custom_call"', text)]
+
+
 class TestInterpretOnlyOnCpu:
     """ops/pallas_call.py: the interpreter is the cpu platform's and
     nobody else's."""
@@ -96,8 +106,15 @@ class TestInterpretOnlyOnCpu:
 
 
 class TestLRNKernels:
-    @pytest.mark.parametrize("shape", [(2, 96, 55, 55), (2, 256, 27, 27)],
-                             ids=["alexnet-norm1", "alexnet-norm2"])
+    # batch a multiple of 128: the (H*W, C, N) view; below it the
+    # (N, C, H*W) view with the whole spatial extent in a block
+    @pytest.mark.parametrize("shape", [
+        (1024, 96, 55, 55), (1024, 256, 27, 27),    # the benchmark's batch
+        (256, 96, 55, 55), (256, 256, 27, 27),      # the recipe's
+        (2, 96, 55, 55), (2, 256, 27, 27),
+        (32, 64, 56, 56),                           # GoogLeNet fp16
+        (1, 96, 55, 55),                            # a serving bucket
+    ], ids=lambda s: "x".join(map(str, s)))
     def test_fwd_bwd_compile_bf16(self, shape):
         from caffe_mpi_tpu.ops.lrn import lrn_across_channels
 
@@ -106,13 +123,41 @@ class TestLRNKernels:
             y, vjp = jax.vjp(f, x)
             return y, vjp(y)[0]
         text = compile_tpu(fwd_bwd, on_chip(shape, jnp.bfloat16))
-        assert mosaic_calls(text) >= 2  # forward + backward kernels
         # the kernels carry their own names into the HLO, and so into a
-        # profiler trace (they used to read `branch_0_fun.N`)
-        calls = [line.split()[0] for line in text.splitlines()
-                 if 'custom_call_target="tpu_custom_call"' in line]
-        assert sorted(c.split(".")[0].lstrip("%") for c in calls
-                      if c != "ROOT") == ["lrn_bwd", "lrn_fwd"], calls
+        # profiler trace (they used to read `branch_0_fun.N`); one call
+        # per direction
+        assert sorted(name.split(".")[0]
+                      for name, _ in kernel_calls(text)) == [
+            "lrn_bwd", "lrn_fwd"]
+
+    def test_bf16_lrn_takes_the_convolution_layout(self):
+        """conv -> relu -> LRN -> max-pool and its gradient at AlexNet's
+        norm1 shape: XLA holds these activations batch-minor, the
+        kernels read and write that order, so every operand of theirs is
+        a bitcast and no pad or copy is filed under the LRN layer."""
+        from caffe_mpi_tpu.net import Net
+        from caffe_mpi_tpu.proto import NetParameter
+        net = Net(NetParameter.from_text(_ALEXNET_HEAD % 1024),
+                  phase="TRAIN", precision="bf16")
+        params, state = net.init(jax.random.PRNGKey(0))
+
+        def grads(p, s, x):
+            return jax.grad(lambda p: jnp.sum(net.apply(
+                p, s, {"data": x}, train=True,
+                rng=jax.random.PRNGKey(0))[0]["pool1"].astype(jnp.float32)))(p)
+        sh = SingleDeviceSharding(v5e_devices()[0])
+        text = compile_tpu(grads, abstract(params, sh), abstract(state, sh),
+                           on_chip((1024, 3, 227, 227), jnp.float32))
+        calls = kernel_calls(text)
+        assert sorted(name.split(".")[0] for name, _ in calls) == [
+            "lrn_bwd", "lrn_fwd"]
+        for name, operands in calls:
+            assert all(o.startswith("bitcast") for o in operands), (
+                name, operands)
+        moved = [line.split("=")[0].strip() for line in text.splitlines()
+                 if re.search(r" (copy|pad)\(", line)
+                 and "caffe.LRN.norm1" in line]
+        assert not moved, moved
 
 
 class TestFlashKernels:

@@ -61,6 +61,41 @@ class LayerNormLayer(Layer):
         return [y], state
 
 
+@register("RMSNorm")
+class RMSNormLayer(Layer):
+    """x / sqrt(mean(x^2, -1) + eps) * scale (rms_norm_param { eps }):
+    the statistics in float32, no mean subtracted, no bias. Stateless."""
+
+    def setup(self, in_shapes: list[Shape]) -> list[Shape]:
+        from ..proto.config import RMSNormParameter
+        self.p = self.lp.rms_norm_param or RMSNormParameter()
+        self.declare("scale", (in_shapes[0][-1],),
+                     FillerParameter(type="constant", value=1.0))
+        return [in_shapes[0]]
+
+    def apply(self, params, state, bottoms, *, train, rng):
+        x = self.f(bottoms[0])
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.p.eps)
+        return [y.astype(x.dtype) * self.f(params["scale"])], state
+
+
+def attention_dims(p, c: int) -> tuple[int, int, int]:
+    """(query heads, key/value heads, head size) of an attention_param over
+    `c` channels; the zero defaults are one key/value head per query head
+    and c / heads."""
+    heads = max(p.num_heads, 1)
+    kv = p.num_kv_heads or heads
+    if p.head_dim == 0 and c % heads:
+        raise ValueError(f"channels {c} not divisible by "
+                         f"num_heads {p.num_heads}")
+    if heads % kv:
+        raise ValueError(f"num_heads {heads} not a multiple of "
+                         f"num_kv_heads {kv}")
+    return heads, kv, p.head_dim or c // heads
+
+
 @register("Attention")
 class AttentionLayer(Layer):
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
@@ -71,30 +106,42 @@ class AttentionLayer(Layer):
             raise ValueError(
                 f"Attention expects (N, S, C) bottom, got {in_shapes[0]}")
         n, s, c = in_shapes[0]
-        if c % max(p.num_heads, 1):
-            raise ValueError(f"channels {c} not divisible by "
-                             f"num_heads {p.num_heads}")
-        self.heads = max(p.num_heads, 1)
+        self.heads, self.kv_heads, self.head_dim = attention_dims(p, c)
+        if p.window and not p.causal:
+            raise ValueError("attention_param window needs causal: true")
+        if p.sequence_parallel and (p.window or self.kv_heads != self.heads):
+            raise ValueError("sequence_parallel attention has neither a "
+                             "window nor grouped key/value heads")
+        if p.rope_theta and self.head_dim % 2:
+            raise ValueError(f"rotary positions over an odd head size "
+                             f"{self.head_dim}")
+        nq, nkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        self.nq, self.nkv = nq, nkv
         filler = p.weight_filler or FillerParameter(type="xavier")
-        self.declare("qkv_weight", (3 * c, c), filler)
-        self.declare("proj_weight", (c, c), filler)
+        self.declare("qkv_weight", (nq + 2 * nkv, c), filler)
+        self.declare("proj_weight", (c, nq), filler)
         if p.bias_term:
             bias = p.bias_filler or FillerParameter(type="constant")
-            self.declare("qkv_bias", (3 * c,), bias)
+            self.declare("qkv_bias", (nq + 2 * nkv,), bias)
             self.declare("proj_bias", (c,), bias)
         return [in_shapes[0]]
 
     def apply(self, params, state, bottoms, *, train, rng):
-        from ..ops.attention import attention, sequence_parallel_attention
+        from ..ops.attention import (attention, rope,
+                                     sequence_parallel_attention)
         p = self.p
         x = self.f(bottoms[0])
         n, s, c = x.shape
         qkv = x @ self.f(params["qkv_weight"]).T
         if p.bias_term:
             qkv = qkv + self.f(params["qkv_bias"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shape = (n, s, self.heads, c // self.heads)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        nq, nkv = self.nq, self.nkv
+        q, k, v = jnp.split(qkv, [nq, nq + nkv], axis=-1)
+        q = q.reshape(n, s, self.heads, self.head_dim)
+        k = k.reshape(n, s, self.kv_heads, self.head_dim)
+        v = v.reshape(n, s, self.kv_heads, self.head_dim)
+        if p.rope_theta:
+            q, k = rope(q, p.rope_theta), rope(k, p.rope_theta)
         mp = self.mesh_plan
         if (p.sequence_parallel and mp is not None
                 and mp.mesh.shape.get("model", 1) > 1):
@@ -113,11 +160,12 @@ class AttentionLayer(Layer):
             # samples)
             out = mp.per_batch_shard(
                 lambda q, k, v: attention(q, k, v, causal=bool(p.causal),
-                                          use_flash=True), q, k, v)
+                                          use_flash=True, window=p.window),
+                q, k, v)
         else:
             out = attention(q, k, v, causal=bool(p.causal),
-                            use_flash=bool(p.use_flash))
-        y = out.reshape(n, s, c) @ self.f(params["proj_weight"]).T
+                            use_flash=bool(p.use_flash), window=p.window)
+        y = out.reshape(n, s, nq) @ self.f(params["proj_weight"]).T
         if p.bias_term:
             y = y + self.f(params["proj_bias"])
         return [y], state
@@ -125,6 +173,15 @@ class AttentionLayer(Layer):
 
 @register("MoE")
 class MoELayer(Layer):
+    """moe_param. Two formulations (ops/moe.py): the capacity one (GShard
+    dispatch/combine tensors, softmax then top-k, tokens past capacity
+    dropped, two biased matrices an expert; second top = the auxiliary
+    load-balancing loss) and, with `dropless: true`, top-k then softmax,
+    rows sorted by expert through grouped matrix products, gated ReLU
+    experts of three unbiased matrices (second top = the rows each held
+    expert received). A second bottom, when given, is what
+    the router scores instead of the tensor the experts transform."""
+
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         p = self.lp.moe_param
         if p is None or p.num_experts < 1 or p.hidden_dim < 1:
@@ -132,30 +189,51 @@ class MoELayer(Layer):
         self.p = p
         c = in_shapes[0][-1]
         self.c = c
+        held = p.experts_held or p.num_experts
+        if not 0 <= p.first_expert <= p.num_experts - held:
+            raise ValueError(
+                f"experts {p.first_expert}..{p.first_expert + held - 1} "
+                f"are not among num_experts {p.num_experts}")
+        if not p.dropless and (held != p.num_experts or len(in_shapes) > 1):
+            raise ValueError("moe_param: experts_held and a router bottom "
+                             "need dropless: true")
+        if len(in_shapes) > 1 and in_shapes[1] != in_shapes[0]:
+            raise ValueError(f"MoE router bottom {in_shapes[1]} != "
+                             f"{in_shapes[0]}")
         filler = p.weight_filler or FillerParameter(type="xavier")
         gate_filler = FillerParameter(type="gaussian", std=0.02)
+        zero = FillerParameter(type="constant")
         self.declare("gate", (c, p.num_experts), gate_filler)
-        self.declare("w1", (p.num_experts, c, p.hidden_dim), filler)
-        self.declare("b1", (p.num_experts, p.hidden_dim),
-                     FillerParameter(type="constant"))
-        self.declare("w2", (p.num_experts, p.hidden_dim, c), filler)
-        self.declare("b2", (p.num_experts, c),
-                     FillerParameter(type="constant"))
+        self.declare("w1", (held, c, p.hidden_dim), filler)
+        if not p.dropless:
+            self.declare("b1", (held, p.hidden_dim), zero)
+        self.declare("w2", (held, p.hidden_dim, c), filler)
+        if p.dropless:
+            self.declare("w3", (held, c, p.hidden_dim), filler)
+        else:
+            self.declare("b2", (held, c), zero)
         tops = [in_shapes[0]]
-        if len(self.lp.top) > 1:  # optional aux-loss top
-            tops.append(())
+        if len(self.lp.top) > 1:  # aux loss / rows per held expert
+            tops.append((held,) if p.dropless else ())
         return tops
 
     def apply(self, params, state, bottoms, *, train, rng):
-        from ..ops.moe import moe_ffn
+        from ..ops.moe import moe_dropless, moe_ffn
         p = self.p
         x = self.f(bottoms[0])
         lead = x.shape[:-1]
         flat = x.reshape(-1, x.shape[-1])
-        y, aux = moe_ffn({k: self.f(v) for k, v in params.items()}, flat,
-                         top_k=max(p.top_k, 1),
-                         capacity_factor=p.capacity_factor)
+        cast = {k: self.f(v) for k, v in params.items()}
+        if p.dropless:
+            scored = self.f(bottoms[1]).reshape(flat.shape) \
+                if len(bottoms) > 1 else flat
+            y, extra = moe_dropless(
+                cast, flat, scored, top_k=max(p.top_k, 1),
+                first_expert=p.first_expert)
+        else:
+            y, extra = moe_ffn(cast, flat, top_k=max(p.top_k, 1),
+                               capacity_factor=p.capacity_factor)
         tops = [y.reshape(*lead, x.shape[-1])]
         if len(self.lp.top) > 1:
-            tops.append(aux)
+            tops.append(extra)
         return tops, state

@@ -51,10 +51,33 @@ def _block_attn(q, k, v, *, scale, mask=None):
     return out, m_safe, l
 
 
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions over the whole head, rotate-half convention:
+    x (B, S, H, D) -> the same, positions 0..S-1 in each sequence, no
+    scaling. Angles and the rotation in float32, the result in x's type.
+    With x = [x1, x2] (halves of D) and a_i = pos * theta^(-i / (D/2)):
+    [x1 cos a - x2 sin a, x2 cos a + x1 sin a]."""
+    s, d = x.shape[1], x.shape[-1]
+    half = d // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = False, use_flash: bool = False,
-              flash_interpret: bool | None = None) -> jnp.ndarray:
-    """Single-device attention: q,k,v (B,S,H,D) -> (B,S,H,D).
+              flash_interpret: bool | None = None,
+              window: int = 0) -> jnp.ndarray:
+    """Single-device attention: q (B,S,H,D), k,v (B,S,Hkv,D) -> (B,S,H,D).
+
+    Grouped heads: with Hkv < H (H a multiple of it) query head n reads
+    key/value head n // (H / Hkv). window > 0 (with causal): key j is
+    visible to query i iff i - window < j <= i.
 
     use_flash: route through the Pallas flash-attention kernels
     (ops/flash_attention.py) — O(S) memory VMEM-tiled online softmax,
@@ -63,15 +86,23 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     masked). flash_interpret: None = the interpreter on the cpu
     platform, Mosaic on any other (ops/pallas_call.py); a bool pins
     it."""
+    if window and not causal:
+        raise ValueError("a sliding window needs causal attention")
     if use_flash:
         from .flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal,
+        return flash_attention(q, k, v, causal=causal, window=window,
                                interpret=flash_interpret)
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scale = 1.0 / math.sqrt(q.shape[-1])
     mask = None
     if causal:
         sq, sk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((sq, sk), bool))[None, None]
+        mask = jnp.tril(jnp.ones((sq, sk), bool))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), -window)
+        mask = mask[None, None]
     out, m, l = _block_attn(q, k, v, scale=scale, mask=mask)
     return out / jnp.maximum(l, 1e-30)[..., None].swapaxes(1, 2)
 

@@ -8,6 +8,11 @@ it in VMEM tiles with an online softmax, O(S) memory instead of O(S^2).
 
 Layout: (B, H, S, D) inside the kernels (sequence-minor tiles). The public
 entry accepts the framework's (B, S, H, D) and transposes at the edges.
+Tiles are 128 to 512 long (`_tile`, from the sequence length alone).
+Grouped heads: K and V have B*Hkv rows and query row i reads row i // (H /
+Hkv) through the block index map. A causal window joins the tile mask, and
+tiles wholly outside it or above the diagonal are not visited, in all three
+kernels. bf16 operands go into the MXU as they are, accumulated in float32.
 Forward grid: (B*H, Sq/BQ) with an inner fori_loop over K tiles,
 accumulating (out, m, l) in registers; it also emits the per-row
 logsumexp, which the backward re-uses to recompute normalized
@@ -28,7 +33,7 @@ resident (the dK/dV kernel: Q and dO), double-buffered by the pipeline.
 `_vmem_params` raises the scoped-VMEM limit to the computed need when it
 passes Mosaic's 16 MiB default, and refuses with FlashVmemError past
 what a v5e core holds — tiling K/V through the grid instead is ROADMAP
-Reach 5.
+Reach 11.
 """
 
 from __future__ import annotations
@@ -64,8 +69,9 @@ def _vmem_params(what, seq, d, dtype):
     double-buffered by the pipeline; None when the default limit is
     enough."""
     resident = 2 * 2 * seq * d * jnp.dtype(dtype).itemsize
-    # tile-sized operands, f32 in-kernel temporaries, compiler scratch
-    need = resident + 8 * 2**20
+    # tile-sized operands, f32 in-kernel temporaries (half a dozen score
+    # tiles: 1 MiB each at 512 x 512), compiler scratch
+    need = resident + (8 + 8 * (_tile(seq) // 256) ** 2) * 2**20
     if need <= _VMEM_DEFAULT:
         return None
     if need > _VMEM_MAX:
@@ -79,7 +85,7 @@ def _vmem_params(what, seq, d, dtype):
     return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
-def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid):
+def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window=0):
     """Valid-score mask for the (qi, j) q x k tile, or None when every
     entry is valid. ONE definition shared by the forward and dQ kernels —
     a mask change applied to only one of them would silently desync
@@ -94,6 +100,8 @@ def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid):
     mask = None
     if causal:
         mask = rows >= cols
+        if window:  # key j visible to query i iff i - window < j <= i
+            mask &= rows - cols < window
     if sk_valid < sk:
         ok = cols < sk_valid
         mask = ok if mask is None else mask & ok
@@ -116,8 +124,27 @@ def _n_k_tiles(sk, bk, sk_valid):
     return -(-sk_valid // bk) if sk_valid < sk else sk // bk
 
 
+def _k_tile_range(qi, bq, bk, sk, n_k, causal, window):
+    """[first, end) of the K tiles query tile `qi` can see: with causal
+    none above the diagonal (and never the fully-padded trailing tiles),
+    with a window none wholly before `first row - window + 1`. ONE
+    definition for the forward and dQ kernels, like the mask."""
+    if not causal:
+        return 0, n_k
+    end = jnp.minimum(jnp.minimum((qi + 1) * bq + bk - 1, sk) // bk, n_k)
+    first = jnp.maximum(qi * bq - window + 1, 0) // bk if window else 0
+    return first, end
+
+
+def _dot(a, b, dims):
+    """MXU product with float32 accumulation; the operands keep their
+    type (bf16 x bf16 products are exact in float32)."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
-                bq, bk, sk_valid, has_bias):
+                bq, bk, sk_valid, has_bias, window=0):
     """rest = ([bias_ref,] o_ref, lse_ref). bias (1, sk) f32 adds to every
     score row — 0 for live keys, -inf for masked ones (ring attention
     uses it to mask globally-padded key positions per rotating block);
@@ -125,19 +152,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
     even in fully-biased-out tiles (blk_m clamps to 0 first)."""
     bias_ref, o_ref, lse_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)  # (bq, d)
+    q = q_ref[0]  # (bq, d)
     n_k = _n_k_tiles(sk, bk, sk_valid)
 
     def body(j, carry):
         out, m, l = carry
-        k = k_ref[0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k = k_ref[0, pl.dslice(j * bk, bk), :]
+        v = v_ref[0, pl.dslice(j * bk, bk), :]
+        s = _dot(q, k, ((1,), (1,))) * scale
         if has_bias:
             s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
-        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid)
+        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window)
         if mask is not None:
             s = jnp.where(mask, s, -jnp.inf)
         blk_m = jnp.max(s, axis=1)
@@ -150,8 +176,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
         alpha = jnp.exp(m - new_m)
         beta = jnp.exp(blk_m - new_m)
         l = l * alpha + blk_l * beta
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
         out = out * alpha[:, None] + pv * beta[:, None]
         return out, new_m, l
 
@@ -159,14 +184,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
     out0 = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    if causal:
-        # only K tiles at or before this Q tile can contribute (and never
-        # the fully-padded trailing tiles)
-        n_iter = jnp.minimum(
-            jnp.minimum((qi + 1) * bq + bk - 1, sk) // bk, n_k)
-    else:
-        n_iter = n_k
-    out, m, l = jax.lax.fori_loop(0, n_iter, body, (out0, m0, l0))
+    first, end = _k_tile_range(qi, bq, bk, sk, n_k, causal, window)
+    out, m, l = jax.lax.fori_loop(first, end, body, (out0, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (out / l_safe[:, None]).astype(o_ref.dtype)
     # logsumexp per row; backward recomputes p = exp(s - lse). m is never
@@ -178,62 +197,54 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   scale, causal, sk, bq, bk, sk_valid, has_bias):
+                   scale, causal, sk, bq, bk, sk_valid, has_bias, window=0):
     bias_ref, dq_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0, 0].astype(jnp.float32)       # (bq,)
     delta = delta_ref[0, 0].astype(jnp.float32)   # (bq,)
     n_k = _n_k_tiles(sk, bk, sk_valid)
 
     def body(j, dq):
-        k = k_ref[0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k = k_ref[0, pl.dslice(j * bk, bk), :]
+        v = v_ref[0, pl.dslice(j * bk, bk), :]
+        s = _dot(q, k, ((1,), (1,))) * scale
         if has_bias:
             s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
         p = jnp.exp(s - lse[:, None])          # normalized probabilities
         # the same mask as the forward (see _tile_mask: padded-column p
         # here can overflow to inf and NaN dQ via inf*0)
-        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid)
+        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window)
         if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, ((1,), (1,)))
         ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        return dq + _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
 
     d = q_ref.shape[-1]
-    if causal:
-        n_iter = jnp.minimum(
-            jnp.minimum((qi + 1) * bq + bk - 1, sk) // bk, n_k)
-    else:
-        n_iter = n_k
-    dq = jax.lax.fori_loop(0, n_iter, body, jnp.zeros((bq, d), jnp.float32))
+    first, end = _k_tile_range(qi, bq, bk, sk, n_k, causal, window)
+    dq = jax.lax.fori_loop(first, end, body,
+                           jnp.zeros((bq, d), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, sq, bq, bk, has_bias):
+                    scale, causal, sq, bq, bk, has_bias, window=0):
     bias_ref, dk_ref, dv_ref = rest if has_bias else (None, *rest)
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)   # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]   # (bk, d)
+    v = v_ref[0]
     n_q = sq // bq
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.dslice(i * bq, bq), :].astype(jnp.float32)
-        do = do_ref[0, pl.dslice(i * bq, bq), :].astype(jnp.float32)
+        q = q_ref[0, pl.dslice(i * bq, bq), :]
+        do = do_ref[0, pl.dslice(i * bq, bq), :]
         lse = lse_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
         delta = delta_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, ((1,), (1,))) * scale
         if has_bias:
             # this kernel's k block is the grid's second axis: the bias
             # slice is the ki-th tile, broadcast over q rows; -inf makes
@@ -243,31 +254,42 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if causal:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+            mask = rows >= cols
+            if window:
+                mask &= rows - cols < window
+            p = jnp.where(mask, p, 0.0)
+        dv = dv + _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _dot(do, v, ((1,), (1,)))
         ds = p * (dp - delta[:, None])
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32) * scale
+        dk = dk + _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
         return dk, dv
 
     d = k_ref.shape[-1]
+    start, end = 0, n_q
     if causal:
         start = (ki * bk) // bq  # earlier Q tiles are fully masked
-    else:
-        start = 0
+        if window:  # and so are those past the last key's window
+            end = jnp.minimum(n_q, ((ki + 1) * bk + window - 2) // bq + 1)
     dk, dv = jax.lax.fori_loop(
-        start, n_q, body,
+        start, end, body,
         (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _tile(s: int) -> int:
+    """Tile length along a sequence of (padded) length `s`: the whole of a
+    short one, else the largest of 512, 256, 128 that divides it. A 128 x
+    128 tile leaves the MXU waiting on the loop around it (16.8 % of the
+    kernels' roofline at S = 8192, PERF.md section 6, PR 27); a causal
+    diagonal in 512-tiles computes 6 % more pairs than in 128-tiles."""
+    if s <= BQ:
+        return s
+    return next((t for t in (512, 256) if s % t == 0), BQ)
+
+
 def _check_tiles(sq: int, sk: int) -> tuple[int, int]:
-    bq = min(BQ, sq)
-    bk = min(BK, sk)
+    bq, bk = _tile(sq), _tile(sk)
     if sq % bq or sk % bk:
         raise ValueError(f"sequence lengths ({sq},{sk}) must be multiples "
                          f"of the tile sizes ({bq},{bk})")
@@ -292,22 +314,26 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None):
-    """(B*H, S, D) inputs -> (out, lse). k_bias: optional (1, Sk) f32
-    additive score bias shared by every row/head (0 live, -inf masked)."""
+def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
+              window=0):
+    """(B*H, S, D) q and (B*Hkv, S, D) k, v -> (out, lse); query row i of
+    the leading axis reads key/value row i // (H / Hkv). k_bias: optional
+    (1, Sk) f32 additive score bias shared by every row/head (0 live,
+    -inf masked)."""
     bh, sq, d = q.shape
     sk = k.shape[1]
+    group = bh // k.shape[0]
     bq, bk = _check_tiles(sq, sk)
     scale = 1.0 / math.sqrt(d)
     has_bias = k_bias is not None
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                sk=sk, bq=bq, bk=bk,
                                sk_valid=sk if sk_valid is None else sk_valid,
-                               has_bias=has_bias)
+                               has_bias=has_bias, window=window)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
     ]
     args = [q, k, v]
     if has_bias:
@@ -327,6 +353,7 @@ def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None):
         ],
         compiler_params=_vmem_params("forward", sk, d, k.dtype),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -338,13 +365,14 @@ def _delta(do, out):
 
 
 def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
-              k_bias=None, delta=None):
+              k_bias=None, delta=None, window=0):
     """out/lse are the GLOBAL attention output/logsumexp for these q rows
     (for plain flash that's this call's own forward; for ring attention
     each per-block call passes the ring-merged values, which makes the
     recomputed p the global probabilities restricted to the block)."""
     bh, sq, d = q.shape
     sk = k.shape[1]
+    group = bh // k.shape[0]
     bq, bk = _check_tiles(sq, sk)
     scale = 1.0 / math.sqrt(d)
     if delta is None:
@@ -352,8 +380,8 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
     has_bias = k_bias is not None
     dq_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),   # q
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),   # k
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),   # v
+        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # k
+        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # v
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),   # do
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # lse
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # delta
@@ -366,18 +394,19 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           sk=sk, bq=bq, bk=bk,
                           sk_valid=sk if sk_valid is None else sk_valid,
-                          has_bias=has_bias),
+                          has_bias=has_bias, window=window),
         grid=(bh, sq // bq),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), q.dtype, q),
         compiler_params=_vmem_params("dQ", sk, d, k.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
     dkv_specs = [
         pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),   # q
-        pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),   # k
-        pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),   # v
+        pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # k
+        pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # v
         pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),   # do
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # lse
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # delta
@@ -386,9 +415,14 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
     if has_bias:
         dkv_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
         dkv_args.append(k_bias)
+    # with grouped heads each query head writes its own dK/dV (float32),
+    # summed over the group below: Q and dO then stay resident across a
+    # head's K tiles
+    kv_dtype = (k.dtype, v.dtype) if group == 1 else (jnp.float32,) * 2
     dk, dv = pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          sq=sq, bq=bq, bk=bk, has_bias=has_bias),
+                          sq=sq, bq=bq, bk=bk, has_bias=has_bias,
+                          window=window),
         grid=(bh, sk // bk),
         in_specs=dkv_specs,
         out_specs=[
@@ -396,12 +430,16 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            _sds((bh, sk, d), k.dtype, k),
-            _sds((bh, sk, d), v.dtype, v),
+            _sds((bh, sk, d), kv_dtype[0], k),
+            _sds((bh, sk, d), kv_dtype[1], v),
         ],
         compiler_params=_vmem_params("dK/dV", sq, d, q.dtype),
         interpret=interpret,
+        name="flash_dkv",
     )(*dkv_args)
+    if group > 1:
+        dk = dk.reshape(-1, group, sk, d).sum(1).astype(k.dtype)
+        dv = dv.reshape(-1, group, sk, d).sum(1).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -429,42 +467,53 @@ def flash_block_bwd(q, k, v, out, lse, do, *, causal=False, k_bias=None,
                      k_bias=k_bias, delta=delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, interpret, sk_valid):
-    out, _ = _fwd_impl(q, k, v, causal, interpret, sk_valid)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, interpret, sk_valid, window):
+    out, _ = _fwd_impl(q, k, v, causal, interpret, sk_valid, window=window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, interpret, sk_valid):
-    out, lse = _fwd_impl(q, k, v, causal, interpret, sk_valid)
+def _flash_fwd(q, k, v, causal, interpret, sk_valid, window):
+    out, lse = _fwd_impl(q, k, v, causal, interpret, sk_valid,
+                         window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, interpret, sk_valid, res, do):
+def _flash_bwd(causal, interpret, sk_valid, window, res, do):
     # sk_valid reaches the dQ kernel (p at padded columns can overflow to
     # inf when lse < -88 and must be zeroed before ds @ k). The dK/dV
     # kernel needs no mask: padded Q rows carry do = 0 (the output
     # slice's cotangent) and padded K/V ROW garbage lands only in output
     # rows the wrapper slices off.
     q, k, v, out, lse = res
-    return _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid)
+    return _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid,
+                     window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = False, interpret: bool | None = None
-                    ) -> jnp.ndarray:
-    """q,k,v: (B, S, H, D) -> (B, S, H, D). Differentiable: jax.grad hits
-    the Pallas backward kernels via custom_vjp.
+                    causal: bool = False, interpret: bool | None = None,
+                    window: int = 0) -> jnp.ndarray:
+    """q (B, S, H, D), k,v (B, S, Hkv, D) -> (B, S, H, D). Differentiable:
+    jax.grad hits the Pallas backward kernels via custom_vjp.
+
+    Grouped heads (Hkv < H): query head n reads key/value head
+    n // (H / Hkv) through the kernels' block index maps, K and V are not
+    repeated. window > 0 (causal only): key j visible to query i iff
+    i - window < j <= i; tiles wholly outside are not visited.
 
     Arbitrary sequence lengths: lengths that don't tile evenly are padded
     up to the (128, 128) q/k tile sizes — padded key columns are masked
     out of the in-kernel softmax, padded query rows are sliced off the
     output (their gradients vanish through the zero cotangent)."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, hkv = k.shape[1], k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key/value heads")
+    if window and not causal:
+        raise ValueError("a sliding window needs causal attention")
     sq_p, sk_p = _pad_len(sq, BQ), _pad_len(sk, BK)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
@@ -472,9 +521,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d)
+    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
     out = _flash(qt, kt, vt, causal, interpret,
-                 sk if sk_p != sk else None)
+                 sk if sk_p != sk else None, window)
     out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)
     return out[:, :sq] if sq_p != sq else out

@@ -17,6 +17,19 @@ shape XLA's GSPMD partitioner understands natively:
             matmul batched over experts, no Python loop
   combine:  gate-weighted einsum back to (T, F)
 
+`moe_dropless` is the other formulation, for fine-grained experts (many
+small ones, several a token) where a (T, E, C) one-hot tensor and dropped
+tokens are both unaffordable: the (T, k) choices are flattened and sorted
+by expert, the rows gathered, the experts run as grouped matrix products
+over the sorted rows (megablox's Pallas kernels; `lax.ragged_dot` on the
+CPU), and the results summed back per token under the router's weights.
+No capacity, no dropped token, static shapes. Its constants are those of
+the one recipe that uses it: top-k then softmax, gated ReLU experts of
+three unbiased matrices. It is told which experts it holds (an
+expert-parallel share): the router still scores all of them, rows routed
+to absent experts sort last and cost no expert FLOPs, and the result is
+the held experts' part.
+
 Expert parallelism = shard the E dimension (expert weights AND the
 (E, C, ...) activation tensors) over a mesh axis via sharding
 constraints; GSPMD then partitions the batched einsums per-expert and
@@ -27,9 +40,14 @@ with DP/TP sharding on the same mesh.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ..utils.spans import (MOE_COMBINE as COMBINE, MOE_DISPATCH as DISPATCH,
+                           MOE_EXPERTS as EXPERTS, MOE_ROUTE as ROUTE)
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,
@@ -141,3 +159,168 @@ def moe_ffn_dense_reference(params: dict, x: jnp.ndarray, *,
             out = h @ params["w2"][ei] + params["b2"][ei]
             y = y + jnp.where(sel[:, None], out * gate_w[:, None], 0.0)
     return y
+
+
+# -- dropless, sorted-by-expert formulation ---------------------------------
+
+def _gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(rows, contraction, output) tile of a grouped product: 512 rows,
+    and of each weight dimension the largest multiple of 128 up to 1280
+    that divides it, so that a tile never straddles the matrix."""
+    side = lambda d: next((t for t in (1280, 1024, 768, 512, 384, 256, 128)
+                           if d % t == 0), d)
+    return (512 if m % 512 == 0 else 128 if m % 128 == 0 else m,
+            side(k), side(n))
+
+
+@jax.custom_vjp
+def _gmm(rows, bank, sizes):
+    """rows (M, K) x bank (G, K, N), rows sorted by group, `sizes` (G,)
+    rows a group -> (M, N), by the Pallas grouped matrix product
+    (jax.experimental.pallas.ops.tpu.megablox). It visits only the row
+    tiles that hold a group's rows; what lies past the last group is
+    left unwritten, in all three products. A custom VJP of its own so
+    that each of the three products (the forward, the rows' gradient, the
+    bank's gradient) gets tiles sized for its own shapes."""
+    return _gmm_fwd(rows, bank, sizes)[0]
+
+
+def _megablox():
+    # the package re-exports its `gmm` FUNCTION over the module of the
+    # same name; the two raw kernels live in the module
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _gmm_fwd(rows, bank, sizes):
+    backend = _megablox()
+    m, k = rows.shape
+    out = backend.gmm(rows, bank, sizes, rows.dtype,
+                      _gmm_tiles(m, k, bank.shape[2]))
+    return out, (rows, bank, sizes)
+
+
+def _gmm_bwd(res, grad):
+    backend = _megablox()
+    rows, bank, sizes = res
+    m, k = rows.shape
+    n = bank.shape[2]
+    d_rows = backend.gmm(grad, bank, sizes, rows.dtype,
+                         _gmm_tiles(m, n, k), transpose_rhs=True)
+    d_bank = backend.tgmm(rows.swapaxes(0, 1), grad, sizes, bank.dtype,
+                          _gmm_tiles(m, k, n))
+    return d_rows, d_bank, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_dot(rows, bank, sizes):
+    """Grouped matrix product over rows sorted by group: `lax.ragged_dot`
+    on the cpu platform (the Pallas kernel is TPU-only), `_gmm` on any
+    other. XLA:TPU's own `ragged_dot` was compared on the chip and is the
+    slower of the two (25.4 against 22.1 ms for the cell's expert layer,
+    forward and backward; PERF.md section 6, PR 27); it also drops the
+    `moe.experts` scope from its kernels' `op_name`."""
+    return jax.lax.platform_dependent(rows, bank, sizes,
+                                      cpu=jax.lax.ragged_dot, default=_gmm)
+
+
+# The sorted buffer holds one row per (token, choice) pair: row p is pair
+# order[p], pair q sits at row inv[q], pair q = j * T + t is token t's j-th
+# choice (choice-major, so that the pairs' (k, T, F) view splits the leading
+# axis: token-major, the (T, k, F) view cost a 0.9 ms re-layout each way).
+# Moving rows between the two orders is a permutation, whose transpose is
+# the inverse permutation: a gather both ways. Left to
+# `jnp.take`'s own transpose rule the backward pass is a scatter-add of
+# T * k rows, which XLA:TPU runs an order of magnitude below a gather's
+# speed (PERF.md section 6, PR 27).
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k):
+    """(T, F) tokens -> (k * T, F) rows in sorted order."""
+    return jnp.take(x, order % x.shape[0], axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inv, k):
+    """(k * T, F) rows in sorted order -> (T, F): each token's k rows
+    summed (in float32). The transpose of `_dispatch`."""
+    back = jnp.take(rows, inv, axis=0).reshape(k, -1, rows.shape[1])
+    return jnp.sum(back.astype(jnp.float32), axis=0).astype(rows.dtype)
+
+
+_dispatch.defvjp(
+    lambda x, order, inv, k: (_dispatch(x, order, inv, k), (order, inv)),
+    lambda k, res, g: (_combine(g, *res, k), None, None))
+_combine.defvjp(
+    lambda rows, order, inv, k: (_combine(rows, order, inv, k),
+                                 (order, inv)),
+    lambda k, res, g: (_dispatch(g, *res, k), None, None))
+
+
+@jax.custom_vjp
+def _permute(values, perm, inverse):
+    """values[perm] for a permutation and its inverse."""
+    return jnp.take(values, perm, axis=0)
+
+
+_permute.defvjp(
+    lambda values, perm, inverse: (jnp.take(values, perm, axis=0),
+                                   (perm, inverse)),
+    lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
+
+
+def route(logits: jnp.ndarray, top_k: int):
+    """(T, E) router logits -> (weights (T, k) float32, expert ids (T, k)):
+    the k largest logits, softmax over those in float32 (which equals
+    softmax over all, select, renormalise)."""
+    top, ids = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+    return jax.nn.softmax(top, axis=-1), ids
+
+
+def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
+                 top_k: int, first_expert: int = 0):
+    """x, router_in: (T, F) -> (y (T, F), rows (E_held,) float32).
+
+    params: `gate` (F, E) scores every expert; the banks `w1`, `w3`
+    (E_held, F, H) and `w2` (E_held, H, F) are experts first_expert ..
+    first_expert + E_held - 1, gated ReLU units without biases:
+    (relu(x w1) * (x w3)) w2. y is the sum over a token's chosen experts
+    THAT ARE HELD of weight * expert(x): what absent experts would add is
+    left out and the weights are not renormalised over the held ones.
+    rows[e] counts the (token, choice) pairs held expert e received.
+
+    Every (token, choice) pair gets a row of the sorted buffer, so shapes
+    are static at top_k * T rows; pairs routed to absent experts sort
+    after the last group, where the grouped products do no work."""
+    held = params["w1"].shape[0]
+    with jax.named_scope(ROUTE):
+        logits = jnp.dot(router_in, params["gate"],
+                         preferred_element_type=jnp.float32)
+        weights, ids = route(logits, top_k)
+    with jax.named_scope(DISPATCH):
+        local = ids.T.reshape(-1) - first_expert
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        live = local[order] < held
+        sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+        # rows past the last group are never written by the grouped
+        # products, forward or backward: the select keeps what the
+        # backward pass leaves there out of the tokens' gradient
+        xs = jnp.where(live[:, None], _dispatch(x, order, inv, top_k), 0)
+    with jax.named_scope(EXPERTS):
+        h = jax.nn.relu(grouped_dot(xs, params["w1"], sizes)) \
+            * grouped_dot(xs, params["w3"], sizes)
+        ys = grouped_dot(h, params["w2"], sizes)
+    with jax.named_scope(COMBINE):
+        # a select, not a product, and before the weighting: rows past
+        # the last group hold whatever the grouped products left there,
+        # and 0 * NaN would carry it into the router's gradient
+        w = _permute(weights.T.reshape(-1), order, inv)
+        ys = jnp.where(live[:, None], ys, 0) * w[:, None].astype(ys.dtype)
+        y = _combine(ys, order, inv, top_k)
+    return y, sizes.astype(jnp.float32)

@@ -459,6 +459,19 @@ class AttentionParameter(Message):
     bias_term: bool = True
     weight_filler: FillerParameter | None = None
     bias_filler: FillerParameter | None = None
+    # grouped key/value heads: query head n reads key/value head
+    # n // (num_heads / num_kv_heads). 0 = as many as query heads
+    num_kv_heads: int = 0
+    # head size; 0 = channels / num_heads. When set, the projections are
+    # ((num_heads + 2 num_kv_heads) * head_dim, C) and (C, num_heads *
+    # head_dim), which need not be square
+    head_dim: int = 0
+    # sliding window: key j is visible to query i iff i - window < j <= i
+    # (with causal). 0 = no window
+    window: int = 0
+    # rotary position embedding over the whole head (rotate-half
+    # convention), positions 0..S-1 in each sequence. 0 = no positions
+    rope_theta: float = 0.0
 
 
 @dataclass
@@ -477,6 +490,13 @@ class LayerNormParameter(Message):
 
 
 @dataclass
+class RMSNormParameter(Message):
+    """TPU-native extension: x / sqrt(mean(x^2) + eps) over the trailing
+    axis, statistics in float32, times a learnable scale (no bias)."""
+    eps: float = 1e-6
+
+
+@dataclass
 class MoEParameter(Message):
     """TPU-native extension (no reference analogue — SURVEY §2.7: EP
     absent): mixture-of-experts FFN with top-k routing and capacity,
@@ -487,6 +507,19 @@ class MoEParameter(Message):
     top_k: int = 1
     capacity_factor: float = 2.0
     weight_filler: FillerParameter | None = None
+    # no capacity and no dropped token: the top_k largest router logits,
+    # softmax over those, rows sorted by expert, grouped matrix products
+    # over gated ReLU experts of three unbiased matrices, (relu(x w1) *
+    # (x w3)) w2 (ops/moe.py moe_dropless). A second bottom, when given,
+    # is what the router scores; the second top is the rows each held
+    # expert received
+    dropless: bool = False
+    # the share of an expert-parallel deployment this layer holds: experts
+    # first_expert .. first_expert + experts_held - 1 of num_experts. The
+    # router keeps num_experts outputs; the result is the held experts'
+    # part. 0 = all of them
+    experts_held: int = 0
+    first_expert: int = 0
 
 
 @dataclass
@@ -824,6 +857,7 @@ class LayerParameter(Message):
     eltwise_param: EltwiseParameter | None = None
     moe_param: MoEParameter | None = None
     layer_norm_param: LayerNormParameter | None = None
+    rms_norm_param: RMSNormParameter | None = None
     parameter_param: ParameterParameter | None = None
     elu_param: ELUParameter | None = None
     embed_param: EmbedParameter | None = None

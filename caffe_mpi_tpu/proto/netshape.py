@@ -72,10 +72,10 @@ BF16_ELIGIBLE = frozenset({
     "HDF5Data", "HingeLoss", "Im2col", "ImageData", "InfogainLoss",
     "InnerProduct", "Input", "L1Loss", "LRN", "LayerNorm", "Log", "MVN",
     "MemoryData", "MoE", "MultinomialLogisticLoss", "PReLU", "Parameter",
-    "Pipeline", "Pooling", "Power", "ReLU", "Reduction", "Reshape",
-    "SPP", "Scale", "Sigmoid", "SigmoidCrossEntropyLoss", "Silence",
-    "Slice", "Softmax", "SoftmaxWithLoss", "Split", "TanH", "Threshold",
-    "Tile", "WindowData",
+    "Pipeline", "Pooling", "Power", "RMSNorm", "ReLU", "Reduction",
+    "Reshape", "SPP", "Scale", "Sigmoid", "SigmoidCrossEntropyLoss",
+    "Silence", "Slice", "Softmax", "SoftmaxWithLoss", "Split", "TanH",
+    "Threshold", "Tile", "WindowData",
 })
 
 # layer types whose first top defaults to loss_weight 1 (losses.py
@@ -819,6 +819,13 @@ def _layer_norm(ctx):
     return [s]
 
 
+@rule("RMSNorm")
+def _rms_norm(ctx):
+    s = ctx.in_shapes[0]
+    ctx.declare("scale", (None if s is None or not s else s[-1],))
+    return [s]
+
+
 # -- activations (activations.py): all elementwise passthrough --------------
 
 @rule("ReLU", "ELU", "Sigmoid", "TanH", "BNLL", "Power", "Exp", "Log",
@@ -1149,14 +1156,28 @@ def _attention(ctx):
         return [None]
     c = s[2]
     heads = max(p.num_heads, 1)
-    if c is not None and c % heads:
+    kv = p.num_kv_heads or heads
+    if p.head_dim == 0 and c is not None and c % heads:
         ctx.problem("shape",
                     f"channels {c} not divisible by num_heads {p.num_heads}")
-    c3 = None if c is None else 3 * c
-    ctx.declare("qkv_weight", (c3, c))
-    ctx.declare("proj_weight", (c, c))
+    if heads % kv:
+        ctx.problem("shape", f"num_heads {heads} not a multiple of "
+                             f"num_kv_heads {kv}")
+    if p.window and not p.causal:
+        ctx.problem("shape", "attention_param window needs causal: true")
+    if p.sequence_parallel and (p.window or kv != heads):
+        ctx.problem("shape", "sequence_parallel attention has neither a "
+                             "window nor grouped key/value heads")
+    hd = p.head_dim or (None if c is None else c // heads)
+    if p.rope_theta and hd is not None and hd % 2:
+        ctx.problem("shape",
+                    f"rotary positions over an odd head size {hd}")
+    nq = None if hd is None else heads * hd
+    nqkv = None if hd is None else (heads + 2 * kv) * hd
+    ctx.declare("qkv_weight", (nqkv, c))
+    ctx.declare("proj_weight", (c, nq))
     if p.bias_term:
-        ctx.declare("qkv_bias", (c3,))
+        ctx.declare("qkv_bias", (nqkv,))
         ctx.declare("proj_bias", (c,))
     return [s]
 
@@ -1169,14 +1190,32 @@ def _moe(ctx):
         return [None] * len(ctx.lp.top)
     s = ctx.in_shapes[0]
     c = None if s is None or not s else s[-1]
+    held = p.experts_held or p.num_experts
+    if not 0 <= p.first_expert <= p.num_experts - held:
+        ctx.problem("shape",
+                    f"experts {p.first_expert}.."
+                    f"{p.first_expert + held - 1} are not among "
+                    f"num_experts {p.num_experts}")
+    if not p.dropless and (held != p.num_experts
+                           or len(ctx.in_shapes) > 1):
+        ctx.problem("shape", "moe_param: experts_held and a router bottom "
+                             "need dropless: true")
+    if len(ctx.in_shapes) > 1 and None not in (s, ctx.in_shapes[1]) \
+            and ctx.in_shapes[1] != s:
+        ctx.problem("shape", f"MoE router bottom {_fmt(ctx.in_shapes[1])} "
+                             f"!= {_fmt(s)}")
     ctx.declare("gate", (c, p.num_experts))
-    ctx.declare("w1", (p.num_experts, c, p.hidden_dim))
-    ctx.declare("b1", (p.num_experts, p.hidden_dim))
-    ctx.declare("w2", (p.num_experts, p.hidden_dim, c))
-    ctx.declare("b2", (p.num_experts, c))
+    ctx.declare("w1", (held, c, p.hidden_dim))
+    if not p.dropless:
+        ctx.declare("b1", (held, p.hidden_dim))
+    ctx.declare("w2", (held, p.hidden_dim, c))
+    if p.dropless:
+        ctx.declare("w3", (held, c, p.hidden_dim))
+    else:
+        ctx.declare("b2", (held, c))
     tops = [s]
     if len(ctx.lp.top) > 1:
-        tops.append(())
+        tops.append((held,) if p.dropless else ())
     return tops
 
 
@@ -1313,7 +1352,17 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         if s0 is None or len(s0) != 3 or not _known(*s0[1:]):
             return None
         _, s, c = s0
-        return 4 * s * c * c + 2 * s * s * c
+        p = getattr(lp, "attention_param", None) if lp is not None else None
+        heads = max(getattr(p, "num_heads", 1), 1)
+        kv = getattr(p, "num_kv_heads", 0) or heads
+        hd = getattr(p, "head_dim", 0) or c // heads
+        # the four projections, then scores and values over the pairs the
+        # mask leaves (a causal window of w: w keys a query, fewer at the
+        # start)
+        w = min(getattr(p, "window", 0) or s, s)
+        pairs = (w * (w + 1) // 2 + (s - w) * w) \
+            if getattr(p, "causal", False) else s * s
+        return s * c * (2 * heads + 2 * kv) * hd + 2 * pairs * heads * hd
     if type_name == "MoE":
         s0 = in_shapes[0] if in_shapes else None
         w1 = param_shapes.get("w1")
@@ -1322,10 +1371,16 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         tokens = _prod(s0[1:-1]) if len(s0) > 2 else 1
         c = s0[-1]
         e, _, h = w1
-        k = max(getattr(getattr(lp, "moe_param", None), "top_k", 1), 1) \
-            if lp is not None else 1
-        return None if not _known(tokens, c) \
-            else tokens * (c * e + k * 2 * c * h)
+        p = getattr(lp, "moe_param", None) if lp is not None else None
+        k = max(getattr(p, "top_k", 1), 1)
+        if not _known(tokens, c):
+            return None
+        if p is not None and p.dropless:
+            # a token's k choices fall on the held experts with
+            # probability held / num_experts each; three matrices
+            return tokens * c * p.num_experts + (
+                tokens * k * e * 3 * c * h // p.num_experts)
+        return tokens * (c * e + k * 2 * c * h)
     return 0
 
 

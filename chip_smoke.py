@@ -347,6 +347,8 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
       over tiles in a different order (first chip run, PR 21: 3e-3 to
       5e-3 observed).
     - flash bf16 inputs, 5e-2: as above plus bf16 I/O rounding.
+    - dropless experts bf16, 5e-2: three bf16 products in a row and a
+      bf16 intermediate against an f32 loop over the same bf16 weights.
     """
     import jax
     import jax.numpy as jnp
@@ -419,6 +421,73 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
         f"flash forward S={s_long} d=128 f32",
         flash(False), plain(False),
         qkv(1, s_long, 2, 128, jnp.float32), 2e-2, on_chip)
+
+    # the published-width shapes of the benchmark's language-model cell
+    # (models/smallthinker_21b_a3b): 28 query heads over 4 key/value heads
+    # of 128 with a window, and the dropless expert layer (6 of 64 experts
+    # a token, 16 held, gated ReLU experts 2560 -> 768 -> 2560)
+    s_win = 2048 // scale
+    q = jnp.asarray(rng.randn(1, s_win, 28, 128).astype(np.float32),
+                    jnp.bfloat16)
+    kv = tuple(jnp.asarray(rng.randn(1, s_win, 4, 128).astype(np.float32),
+                           jnp.bfloat16) for _ in range(2))
+    window = s_win // 2
+
+    def windowed(flash):
+        def f(q, k, v):
+            if not flash:
+                q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+            return attention(q, k, v, causal=True, window=window,
+                             use_flash=flash).astype(jnp.bfloat16)
+        return f
+    results[f"flash-window{window}-28over4-s{s_win}-d128-bf16"] = \
+        _check_kernel(f"flash window {window} 28/4 heads S={s_win} bf16",
+                      with_grad(windowed(True)), with_grad(windowed(False)),
+                      (q, *kv), 5e-2, on_chip)
+
+    from caffe_mpi_tpu.ops.moe import moe_dropless
+    d_model, width, held = 2560 // scale, 768 // scale, 16
+    tokens = 2048 // scale
+    moe_params = {
+        "gate": jnp.asarray(rng.randn(d_model, 64) * 0.02, jnp.float32),
+        **{name: jnp.asarray(rng.randn(*shape) * 0.02, jnp.float32)
+           for name, shape in (("w1", (held, d_model, width)),
+                               ("w3", (held, d_model, width)),
+                               ("w2", (held, width, d_model)))}}
+    x_moe = jnp.asarray(rng.randn(tokens, d_model), jnp.float32)
+
+    def experts_sorted(params, x):
+        cast = {k: v.astype(jnp.bfloat16) for k, v in params.items()}
+        xb = x.astype(jnp.bfloat16)
+        y, rows = moe_dropless(cast, xb, xb, top_k=6, first_expert=16)
+        return y.astype(jnp.float32), rows
+
+    def experts_looped(params, x):
+        # the same choices (bf16 router product), then each held expert
+        # over every token in f32, masked
+        xb = x.astype(jnp.bfloat16)
+        logits = jnp.dot(xb, params["gate"].astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)
+        top, ids = lax.top_k(logits, 6)
+        w = jax.nn.softmax(top, axis=-1)
+        x32 = xb.astype(jnp.float32)
+        f32 = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+        y = jnp.zeros_like(x32)
+        rows = []
+        with jax.default_matmul_precision("highest"):
+            for e in range(held):
+                chosen = ids == 16 + e
+                w_e = jnp.sum(jnp.where(chosen, w, 0.0), -1)
+                out = (jax.nn.relu(x32 @ f32(params["w1"][e]))
+                       * (x32 @ f32(params["w3"][e]))) @ f32(params["w2"][e])
+                y = y + w_e[:, None] * out
+                rows.append(jnp.sum(chosen).astype(jnp.float32))
+        return y, jnp.stack(rows)
+    results[f"moe-dropless-6of64-held16-t{tokens}-bf16"] = _check_kernel(
+        f"dropless experts, {tokens} tokens, 16 of 64 held",
+        with_grad(lambda p, x: experts_sorted(p, x)[0]),
+        with_grad(lambda p, x: experts_looped(p, x)[0]),
+        (moe_params, x_moe), 5e-2, on_chip)
 
     n_dev = len(jax.devices())
     if n_dev >= 4:

@@ -877,6 +877,116 @@ def transformer_lm(batch=8, seq=64, vocab=256, dim=128, heads=4,
     return n
 
 
+SMALLTHINKER = dict(
+    # https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct
+    # config.json: widths as published; depth, experts held and vocabulary
+    # are one chip's share (benchmarks/configs/smallthinker_21b_a3b.json)
+    seq=8192, vocab=37984, dim=2560, heads=28, kv_heads=4, head_dim=128,
+    window=4096, rope_theta=1.5e6, window_layout=(0, 1, 1, 1),
+    rope_layout=(0, 1, 1, 1), experts=64, experts_held=16, top_k=6,
+    expert_width=768, eps=1e-6)
+# the size of tests/test_smallthinker.py and of the benchmark's CPU
+# rehearsal: every mechanism, no width
+SMALLTHINKER_TINY = dict(
+    seq=32, vocab=64, dim=64, heads=4, kv_heads=2, head_dim=16, window=8,
+    rope_theta=1.5e6, window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+    experts=8, experts_held=2, top_k=2, expert_width=32, eps=1e-6)
+
+
+def smallthinker(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim,
+                 window, rope_theta, window_layout, rope_layout, experts,
+                 experts_held, top_k, expert_width, eps, first_expert=0,
+                 use_flash=True, name="smallthinker_21b_a3b"):
+    """SmallThinker-21BA3B-Instruct (arXiv:2507.20984), one chip's share:
+    pre-norm blocks of grouped-head attention (global layers without
+    positions, windowed layers with rotary ones) and a dropless top-k
+    expert layer of gated ReLU experts whose router reads the block's
+    INPUT, before the attention norm. No biases, no q/k norm, no shared
+    expert, untied embedding and head. The layer equations are written
+    out in benchmarks/reference/lm_ref.py."""
+    filler = dict(type="gaussian", std=0.02)
+    n = NetSpec(name)
+    n.tokens, n.label = L.Input(ntop=2, input_param=dict(
+        shape=[dict(dim=[batch, seq]), dict(dim=[batch, seq])]))
+    # the table alone is unit normal (an embedding's usual start): the
+    # blocks act on normalised inputs, so what they add to the residual
+    # stream has a fixed size, and most of what attention adds is common
+    # to every token (a softmax average keeps the tokens' common part and
+    # averages their own away). Against rows of norm 1 (std 0.02) that
+    # common part wins by the third layer, every token's router, which
+    # reads the stream un-normed, picks the same six experts, and whether
+    # they are among the 16 held is a draw of the seed (PERF.md, PR 27)
+    n.embed = L.Embed(n.tokens, input_dim=vocab, num_output=dim,
+                      bias_term=False,
+                      weight_filler=dict(type="gaussian", std=1.0))
+    x = n.embed
+    for b, (windowed, rotary) in enumerate(zip(window_layout, rope_layout)):
+        ln1 = L.RMSNorm(x, eps=eps)
+        setattr(n, f"blk{b}/ln1", ln1)
+        attn = L.Attention(
+            ln1, num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+            causal=True, use_flash=use_flash, bias_term=False,
+            window=window if windowed else 0,
+            rope_theta=rope_theta if rotary else 0.0, weight_filler=filler)
+        setattr(n, f"blk{b}/attn", attn)
+        res1 = L.Eltwise(x, attn)
+        setattr(n, f"blk{b}/res1", res1)
+        ln2 = L.RMSNorm(res1, eps=eps)
+        setattr(n, f"blk{b}/ln2", ln2)
+        # second bottom: what the router scores (the block's input); second
+        # top: rows each held expert received, read by checks, no loss.
+        # Routing is a constant of the step: the router (`gate`, the first
+        # blob) is frozen and nothing trains through it (no gradient into
+        # the second bottom). A share's router sees only the experts held
+        # here lower the loss, and so does whatever feeds it: left to
+        # train, either way every row ends up here (49,152 a layer from
+        # 12,288 within ten iterations; PERF.md, PR 27), where in the
+        # deployment the other chips' experts pull as hard
+        moe, rows = L.MoE(ln2, x, ntop=2, loss_weight=[0.0, 0.0],
+                          param=[dict(lr_mult=0, decay_mult=0)],
+                          propagate_down=[True, False],
+                          moe_param=dict(
+                              num_experts=experts, hidden_dim=expert_width,
+                              top_k=top_k, dropless=True,
+                              experts_held=experts_held,
+                              first_expert=first_expert,
+                              weight_filler=filler))
+        setattr(n, f"blk{b}/moe", moe)
+        setattr(n, f"blk{b}/moe_rows", rows)
+        res2 = L.Eltwise(res1, moe)
+        setattr(n, f"blk{b}/res2", res2)
+        x = res2
+    n.ln_f = L.RMSNorm(x, eps=eps)
+    n.logits = L.InnerProduct(n.ln_f, num_output=vocab, axis=2,
+                              bias_term=False, weight_filler=filler)
+    n.loss = L.SoftmaxWithLoss(n.logits, n.label,
+                               softmax_param=dict(axis=2))
+    n.accuracy = L.Accuracy(n.logits, n.label, axis=2,
+                            include=dict(phase="TEST"))
+    return n
+
+
+def smallthinker_solver(net: str, prefix: str) -> str:
+    return f"""# SmallThinker-21BA3B-Instruct, one chip's share: Adam (this
+# system's coupled L2, none set), fixed 3e-4, global-norm clip 1
+net: "models/smallthinker_21b_a3b/{net}"
+base_lr: 0.0003
+lr_policy: "fixed"
+display: 10
+max_iter: 10000
+momentum: 0.9
+momentum2: 0.95
+type: "Adam"
+clip_gradients: 1.0
+# static: bf16 has float32's exponent range, and the dynamic scale's
+# skip-step guard keeps the old and the new weights and Adam slots alive
+# side by side, 7.9 GB more than a 16 GB chip has beside them
+loss_scale: 1.0
+snapshot: 10000
+snapshot_prefix: "models/smallthinker_21b_a3b/{prefix}"
+"""
+
+
 def transformer_lm_pp_prototxt(batch=8, seq=64, vocab=256, dim=128, heads=4,
                                n_stages=4, micro_batches=4, ffn_hidden=256):
     """Pipeline-parallel transformer_lm variant: the trunk is ONE Pipeline
@@ -1256,6 +1366,23 @@ def main():
         with open(os.path.join(d, "deploy.prototxt"), "w") as f:
             f.write(make_deploy(tv) + "\n")
         print(f"wrote models/{name}/")
+
+    # smallthinker_21b_a3b: the benchmark's recipe at the published widths
+    # and the tiny one its CPU rehearsal and tests/test_smallthinker.py
+    # run (train only: no deploy net, the serving path has no key/value
+    # cache yet)
+    d = os.path.join(out_root, "smallthinker_21b_a3b")
+    os.makedirs(d, exist_ok=True)
+    for net, solver, sizes in (
+            ("train_val.prototxt", "solver.prototxt", SMALLTHINKER),
+            ("tiny_train_val.prototxt", "tiny_solver.prototxt",
+             SMALLTHINKER_TINY)):
+        with open(os.path.join(d, net), "w") as f:
+            f.write(smallthinker(**sizes).to_prototxt() + "\n")
+        with open(os.path.join(d, solver), "w") as f:
+            f.write(smallthinker_solver(net, net.split("train_val")[0]
+                                        + "smallthinker"))
+    print("wrote models/smallthinker_21b_a3b/")
 
     # transformer_lm model-parallel variants: PP trunk (Pipeline layer)
     # and SP attention (sequence_parallel: true), each launchable from one

@@ -7,8 +7,10 @@ MXU; Bias/Scale broadcast arithmetic is fused by XLA into neighboring ops.
 
 from __future__ import annotations
 
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 
 from ..proto.config import FillerParameter
@@ -43,10 +45,50 @@ class InnerProductLayer(Layer):
         return [y.reshape(*lead, self.p.num_output)], state
 
 
+# `_lookup`'s backward pass sums equal ids' rows by a matrix product whose
+# cost is known, 2 T^2 F FLOPs for T tokens of F features (x 4 MXU passes
+# in float32, measured), where the cost of XLA:TPU's row scatter-add is not:
+# on the v5e it took 0.4-3.4 ms at most shapes and 15-17 ms from 6,144 to
+# 32,768 rows of 2,560 bf16 features into 37,984 (PERF.md section 6, PR 28).
+# The product is taken while the chip's peak (197e12 FLOP/s) would run it in
+# under half of those 15 ms, so that neither choice can lose more than about
+# 7 ms; past that `jnp.take`'s own transpose rule stands. The language-model
+# cell is at 1.7e11 (2.8 against 15.1 ms).
+_LOOKUP_PRODUCT_LIMIT = 0.5 * 15e-3 * 197e12 / 2     # T^2 F passes: 7.4e11
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lookup(table, ids, rows):
+    """table[ids] for flat ids into a (rows, F) table."""
+    return jnp.take(table, ids, axis=0)
+
+
+def _lookup_bwd(rows, ids, g):
+    """The table's gradient without a row scatter. Every token's row becomes
+    the float32 sum over all tokens of its id (a 0/1 compare matrix times
+    the cotangent, on the MXU), rounded once; a scalar scatter notes one
+    position for each id present, and the table's rows are gathered from
+    there, absent ids reading zero."""
+    count, width = g.shape
+    f32 = g.dtype == jnp.float32
+    if count * count * width * (4 if f32 else 1) > _LOOKUP_PRODUCT_LIMIT:
+        return jnp.zeros((rows, width), g.dtype).at[ids].add(g), None
+    same = (ids[:, None] == ids[None, :]).astype(g.dtype)
+    tot = jnp.dot(same, g, preferred_element_type=jnp.float32,
+                  precision="highest" if f32 else None).astype(g.dtype)
+    at = jnp.full((rows,), count, jnp.int32).at[ids].set(
+        jnp.arange(count, dtype=jnp.int32), mode="drop")
+    return jnp.take(tot, at, axis=0, mode="fill", fill_value=0), None
+
+
+_lookup.defvjp(lambda table, ids, rows: (_lookup(table, ids, rows), ids),
+               _lookup_bwd)
+
+
 @register("Embed")
 class EmbedLayer(Layer):
-    """Index lookup as one-hot matmul in the reference (embed_layer.cu);
-    here a plain take() gather."""
+    """Index lookup: a one-hot matmul in the reference (embed_layer.cu),
+    here a row gather whose backward pass is `_lookup_bwd`."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         p = self.lp.embed_param
@@ -59,7 +101,8 @@ class EmbedLayer(Layer):
 
     def apply(self, params, state, bottoms, *, train, rng):
         idx = bottoms[0].astype(jnp.int32)
-        y = jnp.take(self.f(params["weight"]), idx, axis=0)
+        y = _lookup(self.f(params["weight"]), idx.reshape(-1),
+                    self.p.input_dim).reshape(*idx.shape, -1)
         if self.p.bias_term:
             y = y + self.f(params["bias"])
         return [y], state

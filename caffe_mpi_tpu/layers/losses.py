@@ -14,6 +14,7 @@ Loss semantics that affect convergence parity and are reproduced exactly:
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -45,19 +46,6 @@ class LossBase(Layer):
         # first top of a *Loss layer carries weight 1 (layer.hpp SetLossWeights)
         return 1.0 if top_idx == 0 else 0.0
 
-    def _normalizer(self, mode: str, outer: int, full: int, valid):
-        """loss_layer.cpp GetNormalizer. `valid` may be a traced scalar."""
-        mode = mode.upper()
-        if mode == "FULL":
-            return float(full)
-        if mode == "VALID":
-            return jnp.maximum(valid.astype(jnp.float32), 1.0)
-        if mode == "BATCH_SIZE":
-            return float(outer)
-        if mode == "NONE":
-            return 1.0
-        raise ValueError(f"unknown loss normalization {mode!r}")
-
     def _norm_mode(self) -> str:
         p = self.lp.loss_param
         if p is None:
@@ -73,6 +61,67 @@ class LossBase(Layer):
         return p.ignore_label if p and p.has("ignore_label") else None
 
 
+def _normalizer(mode: str, outer: int, full: int, valid):
+    """loss_layer.cpp GetNormalizer. `valid` may be a traced scalar."""
+    mode = mode.upper()
+    if mode == "FULL":
+        return float(full)
+    if mode == "VALID":
+        return jnp.maximum(valid.astype(jnp.float32), 1.0)
+    if mode == "BATCH_SIZE":
+        return float(outer)
+    if mode == "NONE":
+        return 1.0
+    raise ValueError(f"unknown loss normalization {mode!r}")
+
+
+def _label_terms(x, labels, axis, ignore, mode):
+    """What both passes of `_softmax_nll` need of the labels: the 0/1 mark
+    of each position's label along the class axis (a compare against an
+    iota: XLA fuses it into its reader, nothing of the logits' size is
+    written), the mask of counted positions, and the normaliser."""
+    labels = jnp.expand_dims(
+        labels.reshape(x.shape[:axis] + x.shape[axis + 1:]), axis)
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) == labels
+    mask = jnp.ones(labels.shape, bool) if ignore is None else labels != ignore
+    norm = _normalizer(mode, x.shape[0], labels.size, jnp.sum(mask))
+    return hit, mask, norm
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _softmax_nll(x, labels, axis, ignore, mode):
+    """Normalised sum over positions of -log softmax(x)[label], in float32
+    from logits of any float type. Differentiated by jax this is a
+    `take_along_axis` whose transpose zero-fills a float32 buffer of the
+    logits' size and scatters into it, on top of a float32 log-softmax kept
+    for the backward pass (1.24 GB and 14.6 ms a step in the language-model
+    cell; PERF.md section 6, PR 28). Here the forward pass keeps the logits
+    as they arrived and one log-sum-exp a position, and the backward pass
+    is one fusion: (softmax - label mark) * mask * g / norm, rounded once."""
+    return _softmax_nll_fwd(x, labels, axis, ignore, mode)[0]
+
+
+def _softmax_nll_fwd(x, labels, axis, ignore, mode):
+    hit, mask, norm = _label_terms(x, labels, axis, ignore, mode)
+    xf = x.astype(jnp.float32)
+    top = jnp.max(xf, axis=axis, keepdims=True)
+    lse = top + jnp.log(jnp.sum(jnp.exp(xf - top), axis=axis, keepdims=True))
+    picked = jnp.sum(jnp.where(hit, xf, 0.0), axis=axis, keepdims=True)
+    loss = jnp.sum(jnp.where(mask, lse - picked, 0.0)) / norm
+    return loss, (x, labels, lse)
+
+
+def _softmax_nll_bwd(axis, ignore, mode, res, g):
+    x, labels, lse = res
+    hit, mask, norm = _label_terms(x, labels, axis, ignore, mode)
+    p = jnp.exp(x.astype(jnp.float32) - lse)
+    dx = jnp.where(mask, (p - hit) * (g / norm), 0.0)
+    return dx.astype(x.dtype), None
+
+
+_softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
+
+
 @register("SoftmaxWithLoss")
 class SoftmaxWithLossLayer(LossBase):
     """Fused log-softmax + NLL (softmax_loss_layer.cpp). Second top, when
@@ -86,27 +135,12 @@ class SoftmaxWithLossLayer(LossBase):
         return tops
 
     def apply(self, params, state, bottoms, *, train, rng):
-        logits = self.f(bottoms[0]).astype(jnp.float32)
-        labels = bottoms[1].astype(jnp.int32)
-        axis = self.axis
-        log_p = jax.nn.log_softmax(logits, axis=axis)
-        # gather the label channel: move class axis last, one-hot-free take
-        lp_last = jnp.moveaxis(log_p, axis, -1)
-        labels_flat = labels.reshape(lp_last.shape[:-1])
-        nll = -jnp.take_along_axis(lp_last, labels_flat[..., None], axis=-1)[..., 0]
-        ignore = self._ignore_label()
-        if ignore is not None:
-            mask = labels_flat != ignore
-            nll = jnp.where(mask, nll, 0.0)
-            valid = jnp.sum(mask)
-        else:
-            valid = jnp.asarray(nll.size)
-        outer = logits.shape[0]
-        norm = self._normalizer(self._norm_mode(), outer, nll.size, valid)
-        loss = jnp.sum(nll) / norm
-        tops = [loss]
+        logits = self.f(bottoms[0])
+        tops = [_softmax_nll(logits, bottoms[1].astype(jnp.int32), self.axis,
+                             self._ignore_label(), self._norm_mode())]
         if len(self.lp.top) > 1:
-            tops.append(jnp.exp(log_p))
+            tops.append(jax.nn.softmax(logits.astype(jnp.float32),
+                                       axis=self.axis))
         return tops, state
 
 

@@ -16,10 +16,9 @@ Python-held stage of the host pipeline — while this module owns:
     the native plane declines (exotic variants: CMYK JPEG, alpha/16-bit
     PNG, GIF/BMP/...) decode here, so coverage never shrinks;
   * decode telemetry (`STATS`): per-path record counters read by
-    tools/bench_data's stage breakdown, bench.py's `ingest` block, and
-    tools/e2e_lmdb_train's run journal — and the counter the
-    decoded-record cache tests assert against (epoch 2 must decode
-    NOTHING).
+    tools/bench_data's stage breakdown and tools/e2e_lmdb_train's run
+    journal — and the counter the decoded-record cache tests assert
+    against (epoch 2 must decode NOTHING).
 
 Pixel contract everywhere: planar CHW, BGR channel order, uint8 —
 matching the reference's OpenCV decode (datasets.parse_datum's

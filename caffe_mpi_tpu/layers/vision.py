@@ -203,14 +203,14 @@ class LRNLayer(Layer):
         # direction) whenever the layer COMPUTES in bf16 — keyed on the
         # input dtype, so both the `precision: bf16` solver knob and the
         # pre-existing FLOAT16 prototxt variants (solver_fp16 recipes)
-        # take the kernels: any bf16 LRN is the same bandwidth offender
-        # (tools/mfu_analysis.py ranking), and neither bf16 spelling
-        # ever had a bitwise contract (in-kernel math is f32, so the
-        # kernels are if anything closer to the f32 reference than the
-        # lax-bf16 lowering they replace). The f32 default keeps the
-        # stock lax path below, bitwise. CAFFE_LRN_PALLAS=0 restores
-        # the old lax lowering for any dtype; =1 forces the kernels for
-        # any float dtype (the A/B lever mfu_analysis uses).
+        # take the kernels: any bf16 LRN is the same bandwidth-bound
+        # layer, and neither bf16 spelling ever had a bitwise contract
+        # (in-kernel math is f32, so the kernels are if anything closer
+        # to the f32 reference than the lax-bf16 lowering they replace).
+        # The f32 default keeps the stock lax path below, bitwise.
+        # CAFFE_LRN_PALLAS=0 restores the old lax lowering for any
+        # dtype; =1 forces the kernels for any float dtype (chip_smoke.py
+        # and benchmarks/run.py refuse to run with it set).
         knob = os.environ.get("CAFFE_LRN_PALLAS", "")
         use_pallas = (self.region != "WITHIN_CHANNEL" and x.ndim == 4
                       and knob != "0"

@@ -17,23 +17,10 @@ Output dim: floor((H + 2p - ((k-1)*dilation + 1)) / s) + 1 — conv uses floor
 
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 from jax import lax
 
 DN = lax.conv_dimension_numbers
-
-# Layout knob (hardware A/B): CAFFE_CONV_LAYOUT=NHWC routes every conv
-# through NHWC/HWIO dimension numbers with transposes at the op edges.
-# RESOLVED round 5 (docs/mfu_analysis.md): on identical AlexNet graphs
-# the NHWC emulation changes neither XLA-counted flops nor bytes and
-# only adds un-cancelled edge transposes, while the measured MFU sits at
-# the f32 bandwidth-bound roofline ceiling — layout is not the
-# bottleneck, HBM traffic is. Default: NCHW (Caffe's logical layout),
-# trusting XLA's TPU layout assignment for the physical tiling; the
-# knob stays for a live on-chip A/B.
-_NHWC = os.environ.get("CAFFE_CONV_LAYOUT", "").upper() == "NHWC"
 
 
 def conv_output_dim(size: int, kernel: int, pad: int, stride: int, dilation: int) -> int:
@@ -45,20 +32,6 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: tuple[int, int],
            pad: tuple[int, int], dilation: tuple[int, int] = (1, 1),
            groups: int = 1, precision: str | None = None) -> jnp.ndarray:
     """x: (N, Cin, H, W); w: (Cout, Cin/groups, kh, kw) -> (N, Cout, oh, ow)."""
-    if _NHWC:
-        xt = x.transpose(0, 2, 3, 1)
-        wt = w.transpose(2, 3, 1, 0)  # OIHW -> HWIO
-        dn = DN(xt.shape, wt.shape, ("NHWC", "HWIO", "NHWC"))
-        out = lax.conv_general_dilated(
-            xt, wt,
-            window_strides=stride,
-            padding=((pad[0], pad[0]), (pad[1], pad[1])),
-            rhs_dilation=dilation,
-            dimension_numbers=dn,
-            feature_group_count=groups,
-            precision=precision,
-        )
-        return out.transpose(0, 3, 1, 2)
     dn = DN(x.shape, w.shape, ("NCHW", "OIHW", "NCHW"))
     return lax.conv_general_dilated(
         x, w,

@@ -6,9 +6,9 @@ LRNComputeOutput / LRNComputeDiff) for the bf16 roofline offender case
 (ISSUE 9). LRN is pure bandwidth: ~zero MACs over N*C*H*W elements,
 and the stock lowering (reduce_window for the channel-window sum, a
 power, and reverse-mode AD re-materializing the scale) makes several
-full HBM passes over the activation per direction. tools/mfu_analysis.py
-ranks it the worst bandwidth-bound layer of the AlexNet bench config
-once bf16 lifts the convs toward MXU peak.
+full HBM passes over the activation per direction
+(`benchmarks/layer_metrics/lrn_ms_per_step.py` and `pallas_ms_per_step.py`
+read what it costs in the AlexNet cells; PERF.md section 5).
 
 Each direction is one kernel that reads its operands once and writes
 its result once: forward reads x and writes y; backward reads x and dy,

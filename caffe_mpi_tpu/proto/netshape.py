@@ -18,7 +18,9 @@ This module is THE single spelling of model-graph structure:
 - `analyze_net()` drives the netlint passes (tools/lint/netlint.py)
 - `tools/summarize.py` renders its per-layer records
 - `utils/flops.py::layer_macs_per_image` delegates to `macs_per_image`
-  here, so tools/mfu_analysis.py's roofline uses the same MAC model
+  here, so `caffe time`, the GPipe stage balancer and the benchmark's
+  `mfu` (checked against its references' own count in
+  benchmarks/tests/) use the same MAC model
 - `net.py` consumes `BF16_INELIGIBLE` (the bf16-eligibility registry)
 
 Every rule mirrors the corresponding layer's `setup()` in
@@ -1396,11 +1398,10 @@ def _dtype_bytes(type_name: str) -> int:
 
 def layer_footprint(info: LayerInfo) -> dict:
     """Per-layer forward+backward traffic estimate at the layer's
-    compute dtype (same model as tools/mfu_analysis.py layer_roofline:
-    fwd reads bottoms + writes tops; bwd re-reads bottoms plus the
-    tops' cotangents and writes bottom cotangents ~ 2x fwd; params at
-    f32 master, read fwd + read/write bwd). All quantities are per
-    declared batch; None where a dim is unknown."""
+    compute dtype: fwd reads bottoms + writes tops; bwd re-reads bottoms
+    plus the tops' cotangents and writes bottom cotangents ~ 2x fwd;
+    params at f32 master, read fwd + read/write bwd. All quantities are
+    per declared batch; None where a dim is unknown."""
     act_bytes = _dtype_bytes(info.fwd_type)
     n_in = 0
     for s in info.in_shapes:

@@ -54,7 +54,7 @@ class CompileCounter:
     """Counts XLA compiles the serving plane performs. Steady-state
     serving must never move it past the warmed bucket count — the
     zero-recompile claim is `count == warmed buckets`, asserted on CPU
-    (tests/test_serving.py) and reported by bench.py's serving block."""
+    (tests/test_serving.py) and by `caffe serve -smoke`."""
 
     def __init__(self):
         self.count = 0
@@ -938,8 +938,8 @@ class ServingEngine:
         its compiled bucket programs: the params tree is shape-identical
         across weight files of one architecture, so a hot swap is a
         host-side import + one device upload — never a recompile
-        (`compile_count` provably unchanged, the zero-recompile-swap
-        claim bench_serving measures).
+        (`compile_count` unchanged: tests/test_serving_resilience.py,
+        tools/serve_watch_smoke.py).
 
         The canary gate runs the smallest already-compiled bucket with
         the CANDIDATE weights before anything reaches the serving path:
@@ -1126,8 +1126,7 @@ class ServingEngine:
 
     def submit_bytes(self, name: str, data: bytes):
         """decode_request + submit_raw in one call — the library
-        spelling of the HTTP upload path (tools/bench_serving.py's
-        ingest phase drives exactly this). Sheds BEFORE decoding: an
+        spelling of the HTTP upload path. Sheds BEFORE decoding: an
         unhealthy engine must not burn host CPU per rejected upload
         (fast-fail is the breaker's whole point under overload)."""
         self._shed_if_unhealthy()

@@ -10,7 +10,8 @@ minutes of recompilation at fleet scale. This module makes the compiled
 executable the durable artifact of record (ISSUE 17).
 
 Design: after each bucket warm, `jax.experimental.serialize_executable`
-payloads (plus their pickled in/out tree defs) land in an on-disk bank,
+payloads (plus their pickled in/out tree defs and the ids of the devices
+the program was compiled for) land in an on-disk bank,
 one entry per **fingerprint** — sha256 over the normalized deploy
 prototxt text, the bucket size, the serve dtype, the program's output
 contract, and the runtime tag (jax + jaxlib versions, backend platform,
@@ -23,7 +24,10 @@ that falls back to a fresh compile, never a crash; a fingerprint
 mismatch (new jaxlib, edited prototxt, different device kind) misses
 silently the same way. Weights are program *inputs*, not part of the
 fingerprint — which is exactly why `-watch` hot-swaps stay
-bank-compatible.
+bank-compatible. An entry is loaded onto the devices it names, not onto
+every device the process sees (jax's default, which turns a one-device
+bucket program into one that wants a shard per device of a four-chip
+host); a process without those devices counts a miss and recompiles.
 
 The engine-level invariant extends PR 7's `compile_count ==
 warmed_buckets` to `compile_count == bank_misses` (and `compile_count +
@@ -120,6 +124,9 @@ class ProgramBank:
         self.stats = stats or BankStats()
         os.makedirs(self.path, exist_ok=True)
         self._runtime: str | None = None
+        # fingerprints whose committed entry this process could not use:
+        # store() replaces those instead of keeping "the committed one"
+        self._refused: set[str] = set()
 
     def runtime(self) -> str:
         """Memoized runtime tag — first call touches the backend, so
@@ -149,16 +156,21 @@ class ProgramBank:
                 self.stats.bump("misses")
             return None
         try:
+            import jax
             with open(entry, "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
+                payload, in_tree, out_tree, device_ids = pickle.load(f)
             from jax.experimental import serialize_executable as se
-            loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         # lint: ok(typed-failure) — any failure = counted miss + fresh
         # compile + repopulate: the bank contract (docs/serving.md)
         except Exception as e:  # noqa: BLE001 — any failure = recompile
             self.stats.bump("misses", "deserialize_failures")
+            self._refused.add(fp)
             log.warning("program bank: entry %s verified but failed to "
-                        "deserialize (%s); recompiling", entry, e)
+                        "deserialize (%r); recompiling", entry, e)
             return None
         self.stats.bump("hits")
         return loaded
@@ -169,9 +181,13 @@ class ProgramBank:
         manifest written LAST. Best-effort by contract."""
         entry = self.entry_path(fp)
         try:
+            import jax
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree),
+            device_ids = sorted({
+                d.id for s in jax.tree.leaves(compiled.input_shardings)
+                for d in s.device_set})
+            blob = pickle.dumps((payload, in_tree, out_tree, device_ids),
                                 protocol=pickle.HIGHEST_PROTOCOL)
         # lint: ok(typed-failure) — counted store_failure; serving
         # continues bank-less for this program by contract
@@ -182,10 +198,12 @@ class ProgramBank:
                         "continues bank-less for this program", fp, e)
             return False
         with _WRITE_LOCK:
-            if resilience.verify_file_manifest(entry) is not None:
+            if (fp not in self._refused
+                    and resilience.verify_file_manifest(entry) is not None):
                 # a concurrent warmer already published this program;
                 # both serializations are valid — keep the committed one
                 return True
+            self._refused.discard(fp)
             try:
                 with atomic_output(entry) as tmp:
                     with open(tmp, "wb") as f:

@@ -24,9 +24,9 @@ reads until a NEW iteration appears) and live-reloads each newly
      previous weights keep serving (`swap_canary_bad` site).
   3. **zero recompiles** — the swap is a host-side weight import + one
      device upload into shape-identical params; the compiled bucket
-     ladder is untouched, so p99 under live traffic holds across the
-     swap (bench_serving's swap-under-traffic phase measures exactly
-     this).
+     ladder is untouched (`compile_count` is asserted unchanged
+     across a swap in tests/test_serving_resilience.py; latency under
+     live traffic across a swap has not been measured).
 
 Sharded (.orbax) snapshot sets carry no flat `.caffemodel`, so the
 watcher logs-and-skips them — the flat formats are the serve feed.

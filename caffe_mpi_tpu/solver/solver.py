@@ -290,8 +290,9 @@ class Solver:
         # dispatch costs host time the device may sit idle for);
         # host_sync_count = display-boundary host materializations (one
         # per display line; the smoothed-loss and rate float()s block on
-        # the same chunk). bench.py reports both deltas over its timed
-        # region (dispatches_per_100_iters / host_syncs).
+        # the same chunk). The benchmark reads both deltas over its timed
+        # window (benchmarks/layer_metrics/dispatches_per_100_iters.py,
+        # host_syncs_per_100_iters.py).
         self.dispatch_count = 0
         self.host_sync_count = 0
         # evaluation telemetry (ISSUE 2): test_dispatch_count = eval
@@ -299,8 +300,8 @@ class Solver:
         # T-batch chunk; the classic fallback counts one per batch);
         # test_pass_count = test nets evaluated; eval_stall_ms = host
         # time the TRAIN loop lost to evaluation (boundary dispatch +
-        # harvest wait), the number the async pipeline exists to bound —
-        # bench.py reports test_dispatches_per_pass / eval_stall_ms.
+        # harvest wait), the number the async pipeline exists to bound
+        # (tests/test_fused_eval.py; tools/e2e_lmdb_train.py prints it).
         self.test_dispatch_count = 0
         self.test_pass_count = 0
         self.eval_stall_ms = 0.0
@@ -335,9 +336,9 @@ class Solver:
         # entry points when train_guard is on; _guard_prev defers the
         # host-side divergence check by one
         # dispatch so the async pipeline never blocks on the chunk it
-        # just launched. skipped_steps / guard_sync_count are the
-        # CPU-visible telemetry bench.py reports (the "guard is ~free"
-        # claim is measured, not asserted).
+        # just launched. skipped_steps / guard_sync_count are host
+        # counters the benchmark reads (benchmarks/drivers/train.py:
+        # skipped steps count as `failed`).
         # dynamic loss scaling (ISSUE 9) reuses the guard machinery: the
         # skip-step select is how an overflowed step is discarded, and
         # the scale/clean-window counters ride the same carry — so a
@@ -564,11 +565,11 @@ class Solver:
                 " (bf16 wire)" if self._precision == "bf16" else "")
 
     def reduction_stats(self) -> dict | None:
-        """Gradient-reduction telemetry for bench.py / the MULTICHIP
-        dryrun: the active bucket plan (mode 'bucketed'), or mode
-        'implicit' with the fallback reason when reduce_overlap could
-        not engage. None when training has no mesh (nothing to
-        reduce)."""
+        """Gradient-reduction telemetry (tests/test_reduction.py, the
+        MULTICHIP dryrun in __graft_entry__.py): the active bucket plan
+        (mode 'bucketed'), or mode 'implicit' with the fallback reason
+        when reduce_overlap could not engage. None when training has no
+        mesh (nothing to reduce)."""
         out = None
         if self._reduction is not None:
             out = self._reduction.stats()
@@ -586,9 +587,8 @@ class Solver:
             out["cross_host_collectives_per_step"] = (
                 out.get("collectives_per_step", 0) if hosts > 1 else 0)
             # ISSUE 19: a generation-managed run (min_hosts) reports
-            # WHICH hosts this generation spans — bench.py's MULTICHIP
-            # dryrun surfaces the per-generation host set alongside
-            # the collective counts
+            # WHICH hosts this generation spans, alongside the
+            # collective counts
             from ..parallel.mesh import cluster_generation
             gen = cluster_generation()
             if gen is not None:

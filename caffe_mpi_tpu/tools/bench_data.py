@@ -16,8 +16,7 @@ JPEG-encoded LMDB — the ImageNet-convert layout, where decode dominates
   * end-to-end Feeder img/s for the PIL path (CAFFE_NATIVE_DECODE=0),
     the fused native path, and the decoded-record cache's post-warmup
     epoch — the A/B the acceptance criterion quotes;
-All of it is CPU-only (no jax import), so bench.py embeds the JSON
-(`--json`) as its `ingest` block.
+All of it is CPU-only (no jax import); `--json` emits one JSON object.
 
 Usage:
     python -m caffe_mpi_tpu.tools.bench_data [-n 4096] [-batch 256] \
@@ -274,8 +273,7 @@ def main(argv=None) -> int:
                    "(0 = auto mode, the prototxt default) — shows "
                    "multi-core scaling of the host pipeline")
     p.add_argument("--json", action="store_true",
-                   help="emit one JSON object instead of text lines "
-                   "(bench.py embeds the `ingest` key)")
+                   help="emit one JSON object instead of text lines")
     p.add_argument("--ingest-only", action="store_true",
                    help="skip the classic backend sweep; just the "
                    "encoded-LMDB ingest section")
@@ -292,7 +290,6 @@ def main(argv=None) -> int:
     if not args.ingest_only:
         # the classic sweep's dataset (~800 MB at the defaults) — the
         # ingest section builds its own, so skip it under --ingest-only
-        # (bench.py runs that mode on every emit path)
         imgs, labels = _make_records(args.n, shape)
     iters = max(args.n // args.batch, 1)
     mode = "raw+aug staging" if args.device_transform else "host transform"

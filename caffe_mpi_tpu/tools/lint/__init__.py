@@ -31,7 +31,7 @@ Framework shape:
   (doc-drift, knob-drift — `self_waiving = True`) are exempt.
 - CLI: `python -m caffe_mpi_tpu.tools.lint [--select P,...] [--json]
   [--changed REF] [--no-stale] [--profile] [paths...]`; default paths
-  are the shipped tree (caffe_mpi_tpu/, tools/, bench.py); `--changed
+  are the shipped tree (caffe_mpi_tpu/, tools/); `--changed
   REF` lints only files named by `git diff --name-only REF` (plus
   explicit paths) for fast pre-commit runs — a typo'd ref is a usage
   error (exit 2), never a false-clean exit 0; exit 1 on any finding;
@@ -379,7 +379,7 @@ def repo_root() -> str:
     return os.path.dirname(pkg)
 
 
-DEFAULT_SCAN = ("caffe_mpi_tpu", "tools", "bench.py")
+DEFAULT_SCAN = ("caffe_mpi_tpu", "tools")
 
 
 def iter_py_files(paths: Iterable[str]) -> Iterator[str]:
@@ -432,7 +432,7 @@ def run_lint(paths: Iterable[str] | None = None,
         builds0 = BUILD_COUNT[0]
     if paths is None:
         # default-scan entries are filtered by existence (a fixture
-        # root need not model bench.py); EXPLICIT paths must exist —
+        # root need not model tools/); EXPLICIT paths must exist —
         # a typo'd CI path silently reporting "clean" is the one
         # failure mode a tripwire cannot afford
         paths = [p for p in (os.path.join(root, t) for t in DEFAULT_SCAN)

@@ -13,7 +13,7 @@ longer exists. Three sources of truth are held equal:
      importable, e.g. from a checkout with a broken module)
   2. the docs:     the `Sites:` list in docs/robustness.md
   3. the code:     literal site names at FAULTS helper call sites
-     under caffe_mpi_tpu/, tools/ and bench.py
+     under caffe_mpi_tpu/ and tools/
 
 Unlike the per-file passes this one always scans the tree rooted at
 the run root (`check_tree`), regardless of which paths were selected —

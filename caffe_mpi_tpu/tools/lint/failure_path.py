@@ -474,11 +474,10 @@ class ThreadCrashPass(LintPass):
 
 _DEADLINE_DIRS = ("tools/", "caffe_mpi_tpu/tools/",
                   "caffe_mpi_tpu/serving/", "caffe_mpi_tpu/solver/")
-_DEADLINE_FILES = ("bench.py",)
 
 
 def _deadline_scope(rel: str) -> bool:
-    return rel.startswith(_DEADLINE_DIRS) or rel in _DEADLINE_FILES
+    return rel.startswith(_DEADLINE_DIRS)
 
 
 @register

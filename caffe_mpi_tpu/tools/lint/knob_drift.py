@@ -14,7 +14,7 @@ performance knob to four legs at once:
                 train` surface — a knob users cannot reach from the
                 CLI is a solver-internal, not a knob)
   3. documented: named in docs/benchmarks.md (the perf-knob runbook)
-  4. consumed:  READ somewhere under caffe_mpi_tpu/ or bench.py
+  4. consumed:  READ somewhere under caffe_mpi_tpu/
                 outside the schema, the CLI plumbing, and this lint
                 package itself — a Load-context attribute access
                 `.knob` or a `"knob"` string literal passed as a call
@@ -77,7 +77,7 @@ DOCS_FILE = os.path.join("docs", "benchmarks.md")
 # `sp.knob = args.knob` is not consumption; the lint package excluded:
 # its own KNOBS registry naming every knob must not satisfy the leg it
 # enforces)
-CONSUMER_SCAN = ("caffe_mpi_tpu", "bench.py")
+CONSUMER_SCAN = ("caffe_mpi_tpu",)
 _EXCLUDED_CONSUMERS = (CONFIG_FILE, CLI_FILE)
 _EXCLUDED_CONSUMER_DIRS = (os.path.join("caffe_mpi_tpu", "tools", "lint"),)
 

@@ -4,10 +4,10 @@ Reference: tools/extra/summarize.py (concise per-layer table to check at a
 glance that the specified computation is the expected one). Earlier
 versions BUILT the net to report real shapes; since ISSUE 15 the table
 comes from the jax-free static shape engine (proto/netshape.py — the
-same records netlint and tools/mfu_analysis.py consume, cross-checked
-bitwise against the real build for the whole zoo), so summarize works
-without a device, without jax, and without datasets: dims a Data
-layer would learn from its DB print as '?'.
+same records netlint consumes, cross-checked bitwise against the real
+build for the whole zoo), so summarize works without a device, without
+jax, and without datasets: dims a Data layer would learn from its DB
+print as '?'.
 
 Usage:
     python -m caffe_mpi_tpu.tools.summarize NET.prototxt [-phase TRAIN|TEST]

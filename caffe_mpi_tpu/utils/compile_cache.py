@@ -11,12 +11,12 @@ directory is part of the cache key, so it must never move between runs:
 - unset: `<checkout>/.jax_cache`, computed from where this package
   lives (gitignored) — never from `~`, a temp name, a pid or the time.
 
-`caffe` (cli.py), the serving engine, bench.py and the tools all call
-`enable_compile_cache()` with no argument, so a bench child and
-`caffe train` share compiles. The same call puts the HLO metadata into
-the cache key: the scope names a profiler trace is read by
-(utils/spans.py) live there, and an entry compiled before a name changed
-must not be served after it.
+`caffe` (cli.py), the serving engine, chip_smoke.py, benchmarks/run.py
+and the tools all call `enable_compile_cache()` with no argument, so a
+benchmark run and `caffe train` share compiles. The same call puts the
+HLO metadata into the cache key: the scope names a profiler trace is read
+by (utils/spans.py) live there, and an entry compiled before a name
+changed must not be served after it.
 
 The reference has no analogue: it compiles ahead of time with nvcc and
 has no JIT compilation step to cache.

@@ -1,7 +1,7 @@
-"""Input-layer shape + synthetic-feed helpers shared by the bench tools
-(bench.py, tools/bench_models.py, tools/mfu_analysis.py) — one definition
-of "rewrite the Input batch dim and build matching feeds" instead of three
-drifting copies."""
+"""Input-layer shape + synthetic-feed helpers: one definition of "rewrite
+the Input batch dim and build matching feeds" (`caffe train -synthetic`
+shares the label-consumer table; tests/test_tpu_aot_compile.py and
+tests/test_multistep.py build their feeds here)."""
 
 from __future__ import annotations
 

@@ -9,9 +9,7 @@ deadline, stays off jax itself, and kills the child's whole process
 group AND reaps it on every exit path (an unreaped zombie pollutes the
 `ps` sweep an operator uses to find who holds the chip).
 
-Used by chip_smoke.py, tools/bench_models.py and the training
-supervisors (bench.py keeps subprocess.run: its child is the direct
-device process with no grandchildren, and run() reaps on timeout).
+Used by chip_smoke.py and the training supervisors (utils/resilience.py).
 """
 
 from __future__ import annotations
@@ -75,8 +73,8 @@ def run_contained(cmd: list[str], timeout: float | None,
     # child, leaking a chip-claiming orphan — the exact failure this
     # module exists to prevent. Caveat: pthread_sigmask masks THIS thread
     # only, so the window closes fully only for single-threaded callers
-    # (chip_smoke, bench_models — the ones that matter); a
-    # process-directed signal may still land on another unblocked thread.
+    # (chip_smoke — the one that matters); a process-directed signal may
+    # still land on another unblocked thread.
     _sigs = {signal.SIGTERM, signal.SIGINT, signal.SIGHUP}
     try:
         prev_mask = signal.pthread_sigmask(signal.SIG_BLOCK, _sigs)

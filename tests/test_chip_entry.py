@@ -1,9 +1,10 @@
 """How the program starts on a chip, held on the CPU (ISSUE 21).
 
 - compile-cache placement: one rule, identical from `caffe`, the serving
-  engine and bench.py (utils/compile_cache.py);
-- chip_smoke.py refuses a non-TPU platform by name, quickly, and its
-  parent — like every launcher of device children — never imports jax;
+  engine and the tools (utils/compile_cache.py);
+- chip_smoke.py and benchmarks/run.py refuse a non-TPU platform by
+  name; chip_smoke.py's parent — like every launcher of device
+  children — never imports jax;
 - an unknown accelerator has no peak and no MFU: `peak_flops` raises.
 
 (That interpret mode is the `cpu` platform's alone is held next to the
@@ -31,9 +32,8 @@ def _python(code: str, **env) -> subprocess.CompletedProcess:
 
 
 # the spellings that used to disagree: cli.main's device commands and
-# ServingEngine.__init__ (home directory) against bench.py and the tools
-# (<repo>/.jax_cache, passed as an argument — held statically below,
-# since bench.py's device child refuses to start without a TPU)
+# ServingEngine.__init__ (home directory) against the tools
+# (<repo>/.jax_cache, passed as an argument — held statically below)
 _CACHE_PROBE = """
 import json, sys
 import jax
@@ -85,11 +85,12 @@ class TestCompileCachePlacement:
         assert seen["cli"] is None
 
     def test_every_caller_uses_the_one_spelling(self):
-        """One function, no argument: bench.py, the tools and the
-        package cannot place the cache anywhere of their own."""
+        """One function, no argument: the smoke, the benchmark, the
+        tools and the package cannot place the cache anywhere of their
+        own."""
         import re
         callers = []
-        for top in ("bench.py", "chip_smoke.py", "tools", "caffe_mpi_tpu"):
+        for top in ("chip_smoke.py", "benchmarks", "tools", "caffe_mpi_tpu"):
             path = os.path.join(_ROOT, top)
             files = [path] if path.endswith(".py") else [
                 os.path.join(d, f) for d, _, fs in os.walk(path)
@@ -102,7 +103,8 @@ class TestCompileCachePlacement:
                         callers.append((os.path.relpath(f, _ROOT),
                                         m.group(1).strip()))
         assert {f for f, _ in callers} >= {
-            "bench.py", "caffe_mpi_tpu/tools/cli.py",
+            "chip_smoke.py", "benchmarks/run.py",
+            "caffe_mpi_tpu/tools/cli.py",
             "caffe_mpi_tpu/serving/engine.py"}
         assert all(arg == "" for _, arg in callers), callers
 
@@ -168,24 +170,21 @@ class TestLaunchersStayOffJax:
         r = _python(code)
         assert r.returncode == 0, r.stderr[-2000:]
 
-    @pytest.mark.parametrize("script", ["bench.py", "tools/bench_models.py"])
-    def test_bench_parents_exit_nonzero_without_a_tpu(self, script):
-        args = ["cifar10_quick"] if "models" in script else []
+    def test_the_benchmark_exits_nonzero_without_a_tpu(self):
+        """The one benchmark command runs in the process that holds the
+        chip (it imports jax itself: there is no parent to keep off it),
+        and off a TPU it names the platform and prints no result."""
+        env = {k: v for k, v in os.environ.items()
+               if not k.startswith("CAFFE_")}   # run.py refuses those first
         r = subprocess.run(
-            [sys.executable, "-X", "importtime",
-             os.path.join(_ROOT, script), *args],
-            env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=_ROOT,
+            [sys.executable, os.path.join(_ROOT, "benchmarks", "run.py"),
+             "--workload", "alexnet_f32", "--seed", "0", "--seconds", "1",
+             "--trace", "0"],
+            env=dict(env, JAX_PLATFORMS="cpu"), cwd=_ROOT,
             capture_output=True, text=True, timeout=300)
         assert r.returncode != 0, "no TPU must not be reported as success"
-        assert "cpu" in (r.stdout + r.stderr)
-        # -X importtime lists the PARENT's imports on stderr; the device
-        # child's own stderr is relayed as plain text without the
-        # "import time:" prefix
-        parent_imports = [l for l in r.stderr.splitlines()
-                          if l.startswith("import time:")]
-        assert parent_imports
-        assert not any(l.split("|")[-1].strip() == "jax"
-                       for l in parent_imports), "parent imported jax"
+        assert "'cpu'" in r.stderr and "No result" in r.stderr
+        assert '"correct"' not in r.stdout and '"metrics"' not in r.stdout
 
 
 class TestPeakFlops:

@@ -10,6 +10,9 @@ device work; tier-1 cheap.
 """
 
 import os
+import re
+
+import pytest
 
 from caffe_mpi_tpu.tools import lint
 
@@ -57,3 +60,58 @@ class TestLintCoverage:
                        "caffe_mpi_tpu/data/leveldb_io.py",
                        "caffe_mpi_tpu/utils/resilience.py"):
             assert needed in targets, needed
+
+
+# ---------------------------------------------------------------------------
+# the commands the documents tell a reader to run
+
+def _expand_braces(token: str) -> list[str]:
+    m = re.search(r"\{([^{}]*,[^{}]*)\}", token)
+    if m is None:
+        return [token]
+    return [t for alt in m.group(1).split(",")
+            for t in _expand_braces(token[:m.start()] + alt
+                                    + token[m.end():])]
+
+
+def _commands_under(path: str, heading: str) -> list[str]:
+    """Lines of the fenced blocks under `heading`, up to the next heading
+    of the same level, comments stripped."""
+    text = open(path, encoding="utf-8").read()
+    level = heading.split(" ")[0]
+    section = text.split(heading + "\n", 1)[1]
+    section = re.split(rf"^{level} ", section, maxsplit=1, flags=re.M)[0]
+    lines = []
+    for block in re.findall(r"^```[^\n]*\n(.*?)^```", section,
+                            flags=re.S | re.M):
+        for line in block.splitlines():
+            line = re.sub(r"(^|\s)#.*$", "", line).strip()
+            if line:
+                lines.append(line)
+    return lines
+
+
+class TestDocumentedCommands:
+    @pytest.mark.parametrize("doc,heading", [
+        ("README.md", "## Tests / bench"), ("CLAUDE.md", "## Commands")])
+    def test_every_documented_command_names_something_that_exists(
+            self, doc, heading):
+        """Every script path (`python x.py`, `chiprun .. python3 x.py`,
+        `x.sh`) and every `python -m module` in the document's command
+        block exists: the block cannot keep sending a reader to a tool
+        that was deleted."""
+        import importlib.util
+        lines = _commands_under(os.path.join(_ROOT, doc), heading)
+        assert len(lines) >= 5, f"{doc}: no command block under {heading!r}"
+        missing = []
+        for line in lines:
+            tokens = line.split()
+            for i, tok in enumerate(tokens):
+                if tok == "-m" and tokens[i - 1].startswith("python"):
+                    if importlib.util.find_spec(tokens[i + 1]) is None:
+                        missing.append((line, tokens[i + 1]))
+                elif re.fullmatch(r"[\w./{},-]+\.(py|sh)", tok):
+                    missing += [(line, p) for p in _expand_braces(tok)
+                                if not os.path.exists(
+                                    os.path.join(_ROOT, p))]
+        assert not missing, "\n".join(f"{p}: in `{l}`" for l, p in missing)

@@ -51,21 +51,6 @@ class TestConvolution:
                        dilation=2, groups=2)
         np.testing.assert_allclose(np.array(y), ref.numpy(), rtol=1e-4, atol=1e-5)
 
-    def test_nhwc_experiment_path_matches_nchw(self, rng, monkeypatch):
-        """The CAFFE_CONV_LAYOUT=NHWC hardware-A/B branch must stay
-        numerically identical to the default path — a silent divergence
-        would invalidate the layout experiment it exists for."""
-        from caffe_mpi_tpu.ops import conv as conv_ops
-        x = rand((2, 4, 9, 9), rng)
-        w = rand((6, 2, 3, 3), rng)
-        ref = conv_ops.conv2d(x, w, (2, 1), (1, 2), dilation=(2, 1),
-                              groups=2)
-        monkeypatch.setattr(conv_ops, "_NHWC", True)
-        out = conv_ops.conv2d(x, w, (2, 1), (1, 2), dilation=(2, 1),
-                              groups=2)
-        np.testing.assert_allclose(np.array(out), np.array(ref), rtol=1e-5,
-                                   atol=1e-6)
-
     def test_asymmetric_kernel_matches_torch(self, rng):
         # 1x7 kernel with asymmetric padding (inception_v3's factorized conv)
         layer, params, state = make_layer(

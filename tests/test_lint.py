@@ -93,9 +93,9 @@ def test_cli_nonexistent_path_is_usage_error_not_false_clean(capsys):
     assert "do not exist" in err
 
 
-def test_default_scan_tolerates_roots_without_bench(tmp_path):
+def test_default_scan_tolerates_roots_without_tools(tmp_path):
     """run_lint(root=fixture) must not crash when the root lacks
-    DEFAULT_SCAN entries like bench.py."""
+    DEFAULT_SCAN entries like tools/."""
     _write(tmp_path, "caffe_mpi_tpu/ok.py", """
         '''Replaces nothing.py:1 — fixture.'''
     """)

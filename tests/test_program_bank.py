@@ -102,7 +102,9 @@ class TestInvariant:
             assert st["hits"] == warm.warmed_buckets
             ok, doc = warm.ready()
             assert ok and doc["bank_hits"] == warm.warmed_buckets
-            # the deserialized program is the stored XLA program: scores
+            # the deserialized program is the stored XLA program, loaded
+            # onto the one device it was compiled for whatever the process
+            # sees (8 virtual devices here, 4 chips on a v5e host): scores
             # on the same inputs + weights are bitwise-identical
             out = warm.classify("m", imgs(5, seed=3))
             assert np.array_equal(np.asarray(ref), np.asarray(out))
@@ -205,8 +207,14 @@ class TestCorruption:
             assert st["deserialize_failures"] == 1
             assert st["verify_rejects"] == 0
             assert eng.compile_count == st["misses"] == 1
+            assert st["stores"] == 1  # the refused entry is replaced
         finally:
             eng.close()
+        warm = start(bank, model, weights)
+        try:
+            assert warm.compile_count == 0
+        finally:
+            warm.close()
 
     def test_fingerprint_mismatch_spoofed_runtime(self, tmp_path,
                                                   monkeypatch):

@@ -279,7 +279,7 @@ class TestEquivalence:
 
 
 # ---------------------------------------------------------------------------
-# Measurement surface (what bench.py / the MULTICHIP dryrun report)
+# Measurement surface (what the MULTICHIP dryrun reports)
 # ---------------------------------------------------------------------------
 
 class TestMeasurement:

@@ -11,9 +11,11 @@ create_imagenet.sh), points the real AlexNet topology's Data layers at
 it (crop 227 + mirror + mean subtraction — the reference training
 transform, data_transformer.cpp), trains N iterations with the same CLI
 path `caffe train` uses, and prints e2e img/s to compare against the
-synthetic-feed bench (bench.py, run on the same chip). The gap between the two IS
-the host-pipeline cost on this host (docs/benchmarks.md feeder table:
-~3.8k img/s/core staged, ~1.7k host-transform).
+synthetic-feed cell on the same chip (`python3 benchmarks/run.py
+--workload alexnet_f32`; PERF.md). The gap between the two is the
+host-pipeline cost on that host. No benchmark cell drives the Feeder
+yet (ROADMAP Reach 2), so neither side of that comparison is in the
+ledger.
 
 Usage: python tools/e2e_lmdb_train.py [--batch N] [--iters N] [--records N]
 Runs on whatever platform jax selects (pin the CPU with JAX_PLATFORMS=cpu).

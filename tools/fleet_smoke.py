@@ -26,7 +26,7 @@ smoke asserts the whole survivable story:
      unit level in tests/test_serving_fleet.py).
 
 Usage: python tools/fleet_smoke.py [--json] [--workdir D]
-Exit 0 iff every claim held. Run by bench_serving.py's `fleet` phase.
+Exit 0 iff every claim held.
 CPU-forced by design: it has not run on a chip.
 """
 

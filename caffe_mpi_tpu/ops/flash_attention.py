@@ -8,18 +8,28 @@ it in VMEM tiles with an online softmax, O(S) memory instead of O(S^2).
 
 Layout: (B, H, S, D) inside the kernels (sequence-minor tiles). The public
 entry accepts the framework's (B, S, H, D) and transposes at the edges.
-Tiles are 128 to 512 long (`_tile`, from the sequence length alone).
-Grouped heads: K and V have B*Hkv rows and query row i reads row i // (H /
-Hkv) through the block index map. A causal window joins the tile mask, and
-tiles wholly outside it or above the diagonal are not visited, in all three
-kernels. bf16 operands go into the MXU as they are, accumulated in float32.
-Forward grid: (B*H, Sq/BQ) with an inner fori_loop over K tiles,
-accumulating (out, m, l) in registers; it also emits the per-row
-logsumexp, which the backward re-uses to recompute normalized
-probabilities tile-by-tile (FlashAttention-2 style) instead of storing P:
+Lengths are padded to a multiple of 128 (a single shorter tile is left as
+it is) and tiles are 128 to 512 long (`_tile`, from the padded length
+alone). Grouped heads: K and V have B*Hkv rows and query row i reads row
+i // (H / Hkv) through the block index map. bf16 operands go into the MXU
+as they are, accumulated in float32.
+
+The mask (causal, a causal window, padded keys) has ONE definition for all
+three kernels (`_band`, `_tile_runs`, `_visit`): tiles wholly outside it
+are not visited, and the visited ones fall into at most three contiguous
+runs — cut by the window's edge, wholly inside, cut by the diagonal or the
+padded tail. Only the cut runs build and apply the mask; the inside run's
+body has none. `tile_counts` says how many tiles each run holds.
+
+Forward grid: (B*H, Sq/BQ) with inner loops over K tiles, accumulating
+(out, m, l) in VMEM scratch; it also emits the per-row logsumexp, which
+the backward re-uses to recompute normalized probabilities tile-by-tile
+(FlashAttention-2 style) instead of storing P:
   dQ kernel: grid (B*H, Sq/BQ), loops K tiles; dS = P * (dO V^T - D)
-  dK/dV kernel: grid (B*H, Sk/BK), loops Q tiles; dV += P^T dO,
-                dK += dS^T Q
+  dK/dV kernel: grid (B*H, Sk/BK), loops Q tiles on TRANSPOSED scores
+                (keys down the sublanes): dV += P^T dO, dK += dS^T Q
+                with no operand to transpose, lse and D broadcast as
+                they are stored
 where D = rowsum(dO * O). Differentiation is wired through jax.custom_vjp,
 so `jax.grad` through `attention(use_flash=True)` hits these kernels.
 
@@ -40,6 +50,7 @@ from __future__ import annotations
 
 import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -85,29 +96,6 @@ def _vmem_params(what, seq, d, dtype):
     return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
-def _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window=0):
-    """Valid-score mask for the (qi, j) q x k tile, or None when every
-    entry is valid. ONE definition shared by the forward and dQ kernels —
-    a mask change applied to only one of them would silently desync
-    gradients from the forward. causal: keys at/before the query only;
-    sk_valid < sk: padded key columns (zero-filled by the wrapper) must
-    not contribute (exp(0-m) != 0 in the softmax denominator; in dQ,
-    p = exp(0 - lse) can overflow to inf)."""
-    if not causal and sk_valid >= sk:
-        return None
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = None
-    if causal:
-        mask = rows >= cols
-        if window:  # key j visible to query i iff i - window < j <= i
-            mask &= rows - cols < window
-    if sk_valid < sk:
-        ok = cols < sk_valid
-        mask = ok if mask is None else mask & ok
-    return mask
-
-
 def _row_slice(i, tile, total):
     """Slice `tile` entries at tile index `i` along the LANE (last) axis
     of a stats/bias row. Mosaic must prove a dynamic lane offset is a
@@ -124,16 +112,119 @@ def _n_k_tiles(sk, bk, sk_valid):
     return -(-sk_valid // bk) if sk_valid < sk else sk // bk
 
 
-def _k_tile_range(qi, bq, bk, sk, n_k, causal, window):
-    """[first, end) of the K tiles query tile `qi` can see: with causal
-    none above the diagonal (and never the fully-padded trailing tiles),
-    with a window none wholly before `first row - window + 1`. ONE
-    definition for the forward and dQ kernels, like the mask."""
+# ---------------------------------------------------------------------------
+# The mask, the tiles a kernel visits and the tiles the mask leaves whole.
+# ONE definition for the forward, dQ and dK/dV kernels: a change applied to
+# only one of them would silently desync gradients from the forward. Each
+# kernel fixes a tile on its grid axis (queries in forward and dQ, keys in
+# dK/dV) and loops over tiles of the other axis; with x a score's position
+# along the grid axis and y along the loop axis, the score is valid iff
+# lo <= x - y < hi (and, with padded keys, its column < sk_valid).
+# ---------------------------------------------------------------------------
+
+def _band(causal, window, keys_on_grid=False):
+    """(lo, hi) of the valid band lo <= x - y < hi; None is unbounded.
+    Queries on the grid: x - y = row - col, causal is 0 <= row - col and a
+    window row - col < window (key j visible to query i iff
+    i - window < j <= i). Keys on the grid: the same predicate read as
+    col - row."""
     if not causal:
-        return 0, n_k
-    end = jnp.minimum(jnp.minimum((qi + 1) * bq + bk - 1, sk) // bk, n_k)
-    first = jnp.maximum(qi * bq - window + 1, 0) // bk if window else 0
-    return first, end
+        return None, None
+    if keys_on_grid:
+        return (1 - window if window else None), 1
+    return 0, (window or None)
+
+
+def _tile_runs(g, bg, bt, n_t, n_full, lo, hi):
+    """(first, in_lo, in_hi, end): grid tile `g` (length bg) visits loop
+    tiles [first, end) (length bt; n_t of them hold an unpadded column,
+    the first n_full only such), every other one holds no valid score. The
+    tiles of [in_lo, in_hi) are INSIDE the mask, every score valid; the
+    mask cuts only [first, in_lo) (x - y near hi: the window's edge in
+    forward and dQ, the diagonal in dK/dV) and [in_hi, end) (near lo, and
+    the padded tail). x - y spans g*bg - t*bt + [-(bt - 1), bg - 1] in
+    tile t and falls as t rises. `g` may be a traced scalar or an array of
+    tile indices; bounds that do not depend on it stay Python ints, so an
+    empty run is known before tracing."""
+    x0 = g * bg
+    first, in_lo, in_hi, end = 0, 0, n_full, n_t
+    if lo is not None:
+        end = jnp.minimum((x0 + bg - 1 - lo) // bt + 1, n_t)
+        in_hi = jnp.minimum((x0 + 1 - lo) // bt, n_full)
+    if hi is not None:
+        first = jnp.minimum(jnp.maximum(x0 - hi + 1, 0) // bt, end)
+        in_lo = jnp.clip((jnp.maximum(x0 + bg - hi, 0) + bt - 1) // bt,
+                         first, end)
+    if lo is not None or hi is not None:
+        in_hi = jnp.clip(in_hi, in_lo, end)
+    return first, in_lo, in_hi, end
+
+
+def _visit(g, tile, *, bg, bt, n_t, band, y_valid=None):
+    """tile(t, mask) over the loop tiles grid tile `g` sees, in rising t.
+    `mask` is the (bg, bt) valid-score mask, grid axis first, on the tiles
+    it cuts and None on the inside run, whose body then holds no iota, no
+    compare and no select; an empty run costs its bounds check. `tile`
+    accumulates into VMEM scratch: values carried from one run's loop into
+    the next are moved register by register at every seam (PERF.md section
+    6, PR 30). y_valid: valid positions along a padded loop axis (key
+    columns zero-filled by the wrapper: exp(0 - m) != 0 in the softmax
+    denominator; in dQ, p = exp(0 - lse) can overflow to inf)."""
+    lo, hi = band
+    n_full = n_t if y_valid is None else y_valid // bt
+    first, in_lo, in_hi, end = _tile_runs(g, bg, bt, n_t, n_full, lo, hi)
+
+    def cut(t, _):
+        # x - y is the same iota difference on every tile plus the scalar
+        # g*bg - t*bt: each bound is one compare with a scalar
+        y = jax.lax.broadcasted_iota(jnp.int32, (bg, bt), 1)
+        diff = jax.lax.broadcasted_iota(jnp.int32, (bg, bt), 0) - y
+        off = g * bg - t * bt
+        conds = [diff >= lo - off] if lo is not None else []
+        if hi is not None:
+            conds.append(diff < hi - off)
+        if n_full < n_t:
+            conds.append(y < y_valid - t * bt)
+        tile(t, functools.reduce(jnp.logical_and, conds))
+
+    for a, b, body in ((first, in_lo, cut),
+                       (in_lo, in_hi, lambda t, _: tile(t, None)),
+                       (in_hi, end, cut)):
+        if not (isinstance(a, int) and isinstance(b, int) and a >= b):
+            jax.lax.fori_loop(a, b, body, None)
+
+
+def tile_counts(sq, sk, causal, window=0, sk_valid=None, *, dkv=False):
+    """(visited, inside): the tiles one head's forward (and dQ) kernel
+    visits over (padded) lengths sq x sk, and those of them it runs
+    without the mask — with dkv=True the dK/dV kernel's, which sees no
+    key padding. A pure function of shape, from the range code the kernels
+    run: how often the unmasked body engages."""
+    bq, bk = _check_tiles(sq, sk)
+    if dkv:
+        g = jnp.arange(sk // bk)
+        runs = _tile_runs(g, bk, bq, sq // bq, sq // bq,
+                          *_band(causal, window, keys_on_grid=True))
+    else:
+        g = jnp.arange(sq // bq)
+        valid = sk if sk_valid is None else sk_valid
+        runs = _tile_runs(g, bq, bk, _n_k_tiles(sk, bk, valid), valid // bk,
+                          *_band(causal, window))
+    first, in_lo, in_hi, end = runs
+    return tuple(int(jnp.sum(jnp.broadcast_to(n, g.shape)))
+                 for n in (end - first, in_hi - in_lo))
+
+
+_LANES = 128
+
+
+def _lanes(x, n):
+    """A (rows, _LANES) row statistic, every lane of a row the same value,
+    as (rows, n) beside a (rows, n) block."""
+    if n == _LANES:
+        return x
+    return x[:, :n] if n < _LANES else jnp.broadcast_to(x[:, :1],
+                                                        (x.shape[0], n))
 
 
 def _dot(a, b, dims):
@@ -145,68 +236,72 @@ def _dot(a, b, dims):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
                 bq, bk, sk_valid, has_bias, window=0):
-    """rest = ([bias_ref,] o_ref, lse_ref). bias (1, sk) f32 adds to every
+    """rest = ([bias_ref,] o_ref, lse_ref, then the scratch accumulators
+    acc_ref (bq, d), m_ref and l_ref (bq, _LANES), a row's running maximum
+    and sum in every lane). bias (1, sk) f32 adds to every
     score row — 0 for live keys, -inf for masked ones (ring attention
     uses it to mask globally-padded key positions per rotating block);
     -inf flows through the existing clamp math: s=-inf -> p=0 exactly,
-    even in fully-biased-out tiles (blk_m clamps to 0 first)."""
+    even in fully-biased-out tiles (blk_m clamps to 0 first). The tile
+    mask takes the same road: s = -inf where it cuts."""
+    *rest, acc_ref, m_ref, l_ref = rest
     bias_ref, o_ref, lse_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
     q = q_ref[0]  # (bq, d)
-    n_k = _n_k_tiles(sk, bk, sk_valid)
+    d = q_ref.shape[-1]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    def body(j, carry):
-        out, m, l = carry
+    def tile(j, mask):
         k = k_ref[0, pl.dslice(j * bk, bk), :]
         v = v_ref[0, pl.dslice(j * bk, bk), :]
         s = _dot(q, k, ((1,), (1,))) * scale
         if has_bias:
             s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
-        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window)
         if mask is not None:
             s = jnp.where(mask, s, -jnp.inf)
-        blk_m = jnp.max(s, axis=1)
+        blk_m = jnp.max(s, axis=1, keepdims=True)
         blk_m = jnp.where(jnp.isneginf(blk_m), 0.0, blk_m)
-        p = jnp.exp(s - blk_m[:, None])
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        blk_l = jnp.sum(p, axis=1)
+        p = jnp.exp(s - blk_m)
+        blk_l = jnp.sum(p, axis=1, keepdims=True)
+        m = m_ref[...]
         new_m = jnp.maximum(m, blk_m)
         alpha = jnp.exp(m - new_m)
         beta = jnp.exp(blk_m - new_m)
-        l = l * alpha + blk_l * beta
+        m_ref[...] = new_m
+        l_ref[...] = l_ref[...] * alpha + blk_l * beta
         pv = _dot(p.astype(v.dtype), v, ((1,), (0,)))
-        out = out * alpha[:, None] + pv * beta[:, None]
-        return out, new_m, l
+        acc_ref[...] = (acc_ref[...] * _lanes(alpha, d)
+                        + pv * _lanes(beta, d))
 
-    d = q_ref.shape[-1]
-    out0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    first, end = _k_tile_range(qi, bq, bk, sk, n_k, causal, window)
-    out, m, l = jax.lax.fori_loop(first, end, body, (out0, m0, l0))
+    _visit(qi, tile, bg=bq, bt=bk, n_t=_n_k_tiles(sk, bk, sk_valid),
+           band=_band(causal, window),
+           y_valid=sk_valid if sk_valid < sk else None)
+    m, l = m_ref[...], l_ref[...]
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (out / l_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc_ref[...] / _lanes(l_safe, d)).astype(o_ref.dtype)
     # logsumexp per row; backward recomputes p = exp(s - lse). m is never
     # -inf here (fully-masked blocks clamp blk_m to 0). Stored (BH, 1, S):
     # Mosaic requires the last two block dims to be (8,128)-tiled or equal
     # to the array dims — the singleton axis satisfies that where a 2D
     # (1, bq) block would not.
-    lse_ref[0, 0] = (m + jnp.log(l_safe)).astype(lse_ref.dtype)
+    lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0].astype(lse_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    scale, causal, sk, bq, bk, sk_valid, has_bias, window=0):
+    *rest, acc_ref = rest
     bias_ref, dq_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
     q = q_ref[0]
     do = do_ref[0]
     lse = lse_ref[0, 0].astype(jnp.float32)       # (bq,)
     delta = delta_ref[0, 0].astype(jnp.float32)   # (bq,)
-    n_k = _n_k_tiles(sk, bk, sk_valid)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def body(j, dq):
+    def tile(j, mask):
         k = k_ref[0, pl.dslice(j * bk, bk), :]
         v = v_ref[0, pl.dslice(j * bk, bk), :]
         s = _dot(q, k, ((1,), (1,))) * scale
@@ -214,67 +309,56 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             s = s + bias_ref[0, _row_slice(j, bk, sk)].astype(
                 jnp.float32)[None, :]
         p = jnp.exp(s - lse[:, None])          # normalized probabilities
-        # the same mask as the forward (see _tile_mask: padded-column p
-        # here can overflow to inf and NaN dQ via inf*0)
-        mask = _tile_mask(qi, j, bq, bk, causal, sk, sk_valid, window)
-        if mask is not None:
+        if mask is not None:  # padded-column p can be inf: NaN via inf*0
             p = jnp.where(mask, p, 0.0)
         dp = _dot(do, v, ((1,), (1,)))
         ds = p * (dp - delta[:, None])
-        return dq + _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
+        acc_ref[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
 
-    d = q_ref.shape[-1]
-    first, end = _k_tile_range(qi, bq, bk, sk, n_k, causal, window)
-    dq = jax.lax.fori_loop(first, end, body,
-                           jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    _visit(qi, tile, bg=bq, bt=bk, n_t=_n_k_tiles(sk, bk, sk_valid),
+           band=_band(causal, window),
+           y_valid=sk_valid if sk_valid < sk else None)
+    dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     scale, causal, sq, bq, bk, has_bias, window=0):
+    *rest, dk_acc, dv_acc = rest
     bias_ref, dk_ref, dv_ref = rest if has_bias else (None, *rest)
     ki = pl.program_id(1)
     k = k_ref[0]   # (bk, d)
     v = v_ref[0]
-    n_q = sq // bq
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    if has_bias:
+        # this kernel's k block is the grid's second axis: the bias slice
+        # is the ki-th tile, one value a key row; -inf makes p exactly 0,
+        # so masked keys get zero dK/dV
+        bias = bias_ref[0].astype(jnp.float32)[:, None]
 
-    def body(i, carry):
-        dk, dv = carry
+    def tile(i, mask):
+        # scores transposed, keys down the sublanes: the per-query lse and
+        # delta rows broadcast as they are stored and no product needs a
+        # transposed operand
         q = q_ref[0, pl.dslice(i * bq, bq), :]
         do = do_ref[0, pl.dslice(i * bq, bq), :]
         lse = lse_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
         delta = delta_ref[0, 0, _row_slice(i, bq, sq)].astype(jnp.float32)
-        s = _dot(q, k, ((1,), (1,))) * scale
+        s = _dot(k, q, ((1,), (1,))) * scale    # (bk, bq)
         if has_bias:
-            # this kernel's k block is the grid's second axis: the bias
-            # slice is the ki-th tile, broadcast over q rows; -inf makes
-            # p exactly 0, so masked keys get zero dK/dV
-            s = s + bias_ref[0].astype(jnp.float32)[None, :]
-        p = jnp.exp(s - lse[:, None])          # (bq, bk)
-        if causal:
-            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = rows >= cols
-            if window:
-                mask &= rows - cols < window
+            s = s + bias
+        p = jnp.exp(s - lse[None, :])
+        if mask is not None:
             p = jnp.where(mask, p, 0.0)
-        dv = dv + _dot(p.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot(do, v, ((1,), (1,)))
-        ds = p * (dp - delta[:, None])
-        dk = dk + _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
-        return dk, dv
+        dv_acc[...] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        dp = _dot(v, do, ((1,), (1,)))
+        ds = p * (dp - delta[None, :])
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, ((1,), (0,))) * scale
 
-    d = k_ref.shape[-1]
-    start, end = 0, n_q
-    if causal:
-        start = (ki * bk) // bq  # earlier Q tiles are fully masked
-        if window:  # and so are those past the last key's window
-            end = jnp.minimum(n_q, ((ki + 1) * bk + window - 2) // bq + 1)
-    dk, dv = jax.lax.fori_loop(
-        start, end, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    _visit(ki, tile, bg=bk, bt=bq, n_t=sq // bq,
+           band=_band(causal, window, keys_on_grid=True))
+    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _tile(s: int) -> int:
@@ -303,42 +387,57 @@ def _pad_len(s: int, tile: int) -> int:
     return s if s <= tile else -(-s // tile) * tile
 
 
-def _sds(shape, dtype, like):
+def _sds(shape, dtype, vma):
     """ShapeDtypeStruct for a pallas_call output, carrying the varying-
-    axis set of `like` — under shard_map (ring attention) outputs must
-    declare how they vary over mesh axes; outside it the vma set is
-    empty/absent and a plain struct is produced."""
-    axes = jax.typeof(like).vma
-    if axes:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=axes)
+    axis set `vma` of the operands — under shard_map (ring attention)
+    outputs must declare how they vary over mesh axes; outside it the set
+    is empty and a plain struct is produced."""
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
-              window=0):
-    """(B*H, S, D) q and (B*Hkv, S, D) k, v -> (out, lse); query row i of
-    the leading axis reads key/value row i // (H / Hkv). k_bias: optional
-    (1, Sk) f32 additive score bias shared by every row/head (0 live,
-    -inf masked)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    group = bh // k.shape[0]
+class _Static(typing.NamedTuple):
+    """What a kernel's program depends on beside its flags: shapes, types,
+    mesh axes. Hashable: the `_*_call` builders below are memoized on it,
+    so that jax traces each distinct kernel once a process and not once
+    a layer and program (a model's layers mostly share one; at 0.1 s a
+    trace, the benchmark's 28 calls were 4 s of every start)."""
+    bh: int
+    group: int      # query heads a key/value head
+    sq: int
+    sk: int
+    d: int
+    q_dtype: jnp.dtype
+    k_dtype: jnp.dtype
+    v_dtype: jnp.dtype
+    vma: frozenset  # mesh axes the operands vary over (shard_map)
+    sk_valid: int
+
+    @classmethod
+    def of(cls, q, k, v, sk_valid):
+        bh, sq, d = q.shape
+        sk = k.shape[1]
+        return cls(bh, bh // k.shape[0], sq, sk, d, q.dtype, k.dtype,
+                   v.dtype, frozenset(jax.typeof(q).vma),
+                   sk if sk_valid is None else sk_valid)
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_call(st: _Static, causal, window, has_bias, interpret):
+    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
     bq, bk = _check_tiles(sq, sk)
-    scale = 1.0 / math.sqrt(d)
-    has_bias = k_bias is not None
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               sk=sk, bq=bq, bk=bk,
-                               sk_valid=sk if sk_valid is None else sk_valid,
-                               has_bias=has_bias, window=window)
+    kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
+                               causal=causal, sk=sk, bq=bq, bk=bk,
+                               sk_valid=st.sk_valid, has_bias=has_bias,
+                               window=window)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
     ]
-    args = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
-        args.append(k_bias)
     return pallas_call(
         kernel,
         grid=(bh, sq // bq),
@@ -348,13 +447,27 @@ def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
             pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            _sds((bh, sq, d), q.dtype, q),
-            _sds((bh, 1, sq), jnp.float32, q),
+            _sds((bh, sq, d), st.q_dtype, st.vma),
+            _sds((bh, 1, sq), jnp.float32, st.vma),
         ],
-        compiler_params=_vmem_params("forward", sk, d, k.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),        # out
+                        pltpu.VMEM((bq, _LANES), jnp.float32),   # m
+                        pltpu.VMEM((bq, _LANES), jnp.float32)],  # l
+        compiler_params=_vmem_params("forward", sk, d, st.k_dtype),
         interpret=interpret,
         name="flash_fwd",
-    )(*args)
+    )
+
+
+def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
+              window=0):
+    """(B*H, S, D) q and (B*Hkv, S, D) k, v -> (out, lse); query row i of
+    the leading axis reads key/value row i // (H / Hkv). k_bias: optional
+    (1, Sk) f32 additive score bias shared by every row/head (0 live,
+    -inf masked)."""
+    bias = () if k_bias is None else (k_bias,)
+    return _fwd_call(_Static.of(q, k, v, sk_valid), causal, window,
+                     bool(bias), interpret)(q, k, v, *bias)
 
 
 def _delta(do, out):
@@ -364,21 +477,11 @@ def _delta(do, out):
                    axis=-1)[:, None, :]
 
 
-def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
-              k_bias=None, delta=None, window=0):
-    """out/lse are the GLOBAL attention output/logsumexp for these q rows
-    (for plain flash that's this call's own forward; for ring attention
-    each per-block call passes the ring-merged values, which makes the
-    recomputed p the global probabilities restricted to the block)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    group = bh // k.shape[0]
+@functools.lru_cache(maxsize=None)
+def _dq_call(st: _Static, causal, window, has_bias, interpret):
+    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
     bq, bk = _check_tiles(sq, sk)
-    scale = 1.0 / math.sqrt(d)
-    if delta is None:
-        delta = _delta(do, out)
-    has_bias = k_bias is not None
-    dq_specs = [
+    in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),   # q
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # k
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # v
@@ -386,24 +489,29 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # lse
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # delta
     ]
-    dq_args = [q, k, v, do, lse, delta]
     if has_bias:
-        dq_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
-        dq_args.append(k_bias)
-    dq = pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          sk=sk, bq=bq, bk=bk,
-                          sk_valid=sk if sk_valid is None else sk_valid,
-                          has_bias=has_bias, window=window),
+        in_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
+    return pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=1.0 / math.sqrt(d),
+                          causal=causal, sk=sk, bq=bq, bk=bk,
+                          sk_valid=st.sk_valid, has_bias=has_bias,
+                          window=window),
         grid=(bh, sq // bq),
-        in_specs=dq_specs,
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
-        out_shape=_sds((bh, sq, d), q.dtype, q),
-        compiler_params=_vmem_params("dQ", sk, d, k.dtype),
+        out_shape=_sds((bh, sq, d), st.q_dtype, st.vma),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_vmem_params("dQ", sk, d, st.k_dtype),
         interpret=interpret,
         name="flash_dq",
-    )(*dq_args)
-    dkv_specs = [
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _dkv_call(st: _Static, causal, window, has_bias, interpret):
+    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
+    bq, bk = _check_tiles(sq, sk)
+    in_specs = [
         pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),   # q
         pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # k
         pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # v
@@ -411,35 +519,49 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # lse
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # delta
     ]
-    dkv_args = [q, k, v, do, lse, delta]
     if has_bias:
-        dkv_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
-        dkv_args.append(k_bias)
+        in_specs.append(pl.BlockSpec((1, bk), lambda i, j: (0, j)))
     # with grouped heads each query head writes its own dK/dV (float32),
-    # summed over the group below: Q and dO then stay resident across a
-    # head's K tiles
-    kv_dtype = (k.dtype, v.dtype) if group == 1 else (jnp.float32,) * 2
-    dk, dv = pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          sq=sq, bq=bq, bk=bk, has_bias=has_bias,
-                          window=window),
+    # summed over the group by the caller: Q and dO then stay resident
+    # across a head's K tiles
+    kv_dtype = (st.k_dtype, st.v_dtype) if group == 1 else (jnp.float32,) * 2
+    return pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=1.0 / math.sqrt(d),
+                          causal=causal, sq=sq, bq=bq, bk=bk,
+                          has_bias=has_bias, window=window),
         grid=(bh, sk // bk),
-        in_specs=dkv_specs,
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            _sds((bh, sk, d), kv_dtype[0], k),
-            _sds((bh, sk, d), kv_dtype[1], v),
+            _sds((bh, sk, d), kv_dtype[0], st.vma),
+            _sds((bh, sk, d), kv_dtype[1], st.vma),
         ],
-        compiler_params=_vmem_params("dK/dV", sq, d, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        compiler_params=_vmem_params("dK/dV", sq, d, st.q_dtype),
         interpret=interpret,
         name="flash_dkv",
-    )(*dkv_args)
-    if group > 1:
-        dk = dk.reshape(-1, group, sk, d).sum(1).astype(k.dtype)
-        dv = dv.reshape(-1, group, sk, d).sum(1).astype(v.dtype)
+    )
+
+
+def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
+              k_bias=None, delta=None, window=0):
+    """out/lse are the GLOBAL attention output/logsumexp for these q rows
+    (for plain flash that's this call's own forward; for ring attention
+    each per-block call passes the ring-merged values, which makes the
+    recomputed p the global probabilities restricted to the block)."""
+    if delta is None:
+        delta = _delta(do, out)
+    st = _Static.of(q, k, v, sk_valid)
+    args = (q, k, v, do, lse, delta, *(() if k_bias is None else (k_bias,)))
+    flags = (causal, window, k_bias is not None, interpret)
+    dq = _dq_call(st, *flags)(*args)
+    dk, dv = _dkv_call(st, *flags)(*args)
+    if st.group > 1:
+        dk = dk.reshape(-1, st.group, st.sk, st.d).sum(1).astype(k.dtype)
+        dv = dv.reshape(-1, st.group, st.sk, st.d).sum(1).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -504,10 +626,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     repeated. window > 0 (causal only): key j visible to query i iff
     i - window < j <= i; tiles wholly outside are not visited.
 
-    Arbitrary sequence lengths: lengths that don't tile evenly are padded
-    up to the (128, 128) q/k tile sizes — padded key columns are masked
-    out of the in-kernel softmax, padded query rows are sliced off the
-    output (their gradients vanish through the zero cotangent)."""
+    Arbitrary sequence lengths: a length over 128 is padded up to a
+    multiple of 128 and walked in tiles of 128 to 512 (`_tile`) — padded
+    key columns are masked out of the in-kernel softmax, padded query rows
+    are sliced off the output (their gradients vanish through the zero
+    cotangent)."""
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     if h % hkv:

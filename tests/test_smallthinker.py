@@ -133,14 +133,27 @@ class TestLayers:
 
     @pytest.mark.parametrize("heads,kv,window,seq", [
         (4, 2, 8, 32), (4, 2, 0, 32), (4, 1, 130, 384), (6, 2, 200, 300),
-        (2, 2, 70, 256)],
+        (2, 2, 70, 256),
+        # every class of tile (window edge, inside, diagonal, padded tail)
+        # and every empty-run corner of _tile_runs: five tiles of 128 ...
+        (4, 2, 0, 640), (4, 2, 200, 640), (4, 2, 256, 640), (4, 2, 128, 640),
+        (4, 2, 1000, 640), (4, 2, 0, 600), (4, 2, 200, 600),
+        # ... tiles of 512 with the window one tile long (no inside tile)
+        # and two (an inside tile between window edge and diagonal) ...
+        (4, 2, 512, 1024), (4, 2, 512, 1536), (2, 1, 1024, 2048),
+        # ... and a single tile, which takes the masked body alone
+        (4, 2, 0, 96), (4, 2, 40, 96)],
         ids=["4over2-w8", "4over2-global", "4over1-w130", "6over2-w200-pad",
-             "2over2-w70"])
+             "2over2-w70", "s640-global", "s640-w200", "s640-w256",
+             "s640-w128", "s640-w-over-s", "s600-pad-global", "s600-pad-w200",
+             "s1024-w512", "s1536-w512", "s2048-w1024", "s96-global",
+             "s96-w40"])
     def test_flash_against_jnp_forward_and_gradient(self, heads, kv, window,
                                                     seq):
-        """The flash kernels in the interpreter (window in the tile mask,
-        tiles outside it skipped, key/value heads by index map) against
-        the jnp path with an explicit mask and repeated heads."""
+        """The flash kernels in the interpreter (the mask on the tiles it
+        cuts alone, tiles outside it skipped, key/value heads by index
+        map) against the jnp path with an explicit mask and repeated
+        heads."""
         ks = jax.random.split(jax.random.PRNGKey(seq + window), 4)
         q = jax.random.normal(ks[0], (2, seq, heads, 16))
         k = jax.random.normal(ks[1], (2, seq, kv, 16))

@@ -1,0 +1,16 @@
+"""Device time per training iteration under the program's `moe.shared`
+scope: the shared expert's three products and its gate, which every token
+passes through in each expert layer, forward and backward
+(scope_reduce.py). None where no operation carries the scope. Layer:
+Net_layers. Moves train_samples_per_s in the latent-attention cell."""
+
+import scope_reduce
+
+SCOPE = "moe.shared"
+
+
+def compute(run: dict, trace: dict | None):
+    seconds = scope_reduce.for_run(run, trace, SCOPE)
+    if not seconds:
+        return None
+    return 1e3 * seconds / run["traced_iters"]

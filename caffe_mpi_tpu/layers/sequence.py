@@ -61,6 +61,15 @@ class LayerNormLayer(Layer):
         return [y], state
 
 
+def rms_normalize(x, eps: float):
+    """x / sqrt(mean(x^2, -1) + eps): the statistics in float32, the
+    result in x's type; the caller applies the scale."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype)
+
+
 @register("RMSNorm")
 class RMSNormLayer(Layer):
     """x / sqrt(mean(x^2, -1) + eps) * scale (rms_norm_param { eps }):
@@ -74,11 +83,8 @@ class RMSNormLayer(Layer):
         return [in_shapes[0]]
 
     def apply(self, params, state, bottoms, *, train, rng):
-        x = self.f(bottoms[0])
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.p.eps)
-        return [y.astype(x.dtype) * self.f(params["scale"])], state
+        return [rms_normalize(self.f(bottoms[0]), self.p.eps)
+                * self.f(params["scale"])], state
 
 
 def attention_dims(p, c: int) -> tuple[int, int, int]:
@@ -96,8 +102,30 @@ def attention_dims(p, c: int) -> tuple[int, int, int]:
     return heads, kv, p.head_dim or c // heads
 
 
+def latent_dims(p) -> tuple[int, int, int]:
+    """(lanes without positions, rotary lanes, value lanes) of a head of a
+    latent attention_param; refuses what that path has no meaning for."""
+    nope, rot, vd = p.qk_nope_head_dim, p.qk_rope_head_dim, p.v_head_dim
+    if min(p.q_lora_rank, nope, rot, vd) < 1 or rot % 2:
+        raise ValueError(
+            "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+            "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim")
+    if (p.num_kv_heads or p.head_dim or p.window or p.sequence_parallel
+            or p.bias_term or not p.rope_theta):
+        raise ValueError(
+            "latent attention has rope_theta and bias_term: false, and "
+            "neither num_kv_heads, head_dim, window nor sequence_parallel")
+    return nope, rot, vd
+
+
 @register("Attention")
 class AttentionLayer(Layer):
+    """attention_param. Two forms: fused QKV heads (grouped, windowed,
+    rotary over the whole head) and, with kv_lora_rank > 0, latent
+    attention (`_setup_latent`, `_latent_qkv`): low-rank query and
+    key/value projections, a head of unequal query/key and value widths,
+    rotary over a part of it."""
+
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         from ..proto.config import AttentionParameter
         p = self.lp.attention_param or AttentionParameter()
@@ -106,6 +134,9 @@ class AttentionLayer(Layer):
             raise ValueError(
                 f"Attention expects (N, S, C) bottom, got {in_shapes[0]}")
         n, s, c = in_shapes[0]
+        if p.kv_lora_rank:
+            self._setup_latent(c)
+            return [in_shapes[0]]
         self.heads, self.kv_heads, self.head_dim = attention_dims(p, c)
         if p.window and not p.causal:
             raise ValueError("attention_param window needs causal: true")
@@ -126,22 +157,66 @@ class AttentionLayer(Layer):
             self.declare("proj_bias", (c,), bias)
         return [in_shapes[0]]
 
+    def _setup_latent(self, c: int):
+        p = self.p
+        self.latent = nope, rot, vd = latent_dims(p)
+        self.heads = max(p.num_heads, 1)
+        self.nq = self.heads * vd          # what the output product reads
+        filler = p.weight_filler or FillerParameter(type="xavier")
+        one = FillerParameter(type="constant", value=1.0)
+        self.declare("q_a_weight", (p.q_lora_rank, c), filler)
+        self.declare("q_norm", (p.q_lora_rank,), one)
+        self.declare("q_b_weight", (self.heads * (nope + rot),
+                                    p.q_lora_rank), filler)
+        self.declare("kv_a_weight", (p.kv_lora_rank + rot, c), filler)
+        self.declare("kv_norm", (p.kv_lora_rank,), one)
+        self.declare("kv_b_weight", (self.heads * (nope + vd),
+                                     p.kv_lora_rank), filler)
+        self.declare("proj_weight", (c, self.nq), filler)
+
+    def _latent_qkv(self, params, x):
+        """q, k (N, S, H, nope + rot) and v (N, S, H, vd): the rotary lanes
+        last, the one rotary key head repeated under every query head."""
+        from ..ops.attention import rope, rope_pairs
+        p = self.p
+        nope, rot, vd = self.latent
+        n, s, _ = x.shape
+        w = lambda name: self.f(params[name])
+        rms = lambda t, scale: rms_normalize(t, p.norm_eps) * scale
+        turn = rope_pairs if p.rope_interleave else rope
+        q = rms(x @ w("q_a_weight").T, w("q_norm")) @ w("q_b_weight").T
+        q = q.reshape(n, s, self.heads, nope + rot)
+        kv = x @ w("kv_a_weight").T
+        k_r = turn(kv[..., None, p.kv_lora_rank:], p.rope_theta)
+        kv = rms(kv[..., :p.kv_lora_rank], w("kv_norm")) \
+            @ w("kv_b_weight").T
+        kv = kv.reshape(n, s, self.heads, nope + vd)
+        q = jnp.concatenate(
+            [q[..., :nope], turn(q[..., nope:], p.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_r, (n, s, self.heads, rot))], axis=-1)
+        return q, k, kv[..., nope:]
+
     def apply(self, params, state, bottoms, *, train, rng):
         from ..ops.attention import (attention, rope,
                                      sequence_parallel_attention)
         p = self.p
         x = self.f(bottoms[0])
         n, s, c = x.shape
-        qkv = x @ self.f(params["qkv_weight"]).T
-        if p.bias_term:
-            qkv = qkv + self.f(params["qkv_bias"])
-        nq, nkv = self.nq, self.nkv
-        q, k, v = jnp.split(qkv, [nq, nq + nkv], axis=-1)
-        q = q.reshape(n, s, self.heads, self.head_dim)
-        k = k.reshape(n, s, self.kv_heads, self.head_dim)
-        v = v.reshape(n, s, self.kv_heads, self.head_dim)
-        if p.rope_theta:
-            q, k = rope(q, p.rope_theta), rope(k, p.rope_theta)
+        if p.kv_lora_rank:
+            q, k, v = self._latent_qkv(params, x)
+        else:
+            qkv = x @ self.f(params["qkv_weight"]).T
+            if p.bias_term:
+                qkv = qkv + self.f(params["qkv_bias"])
+            nq, nkv = self.nq, self.nkv
+            q, k, v = jnp.split(qkv, [nq, nq + nkv], axis=-1)
+            q = q.reshape(n, s, self.heads, self.head_dim)
+            k = k.reshape(n, s, self.kv_heads, self.head_dim)
+            v = v.reshape(n, s, self.kv_heads, self.head_dim)
+            if p.rope_theta:
+                q, k = rope(q, p.rope_theta), rope(k, p.rope_theta)
         mp = self.mesh_plan
         if (p.sequence_parallel and mp is not None
                 and mp.mesh.shape.get("model", 1) > 1):
@@ -165,7 +240,7 @@ class AttentionLayer(Layer):
         else:
             out = attention(q, k, v, causal=bool(p.causal),
                             use_flash=bool(p.use_flash), window=p.window)
-        y = out.reshape(n, s, nq) @ self.f(params["proj_weight"]).T
+        y = out.reshape(n, s, self.nq) @ self.f(params["proj_weight"]).T
         if p.bias_term:
             y = y + self.f(params["proj_bias"])
         return [y], state
@@ -176,11 +251,16 @@ class MoELayer(Layer):
     """moe_param. Two formulations (ops/moe.py): the capacity one (GShard
     dispatch/combine tensors, softmax then top-k, tokens past capacity
     dropped, two biased matrices an expert; second top = the auxiliary
-    load-balancing loss) and, with `dropless: true`, top-k then softmax,
-    rows sorted by expert through grouped matrix products, gated ReLU
-    experts of three unbiased matrices (second top = the rows each held
-    expert received). A second bottom, when given, is what
-    the router scores instead of the tensor the experts transform."""
+    load-balancing loss) and, with `dropless: true`, rows sorted by expert
+    through grouped matrix products over gated experts of three unbiased
+    matrices (second top = the rows each held expert received). Of the
+    dropless path `scoring` (softmax over the top-k logits | sigmoid
+    scores chosen under the `select_bias` blob, renormalised, times
+    `routed_scaling_factor`), the gate's `activation` (relu | silu) and
+    `shared_experts` (a gated unit every token passes through) are
+    parameters; their defaults are the layer as it was. A second bottom,
+    when given, is what the router scores instead of the tensor the
+    experts transform."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         p = self.lp.moe_param
@@ -200,10 +280,24 @@ class MoELayer(Layer):
         if len(in_shapes) > 1 and in_shapes[1] != in_shapes[0]:
             raise ValueError(f"MoE router bottom {in_shapes[1]} != "
                              f"{in_shapes[0]}")
+        plain = (p.scoring == "softmax" and p.activation == "relu"
+                 and p.routed_scaling_factor == 1.0 and not p.shared_experts)
+        if not p.dropless and not plain:
+            raise ValueError("moe_param: scoring, routed_scaling_factor, "
+                             "activation and shared_experts need "
+                             "dropless: true")
+        if p.scoring not in ("softmax", "sigmoid") \
+                or p.activation not in ("relu", "silu"):
+            raise ValueError(f"moe_param: scoring {p.scoring!r} (softmax | "
+                             f"sigmoid), activation {p.activation!r} (relu "
+                             f"| silu)")
         filler = p.weight_filler or FillerParameter(type="xavier")
         gate_filler = FillerParameter(type="gaussian", std=0.02)
         zero = FillerParameter(type="constant")
         self.declare("gate", (c, p.num_experts), gate_filler)
+        if p.scoring == "sigmoid":
+            self.declare("select_bias", (p.num_experts,),
+                         p.bias_filler or zero)
         self.declare("w1", (held, c, p.hidden_dim), filler)
         if not p.dropless:
             self.declare("b1", (held, p.hidden_dim), zero)
@@ -212,6 +306,11 @@ class MoELayer(Layer):
             self.declare("w3", (held, c, p.hidden_dim), filler)
         else:
             self.declare("b2", (held, c), zero)
+        if p.shared_experts:
+            wide = p.shared_experts * p.hidden_dim
+            self.declare("shared_w1", (c, wide), filler)
+            self.declare("shared_w3", (c, wide), filler)
+            self.declare("shared_w2", (wide, c), filler)
         tops = [in_shapes[0]]
         if len(self.lp.top) > 1:  # aux loss / rows per held expert
             tops.append((held,) if p.dropless else ())
@@ -229,7 +328,8 @@ class MoELayer(Layer):
                 if len(bottoms) > 1 else flat
             y, extra = moe_dropless(
                 cast, flat, scored, top_k=max(p.top_k, 1),
-                first_expert=p.first_expert)
+                first_expert=p.first_expert, scoring=p.scoring,
+                scale=p.routed_scaling_factor, activation=p.activation)
         else:
             y, extra = moe_ffn(cast, flat, top_k=max(p.top_k, 1),
                                capacity_factor=p.capacity_factor)

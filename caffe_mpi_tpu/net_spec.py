@@ -45,7 +45,7 @@ _PARAM_FIELD = {
 _TOP_LEVEL = {"name", "bottom", "top", "include", "exclude", "loss_weight",
               "param", "propagate_down", "phase", "transform_param",
               "loss_param", "forward_type", "backward_type", "forward_math",
-              "backward_math", "ntop", "in_place"}
+              "backward_math", "ntop", "in_place", "remat"}
 
 _ENUM_FIELDS = {"pool", "operation", "norm_region", "backend", "phase",
                 "variance_norm", "norm", "round_mode"}

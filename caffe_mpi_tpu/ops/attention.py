@@ -69,11 +69,34 @@ def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     return out.astype(x.dtype)
 
 
+def rope_pairs(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions over the whole of x's last axis, adjacent-pair
+    convention (`rope_interleave`): (x_2i, x_2i+1) turned by the angle
+    a_i = pos * theta^(-2i / D), positions 0..S-1, no scaling:
+    [x_2i cos a_i - x_2i+1 sin a_i, x_2i+1 cos a_i + x_2i sin a_i], each in
+    its own lane. x (B, S, H, D). The pair's other lane comes by two lane
+    rotations and a select, so nothing is re-laid out. Angles and the
+    rotation in float32, the result in x's type."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.repeat(jnp.arange(s, dtype=jnp.float32)[:, None]
+                     * inv[None, :], 2, axis=-1)            # (S, D)
+    even = jnp.arange(d) % 2 == 0
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.where(even, -jnp.sin(ang), jnp.sin(ang))[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    other = jnp.where(even, jnp.roll(x32, -1, axis=-1),
+                      jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + other * sin).astype(x.dtype)
+
+
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = False, use_flash: bool = False,
               flash_interpret: bool | None = None,
               window: int = 0) -> jnp.ndarray:
-    """Single-device attention: q (B,S,H,D), k,v (B,S,Hkv,D) -> (B,S,H,D).
+    """Single-device attention: q (B,S,H,D), k (B,S,Hkv,D), v (B,S,Hkv,Dv)
+    -> (B,S,H,Dv), scores scaled by 1 / sqrt(D); Dv is D everywhere but in
+    latent attention.
 
     Grouped heads: with Hkv < H (H a multiple of it) query head n reads
     key/value head n // (H / Hkv). window > 0 (with causal): key j is

@@ -8,6 +8,12 @@ it in VMEM tiles with an online softmax, O(S) memory instead of O(S^2).
 
 Layout: (B, H, S, D) inside the kernels (sequence-minor tiles). The public
 entry accepts the framework's (B, S, H, D) and transposes at the edges.
+Two head widths: queries and keys are `d` wide, values (and so the output)
+`dv` wide; they differ in latent attention, whose scores run over 128 +
+64 rotary lanes and whose values are 128 wide. A width that is no multiple
+of 128 is one operand all the same: the block spans the whole last axis,
+Mosaic pads its lanes in VMEM, and the MXU's second pass over the
+contraction runs half empty (PERF.md section 6, PR 31).
 Lengths are padded to a multiple of 128 (a single shorter tile is left as
 it is) and tiles are 128 to 512 long (`_tile`, from the padded length
 alone). Grouped heads: K and V have B*Hkv rows and query row i reads row
@@ -54,6 +60,7 @@ import typing
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -61,6 +68,7 @@ from .pallas_call import pallas_call
 
 BQ = 128  # query tile (MXU-aligned)
 BK = 128  # key tile
+_LANES = 128
 
 # Mosaic's default scoped-VMEM limit, and the most this module will ask
 # for: a v5e TensorCore has 128 MiB of VMEM, and the compiler needs room
@@ -74,12 +82,13 @@ class FlashVmemError(ValueError):
     the VMEM this module is willing to request."""
 
 
-def _vmem_params(what, seq, d, dtype):
-    """CompilerParams for a kernel holding two (seq, d) blocks of
-    `dtype` resident per grid step — K and V, or Q and dO — each
-    double-buffered by the pipeline; None when the default limit is
-    enough."""
-    resident = 2 * 2 * seq * d * jnp.dtype(dtype).itemsize
+def _vmem_params(what, seq, d, dv, dtype):
+    """CompilerParams for a kernel holding a (seq, d) and a (seq, dv)
+    block of `dtype` resident per grid step — K and V, or Q and dO — each
+    double-buffered by the pipeline, lanes padded to 128; None when the
+    default limit is enough."""
+    lanes = sum(-(-w // _LANES) * _LANES for w in (d, dv))
+    resident = 2 * seq * lanes * jnp.dtype(dtype).itemsize
     # tile-sized operands, f32 in-kernel temporaries (half a dozen score
     # tiles: 1 MiB each at 512 x 512), compiler scratch
     need = resident + (8 + 8 * (_tile(seq) // 256) ** 2) * 2**20
@@ -87,8 +96,8 @@ def _vmem_params(what, seq, d, dtype):
         return None
     if need > _VMEM_MAX:
         raise FlashVmemError(
-            f"flash attention {what}: two resident blocks of "
-            f"({seq}, {d}) {jnp.dtype(dtype).name} need "
+            f"flash attention {what}: resident blocks of "
+            f"({seq}, {d}) and ({seq}, {dv}) {jnp.dtype(dtype).name} need "
             f"{need / 2**20:.0f} MiB of VMEM, over the "
             f"{_VMEM_MAX / 2**20:.0f} MiB this kernel may request; shard "
             f"the sequence (attention_param sequence_parallel) or use "
@@ -215,9 +224,6 @@ def tile_counts(sq, sk, causal, window=0, sk_valid=None, *, dkv=False):
                  for n in (end - first, in_hi - in_lo))
 
 
-_LANES = 128
-
-
 def _lanes(x, n):
     """A (rows, _LANES) row statistic, every lane of a row the same value,
     as (rows, n) beside a (rows, n) block."""
@@ -237,7 +243,7 @@ def _dot(a, b, dims):
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
                 bq, bk, sk_valid, has_bias, window=0):
     """rest = ([bias_ref,] o_ref, lse_ref, then the scratch accumulators
-    acc_ref (bq, d), m_ref and l_ref (bq, _LANES), a row's running maximum
+    acc_ref (bq, dv), m_ref and l_ref (bq, _LANES), a row's running maximum
     and sum in every lane). bias (1, sk) f32 adds to every
     score row — 0 for live keys, -inf for masked ones (ring attention
     uses it to mask globally-padded key positions per rotating block);
@@ -248,7 +254,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
     bias_ref, o_ref, lse_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
     q = q_ref[0]  # (bq, d)
-    d = q_ref.shape[-1]
+    d = acc_ref.shape[-1]   # the values' width
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -407,7 +413,8 @@ class _Static(typing.NamedTuple):
     group: int      # query heads a key/value head
     sq: int
     sk: int
-    d: int
+    d: int          # width of a query and a key
+    dv: int         # width of a value, and of the output
     q_dtype: jnp.dtype
     k_dtype: jnp.dtype
     v_dtype: jnp.dtype
@@ -418,14 +425,14 @@ class _Static(typing.NamedTuple):
     def of(cls, q, k, v, sk_valid):
         bh, sq, d = q.shape
         sk = k.shape[1]
-        return cls(bh, bh // k.shape[0], sq, sk, d, q.dtype, k.dtype,
-                   v.dtype, frozenset(jax.typeof(q).vma),
+        return cls(bh, bh // k.shape[0], sq, sk, d, v.shape[2], q.dtype,
+                   k.dtype, v.dtype, frozenset(jax.typeof(q).vma),
                    sk if sk_valid is None else sk_valid)
 
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(st: _Static, causal, window, has_bias, interpret):
-    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
+    bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
                                causal=causal, sk=sk, bq=bq, bk=bk,
@@ -434,7 +441,7 @@ def _fwd_call(st: _Static, causal, window, has_bias, interpret):
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, sk, dv), lambda i, j: (i // group, 0, 0)),
     ]
     if has_bias:
         in_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
@@ -443,17 +450,17 @@ def _fwd_call(st: _Static, causal, window, has_bias, interpret):
         grid=(bh, sq // bq),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, bq, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            _sds((bh, sq, d), st.q_dtype, st.vma),
+            _sds((bh, sq, dv), st.q_dtype, st.vma),
             _sds((bh, 1, sq), jnp.float32, st.vma),
         ],
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),        # out
+        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),       # out
                         pltpu.VMEM((bq, _LANES), jnp.float32),   # m
                         pltpu.VMEM((bq, _LANES), jnp.float32)],  # l
-        compiler_params=_vmem_params("forward", sk, d, st.k_dtype),
+        compiler_params=_vmem_params("forward", sk, d, dv, st.k_dtype),
         interpret=interpret,
         name="flash_fwd",
     )
@@ -479,13 +486,13 @@ def _delta(do, out):
 
 @functools.lru_cache(maxsize=None)
 def _dq_call(st: _Static, causal, window, has_bias, interpret):
-    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
+    bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),   # q
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # k
-        pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),   # v
-        pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),   # do
+        pl.BlockSpec((1, sk, dv), lambda i, j: (i // group, 0, 0)),  # v
+        pl.BlockSpec((1, bq, dv), lambda i, j: (i, j, 0)),  # do
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # lse
         pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, j)),   # delta
     ]
@@ -501,7 +508,7 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret):
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), st.q_dtype, st.vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_vmem_params("dQ", sk, d, st.k_dtype),
+        compiler_params=_vmem_params("dQ", sk, d, dv, st.k_dtype),
         interpret=interpret,
         name="flash_dq",
     )
@@ -509,13 +516,13 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret):
 
 @functools.lru_cache(maxsize=None)
 def _dkv_call(st: _Static, causal, window, has_bias, interpret):
-    bh, group, sq, sk, d = st.bh, st.group, st.sq, st.sk, st.d
+    bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
         pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),   # q
         pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # k
-        pl.BlockSpec((1, bk, d), lambda i, j: (i // group, j, 0)),   # v
-        pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),   # do
+        pl.BlockSpec((1, bk, dv), lambda i, j: (i // group, j, 0)),  # v
+        pl.BlockSpec((1, sq, dv), lambda i, j: (i, 0, 0)),  # do
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # lse
         pl.BlockSpec((1, 1, sq), lambda i, j: (i, 0, 0)),   # delta
     ]
@@ -533,14 +540,15 @@ def _dkv_call(st: _Static, causal, window, has_bias, interpret):
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             _sds((bh, sk, d), kv_dtype[0], st.vma),
-            _sds((bh, sk, d), kv_dtype[1], st.vma),
+            _sds((bh, sk, dv), kv_dtype[1], st.vma),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
-        compiler_params=_vmem_params("dK/dV", sq, d, st.q_dtype),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, dv), jnp.float32)],
+        compiler_params=_vmem_params("dK/dV", sq, d, dv, st.q_dtype),
         interpret=interpret,
         name="flash_dkv",
     )
@@ -561,7 +569,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
     dk, dv = _dkv_call(st, *flags)(*args)
     if st.group > 1:
         dk = dk.reshape(-1, st.group, st.sk, st.d).sum(1).astype(k.dtype)
-        dv = dv.reshape(-1, st.group, st.sk, st.d).sum(1).astype(v.dtype)
+        dv = dv.reshape(-1, st.group, st.sk, st.dv).sum(1).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -595,9 +603,18 @@ def _flash(q, k, v, causal, interpret, sk_valid, window):
     return out
 
 
+# Under a layer's `remat: true` (net.py) these two residuals are kept and
+# the forward kernel is not run again in the backward pass: what is
+# computed again is what leads up to q, k and v. Without a checkpoint
+# around the call the names do nothing.
+KEPT_UNDER_REMAT = ("flash.out", "flash.lse")
+
+
 def _flash_fwd(q, k, v, causal, interpret, sk_valid, window):
     out, lse = _fwd_impl(q, k, v, causal, interpret, sk_valid,
                          window=window)
+    out, lse = (checkpoint_name(x, name)
+                for x, name in zip((out, lse), KEPT_UNDER_REMAT))
     return out, (q, k, v, out, lse)
 
 
@@ -618,8 +635,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = False, interpret: bool | None = None,
                     window: int = 0) -> jnp.ndarray:
-    """q (B, S, H, D), k,v (B, S, Hkv, D) -> (B, S, H, D). Differentiable:
-    jax.grad hits the Pallas backward kernels via custom_vjp.
+    """q (B, S, H, D), k (B, S, Hkv, D), v (B, S, Hkv, Dv) -> (B, S, H,
+    Dv); scores are scaled by 1 / sqrt(D). Differentiable: jax.grad hits
+    the Pallas backward kernels via custom_vjp.
 
     Grouped heads (Hkv < H): query head n reads key/value head
     n // (H / Hkv) through the kernels' block index maps, K and V are not
@@ -632,7 +650,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     are sliced off the output (their gradients vanish through the zero
     cotangent)."""
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     if h % hkv:
         raise ValueError(f"{h} query heads over {hkv} key/value heads")
     if window and not causal:
@@ -645,8 +663,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, dv)
     out = _flash(qt, kt, vt, causal, interpret,
                  sk if sk_p != sk else None, window)
-    out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, sq_p, dv).transpose(0, 2, 1, 3)
     return out[:, :sq] if sq_p != sq else out

@@ -23,12 +23,16 @@ tokens are both unaffordable: the (T, k) choices are flattened and sorted
 by expert, the rows gathered, the experts run as grouped matrix products
 over the sorted rows (megablox's Pallas kernels; `lax.ragged_dot` on the
 CPU), and the results summed back per token under the router's weights.
-No capacity, no dropped token, static shapes. Its constants are those of
-the one recipe that uses it: top-k then softmax, gated ReLU experts of
-three unbiased matrices. It is told which experts it holds (an
+No capacity, no dropped token, static shapes. Experts are gated units of
+three unbiased matrices. Parameters (`moe_param`, two recipes use them):
+the scoring (`route`: top-k then softmax, or sigmoid scores chosen under a
+selection bias and renormalised, times a scaling factor), the gate's
+activation (relu | silu), and shared experts, one gated unit every token
+passes through (scope `moe.shared`). It is told which experts it holds (an
 expert-parallel share): the router still scores all of them, rows routed
 to absent experts sort last and cost no expert FLOPs, and the result is
-the held experts' part.
+the held experts' part plus, computed here for this chip's own tokens, the
+shared experts'.
 
 Expert parallelism = shard the E dimension (expert weights AND the
 (E, C, ...) activation tensors) over a mesh axis via sharding
@@ -47,7 +51,10 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..utils.spans import (MOE_COMBINE as COMBINE, MOE_DISPATCH as DISPATCH,
-                           MOE_EXPERTS as EXPERTS, MOE_ROUTE as ROUTE)
+                           MOE_EXPERTS as EXPERTS, MOE_ROUTE as ROUTE,
+                           MOE_SHARED as SHARED)
+
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,
@@ -272,34 +279,56 @@ _permute.defvjp(
     lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
 
 
-def route(logits: jnp.ndarray, top_k: int):
-    """(T, E) router logits -> (weights (T, k) float32, expert ids (T, k)):
-    the k largest logits, softmax over those in float32 (which equals
-    softmax over all, select, renormalise)."""
-    top, ids = jax.lax.top_k(logits.astype(jnp.float32), top_k)
-    return jax.nn.softmax(top, axis=-1), ids
+def route(logits: jnp.ndarray, top_k: int, scoring: str = "softmax",
+          select_bias: jnp.ndarray | None = None, scale: float = 1.0):
+    """(T, E) router logits -> (weights (T, k) float32, expert ids (T, k)).
+    "softmax": the k largest logits, softmax over those in float32 (which
+    equals softmax over all, select, renormalise). "sigmoid": s =
+    sigmoid(logits) in float32, the k largest of s + select_bias (E,), and
+    weights scale * s / (sum of the chosen s + 1e-20): the bias selects and
+    does not weigh."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        top, ids = jax.lax.top_k(logits, top_k)
+        return jax.nn.softmax(top, axis=-1), ids
+    if scoring != "sigmoid":
+        raise ValueError(f"moe scoring {scoring!r}: softmax or sigmoid")
+    scores = jax.nn.sigmoid(logits)
+    biased = scores if select_bias is None \
+        else scores + select_bias.astype(jnp.float32)
+    _, ids = jax.lax.top_k(biased, top_k)
+    top = jnp.take_along_axis(scores, ids, axis=-1)
+    return scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20), ids
 
 
 def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
-                 top_k: int, first_expert: int = 0):
+                 top_k: int, first_expert: int = 0,
+                 scoring: str = "softmax", scale: float = 1.0,
+                 activation: str = "relu"):
     """x, router_in: (T, F) -> (y (T, F), rows (E_held,) float32).
 
-    params: `gate` (F, E) scores every expert; the banks `w1`, `w3`
-    (E_held, F, H) and `w2` (E_held, H, F) are experts first_expert ..
-    first_expert + E_held - 1, gated ReLU units without biases:
-    (relu(x w1) * (x w3)) w2. y is the sum over a token's chosen experts
+    params: `gate` (F, E) scores every expert (`route`; `select_bias` (E,)
+    with sigmoid scoring); the banks `w1`, `w3` (E_held, F, H) and `w2`
+    (E_held, H, F) are experts first_expert .. first_expert + E_held - 1,
+    gated units without biases: (act(x w1) * (x w3)) w2, act = relu |
+    silu. y is the sum over a token's chosen experts
     THAT ARE HELD of weight * expert(x): what absent experts would add is
     left out and the weights are not renormalised over the held ones.
+    With `shared_w1`, `shared_w3` (F, Hs) and `shared_w2` (Hs, F), the
+    shared experts' (act(x shared_w1) * (x shared_w3)) shared_w2 is added
+    for every token, unweighted.
     rows[e] counts the (token, choice) pairs held expert e received.
 
     Every (token, choice) pair gets a row of the sorted buffer, so shapes
     are static at top_k * T rows; pairs routed to absent experts sort
     after the last group, where the grouped products do no work."""
     held = params["w1"].shape[0]
+    act = ACTIVATIONS[activation]
     with jax.named_scope(ROUTE):
         logits = jnp.dot(router_in, params["gate"],
                          preferred_element_type=jnp.float32)
-        weights, ids = route(logits, top_k)
+        weights, ids = route(logits, top_k, scoring,
+                             params.get("select_bias"), scale)
     with jax.named_scope(DISPATCH):
         local = ids.T.reshape(-1) - first_expert
         local = jnp.where((local >= 0) & (local < held), local, held)
@@ -313,7 +342,7 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
         # backward pass leaves there out of the tokens' gradient
         xs = jnp.where(live[:, None], _dispatch(x, order, inv, top_k), 0)
     with jax.named_scope(EXPERTS):
-        h = jax.nn.relu(grouped_dot(xs, params["w1"], sizes)) \
+        h = act(grouped_dot(xs, params["w1"], sizes)) \
             * grouped_dot(xs, params["w3"], sizes)
         ys = grouped_dot(h, params["w2"], sizes)
     with jax.named_scope(COMBINE):
@@ -323,4 +352,8 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
         w = _permute(weights.T.reshape(-1), order, inv)
         ys = jnp.where(live[:, None], ys, 0) * w[:, None].astype(ys.dtype)
         y = _combine(ys, order, inv, top_k)
+    if "shared_w1" in params:
+        with jax.named_scope(SHARED):
+            y = y + (act(x @ params["shared_w1"])
+                     * (x @ params["shared_w3"])) @ params["shared_w2"]
     return y, sizes.astype(jnp.float32)

@@ -472,6 +472,27 @@ class AttentionParameter(Message):
     # rotary position embedding over the whole head (rotate-half
     # convention), positions 0..S-1 in each sequence. 0 = no positions
     rope_theta: float = 0.0
+    # latent attention (kv_lora_rank > 0; arXiv:2405.04434): queries and
+    # keys/values come through low-rank projections with an RMSNorm
+    # between the two factors, a head is qk_nope_head_dim lanes without
+    # positions plus qk_rope_head_dim rotary ones, the rotary key is ONE
+    # head that every query head shares, and values are v_head_dim wide.
+    # Blobs: q_a_weight (q_lora_rank, C), q_norm (q_lora_rank),
+    # q_b_weight (heads * (nope + rope), q_lora_rank), kv_a_weight
+    # (kv_lora_rank + rope, C), kv_norm (kv_lora_rank), kv_b_weight
+    # (heads * (nope + v), kv_lora_rank; a head's rows are its key rows,
+    # then its value rows), proj_weight (C, heads * v). num_kv_heads,
+    # head_dim, window and sequence_parallel have no meaning here
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the rotary lanes turn in adjacent pairs (x_2i, x_2i+1) instead of
+    # rotate-half; latent attention only
+    rope_interleave: bool = False
+    # eps of the two RMSNorms inside the latent path
+    norm_eps: float = 1e-6
 
 
 @dataclass
@@ -520,6 +541,22 @@ class MoEParameter(Message):
     # part. 0 = all of them
     experts_held: int = 0
     first_expert: int = 0
+    # the rest is the dropless path's. scoring "softmax": the top_k largest
+    # logits, softmax over those. "sigmoid" (arXiv:2412.19437): s =
+    # sigmoid(logits); the top_k largest of s + select_bias (a blob after
+    # `gate`, one value an expert; it selects and does not weigh); weights
+    # routed_scaling_factor * s / sum of the chosen s
+    scoring: str = "softmax"
+    # filler of select_bias (default: zeros)
+    bias_filler: FillerParameter | None = None
+    routed_scaling_factor: float = 1.0
+    # the gate's activation in an expert, act(x w1) * (x w3): relu | silu
+    activation: str = "relu"
+    # shared experts: one gated unit of shared_experts * hidden_dim that
+    # every token passes through, added to the routed experts' part (blobs
+    # shared_w1, shared_w3 (C, n h), shared_w2 (n h, C)). Under expert
+    # parallelism every chip computes it for its own tokens
+    shared_experts: int = 0
 
 
 @dataclass

@@ -1158,6 +1158,28 @@ def _attention(ctx):
         return [None]
     c = s[2]
     heads = max(p.num_heads, 1)
+    if p.kv_lora_rank:
+        # latent attention (layers/sequence.py latent_dims)
+        nope, rot, vd = p.qk_nope_head_dim, p.qk_rope_head_dim, p.v_head_dim
+        if min(p.q_lora_rank, nope, rot, vd) < 1 or rot % 2:
+            ctx.problem("shape", "latent attention (kv_lora_rank > 0) needs "
+                                 "q_lora_rank, qk_nope_head_dim, v_head_dim "
+                                 "and an even qk_rope_head_dim")
+            return [None]
+        if (p.num_kv_heads or p.head_dim or p.window or p.sequence_parallel
+                or p.bias_term or not p.rope_theta):
+            ctx.problem("shape", "latent attention has rope_theta and "
+                                 "bias_term: false, and neither "
+                                 "num_kv_heads, head_dim, window nor "
+                                 "sequence_parallel")
+        ctx.declare("q_a_weight", (p.q_lora_rank, c))
+        ctx.declare("q_norm", (p.q_lora_rank,))
+        ctx.declare("q_b_weight", (heads * (nope + rot), p.q_lora_rank))
+        ctx.declare("kv_a_weight", (p.kv_lora_rank + rot, c))
+        ctx.declare("kv_norm", (p.kv_lora_rank,))
+        ctx.declare("kv_b_weight", (heads * (nope + vd), p.kv_lora_rank))
+        ctx.declare("proj_weight", (c, heads * vd))
+        return [s]
     kv = p.num_kv_heads or heads
     if p.head_dim == 0 and c is not None and c % heads:
         ctx.problem("shape",
@@ -1206,7 +1228,20 @@ def _moe(ctx):
             and ctx.in_shapes[1] != s:
         ctx.problem("shape", f"MoE router bottom {_fmt(ctx.in_shapes[1])} "
                              f"!= {_fmt(s)}")
+    plain = (p.scoring == "softmax" and p.activation == "relu"
+             and p.routed_scaling_factor == 1.0 and not p.shared_experts)
+    if not p.dropless and not plain:
+        ctx.problem("shape", "moe_param: scoring, routed_scaling_factor, "
+                             "activation and shared_experts need "
+                             "dropless: true")
+    if p.scoring not in ("softmax", "sigmoid") \
+            or p.activation not in ("relu", "silu"):
+        ctx.problem("shape", f"moe_param: scoring {p.scoring!r} (softmax | "
+                             f"sigmoid), activation {p.activation!r} (relu "
+                             f"| silu)")
     ctx.declare("gate", (c, p.num_experts))
+    if p.scoring == "sigmoid":
+        ctx.declare("select_bias", (p.num_experts,))
     ctx.declare("w1", (held, c, p.hidden_dim))
     if not p.dropless:
         ctx.declare("b1", (held, p.hidden_dim))
@@ -1215,6 +1250,11 @@ def _moe(ctx):
         ctx.declare("w3", (held, c, p.hidden_dim))
     else:
         ctx.declare("b2", (held, c))
+    if p.shared_experts:
+        wide = p.shared_experts * p.hidden_dim
+        ctx.declare("shared_w1", (c, wide))
+        ctx.declare("shared_w3", (c, wide))
+        ctx.declare("shared_w2", (wide, c))
     tops = [s]
     if len(ctx.lp.top) > 1:
         tops.append((held,) if p.dropless else ())
@@ -1364,6 +1404,14 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         w = min(getattr(p, "window", 0) or s, s)
         pairs = (w * (w + 1) // 2 + (s - w) * w) \
             if getattr(p, "causal", False) else s * s
+        if getattr(p, "kv_lora_rank", 0):
+            # latent attention: the seven blobs' products, scores over
+            # nope + rope lanes, values over v_head_dim
+            qk = p.qk_nope_head_dim + p.qk_rope_head_dim
+            return s * sum(_prod(shape) for name, shape
+                           in param_shapes.items() if name.endswith(
+                               "weight")) \
+                + pairs * heads * (qk + p.v_head_dim)
         return s * c * (2 * heads + 2 * kv) * hd + 2 * pairs * heads * hd
     if type_name == "MoE":
         s0 = in_shapes[0] if in_shapes else None
@@ -1380,8 +1428,10 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         if p is not None and p.dropless:
             # a token's k choices fall on the held experts with
             # probability held / num_experts each; three matrices
+            # plus the shared experts, which every token passes through
             return tokens * c * p.num_experts + (
-                tokens * k * e * 3 * c * h // p.num_experts)
+                tokens * k * e * 3 * c * h // p.num_experts) \
+                + tokens * p.shared_experts * 3 * c * h
         return tokens * (c * e + k * 2 * c * h)
     return 0
 

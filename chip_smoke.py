@@ -438,7 +438,7 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
                                  use_flash=True).astype(jnp.bfloat16)
 
             @jax.checkpoint
-            def group(qkv):   # (7 query heads, 1 key/value head)
+            def group(qkv):   # (a group's query heads, 1 key/value head)
                 qg, kg, vg = (t.astype(jnp.float32)[None] for t in qkv)
                 return attention(qg.transpose(0, 2, 1, 3), kg[:, :, None],
                                  vg[:, :, None], causal=True,
@@ -448,8 +448,8 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
             out = jax.lax.map(group, (
                 q[0].reshape(s, hkv, h // hkv, d).transpose(1, 2, 0, 3),
                 k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2)))
-            return out.transpose(1, 0, 2, 3).reshape(b, s, h, d).astype(
-                jnp.bfloat16)
+            return out.transpose(1, 0, 2, 3).reshape(
+                b, s, h, v.shape[-1]).astype(jnp.bfloat16)
         return f
     for s_win in (2048 // scale, 8192 // scale):
         q = jnp.asarray(rng.randn(1, s_win, 28, 128).astype(np.float32),
@@ -462,6 +462,20 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
                 f"flash window {window} 28/4 heads S={s_win} bf16",
                 with_grad(windowed(True, window)),
                 with_grad(windowed(False, window)), (q, *kv), 5e-2, on_chip)
+
+    # latent attention's shape in the benchmark's second language-model
+    # cell (models/joyai_llm_flash): 32 heads, queries and keys 192 wide
+    # (128 + 64 rotary lanes), values 128 wide, S 8192, no window
+    s_mla = 8192 // scale
+    q, k = (jnp.asarray(rng.randn(1, s_mla, 32, 192).astype(np.float32),
+                        jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(rng.randn(1, s_mla, 32, 128).astype(np.float32),
+                    jnp.bfloat16)
+    results[f"flash-latent-32heads-s{s_mla}-d192-dv128-bf16"] = \
+        _check_kernel(f"flash causal 32 heads S={s_mla} d=192 dv=128 bf16",
+                      with_grad(windowed(True, 0)),
+                      with_grad(windowed(False, 0)), (q, k, v), 5e-2,
+                      on_chip)
 
     from caffe_mpi_tpu.ops.moe import moe_dropless
     d_model, width, held = 2560 // scale, 768 // scale, 16

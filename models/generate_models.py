@@ -987,6 +987,153 @@ snapshot_prefix: "models/smallthinker_21b_a3b/{prefix}"
 """
 
 
+JOYAI = dict(
+    # https://huggingface.co/jdopensource/JoyAI-LLM-Flash config.json:
+    # widths as published; depth, experts held and vocabulary are one
+    # chip's share (benchmarks/configs/joyai_llm_flash.json)
+    seq=8192, vocab=16160, dim=2048, heads=32, q_lora=1536, kv_lora=512,
+    nope=128, rot=64, vd=128, rope_theta=3.2e7, dense_width=7168,
+    expert_layers=4, experts=256, experts_held=16, top_k=8,
+    expert_width=768, shared_experts=1, scaling=2.5, eps=1e-6)
+# the size of tests/test_joyai.py and of the benchmark's CPU rehearsal:
+# every mechanism, no width (32 experts in 16 shares of 2)
+JOYAI_TINY = dict(
+    seq=32, vocab=64, dim=64, heads=4, q_lora=48, kv_lora=32, nope=16,
+    rot=8, vd=16, rope_theta=3.2e7, dense_width=96, expert_layers=2,
+    experts=32, experts_held=2, top_k=4, expert_width=32,
+    shared_experts=1, scaling=2.5, eps=1e-6)
+JOYAI_MTP_WEIGHT = 0.3
+# layers computed again in the backward pass (LayerParameter.remat)
+JOYAI_REMAT = ("attn",)
+# the selection bias: a level the choice cannot see and the weights would,
+# a spread that decides the eighth choice of about half the tokens
+JOYAI_SELECT_BIAS = dict(type="gaussian", mean=-0.8, std=0.01)
+
+
+def joyai_llm_flash(batch=1, *, seq, vocab, dim, heads, q_lora, kv_lora,
+                    nope, rot, vd, rope_theta, dense_width, expert_layers,
+                    experts, experts_held, top_k, expert_width,
+                    shared_experts, scaling, eps, first_expert=0,
+                    use_flash=True, remat=(), name="joyai_llm_flash"):
+    """JoyAI-LLM-Flash (DeepSeek-V3 family, arXiv:2412.19437), one chip's
+    share: pre-norm blocks of latent attention (arXiv:2405.04434) and a
+    feed-forward that is a dense gated-SiLU MLP in the leading block and,
+    after it, a dropless expert layer with sigmoid scores chosen under a
+    selection bias, SiLU-gated experts and one shared expert; then the
+    head and the multi-token-prediction module (one more expert block on
+    [norm(Emb(next token)) | norm(trunk output)], the embedding table and
+    the head shared with the trunk by name, its loss weighted 0.3). No
+    biases, untied embedding and head. The layer equations are written out
+    in benchmarks/reference/joyai_ref.py. `remat`: layer-name suffixes
+    whose layers are computed again in the backward pass."""
+    filler = dict(type="gaussian", std=0.02)
+    n = NetSpec(name)
+    # label = the next token (also what the MTP module embeds), label_mtp
+    # the one after
+    n.tokens, n.label, n.label_mtp = L.Input(ntop=3, input_param=dict(
+        shape=[dict(dim=[batch, seq])] * 3))
+    again = lambda layer: dict(remat=True) \
+        if any(layer.endswith(suffix) for suffix in remat) else {}
+
+    def put(layer, top):
+        setattr(n, layer, top)
+        return top
+
+    def norm(layer, x):
+        return put(layer, L.RMSNorm(x, eps=eps, **again(layer)))
+
+    def block(b, x, dense):
+        ln1 = norm(f"{b}/ln1", x)
+        attn = put(f"{b}/attn", L.Attention(
+            ln1, num_heads=heads, causal=True, use_flash=use_flash,
+            bias_term=False, rope_theta=rope_theta, q_lora_rank=q_lora,
+            kv_lora_rank=kv_lora, qk_nope_head_dim=nope,
+            qk_rope_head_dim=rot, v_head_dim=vd, rope_interleave=True,
+            norm_eps=eps, weight_filler=filler, **again(f"{b}/attn")))
+        res1 = put(f"{b}/res1", L.Eltwise(x, attn))
+        ln2 = norm(f"{b}/ln2", res1)
+        if dense:
+            # silu(m G) * (m U) as m G * sigmoid(m G) * m U
+            product = dict(num_output=dense_width, axis=2, bias_term=False,
+                           weight_filler=filler)
+            gate = put(f"{b}/gate", L.InnerProduct(ln2, **product))
+            up = put(f"{b}/up", L.InnerProduct(ln2, **product))
+            sig = put(f"{b}/sig", L.Sigmoid(gate))
+            act = put(f"{b}/act", L.Eltwise(gate, sig, up, operation="PROD"))
+            ffn = put(f"{b}/down", L.InnerProduct(
+                act, num_output=dim, axis=2, bias_term=False,
+                weight_filler=filler))
+        else:
+            # the router scores the experts' own input (the same blob,
+            # twice). Routing is a constant of the step, as in
+            # smallthinker(): the router matrix and the selection bias
+            # (the first two blobs) are frozen and nothing trains through
+            # the scores. Second top: rows each held expert received
+            ffn, rows = L.MoE(
+                ln2, ln2, ntop=2, loss_weight=[0.0, 0.0],
+                param=[dict(lr_mult=0, decay_mult=0)] * 2,
+                propagate_down=[True, False],
+                moe_param=dict(
+                    num_experts=experts, hidden_dim=expert_width,
+                    top_k=top_k, dropless=True, experts_held=experts_held,
+                    first_expert=first_expert, scoring="sigmoid",
+                    routed_scaling_factor=scaling, activation="silu",
+                    shared_experts=shared_experts, weight_filler=filler,
+                    bias_filler=JOYAI_SELECT_BIAS))
+            put(f"{b}/moe", ffn)
+            put(f"{b}/moe_rows", rows)
+        return put(f"{b}/res2", L.Eltwise(res1, ffn))
+
+    table = dict(input_dim=vocab, num_output=dim, bias_term=False,
+                 weight_filler=dict(type="gaussian", std=1.0),
+                 param=[dict(name="embed_w")])
+    head = dict(num_output=vocab, axis=2, bias_term=False,
+                weight_filler=filler, param=[dict(name="head_w")])
+    n.embed = L.Embed(n.tokens, **table)
+    x = n.embed
+    for l in range(1 + expert_layers):
+        x = block(f"blk{l}", x, dense=l == 0)
+    n.ln_f = L.RMSNorm(x, eps=eps)
+    n.logits = L.InnerProduct(n.ln_f, **head)
+    n.loss = L.SoftmaxWithLoss(n.logits, n.label,
+                               softmax_param=dict(axis=2))
+    # multi-token prediction, depth 1: the trunk's output BEFORE its last
+    # norm beside the next token's embedding, one expert block, the
+    # trunk's head
+    emb = put("mtp/embed", L.Embed(n.label, **table))
+    cat = put("mtp/cat", L.Concat(norm("mtp/enorm", emb),
+                                  norm("mtp/hnorm", x), axis=2))
+    z = put("mtp/eh_proj", L.InnerProduct(
+        cat, num_output=dim, axis=2, bias_term=False, weight_filler=filler))
+    z = block("mtp", z, dense=False)
+    logits = put("mtp/logits", L.InnerProduct(norm("mtp/ln_f", z), **head))
+    put("mtp/loss", L.SoftmaxWithLoss(
+        logits, n.label_mtp, loss_weight=JOYAI_MTP_WEIGHT,
+        softmax_param=dict(axis=2)))
+    n.accuracy = L.Accuracy(n.logits, n.label, axis=2,
+                            include=dict(phase="TEST"))
+    return n
+
+
+def joyai_solver(net: str, prefix: str) -> str:
+    return f"""# JoyAI-LLM-Flash, one chip's share: Adam (this system's
+# coupled L2, none set), fixed 3e-4, global-norm clip 1; static loss scale
+# for the reason models/smallthinker_21b_a3b/solver.prototxt gives
+net: "models/joyai_llm_flash/{net}"
+base_lr: 0.0003
+lr_policy: "fixed"
+display: 10
+max_iter: 10000
+momentum: 0.9
+momentum2: 0.95
+type: "Adam"
+clip_gradients: 1.0
+loss_scale: 1.0
+snapshot: 10000
+snapshot_prefix: "models/joyai_llm_flash/{prefix}"
+"""
+
+
 def transformer_lm_pp_prototxt(batch=8, seq=64, vocab=256, dim=128, heads=4,
                                n_stages=4, micro_batches=4, ffn_hidden=256):
     """Pipeline-parallel transformer_lm variant: the trunk is ONE Pipeline
@@ -1367,22 +1514,26 @@ def main():
             f.write(make_deploy(tv) + "\n")
         print(f"wrote models/{name}/")
 
-    # smallthinker_21b_a3b: the benchmark's recipe at the published widths
-    # and the tiny one its CPU rehearsal and tests/test_smallthinker.py
-    # run (train only: no deploy net, the serving path has no key/value
-    # cache yet)
-    d = os.path.join(out_root, "smallthinker_21b_a3b")
-    os.makedirs(d, exist_ok=True)
-    for net, solver, sizes in (
-            ("train_val.prototxt", "solver.prototxt", SMALLTHINKER),
-            ("tiny_train_val.prototxt", "tiny_solver.prototxt",
-             SMALLTHINKER_TINY)):
-        with open(os.path.join(d, net), "w") as f:
-            f.write(smallthinker(**sizes).to_prototxt() + "\n")
-        with open(os.path.join(d, solver), "w") as f:
-            f.write(smallthinker_solver(net, net.split("train_val")[0]
-                                        + "smallthinker"))
-    print("wrote models/smallthinker_21b_a3b/")
+    # the language-model configurations of the benchmark: the recipe at the
+    # published widths and the tiny one its CPU rehearsal and tests run
+    # (tests/test_smallthinker.py, tests/test_joyai.py). Train only: no
+    # deploy net, the serving path has no key/value cache yet
+    for family, build, solver_text, real, tiny, prefix in (
+            ("smallthinker_21b_a3b", smallthinker, smallthinker_solver,
+             SMALLTHINKER, SMALLTHINKER_TINY, "smallthinker"),
+            ("joyai_llm_flash", joyai_llm_flash, joyai_solver,
+             dict(JOYAI, remat=JOYAI_REMAT),
+             dict(JOYAI_TINY, remat=JOYAI_REMAT), "joyai")):
+        d = os.path.join(out_root, family)
+        os.makedirs(d, exist_ok=True)
+        for net, solver, sizes in (
+                ("train_val.prototxt", "solver.prototxt", real),
+                ("tiny_train_val.prototxt", "tiny_solver.prototxt", tiny)):
+            with open(os.path.join(d, net), "w") as f:
+                f.write(build(**sizes).to_prototxt() + "\n")
+            with open(os.path.join(d, solver), "w") as f:
+                f.write(solver_text(net, net.split("train_val")[0] + prefix))
+        print(f"wrote models/{family}/")
 
     # transformer_lm model-parallel variants: PP trunk (Pipeline layer)
     # and SP attention (sequence_parallel: true), each launchable from one

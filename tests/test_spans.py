@@ -339,3 +339,43 @@ def test_profile_is_refused_where_nothing_reads_it(command, capsys):
         main([command, "-profile", "/nonexistent"])
     assert exit_.value.code == 2
     assert "-profile is read by train and time" in capsys.readouterr().err
+
+
+# -- scopes inside a dropless expert layer (ops/moe.py) -----------------------
+
+MOE_SCOPES = [spans.MOE_ROUTE, spans.MOE_DISPATCH, spans.MOE_EXPERTS,
+              spans.MOE_COMBINE, spans.MOE_SHARED]
+
+
+@pytest.fixture(scope="module")
+def expert_layer_op_names():
+    """`op_name`s of the compiled gradient of a dropless layer with a
+    shared expert (sigmoid scoring, a selection bias, SiLU gates)."""
+    import jax
+    import jax.numpy as jnp
+    from caffe_mpi_tpu.ops.moe import moe_dropless
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    shapes = {"gate": (16, 8), "select_bias": (8,), "w1": (2, 16, 8),
+              "w3": (2, 16, 8), "w2": (2, 8, 16), "shared_w1": (16, 8),
+              "shared_w3": (16, 8), "shared_w2": (8, 16)}
+    params = {k: jax.random.normal(key, shape)
+              for key, (k, shape) in zip(ks, shapes.items())}
+    x = jax.random.normal(ks[-1], (12, 16))
+
+    def loss(params, x):
+        y, _ = moe_dropless(params, x, x, top_k=2, scoring="sigmoid",
+                            scale=2.5, activation="silu")
+        return jnp.sum(y * y)
+    return op_names(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text())
+
+
+@pytest.mark.parametrize("scope", MOE_SCOPES)
+def test_expert_layer_scopes_are_in_the_table_and_in_the_step(
+        scope, expert_layer_op_names):
+    assert f"| `{scope}` | scope |" in spans.__doc__
+    carrying = [n for n in expert_layer_op_names if f"({scope})" in n]
+    assert {"transpose(" in n for n in carrying} == {False, True}, scope
+    # a scope of its own: no operation sits under two of them
+    assert not any(other in n for n in carrying
+                   for other in MOE_SCOPES if other != scope)

@@ -298,6 +298,82 @@ class TestSmallThinkerCell:
         assert len(calls) == expected, (len(calls), expected)
 
 
+class TestJoyAICell:
+    """The benchmark's latent-attention cell (joyai_flash_bf16_s8k_epshare)
+    at its published widths: the flash kernels with a 192-wide query/key
+    operand beside 128-wide values, and the whole train step as the
+    `train_mla_lm` driver builds it."""
+
+    def test_flash_at_unequal_widths_at_the_cell_shape(self):
+        from caffe_mpi_tpu.ops.flash_attention import flash_attention
+        qk = on_chip((1, 8192, 32, 192), jnp.bfloat16)
+        v = on_chip((1, 8192, 32, 128), jnp.bfloat16)
+
+        def f(q, k, v):
+            out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, causal=True), q, k, v)
+            return out, vjp(out)
+        text = compile_tpu(f, qk, qk, v)   # Mosaic refuses what VMEM lacks
+        names = sorted(re.sub(r"\.\d+$", "", name)
+                       for name, _ in kernel_calls(text))
+        assert names == ["flash_dkv", "flash_dq", "flash_fwd"]
+        # one 192-wide operand, not padded to 256 by the wrapper
+        assert "bf16[32,8192,192]" in text
+        assert "bf16[32,8192,256]" not in text
+
+    def test_whole_step_compiles_and_fits_the_chip(self):
+        import os
+        import sys
+        from pathlib import Path
+        root = Path(__file__).resolve().parent.parent
+        bench = root / "benchmarks"
+        sys.path[:0] = [p for p in (str(bench),) if p not in sys.path]
+        import run as harness
+        driver = harness.load_module(bench / "drivers" / "train_mla_lm.py")
+        cell = harness.load_cell("joyai_flash_bf16_s8k_epshare",
+                                 rehearse=False)
+        job = driver.train.build_job(
+            cell, 0, Path(os.environ.get("TMPDIR", "/tmp")) / "aot_mla_lm")
+        solver = job.solver
+        try:
+            assert not solver._guard_on   # static loss scale: one state
+            rep = SingleDeviceSharding(v5e_devices()[0])
+            feeds = {k: jax.ShapeDtypeStruct((1, *shape), jnp.int32,
+                                             sharding=rep)
+                     for k, (shape, _) in solver.net.feed_specs.items()}
+            assert {k: v.shape for k, v in feeds.items()} == {
+                k: (1, 1, 8192) for k in ("tokens", "label", "label_mtp")}
+            args = [abstract(solver.params, rep),
+                    abstract(solver.net_state, rep),
+                    abstract(solver.opt_state, rep), feeds,
+                    abstract(jnp.int32(0), rep),
+                    abstract(solver.base_rng, rep)]
+            compiled = (jax.jit(solver._iteration_fn(),
+                                donate_argnums=(0, 1, 2))
+                        .trace(*args).lower(lowering_platforms=("tpu",))
+                        .compile())
+        finally:
+            solver.close()
+        mem = compiled.memory_analysis()
+        # f32 masters and Adam's two slots, 12 bytes a parameter
+        assert mem.argument_size_in_bytes >= 12 * 680_441_088
+        live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        # the chip's allocator has 16.909e9 bytes. 14.68e9 here with the
+        # six attention layers' `remat: true` (their flash outputs kept,
+        # the projections computed again); 16.29e9 without it, 16.53e9
+        # with the norms computed again as well (PERF.md, PR 31)
+        assert live < 15.2e9, live
+        calls = [re.sub(r"\.\d+$", "", name)
+                 for name, _ in kernel_calls(compiled.as_text())]
+        flash = [c for c in calls if c.startswith("flash_")]
+        # remat does not run the forward kernel a second time
+        assert sorted(flash) == (["flash_dkv"] * 6 + ["flash_dq"] * 6
+                                 + ["flash_fwd"] * 6)
+        expected = cell["config"]["checks"]["pallas_calls_per_step"]["bf16"]
+        assert len(calls) == expected == 63, (len(calls), expected)
+
+
 _ALEXNET_HEAD = """
 name: "alexnet_head"
 layer { name: "data" type: "Input" top: "data"

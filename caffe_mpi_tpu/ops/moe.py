@@ -29,10 +29,18 @@ the scoring (`route`: top-k then softmax, or sigmoid scores chosen under a
 selection bias and renormalised, times a scaling factor), the gate's
 activation (relu | silu), and shared experts, one gated unit every token
 passes through (scope `moe.shared`). It is told which experts it holds (an
-expert-parallel share): the router still scores all of them, rows routed
-to absent experts sort last and cost no expert FLOPs, and the result is
-the held experts' part plus, computed here for this chip's own tokens, the
-shared experts'.
+expert-parallel share): the router still scores all of them, and the
+result is the held experts' part plus, computed here for this chip's own
+tokens, the shared experts'. Rows routed to absent experts sort last. They
+cost no expert FLOPs, but a buffer of every pair costs everything else on
+them, forward and backward (the gather, two selects, the activation, the
+weighting, the re-layouts the transposed products need): 75 % and 94 % of
+the two recipes' rows. So a layer that holds a share sizes its buffer by a
+bound on the rows its held experts receive, 1.5 x their average share,
+and the device, which has the live count before the first gather, takes
+the buffer of every pair instead whenever the count reaches the bound
+(`_bounded_rows`, scope `moe.fallback`): a skewed router costs time, never
+a row.
 
 Expert parallelism = shard the E dimension (expert weights AND the
 (E, C, ...) activation tensors) over a mesh axis via sharding
@@ -51,8 +59,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..utils.spans import (MOE_COMBINE as COMBINE, MOE_DISPATCH as DISPATCH,
-                           MOE_EXPERTS as EXPERTS, MOE_ROUTE as ROUTE,
-                           MOE_SHARED as SHARED)
+                           MOE_EXPERTS as EXPERTS, MOE_FALLBACK as FALLBACK,
+                           MOE_ROUTE as ROUTE, MOE_SHARED as SHARED)
 
 ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
@@ -170,13 +178,16 @@ def moe_ffn_dense_reference(params: dict, x: jnp.ndarray, *,
 
 # -- dropless, sorted-by-expert formulation ---------------------------------
 
+ROW_TILE = 512   # rows of a grouped product's tile, the row bound's unit
+
+
 def _gmm_tiles(m: int, k: int, n: int) -> tuple[int, int, int]:
     """(rows, contraction, output) tile of a grouped product: 512 rows,
     and of each weight dimension the largest multiple of 128 up to 1280
     that divides it, so that a tile never straddles the matrix."""
     side = lambda d: next((t for t in (1280, 1024, 768, 512, 384, 256, 128)
                            if d % t == 0), d)
-    return (512 if m % 512 == 0 else 128 if m % 128 == 0 else m,
+    return (ROW_TILE if m % ROW_TILE == 0 else 128 if m % 128 == 0 else m,
             side(k), side(n))
 
 
@@ -234,28 +245,51 @@ def grouped_dot(rows, bank, sizes):
                                       cpu=jax.lax.ragged_dot, default=_gmm)
 
 
-# The sorted buffer holds one row per (token, choice) pair: row p is pair
-# order[p], pair q sits at row inv[q], pair q = j * T + t is token t's j-th
-# choice (choice-major, so that the pairs' (k, T, F) view splits the leading
-# axis: token-major, the (T, k, F) view cost a 0.9 ms re-layout each way).
-# Moving rows between the two orders is a permutation, whose transpose is
-# the inverse permutation: a gather both ways. Left to
-# `jnp.take`'s own transpose rule the backward pass is a scatter-add of
-# T * k rows, which XLA:TPU runs an order of magnitude below a gather's
-# speed (PERF.md section 6, PR 27).
+def _ragged_dot_t(rows, bank, sizes, grad):
+    return jax.vjp(lambda r, b: jax.lax.ragged_dot(r, b, sizes), rows,
+                   bank)[1](grad)
+
+
+def grouped_dot_t(rows, bank, sizes, grad):
+    """`grouped_dot(rows, bank, sizes)`'s two transposes on `grad`: the
+    rows' gradient (M, K) and the bank's (G, K, N)."""
+    return jax.lax.platform_dependent(
+        rows, bank, sizes, grad, cpu=_ragged_dot_t,
+        default=lambda *operands: _gmm_bwd(operands[:3], operands[3])[:2])
+
+
+# The sorted buffer's row p is pair order[p], pair q sits at row inv[q], pair
+# q = j * T + t is token t's j-th choice (choice-major, so that the pairs'
+# (k, T, F) view splits the leading axis: token-major, the (T, k, F) view
+# cost a 0.9 ms re-layout each way). The buffer holds the first R rows of
+# that order (`order` arrives cut to R; R = k * T holds every pair). Moving
+# rows between the two orders is a permutation, whose transpose is the
+# inverse permutation: a gather both ways. A buffer of R < k * T rows holds
+# fewer than R live ones, so its last row is dead and zero, and a pair whose
+# row lies past R reads that one: the gather clips its indices, where
+# `jnp.take`'s own out-of-range rule (fill) costs a select over all k * T
+# rows after it (0.8 ms; PERF.md section 6, PR 32). Left to `jnp.take`'s
+# own transpose rule the backward pass is a scatter-add of T * k rows, which
+# XLA:TPU runs an order of magnitude below a gather's speed (PERF.md
+# section 6, PR 27).
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch(x, order, inv, k):
-    """(T, F) tokens -> (k * T, F) rows in sorted order."""
-    return jnp.take(x, order % x.shape[0], axis=0)
+    """(T, F) tokens -> (R, F) rows in sorted order."""
+    return jnp.take(x, order % x.shape[0], axis=0, mode="clip")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _combine(rows, order, inv, k):
-    """(k * T, F) rows in sorted order -> (T, F): each token's k rows
-    summed (in float32). The transpose of `_dispatch`."""
-    back = jnp.take(rows, inv, axis=0).reshape(k, -1, rows.shape[1])
+    """(R, F) rows in sorted order, dead ones zero -> (T, F): each token's
+    k rows summed (in float32). The transpose of `_dispatch`."""
+    back = _unsort(rows, inv).reshape(k, -1, rows.shape[1])
     return jnp.sum(back.astype(jnp.float32), axis=0).astype(rows.dtype)
+
+
+def _unsort(rows, inv):
+    """rows[inv] over every pair: the last row for a pair past the end."""
+    return jnp.take(rows, inv, axis=0, mode="clip")
 
 
 _dispatch.defvjp(
@@ -268,15 +302,14 @@ _combine.defvjp(
 
 
 @jax.custom_vjp
-def _permute(values, perm, inverse):
-    """values[perm] for a permutation and its inverse."""
-    return jnp.take(values, perm, axis=0)
+def _permute(values, order, inv):
+    """values[order], (k * T,) -> (R,), under the same pair of orders."""
+    return jnp.take(values, order, axis=0, mode="clip")
 
 
 _permute.defvjp(
-    lambda values, perm, inverse: (jnp.take(values, perm, axis=0),
-                                   (perm, inverse)),
-    lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
+    lambda values, order, inv: (_permute(values, order, inv), inv),
+    lambda inv, g: (_unsort(g, inv), None, None))
 
 
 def route(logits: jnp.ndarray, top_k: int, scoring: str = "softmax",
@@ -301,6 +334,125 @@ def route(logits: jnp.ndarray, top_k: int, scoring: str = "softmax",
     return scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20), ids
 
 
+def _row_bound(pairs: int, held: int, experts: int) -> int:
+    """Rows of the sorted buffer for `pairs` (token, choice) pairs of which
+    the `held` of `experts` experts receive their share on average: the
+    smallest multiple of the row tile at or over 1.5 x that share (the
+    held experts of the two recipes received 0.79-1.17 x theirs over every
+    seed read on the chip, PERF.md section 6, PR 32), and `pairs` where
+    that is no fewer."""
+    tiles = -(-3 * pairs * held // (2 * experts * ROW_TILE))
+    return min(tiles * ROW_TILE, pairs)
+
+
+def _sorted_rows(dot, k, act, x, w, banks, order, inv, sizes):
+    """The held experts over the sorted buffer's first R = len(order) rows,
+    which have to hold every live one: x (T, F), w (k * T,) the pairs'
+    weights, banks (w1, w3, w2), `dot` the grouped product -> (y (T, F),
+    what the backward pass reads again: xs (R, F), the two products
+    (R, H), ys (R, F))."""
+    w1, w3, w2 = banks
+    live = _live(order, sizes)
+    with jax.named_scope(DISPATCH):
+        xs = jnp.where(live, _dispatch(x, order, inv, k), 0)
+    with jax.named_scope(EXPERTS):
+        a = dot(xs, w1, sizes)
+        b = dot(xs, w3, sizes)
+        ys = dot(act(a) * b, w2, sizes)
+    with jax.named_scope(COMBINE):
+        y = _combine(_weigh(ys, _permute(w, order, inv), live),
+                     order, inv, k)
+    return y, (xs, a, b, ys)
+
+
+def _live(order, sizes):
+    """(R, 1) mask of the buffer's live rows, which sort first. Rows past
+    the last group are never written by the grouped products, forward or
+    backward: selects on this mask, not products (0 * NaN), keep what is
+    left there out of the result and out of every gradient."""
+    return (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+
+
+def _weigh(ys, w, live):
+    return jnp.where(live, ys, 0) * w[:, None].astype(ys.dtype)
+
+
+def _sorted_rows_bwd(k, act, w, banks, order, inv, sizes, saved, g):
+    """Gradients of `_sorted_rows`' y with respect to (x, w, banks) from
+    what it saved: each stage's own transpose, in the stages' scopes."""
+    w1, w3, w2 = banks
+    xs, a, b, ys = saved
+    live = _live(order, sizes)
+    with jax.named_scope(COMBINE):
+        d_ys, d_w = jax.vjp(lambda ys, w: _weigh(ys, w, live), ys,
+                            _permute(w, order, inv)
+                            )[1](_dispatch(g, order, inv, k))
+        d_w = _unsort(d_w, inv)
+    with jax.named_scope(EXPERTS):
+        h, gate_bwd = jax.vjp(lambda a, b: act(a) * b, a, b)
+        d_h, d_w2 = grouped_dot_t(h, w2, sizes, d_ys)
+        d_a, d_b = gate_bwd(d_h)
+        d_xs1, d_w1 = grouped_dot_t(xs, w1, sizes, d_a)
+        d_xs3, d_w3 = grouped_dot_t(xs, w3, sizes, d_b)
+    with jax.named_scope(DISPATCH):
+        d_x = _combine(jnp.where(live, d_xs1 + d_xs3, 0), order, inv, k)
+    return d_x, d_w, (d_w1, d_w3, d_w2)
+
+
+# Fewer rows than pairs: the bound holds every live row, and a dead one
+# after them, unless the router sends the held experts 1.5 x their share
+# or more, which the device decides from the live count before the first
+# gather. `lax.cond` runs one branch: the bounded one, or (scope
+# `moe.fallback`) the buffer of every pair, so that no row is ever
+# dropped. Differentiated by jax, a `cond` returns both branches'
+# residuals, the untaken one's as zeros at full size; this rule keeps the
+# bounded branch's alone, and the fallback's backward pass runs its forward
+# pass again.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_rows(k, act, bound, x, w, banks, order, inv, sizes):
+    return _bounded_rows_fwd(k, act, bound, x, w, banks, order, inv,
+                             sizes)[0]
+
+
+def _fallback(k, act, x, w, banks, order, inv, sizes):
+    """The buffer of every pair. Its grouped products are XLA's own
+    `ragged_dot` on every platform: 15 % slower on the chip than the Pallas
+    kernels (`grouped_dot`), but eleven more of those a layer, at a second
+    row count, cost every start of the program 8 s of tracing and of
+    loading a step two thirds larger (PERF.md section 6, PR 32), for a
+    branch that a balanced router never takes."""
+    with jax.named_scope(FALLBACK):
+        return _sorted_rows(jax.lax.ragged_dot, k, act, x, w, banks, order,
+                            inv, sizes)[0]
+
+
+def _bounded_rows_fwd(k, act, bound, x, w, banks, order, inv, sizes):
+    bounded = lambda: _sorted_rows(grouped_dot, k, act, x, w, banks,
+                                   order[:bound], inv, sizes)
+    wide, narrow = (bound, x.shape[1]), (bound, banks[0].shape[2])
+    y, saved = jax.lax.cond(
+        jnp.sum(sizes) < bound, bounded,
+        lambda: (_fallback(k, act, x, w, banks, order, inv, sizes),
+                 tuple(jnp.zeros(shape, x.dtype)
+                       for shape in (wide, narrow, narrow, wide))))
+    return y, (x, w, banks, order, inv, sizes, saved)
+
+
+def _bounded_rows_bwd(k, act, bound, res, g):
+    x, w, banks, order, inv, sizes, saved = res
+    return jax.lax.cond(
+        jnp.sum(sizes) < bound,
+        lambda: _sorted_rows_bwd(k, act, w, banks, order[:bound], inv, sizes,
+                                 saved, g),
+        lambda: jax.vjp(lambda x, w, banks: _fallback(
+            k, act, x, w, banks, order, inv, sizes), x, w, banks)[1](g)
+    ) + (None, None, None)
+
+
+_bounded_rows.defvjp(_bounded_rows_fwd, _bounded_rows_bwd)
+
+
 def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
                  top_k: int, first_expert: int = 0,
                  scoring: str = "softmax", scale: float = 1.0,
@@ -319,9 +471,12 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
     for every token, unweighted.
     rows[e] counts the (token, choice) pairs held expert e received.
 
-    Every (token, choice) pair gets a row of the sorted buffer, so shapes
-    are static at top_k * T rows; pairs routed to absent experts sort
-    after the last group, where the grouped products do no work."""
+    The (token, choice) pairs are sorted by expert, those of absent
+    experts last, and the sorted buffer holds the first R rows of that
+    order: R = `_row_bound`, static, top_k * T when every expert is held.
+    With fewer, R is 1.5 x the held experts' average share, and the device
+    takes the buffer of all top_k * T rows instead (`_bounded_rows`)
+    whenever the live rows reach it: nothing is dropped either way."""
     held = params["w1"].shape[0]
     act = ACTIVATIONS[activation]
     with jax.named_scope(ROUTE):
@@ -335,23 +490,14 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
-        live = local[order] < held
         sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
-        # rows past the last group are never written by the grouped
-        # products, forward or backward: the select keeps what the
-        # backward pass leaves there out of the tokens' gradient
-        xs = jnp.where(live[:, None], _dispatch(x, order, inv, top_k), 0)
-    with jax.named_scope(EXPERTS):
-        h = act(grouped_dot(xs, params["w1"], sizes)) \
-            * grouped_dot(xs, params["w3"], sizes)
-        ys = grouped_dot(h, params["w2"], sizes)
-    with jax.named_scope(COMBINE):
-        # a select, not a product, and before the weighting: rows past
-        # the last group hold whatever the grouped products left there,
-        # and 0 * NaN would carry it into the router's gradient
-        w = _permute(weights.T.reshape(-1), order, inv)
-        ys = jnp.where(live[:, None], ys, 0) * w[:, None].astype(ys.dtype)
-        y = _combine(ys, order, inv, top_k)
+    bound = _row_bound(order.shape[0], held, logits.shape[1])
+    routed = (x, weights.T.reshape(-1),
+              (params["w1"], params["w3"], params["w2"]), order, inv, sizes)
+    if bound < order.shape[0]:
+        y = _bounded_rows(top_k, act, bound, *routed)
+    else:
+        y = _sorted_rows(grouped_dot, top_k, act, *routed)[0]
     if "shared_w1" in params:
         with jax.named_scope(SHARED):
             y = y + (act(x @ params["shared_w1"])

@@ -29,6 +29,7 @@ to tell them apart.
 | `moe.experts` | scope | the grouped matrix products over the held experts' rows and the activation between them |
 | `moe.combine` | scope | weighting by the router's weights and the sum of each token's rows (a gather by the inverse permutation) |
 | `moe.shared` | scope | the shared experts' gated unit, which every token passes through (no sort, no weights) |
+| `moe.fallback` | scope | around `moe.dispatch` / `moe.experts` / `moe.combine` where they run on a buffer of every (token, choice) pair because the held experts' rows reached the layer's row bound; no time under it = the bounded branch ran every time |
 | `solver.update` | scope | unscale, clip, LR policy, optimizer update, master-weight cast, skip-step guard |
 | `solver.reduce` | scope | the bucketed gradient psums of `reduce_overlap` (parallel/reduction.py) |
 | `caffe/solver/iter` | step span | one pass of `Solver.step`'s loop (`step_num` = its first iteration) |
@@ -57,6 +58,7 @@ MOE_DISPATCH = "moe.dispatch"
 MOE_EXPERTS = "moe.experts"
 MOE_COMBINE = "moe.combine"
 MOE_SHARED = "moe.shared"
+MOE_FALLBACK = "moe.fallback"
 REDUCE = "solver.reduce"
 ITER = "solver/iter"
 _SCOPE = re.compile(r"caffe\.([A-Za-z0-9_]+)\.([A-Za-z0-9_.~%-]*)")

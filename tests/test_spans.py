@@ -379,3 +379,45 @@ def test_expert_layer_scopes_are_in_the_table_and_in_the_step(
     # a scope of its own: no operation sits under two of them
     assert not any(other in n for n in carrying
                    for other in MOE_SCOPES if other != scope)
+
+
+@pytest.mark.parametrize("held", [2, 8], ids=["a-share", "every-expert"])
+def test_fallback_scope_opens_inside_a_sharing_layer_only(held, monkeypatch):
+    """`moe.fallback` wraps the other scopes of the branch that runs on a
+    buffer of every (token, choice) pair. A layer that holds a share of
+    the experts carries it, forward and backward, always under its own
+    layer scope; a layer that holds them all has one buffer and no such
+    branch."""
+    import jax.numpy as jnp
+    from caffe_mpi_tpu.net import Net
+    from caffe_mpi_tpu.ops import moe as moe_ops
+    assert f"| `{spans.MOE_FALLBACK}` | scope |" in spans.__doc__
+    monkeypatch.setattr(moe_ops, "ROW_TILE", 8)   # 64 pairs: a bound of 24
+    net = Net(NetParameter.from_text(f"""
+        layer {{ name: "in" type: "Input" top: "x"
+                 input_param {{ shape {{ dim: 1 dim: 32 dim: 16 }} }} }}
+        layer {{ name: "blk/moe" type: "MoE" bottom: "x" top: "y"
+                 moe_param {{ num_experts: 8 hidden_dim: 8 top_k: 2
+                              dropless: true experts_held: {held} }} }}
+        """), phase="TRAIN")
+    params, state = net.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 16))
+
+    def loss(params, x):
+        blobs, _, _ = net.apply(params, state, {"x": x}, train=True,
+                                rng=None)
+        return jnp.sum(blobs["y"] ** 2)
+    names = op_names(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text())
+    carrying = [n for n in names if spans.MOE_FALLBACK in n]
+    if held == 8:
+        assert not carrying
+        return
+    assert {"transpose(" in n for n in carrying} == {False, True}
+    for name in carrying:
+        assert spans.parse_scope(name) == ("MoE", "blk/moe"), name
+    inner = {scope for scope in MOE_SCOPES for n in carrying
+             if f"{spans.MOE_FALLBACK})/{scope}" in n
+             or f"{spans.MOE_FALLBACK}/{scope}" in n}
+    assert inner == {spans.MOE_DISPATCH, spans.MOE_EXPERTS,
+                     spans.MOE_COMBINE}

@@ -11,6 +11,7 @@ Fan-in/fan-out conventions match filler.hpp: for a weight of shape
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -44,6 +45,14 @@ def fill(filler: FillerParameter | None, key: jax.Array, shape: tuple[int, ...],
     """Create an initialized parameter array per the filler spec."""
     if filler is None:
         filler = FillerParameter()
+    if filler.tile > 1:
+        # the first 1/tile of the last axis, repeated
+        if not shape or shape[-1] % filler.tile:
+            raise ValueError(f"filler tile {filler.tile} does not divide "
+                             f"the last axis of {shape}")
+        part = fill(dataclasses.replace(filler, tile=1), key,
+                    (*shape[:-1], shape[-1] // filler.tile), dtype)
+        return jnp.tile(part, (1,) * (len(shape) - 1) + (filler.tile,))
     ftype = filler.type
     if ftype == "constant":
         return jnp.full(shape, filler.value, dtype)

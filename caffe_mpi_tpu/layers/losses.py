@@ -88,35 +88,43 @@ def _label_terms(x, labels, axis, ignore, mode):
     return hit, mask, norm
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _softmax_nll(x, labels, axis, ignore, mode):
-    """Normalised sum over positions of -log softmax(x)[label], in float32
-    from logits of any float type. Differentiated by jax this is a
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _softmax_nll(x, labels, weights, axis, ignore, mode):
+    """Normalised sum over positions of -log softmax(x)[label], each times
+    its weight where `weights` (one a position, constants of the step) is
+    not None, in float32 from logits of any float type. Differentiated by
+    jax this is a
     `take_along_axis` whose transpose zero-fills a float32 buffer of the
     logits' size and scatters into it, on top of a float32 log-softmax kept
     for the backward pass (1.24 GB and 14.6 ms a step in the language-model
     cell; PERF.md section 6, PR 28). Here the forward pass keeps the logits
     as they arrived and one log-sum-exp a position, and the backward pass
     is one fusion: (softmax - label mark) * mask * g / norm, rounded once."""
-    return _softmax_nll_fwd(x, labels, axis, ignore, mode)[0]
+    return _softmax_nll_fwd(x, labels, weights, axis, ignore, mode)[0]
 
 
-def _softmax_nll_fwd(x, labels, axis, ignore, mode):
+def _softmax_nll_fwd(x, labels, weights, axis, ignore, mode):
     hit, mask, norm = _label_terms(x, labels, axis, ignore, mode)
     xf = x.astype(jnp.float32)
     top = jnp.max(xf, axis=axis, keepdims=True)
     lse = top + jnp.log(jnp.sum(jnp.exp(xf - top), axis=axis, keepdims=True))
     picked = jnp.sum(jnp.where(hit, xf, 0.0), axis=axis, keepdims=True)
-    loss = jnp.sum(jnp.where(mask, lse - picked, 0.0)) / norm
-    return loss, (x, labels, lse)
+    nll = lse - picked
+    if weights is not None:
+        nll = nll * weights.astype(jnp.float32).reshape(mask.shape)
+    loss = jnp.sum(jnp.where(mask, nll, 0.0)) / norm
+    return loss, (x, labels, weights, lse)
 
 
 def _softmax_nll_bwd(axis, ignore, mode, res, g):
-    x, labels, lse = res
+    x, labels, weights, lse = res
     hit, mask, norm = _label_terms(x, labels, axis, ignore, mode)
     p = jnp.exp(x.astype(jnp.float32) - lse)
-    dx = jnp.where(mask, (p - hit) * (g / norm), 0.0)
-    return dx.astype(x.dtype), None
+    scale = g / norm
+    if weights is not None:
+        scale = scale * weights.astype(jnp.float32).reshape(mask.shape)
+    dx = jnp.where(mask, (p - hit) * scale, 0.0)
+    return dx.astype(x.dtype), None, None
 
 
 _softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
@@ -125,10 +133,17 @@ _softmax_nll.defvjp(_softmax_nll_fwd, _softmax_nll_bwd)
 @register("SoftmaxWithLoss")
 class SoftmaxWithLossLayer(LossBase):
     """Fused log-softmax + NLL (softmax_loss_layer.cpp). Second top, when
-    requested, is the softmax output."""
+    requested, is the softmax output. A third bottom, when given, holds one
+    weight a position (a TPU-native extension): the loss is sum(w * nll) /
+    normalizer, ignore_label and the normalization modes as without it (FULL
+    divides by every position); the weights are data and get no gradient."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         self.axis = _softmax_axis(self.lp, len(in_shapes[0]))
+        if len(in_shapes) > 2 and math.prod(in_shapes[2]) \
+                != math.prod(in_shapes[1]):
+            raise ValueError(f"SoftmaxWithLoss weights {in_shapes[2]} are "
+                             f"not one a label {in_shapes[1]}")
         tops = [()]
         if len(self.lp.top) > 1:
             tops.append(in_shapes[0])
@@ -136,8 +151,10 @@ class SoftmaxWithLossLayer(LossBase):
 
     def apply(self, params, state, bottoms, *, train, rng):
         logits = self.f(bottoms[0])
-        tops = [_softmax_nll(logits, bottoms[1].astype(jnp.int32), self.axis,
-                             self._ignore_label(), self._norm_mode())]
+        weights = bottoms[2] if len(bottoms) > 2 else None
+        tops = [_softmax_nll(logits, bottoms[1].astype(jnp.int32), weights,
+                             self.axis, self._ignore_label(),
+                             self._norm_mode())]
         if len(self.lp.top) > 1:
             tops.append(jax.nn.softmax(logits.astype(jnp.float32),
                                        axis=self.axis))

@@ -1,4 +1,5 @@
-"""Sequence layers: Attention + MoE — TPU-native layer types with NO
+"""Sequence layers: Attention, MoE and the block-diffusion noise —
+TPU-native layer types with NO
 reference analogue (SURVEY §5.7: the reference is a CNN-era framework
 with no attention op; §2.7: no MoE/EP). They make the framework's
 long-context and expert-parallel machinery (ops/attention.py, ops/moe.py)
@@ -115,12 +116,16 @@ def latent_dims(p) -> tuple[int, int, int]:
         raise ValueError(
             "latent attention has rope_theta and bias_term: false, and "
             "neither num_kv_heads, head_dim, window nor sequence_parallel")
+    if p.block_diffusion or p.qk_norm:
+        raise ValueError("latent attention has neither block_diffusion "
+                         "nor qk_norm")
     return nope, rot, vd
 
 
 @register("Attention")
 class AttentionLayer(Layer):
-    """attention_param. Two forms: fused QKV heads (grouped, windowed,
+    """attention_param. Two forms: fused QKV heads (grouped; windowed or
+    under the block-diffusion mask; an RMSNorm on each query and key head;
     rotary over the whole head) and, with kv_lora_rank > 0, latent
     attention (`_setup_latent`, `_latent_qkv`): low-rank query and
     key/value projections, a head of unequal query/key and value widths,
@@ -146,6 +151,10 @@ class AttentionLayer(Layer):
         if p.rope_theta and self.head_dim % 2:
             raise ValueError(f"rotary positions over an odd head size "
                              f"{self.head_dim}")
+        from ..proto.netshape import block_diffusion_problem
+        problem = block_diffusion_problem(p, s)
+        if problem:
+            raise ValueError(f"attention_param: {problem}")
         nq, nkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
         self.nq, self.nkv = nq, nkv
         filler = p.weight_filler or FillerParameter(type="xavier")
@@ -155,6 +164,10 @@ class AttentionLayer(Layer):
             bias = p.bias_filler or FillerParameter(type="constant")
             self.declare("qkv_bias", (nq + 2 * nkv,), bias)
             self.declare("proj_bias", (c,), bias)
+        if p.qk_norm:
+            one = FillerParameter(type="constant", value=1.0)
+            self.declare("q_norm", (self.head_dim,), one)
+            self.declare("k_norm", (self.head_dim,), one)
         return [in_shapes[0]]
 
     def _setup_latent(self, c: int):
@@ -215,8 +228,15 @@ class AttentionLayer(Layer):
             q = q.reshape(n, s, self.heads, self.head_dim)
             k = k.reshape(n, s, self.kv_heads, self.head_dim)
             v = v.reshape(n, s, self.kv_heads, self.head_dim)
+            if p.qk_norm:
+                q = rms_normalize(q, p.norm_eps) * self.f(params["q_norm"])
+                k = rms_normalize(k, p.norm_eps) * self.f(params["k_norm"])
             if p.rope_theta:
-                q, k = rope(q, p.rope_theta), rope(k, p.rope_theta)
+                # the two halves of a block-diffusion sequence sit at the
+                # same positions
+                period = s // 2 if p.block_diffusion else 0
+                q = rope(q, p.rope_theta, period)
+                k = rope(k, p.rope_theta, period)
         mp = self.mesh_plan
         if (p.sequence_parallel and mp is not None
                 and mp.mesh.shape.get("model", 1) > 1):
@@ -234,16 +254,71 @@ class AttentionLayer(Layer):
             # partition: split the batch by hand (attention never mixes
             # samples)
             out = mp.per_batch_shard(
-                lambda q, k, v: attention(q, k, v, causal=bool(p.causal),
-                                          use_flash=True, window=p.window),
+                lambda q, k, v: attention(
+                    q, k, v, causal=bool(p.causal), use_flash=True,
+                    window=p.window, block_diffusion=p.block_diffusion),
                 q, k, v)
         else:
             out = attention(q, k, v, causal=bool(p.causal),
-                            use_flash=bool(p.use_flash), window=p.window)
+                            use_flash=bool(p.use_flash), window=p.window,
+                            block_diffusion=p.block_diffusion)
         y = out.reshape(n, s, self.nq) @ self.f(params["proj_weight"]).T
         if p.bias_term:
             y = y + self.f(params["proj_bias"])
         return [y], state
+
+
+def block_diffusion_noise(key, tokens, block_length: int, mask_id: int,
+                          t_min: float, ignore_label: int):
+    """One draw of block-diffusion noise (arXiv:2503.09573) over token ids
+    (N, L): each block of block_length draws t ~ U(t_min, 1) and masks
+    each of its tokens independently with probability t. Returns ids (N,
+    2 L) = [noisy | clean], labels (N, L), weights (N, L) float32 = 1 / t
+    where masked, and the count of masked positions. Two uniform draws, a
+    repeat, compares and selects: no sort, no scatter."""
+    n, l = tokens.shape
+    blocks = -(-l // block_length)
+    key_t, key_u = jax.random.split(key)
+    t = jax.random.uniform(key_t, (n, blocks), jnp.float32, t_min, 1.0)
+    t = jnp.repeat(t, block_length, axis=1)[:, :l]
+    masked = jax.random.uniform(key_u, (n, l), jnp.float32) < t
+    ids = jnp.concatenate([jnp.where(masked, mask_id, tokens), tokens], 1)
+    return (ids, jnp.where(masked, tokens, ignore_label),
+            jnp.where(masked, 1.0 / t, 0.0),
+            jnp.sum(masked, dtype=jnp.float32))
+
+
+@register("BlockDiffusionNoise")
+class BlockDiffusionNoiseLayer(Layer):
+    """block_diffusion_param: the data side of block-diffusion training.
+    Bottom: token ids (N, L). Tops: ids (N, 2 L) = [x_t | x_0] for the
+    Embed, labels (N, L) (ignore_label where not masked) and weights (N,
+    L) (1 / t of the block where masked) for SoftmaxWithLoss, and
+    optionally how many positions were masked. The draw comes from the
+    layer's `rng`, which Net folds from the step's (fresh every TRAIN
+    step, reproducible from a stated key); without one, key 0."""
+
+    def setup(self, in_shapes: list[Shape]) -> list[Shape]:
+        p = self.lp.block_diffusion_param
+        if p is None or p.block_length < 1 or not 0.0 < p.t_min <= 1.0:
+            raise ValueError("block_diffusion_param needs block_length >= "
+                             "1 and 0 < t_min <= 1")
+        if len(in_shapes[0]) != 2 or not 3 <= len(self.lp.top) <= 4:
+            raise ValueError(
+                f"BlockDiffusionNoise expects (N, L) token ids and tops "
+                f"ids, labels, weights[, masked count]; got {in_shapes[0]} "
+                f"and {len(self.lp.top)} tops")
+        self.p = p
+        n, l = in_shapes[0]
+        return [(n, 2 * l), (n, l), (n, l), ()][:len(self.lp.top)]
+
+    def apply(self, params, state, bottoms, *, train, rng):
+        p = self.p
+        key = jax.random.PRNGKey(0) if rng is None else rng
+        tops = block_diffusion_noise(
+            key, bottoms[0].astype(jnp.int32), p.block_length, p.mask_id,
+            p.t_min, p.ignore_label)
+        return list(tops[:len(self.lp.top)]), state
 
 
 @register("MoE")
@@ -292,7 +367,8 @@ class MoELayer(Layer):
                              f"sigmoid), activation {p.activation!r} (relu "
                              f"| silu)")
         filler = p.weight_filler or FillerParameter(type="xavier")
-        gate_filler = FillerParameter(type="gaussian", std=0.02)
+        gate_filler = p.gate_filler or FillerParameter(type="gaussian",
+                                                       std=0.02)
         zero = FillerParameter(type="constant")
         self.declare("gate", (c, p.num_experts), gate_filler)
         if p.scoring == "sigmoid":

@@ -23,6 +23,7 @@ _PARAM_FIELD = {
     "Crop": "crop_param", "Data": "data_param", "Dropout": "dropout_param",
     "Attention": "attention_param", "LayerNorm": "layer_norm_param",
     "MoE": "moe_param", "Parameter": "parameter_param",
+    "BlockDiffusionNoise": "block_diffusion_param",
     "RMSNorm": "rms_norm_param",
     "DummyData": "dummy_data_param", "Eltwise": "eltwise_param",
     "ELU": "elu_param", "Embed": "embed_param", "Exp": "exp_param",

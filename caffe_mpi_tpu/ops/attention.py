@@ -51,16 +51,21 @@ def _block_attn(q, k, v, *, scale, mask=None):
     return out, m_safe, l
 
 
-def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rope(x: jnp.ndarray, theta: float, period: int = 0) -> jnp.ndarray:
     """Rotary positions over the whole head, rotate-half convention:
-    x (B, S, H, D) -> the same, positions 0..S-1 in each sequence, no
-    scaling. Angles and the rotation in float32, the result in x's type.
+    x (B, S, H, D) -> the same, positions 0..S-1 in each sequence (row i
+    at i mod `period` where one is given: the two halves of a block-
+    diffusion sequence sit at the same positions), no scaling. Angles and
+    the rotation in float32, the result in x's type.
     With x = [x1, x2] (halves of D) and a_i = pos * theta^(-i / (D/2)):
     [x1 cos a - x2 sin a, x2 cos a + x1 sin a]."""
     s, d = x.shape[1], x.shape[-1]
     half = d // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    pos = jnp.arange(s, dtype=jnp.float32)
+    if period:
+        pos = (jnp.arange(s) % period).astype(jnp.float32)
+    ang = pos[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
     x32 = x.astype(jnp.float32)
@@ -93,14 +98,19 @@ def rope_pairs(x: jnp.ndarray, theta: float) -> jnp.ndarray:
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = False, use_flash: bool = False,
               flash_interpret: bool | None = None,
-              window: int = 0) -> jnp.ndarray:
+              window: int = 0, block_diffusion: int = 0) -> jnp.ndarray:
     """Single-device attention: q (B,S,H,D), k (B,S,Hkv,D), v (B,S,Hkv,Dv)
     -> (B,S,H,Dv), scores scaled by 1 / sqrt(D); Dv is D everywhere but in
     latent attention.
 
     Grouped heads: with Hkv < H (H a multiple of it) query head n reads
     key/value head n // (H / Hkv). window > 0 (with causal): key j is
-    visible to query i iff i - window < j <= i.
+    visible to query i iff i - window < j <= i. block_diffusion > 0
+    (neither causal nor a window): q, k and v are one `[noisy | clean]`
+    sequence, two halves of S / 2 in blocks of that length, and row i sees
+    column j under the block-diffusion mask (flash_attention.py
+    `_bd_valid`: inside its block among the noisy, the blocks before its
+    own among the clean; a clean row the clean blocks up to its own).
 
     use_flash: route through the Pallas flash-attention kernels
     (ops/flash_attention.py) — O(S) memory VMEM-tiled online softmax,
@@ -111,10 +121,14 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     it."""
     if window and not causal:
         raise ValueError("a sliding window needs causal attention")
+    if use_flash or block_diffusion:
+        from . import flash_attention as flash
+        flash.check_block_diffusion(block_diffusion, q.shape[1], k.shape[1],
+                                    causal, window)
     if use_flash:
-        from .flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=flash_interpret)
+        return flash.flash_attention(q, k, v, causal=causal, window=window,
+                                     block_diffusion=block_diffusion,
+                                     interpret=flash_interpret)
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
@@ -126,6 +140,10 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         if window:
             mask &= ~jnp.tril(jnp.ones((sq, sk), bool), -window)
         mask = mask[None, None]
+    if block_diffusion:
+        at = jnp.arange(q.shape[1], dtype=jnp.int32)
+        mask = flash._bd_valid(at[:, None], at[None, :], q.shape[1] // 2,
+                               block_diffusion)[None, None]
     out, m, l = _block_attn(q, k, v, scale=scale, mask=mask)
     return out / jnp.maximum(l, 1e-30)[..., None].swapaxes(1, 2)
 

@@ -20,12 +20,15 @@ alone). Grouped heads: K and V have B*Hkv rows and query row i reads row
 i // (H / Hkv) through the block index map. bf16 operands go into the MXU
 as they are, accumulated in float32.
 
-The mask (causal, a causal window, padded keys) has ONE definition for all
-three kernels (`_band`, `_tile_runs`, `_visit`): tiles wholly outside it
-are not visited, and the visited ones fall into at most three contiguous
-runs — cut by the window's edge, wholly inside, cut by the diagonal or the
-padded tail. Only the cut runs build and apply the mask; the inside run's
-body has none. `tile_counts` says how many tiles each run holds.
+The mask has ONE definition for all three kernels (`_band` and
+`_tile_runs` for a band: causal, a causal window; `_bd_valid` and
+`_bd_runs` for block diffusion over a `[noisy | clean]` sequence; padded
+keys in both; `_visit` walks either): tiles wholly outside it are not
+visited, and the visited ones fall into contiguous runs, each either cut
+by the mask's edge (the window's edge, the diagonal, a block's border, the
+padded tail) or wholly inside. Only the cut runs build and apply the mask;
+an inside run's body has none. `tile_counts` says how many tiles the runs
+hold.
 
 Forward grid: (B*H, Sq/BQ) with inner loops over K tiles, accumulating
 (out, m, l) in VMEM scratch; it also emits the per-row logsumexp, which
@@ -169,59 +172,180 @@ def _tile_runs(g, bg, bt, n_t, n_full, lo, hi):
     return first, in_lo, in_hi, end
 
 
-def _visit(g, tile, *, bg, bt, n_t, band, y_valid=None):
+# Block diffusion (arXiv:2503.09573): the sequence is `[noisy | clean]`, two
+# halves of `half` positions each cut into blocks of `block`; position i has
+# pos(i) = i - half in the clean half and i in the noisy one, and blk(i) =
+# pos(i) // block. Row i sees column j iff
+#   both noisy: blk(j) == blk(i);    noisy row, clean column: blk(j) < blk(i);
+#   both clean: blk(j) <= blk(i);    clean row, noisy column: never.
+# Positions past the two halves (padding) count as clean ones.
+
+def _bd_valid(rows, cols, half, block):
+    """The block-diffusion mask of integer positions `rows` against `cols`
+    (broadcast against each other). One integer division a position; a
+    score costs two compares: a row sees the clean columns whose block is
+    at most `t` and the noisy ones whose block is `n`."""
+    def blocks(idx):
+        clean = idx >= half
+        return clean, jax.lax.div(jnp.where(clean, idx - half, idx),
+                                  jnp.int32(block))
+    r_clean, r_blk = blocks(rows)
+    c_clean, c_blk = blocks(cols)
+    t = jnp.where(r_clean, r_blk, r_blk - 1)
+    n = jnp.where(r_clean, -1, r_blk)
+    # a noisy column never passes the first compare, a clean one never the
+    # second
+    among_clean = jnp.where(c_clean, c_blk, jnp.int32(2**30))
+    among_noisy = jnp.where(c_clean, -2, c_blk)
+    return (among_clean <= t) | (among_noisy == n)
+
+
+def _span_tiles(span, bt, start, n_t, n_full):
+    """(first, in_lo, in_hi, end) as `_tile_runs` gives them, of the loop
+    positions [vis_lo, vis_hi) a grid tile sees, of which every position of
+    the tile sees [all_lo, all_hi); no tile before `start`."""
+    vis_lo, vis_hi, all_lo, all_hi = span
+    end = jnp.clip((vis_hi + bt - 1) // bt, start, n_t)
+    first = jnp.where(vis_hi > vis_lo, jnp.clip(vis_lo // bt, start, end),
+                      end)
+    in_lo = jnp.clip((all_lo + bt - 1) // bt, first, end)
+    in_hi = jnp.clip(jnp.minimum(all_hi // bt, n_full), in_lo, end)
+    return first, in_lo, in_hi, end
+
+
+def _bd_runs(g, bg, bt, n_t, n_full, half, block, keys_on_grid):
+    """[(a, b, cut)]: grid tile `g` visits the loop tiles of each [a, b) in
+    turn; a run with `cut` holds tiles the block-diffusion mask cuts, one
+    without it tiles wholly inside. The loop positions a grid tile sees
+    are two spans, the first among the noisy positions and the second among
+    the clean ones, each a run of cut tiles, one of inside tiles and one of
+    cut tiles again (`_span_tiles`); the spans' ends come from the blocks
+    of the tile's first and last position in each half (blk is monotone
+    inside a half). A run that the shapes alone leave empty is left out: a
+    tile lies inside one block only if the block is that long, and a span
+    that ends where the half does ends on a tile's edge if the half does."""
+    L, B = half, block
+    x0 = g * bg
+    x1 = x0 + bg - 1
+    noisy, clean = x0 < L, x1 >= L          # the halves the tile touches
+    n0, n1 = x0 // B, jnp.minimum(x1, L - 1) // B        # its noisy blocks
+    c0, c1 = (jnp.maximum(x0, L) - L) // B, (x1 - L) // B  # its clean ones
+    one_block = noisy & ~clean & (n0 == n1) if B >= bt else False
+    own_lo, own_hi = n0 * B, jnp.minimum((n1 + 1) * B, L)
+    aligned = L % bt == 0
+    if not keys_on_grid:
+        # noisy columns: the blocks of the tile's noisy rows, seen by every
+        # row only if they are one block. Clean columns: a noisy row sees
+        # the blocks before its own, a clean row those up to its own
+        lo, hi = jnp.where(noisy, own_lo, 0), jnp.where(noisy, own_hi, 0)
+        seen = jnp.maximum(jnp.where(noisy, n1 * B, 0),
+                           jnp.where(clean, (c1 + 1) * B, 0))
+        by_all = jnp.minimum(jnp.where(noisy, n0 * B, seen),
+                             jnp.where(clean, (c0 + 1) * B, seen))
+        spans = [((lo, hi, jnp.where(one_block, lo, hi), hi),
+                  (True, B >= bt, B >= bt)),
+                 ((L, L + seen, L, L + by_all), (not aligned, True, True))]
+    else:
+        # noisy rows: the block of a noisy column, the blocks after a clean
+        # column's. Clean rows: a clean column's block and every later one.
+        # A tile that touches both halves is cut everywhere
+        end = n_t * bt
+        lo = jnp.minimum(jnp.minimum(jnp.where(noisy, own_lo, L),
+                                     jnp.where(clean, (c0 + 1) * B, L)), L)
+        hi = jnp.where(clean, L, own_hi)
+        all_lo = jnp.where(noisy & clean, hi, jnp.where(
+            one_block, lo,
+            jnp.where(clean, jnp.minimum((c1 + 1) * B, L), hi)))
+        only_clean = clean & ~noisy
+        spans = [((lo, hi, all_lo, hi),
+                  (True, True, not (aligned and (B < bt or B % bt == 0)))),
+                 ((jnp.where(clean, L + c0 * B, 0), jnp.where(clean, end, 0),
+                   jnp.where(only_clean, L + c1 * B, jnp.where(clean, end,
+                                                               0)),
+                   jnp.where(clean, end, 0)), (True, True, False))]
+    runs, start = [], 0
+    for span, (lead, inside, tail) in spans:
+        first, in_lo, in_hi, end = _span_tiles(span, bt, start, n_t, n_full)
+        runs += ([(first, in_lo, True)] * lead + [(in_lo, in_hi, False)]
+                 * inside + [(in_hi, end, True)] * tail)
+        start = end
+    return runs
+
+
+def _runs(g, bg, bt, n_t, n_full, band, bd, keys_on_grid):
+    """[(a, b, cut)] of either mask: the band's three runs (window's edge,
+    inside, diagonal or padded tail) or the block-diffusion mask's."""
+    if bd is not None:
+        return _bd_runs(g, bg, bt, n_t, n_full, *bd, keys_on_grid)
+    first, in_lo, in_hi, end = _tile_runs(g, bg, bt, n_t, n_full, *band)
+    return [(first, in_lo, True), (in_lo, in_hi, False), (in_hi, end, True)]
+
+
+def _visit(g, tile, *, bg, bt, n_t, band, y_valid=None, bd=None,
+           keys_on_grid=False):
     """tile(t, mask) over the loop tiles grid tile `g` sees, in rising t.
     `mask` is the (bg, bt) valid-score mask, grid axis first, on the tiles
-    it cuts and None on the inside run, whose body then holds no iota, no
+    it cuts and None on an inside run, whose body then holds no iota, no
     compare and no select; an empty run costs its bounds check. `tile`
     accumulates into VMEM scratch: values carried from one run's loop into
     the next are moved register by register at every seam (PERF.md section
     6, PR 30). y_valid: valid positions along a padded loop axis (key
     columns zero-filled by the wrapper: exp(0 - m) != 0 in the softmax
-    denominator; in dQ, p = exp(0 - lse) can overflow to inf)."""
+    denominator; in dQ, p = exp(0 - lse) can overflow to inf). bd: (half,
+    block) of the block-diffusion mask, which then stands where the band
+    does; keys_on_grid: the grid axis holds the mask's columns."""
     lo, hi = band
     n_full = n_t if y_valid is None else y_valid // bt
-    first, in_lo, in_hi, end = _tile_runs(g, bg, bt, n_t, n_full, lo, hi)
+    runs = _runs(g, bg, bt, n_t, n_full, band, bd, keys_on_grid)
 
     def cut(t, _):
-        # x - y is the same iota difference on every tile plus the scalar
-        # g*bg - t*bt: each bound is one compare with a scalar
         y = jax.lax.broadcasted_iota(jnp.int32, (bg, bt), 1)
-        diff = jax.lax.broadcasted_iota(jnp.int32, (bg, bt), 0) - y
-        off = g * bg - t * bt
-        conds = [diff >= lo - off] if lo is not None else []
-        if hi is not None:
-            conds.append(diff < hi - off)
+        if bd is None:
+            # x - y is the same iota difference on every tile plus the
+            # scalar g*bg - t*bt: each bound is one compare with a scalar
+            diff = jax.lax.broadcasted_iota(jnp.int32, (bg, bt), 0) - y
+            off = g * bg - t * bt
+            conds = [diff >= lo - off] if lo is not None else []
+            if hi is not None:
+                conds.append(diff < hi - off)
+        else:
+            # blocks from one column and one row of positions, then two
+            # compares a score
+            x = g * bg + jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0)
+            z = t * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+            conds = [_bd_valid(z, x, *bd) if keys_on_grid
+                     else _bd_valid(x, z, *bd)]
         if n_full < n_t:
             conds.append(y < y_valid - t * bt)
         tile(t, functools.reduce(jnp.logical_and, conds))
 
-    for a, b, body in ((first, in_lo, cut),
-                       (in_lo, in_hi, lambda t, _: tile(t, None)),
-                       (in_hi, end, cut)):
+    for a, b, is_cut in runs:
         if not (isinstance(a, int) and isinstance(b, int) and a >= b):
-            jax.lax.fori_loop(a, b, body, None)
+            jax.lax.fori_loop(a, b, cut if is_cut
+                              else lambda t, _: tile(t, None), None)
 
 
-def tile_counts(sq, sk, causal, window=0, sk_valid=None, *, dkv=False):
+def tile_counts(sq, sk, causal, window=0, sk_valid=None, *, dkv=False,
+                bd=None):
     """(visited, inside): the tiles one head's forward (and dQ) kernel
     visits over (padded) lengths sq x sk, and those of them it runs
     without the mask — with dkv=True the dK/dV kernel's, which sees no
-    key padding. A pure function of shape, from the range code the kernels
-    run: how often the unmasked body engages."""
+    key padding; with bd = (half, block) under the block-diffusion mask. A
+    pure function of shape, from the range code the kernels run: how often
+    the unmasked body engages."""
     bq, bk = _check_tiles(sq, sk)
     if dkv:
-        g = jnp.arange(sk // bk)
-        runs = _tile_runs(g, bk, bq, sq // bq, sq // bq,
-                          *_band(causal, window, keys_on_grid=True))
+        g, shape = jnp.arange(sk // bk), (bk, bq, sq // bq, sq // bq)
     else:
-        g = jnp.arange(sq // bq)
         valid = sk if sk_valid is None else sk_valid
-        runs = _tile_runs(g, bq, bk, _n_k_tiles(sk, bk, valid), valid // bk,
-                          *_band(causal, window))
-    first, in_lo, in_hi, end = runs
-    return tuple(int(jnp.sum(jnp.broadcast_to(n, g.shape)))
-                 for n in (end - first, in_hi - in_lo))
+        g = jnp.arange(sq // bq)
+        shape = (bq, bk, _n_k_tiles(sk, bk, valid), valid // bk)
+    runs = _runs(g, *shape, _band(causal, window, keys_on_grid=dkv), bd,
+                 dkv)
+    tiles = lambda spans: int(sum(jnp.sum(jnp.broadcast_to(b - a, g.shape))
+                                  for a, b in spans))
+    return (tiles((a, b) for a, b, _ in runs),
+            tiles((a, b) for a, b, cut in runs if not cut))
 
 
 def _lanes(x, n):
@@ -241,7 +365,7 @@ def _dot(a, b, dims):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
-                bq, bk, sk_valid, has_bias, window=0):
+                bq, bk, sk_valid, has_bias, window=0, bd=None):
     """rest = ([bias_ref,] o_ref, lse_ref, then the scratch accumulators
     acc_ref (bq, dv), m_ref and l_ref (bq, _LANES), a row's running maximum
     and sum in every lane). bias (1, sk) f32 adds to every
@@ -283,7 +407,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
                         + pv * _lanes(beta, d))
 
     _visit(qi, tile, bg=bq, bt=bk, n_t=_n_k_tiles(sk, bk, sk_valid),
-           band=_band(causal, window),
+           band=_band(causal, window), bd=bd,
            y_valid=sk_valid if sk_valid < sk else None)
     m, l = m_ref[...], l_ref[...]
     l_safe = jnp.maximum(l, 1e-30)
@@ -297,7 +421,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sk,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   scale, causal, sk, bq, bk, sk_valid, has_bias, window=0):
+                   scale, causal, sk, bq, bk, sk_valid, has_bias, window=0,
+                   bd=None):
     *rest, acc_ref = rest
     bias_ref, dq_ref = rest if has_bias else (None, *rest)
     qi = pl.program_id(1)
@@ -322,13 +447,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         acc_ref[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
 
     _visit(qi, tile, bg=bq, bt=bk, n_t=_n_k_tiles(sk, bk, sk_valid),
-           band=_band(causal, window),
+           band=_band(causal, window), bd=bd,
            y_valid=sk_valid if sk_valid < sk else None)
     dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, sq, bq, bk, has_bias, window=0):
+                    scale, causal, sq, bq, bk, has_bias, window=0, bd=None):
     *rest, dk_acc, dv_acc = rest
     bias_ref, dk_ref, dv_ref = rest if has_bias else (None, *rest)
     ki = pl.program_id(1)
@@ -362,7 +487,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_acc[...] += _dot(ds.astype(q.dtype), q, ((1,), (0,))) * scale
 
     _visit(ki, tile, bg=bk, bt=bq, n_t=sq // bq,
-           band=_band(causal, window, keys_on_grid=True))
+           band=_band(causal, window, keys_on_grid=True), bd=bd,
+           keys_on_grid=True)
     dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
     dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
@@ -431,13 +557,13 @@ class _Static(typing.NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(st: _Static, causal, window, has_bias, interpret):
+def _fwd_call(st: _Static, causal, window, has_bias, interpret, bd=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
                                causal=causal, sk=sk, bq=bq, bk=bk,
                                sk_valid=st.sk_valid, has_bias=has_bias,
-                               window=window)
+                               window=window, bd=bd)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, sk, d), lambda i, j: (i // group, 0, 0)),
@@ -467,14 +593,14 @@ def _fwd_call(st: _Static, causal, window, has_bias, interpret):
 
 
 def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
-              window=0):
+              window=0, bd=None):
     """(B*H, S, D) q and (B*Hkv, S, D) k, v -> (out, lse); query row i of
     the leading axis reads key/value row i // (H / Hkv). k_bias: optional
     (1, Sk) f32 additive score bias shared by every row/head (0 live,
     -inf masked)."""
     bias = () if k_bias is None else (k_bias,)
     return _fwd_call(_Static.of(q, k, v, sk_valid), causal, window,
-                     bool(bias), interpret)(q, k, v, *bias)
+                     bool(bias), interpret, bd)(q, k, v, *bias)
 
 
 def _delta(do, out):
@@ -485,7 +611,7 @@ def _delta(do, out):
 
 
 @functools.lru_cache(maxsize=None)
-def _dq_call(st: _Static, causal, window, has_bias, interpret):
+def _dq_call(st: _Static, causal, window, has_bias, interpret, bd=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
@@ -502,7 +628,7 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret):
         functools.partial(_bwd_dq_kernel, scale=1.0 / math.sqrt(d),
                           causal=causal, sk=sk, bq=bq, bk=bk,
                           sk_valid=st.sk_valid, has_bias=has_bias,
-                          window=window),
+                          window=window, bd=bd),
         grid=(bh, sq // bq),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0)),
@@ -515,7 +641,7 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _dkv_call(st: _Static, causal, window, has_bias, interpret):
+def _dkv_call(st: _Static, causal, window, has_bias, interpret, bd=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
@@ -535,7 +661,7 @@ def _dkv_call(st: _Static, causal, window, has_bias, interpret):
     return pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=1.0 / math.sqrt(d),
                           causal=causal, sq=sq, bq=bq, bk=bk,
-                          has_bias=has_bias, window=window),
+                          has_bias=has_bias, window=window, bd=bd),
         grid=(bh, sk // bk),
         in_specs=in_specs,
         out_specs=[
@@ -555,7 +681,7 @@ def _dkv_call(st: _Static, causal, window, has_bias, interpret):
 
 
 def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
-              k_bias=None, delta=None, window=0):
+              k_bias=None, delta=None, window=0, bd=None):
     """out/lse are the GLOBAL attention output/logsumexp for these q rows
     (for plain flash that's this call's own forward; for ring attention
     each per-block call passes the ring-merged values, which makes the
@@ -564,7 +690,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         delta = _delta(do, out)
     st = _Static.of(q, k, v, sk_valid)
     args = (q, k, v, do, lse, delta, *(() if k_bias is None else (k_bias,)))
-    flags = (causal, window, k_bias is not None, interpret)
+    flags = (causal, window, k_bias is not None, interpret, bd)
     dq = _dq_call(st, *flags)(*args)
     dk, dv = _dkv_call(st, *flags)(*args)
     if st.group > 1:
@@ -597,9 +723,10 @@ def flash_block_bwd(q, k, v, out, lse, do, *, causal=False, k_bias=None,
                      k_bias=k_bias, delta=delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, interpret, sk_valid, window):
-    out, _ = _fwd_impl(q, k, v, causal, interpret, sk_valid, window=window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, interpret, sk_valid, window, bd):
+    out, _ = _fwd_impl(q, k, v, causal, interpret, sk_valid, window=window,
+                       bd=bd)
     return out
 
 
@@ -610,15 +737,15 @@ def _flash(q, k, v, causal, interpret, sk_valid, window):
 KEPT_UNDER_REMAT = ("flash.out", "flash.lse")
 
 
-def _flash_fwd(q, k, v, causal, interpret, sk_valid, window):
+def _flash_fwd(q, k, v, causal, interpret, sk_valid, window, bd):
     out, lse = _fwd_impl(q, k, v, causal, interpret, sk_valid,
-                         window=window)
+                         window=window, bd=bd)
     out, lse = (checkpoint_name(x, name)
                 for x, name in zip((out, lse), KEPT_UNDER_REMAT))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, interpret, sk_valid, window, res, do):
+def _flash_bwd(causal, interpret, sk_valid, window, bd, res, do):
     # sk_valid reaches the dQ kernel (p at padded columns can overflow to
     # inf when lse < -88 and must be zeroed before ds @ k). The dK/dV
     # kernel needs no mask: padded Q rows carry do = 0 (the output
@@ -626,15 +753,26 @@ def _flash_bwd(causal, interpret, sk_valid, window, res, do):
     # rows the wrapper slices off.
     q, k, v, out, lse = res
     return _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid,
-                     window=window)
+                     window=window, bd=bd)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def check_block_diffusion(block: int, sq: int, sk: int, causal, window):
+    """Refuse what the block-diffusion mask has no meaning with."""
+    if block and (causal or window or block < 0 or sq != sk or sq % 2):
+        raise ValueError(
+            f"block diffusion (block length {block}) is a mask of its own "
+            f"over one [noisy | clean] sequence of even length: neither "
+            f"causal nor window, and queries and keys alike (got causal "
+            f"{bool(causal)}, window {window}, lengths {sq} and {sk})")
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = False, interpret: bool | None = None,
-                    window: int = 0) -> jnp.ndarray:
+                    window: int = 0, block_diffusion: int = 0
+                    ) -> jnp.ndarray:
     """q (B, S, H, D), k (B, S, Hkv, D), v (B, S, Hkv, Dv) -> (B, S, H,
     Dv); scores are scaled by 1 / sqrt(D). Differentiable: jax.grad hits
     the Pallas backward kernels via custom_vjp.
@@ -643,6 +781,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     n // (H / Hkv) through the kernels' block index maps, K and V are not
     repeated. window > 0 (causal only): key j visible to query i iff
     i - window < j <= i; tiles wholly outside are not visited.
+    block_diffusion > 0 (neither causal nor a window): the sequence is
+    `[noisy | clean]`, two halves of S / 2 in blocks of that length, under
+    the block-diffusion mask (`_bd_valid`).
 
     Arbitrary sequence lengths: a length over 128 is padded up to a
     multiple of 128 and walked in tiles of 128 to 512 (`_tile`) — padded
@@ -655,6 +796,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         raise ValueError(f"{h} query heads over {hkv} key/value heads")
     if window and not causal:
         raise ValueError("a sliding window needs causal attention")
+    check_block_diffusion(block_diffusion, sq, sk, causal, window)
+    bd = (sq // 2, block_diffusion) if block_diffusion else None
     sq_p, sk_p = _pad_len(sq, BQ), _pad_len(sk, BK)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
@@ -665,6 +808,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, dv)
     out = _flash(qt, kt, vt, causal, interpret,
-                 sk if sk_p != sk else None, window)
+                 sk if sk_p != sk else None, window, bd)
     out = out.reshape(b, h, sq_p, dv).transpose(0, 2, 1, 3)
     return out[:, :sq] if sq_p != sq else out

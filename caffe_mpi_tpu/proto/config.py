@@ -220,6 +220,10 @@ class FillerParameter(Message):
     sparse: int = -1
     # xavier/msra normalization choice: FAN_IN / FAN_OUT / AVERAGE
     variance_norm: str = "FAN_IN"
+    # TPU-native extension: fill the first 1/tile of the last axis and
+    # repeat it, so that entries j and j + last/tile start equal (a router
+    # whose experts come in tile groups with equal columns)
+    tile: int = 1
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +474,8 @@ class AttentionParameter(Message):
     # (with causal). 0 = no window
     window: int = 0
     # rotary position embedding over the whole head (rotate-half
-    # convention), positions 0..S-1 in each sequence. 0 = no positions
+    # convention), positions 0..S-1 in each sequence (i mod S/2 under
+    # block_diffusion). 0 = no positions
     rope_theta: float = 0.0
     # latent attention (kv_lora_rank > 0; arXiv:2405.04434): queries and
     # keys/values come through low-rank projections with an RMSNorm
@@ -491,8 +496,21 @@ class AttentionParameter(Message):
     # the rotary lanes turn in adjacent pairs (x_2i, x_2i+1) instead of
     # rotate-half; latent attention only
     rope_interleave: bool = False
-    # eps of the two RMSNorms inside the latent path
+    # eps of the two RMSNorms inside the latent path, and of qk_norm's
     norm_eps: float = 1e-6
+    # block diffusion (arXiv:2503.09573), the block length; 0 = off. The
+    # bottom is one [noisy | clean] sequence of two halves of S / 2 (the
+    # BlockDiffusionNoise layer's first top, embedded): row i sits at
+    # position i mod S/2 in block (i mod S/2) // block_diffusion, a noisy
+    # row sees its own block among the noisy and the blocks before it
+    # among the clean, a clean row the clean blocks up to its own. The
+    # mask and the positions go together; neither causal nor window, and
+    # not the latent or sequence_parallel paths
+    block_diffusion: int = 0
+    # an RMSNorm with a learnable scale of head_dim on each query and each
+    # key head, before the rotary turn: blobs q_norm and k_norm (head_dim,),
+    # filled with ones. Not in the latent path, which has norms of its own
+    qk_norm: bool = False
 
 
 @dataclass
@@ -547,8 +565,10 @@ class MoEParameter(Message):
     # `gate`, one value an expert; it selects and does not weigh); weights
     # routed_scaling_factor * s / sum of the chosen s
     scoring: str = "softmax"
-    # filler of select_bias (default: zeros)
+    # filler of select_bias (default: zeros), and of the router matrix
+    # `gate` (default: gaussian 0.02)
     bias_filler: FillerParameter | None = None
+    gate_filler: FillerParameter | None = None
     routed_scaling_factor: float = 1.0
     # the gate's activation in an expert, act(x w1) * (x w3): relu | silu
     activation: str = "relu"
@@ -557,6 +577,23 @@ class MoEParameter(Message):
     # shared_w1, shared_w3 (C, n h), shared_w2 (n h, C)). Under expert
     # parallelism every chip computes it for its own tokens
     shared_experts: int = 0
+
+
+@dataclass
+class BlockDiffusionParameter(Message):
+    """TPU-native extension: the noise of block-diffusion training
+    (arXiv:2503.09573; layers/sequence.py BlockDiffusionNoise). Of a
+    bottom of token ids (N, L) in blocks of block_length, each block b
+    draws t_b ~ U(t_min, 1) and masks each of its tokens independently
+    with probability t_b. Tops: ids (N, 2 L) = [noisy | clean], mask_id
+    where masked; labels (N, L), the clean id where masked and
+    ignore_label elsewhere; weights (N, L), 1 / t_b where masked and 0
+    elsewhere; optionally the count of masked positions. The draw comes
+    from the layer's per-step rng, fresh every step."""
+    block_length: int = 1
+    mask_id: int = 0
+    t_min: float = 1e-3
+    ignore_label: int = -1
 
 
 @dataclass
@@ -893,6 +930,7 @@ class LayerParameter(Message):
     dummy_data_param: DummyDataParameter | None = None
     eltwise_param: EltwiseParameter | None = None
     moe_param: MoEParameter | None = None
+    block_diffusion_param: BlockDiffusionParameter | None = None
     layer_norm_param: LayerNormParameter | None = None
     rms_norm_param: RMSNormParameter | None = None
     parameter_param: ParameterParameter | None = None

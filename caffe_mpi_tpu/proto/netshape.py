@@ -68,7 +68,7 @@ BF16_INELIGIBLE = frozenset({
 })
 BF16_ELIGIBLE = frozenset({
     "AbsVal", "Accuracy", "ArgMax", "Attention", "BNLL", "BatchNorm",
-    "BatchReindex", "Bias", "Concat", "ContrastiveLoss", "Convolution",
+    "BatchReindex", "BlockDiffusionNoise", "Bias", "Concat", "ContrastiveLoss", "Convolution",
     "Crop", "Data", "Deconvolution", "Dropout", "DummyData", "ELU",
     "Eltwise", "Embed", "EuclideanLoss", "Exp", "Filter", "Flatten",
     "HDF5Data", "HingeLoss", "Im2col", "ImageData", "InfogainLoss",
@@ -899,6 +899,12 @@ def _softmax_loss(ctx):
                         f"softmax axis {axis} out of range for {_fmt(s)}")
         else:
             _check_label_counts(ctx, axis)
+    if len(ctx.in_shapes) > 2:
+        labels, weights = (_prod(t) if t is not None else None
+                           for t in ctx.in_shapes[1:3])
+        if _known(labels, weights) and labels != weights:
+            ctx.problem("shape", f"weights {_fmt(ctx.in_shapes[2])} are not "
+                                 f"one a label {_fmt(ctx.in_shapes[1])}")
     tops = [()]
     if len(ctx.lp.top) > 1:
         tops.append(s)
@@ -1145,6 +1151,21 @@ def _detectnet(ctx):
 
 # -- sequence layers (sequence.py) ------------------------------------------
 
+def block_diffusion_problem(p, s) -> "str | None":
+    """What is wrong with an attention_param's block_diffusion over a
+    bottom of `s` positions (None: not known), or None. The one spelling:
+    layers/sequence.py raises what this returns."""
+    if p.block_diffusion < 0:
+        return f"block_diffusion {p.block_diffusion} is no block length"
+    if p.block_diffusion and (p.causal or p.window or p.sequence_parallel):
+        return ("block_diffusion is a mask of its own: neither causal, "
+                "window nor sequence_parallel (the ring path)")
+    if p.block_diffusion and s is not None and s % 2:
+        return (f"block_diffusion reads a [noisy | clean] sequence of two "
+                f"equal halves, not {s} positions")
+    return None
+
+
 @rule("Attention")
 def _attention(ctx):
     from .config import AttentionParameter
@@ -1172,6 +1193,9 @@ def _attention(ctx):
                                  "bias_term: false, and neither "
                                  "num_kv_heads, head_dim, window nor "
                                  "sequence_parallel")
+        if p.block_diffusion or p.qk_norm:
+            ctx.problem("shape", "latent attention has neither "
+                                 "block_diffusion nor qk_norm")
         ctx.declare("q_a_weight", (p.q_lora_rank, c))
         ctx.declare("q_norm", (p.q_lora_rank,))
         ctx.declare("q_b_weight", (heads * (nope + rot), p.q_lora_rank))
@@ -1196,6 +1220,9 @@ def _attention(ctx):
     if p.rope_theta and hd is not None and hd % 2:
         ctx.problem("shape",
                     f"rotary positions over an odd head size {hd}")
+    problem = block_diffusion_problem(p, s[1])
+    if problem:
+        ctx.problem("shape", problem)
     nq = None if hd is None else heads * hd
     nqkv = None if hd is None else (heads + 2 * kv) * hd
     ctx.declare("qkv_weight", (nqkv, c))
@@ -1203,6 +1230,9 @@ def _attention(ctx):
     if p.bias_term:
         ctx.declare("qkv_bias", (nqkv,))
         ctx.declare("proj_bias", (c,))
+    if p.qk_norm:
+        ctx.declare("q_norm", (hd,))
+        ctx.declare("k_norm", (hd,))
     return [s]
 
 
@@ -1259,6 +1289,25 @@ def _moe(ctx):
     if len(ctx.lp.top) > 1:
         tops.append((held,) if p.dropless else ())
     return tops
+
+
+@rule("BlockDiffusionNoise")
+def _block_diffusion_noise(ctx):
+    p = ctx.lp.block_diffusion_param
+    s = ctx.in_shapes[0] if ctx.in_shapes else None
+    if not 3 <= len(ctx.lp.top) <= 4:
+        ctx.problem("wiring", "BlockDiffusionNoise has tops ids, labels, "
+                              "weights and, optionally, the masked count")
+    if p is None or p.block_length < 1 or not 0.0 < p.t_min <= 1.0:
+        ctx.problem("shape", "block_diffusion_param needs block_length >= "
+                             "1 and 0 < t_min <= 1")
+    if s is None or len(s) != 2:
+        if s is not None:
+            ctx.problem("shape", f"BlockDiffusionNoise expects (N, L) token "
+                                 f"ids, got {_fmt(s)}")
+        return [None] * len(ctx.lp.top)
+    n, l = s
+    return [(n, None if l is None else 2 * l), s, s, ()][:len(ctx.lp.top)]
 
 
 @rule("Pipeline")
@@ -1404,6 +1453,11 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         w = min(getattr(p, "window", 0) or s, s)
         pairs = (w * (w + 1) // 2 + (s - w) * w) \
             if getattr(p, "causal", False) else s * s
+        if getattr(p, "block_diffusion", 0):
+            # [noisy | clean], halves of s / 2 in blocks of b: a noisy row
+            # sees its block and the clean blocks before it, a clean row
+            # the clean blocks up to its own
+            pairs = (s // 2) * (s // 2 + p.block_diffusion)
         if getattr(p, "kv_lora_rank", 0):
             # latent attention: the seven blobs' products, scores over
             # nope + rope lanes, values over v_head_dim

@@ -23,7 +23,7 @@ to tell them apart.
 
 | name | kind | covers |
 |---|---|---|
-| `caffe.<Type>.<name>` | scope | one layer's `apply` (Net.apply_range, Pipeline blocks) |
+| `caffe.<Type>.<name>` | scope | one layer's `apply` (Net.apply_range, Pipeline blocks); the block-diffusion recipe's noise layer reads `caffe.BlockDiffusionNoise.ids`, the slice of its noisy half `caffe.Slice.noisy` |
 | `moe.route` | scope | inside a dropless `MoE` layer: router product, top-k, softmax (ops/moe.py) |
 | `moe.dispatch` | scope | sort of the (token, choice) pairs by expert, group sizes, gather of the rows |
 | `moe.experts` | scope | the grouped matrix products over the held experts' rows and the activation between them |

@@ -431,18 +431,21 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
     # diagonal) holds tiles; its jnp reference goes one key/value head at a
     # time, recomputed in its backward pass: 28 heads of 8192 x 8192
     # float32 scores do not fit the chip at once
-    def windowed(flash, window):
+    def windowed(flash, window, block=0):
+        # block: the block-diffusion mask in the band's place
+        mask = dict(block_diffusion=block) if block \
+            else dict(causal=True, window=window)
+
         def f(q, k, v):
             if flash:
-                return attention(q, k, v, causal=True, window=window,
-                                 use_flash=True).astype(jnp.bfloat16)
+                return attention(q, k, v, use_flash=True,
+                                 **mask).astype(jnp.bfloat16)
 
             @jax.checkpoint
             def group(qkv):   # (a group's query heads, 1 key/value head)
                 qg, kg, vg = (t.astype(jnp.float32)[None] for t in qkv)
                 return attention(qg.transpose(0, 2, 1, 3), kg[:, :, None],
-                                 vg[:, :, None], causal=True,
-                                 window=window)[0]
+                                 vg[:, :, None], **mask)[0]
             b, s, h, d = q.shape
             hkv = k.shape[2]
             out = jax.lax.map(group, (
@@ -475,6 +478,21 @@ def leg_kernels(*, on_chip: bool = True, scale: int = 1) -> dict:
         _check_kernel(f"flash causal 32 heads S={s_mla} d=192 dv=128 bf16",
                       with_grad(windowed(True, 0)),
                       with_grad(windowed(False, 0)), (q, k, v), 5e-2,
+                      on_chip)
+
+    # the block-diffusion cell's heads (models/sdar_30b_a3b): 32 query heads
+    # over 4 key/value heads of 128 under the block mask, blocks of 4, over
+    # a [noisy | clean] sequence of 2 x 4,096 rows (half the cell's: 8 heads
+    # of 16,384 x 16,384 float32 scores do not fit beside the rest)
+    s_bd = 8192 // scale
+    q = jnp.asarray(rng.randn(1, s_bd, 32, 128).astype(np.float32),
+                    jnp.bfloat16)
+    kv = tuple(jnp.asarray(rng.randn(1, s_bd, 4, 128).astype(np.float32),
+                           jnp.bfloat16) for _ in range(2))
+    results[f"flash-blockdiffusion4-32over4-s{s_bd}-d128-bf16"] = \
+        _check_kernel(f"flash block diffusion 4, 32/4 heads S={s_bd} bf16",
+                      with_grad(windowed(True, 0, block=4)),
+                      with_grad(windowed(False, 0, block=4)), (q, *kv), 5e-2,
                       on_chip)
 
     from caffe_mpi_tpu.ops.moe import moe_dropless

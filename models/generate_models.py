@@ -1134,6 +1134,141 @@ snapshot_prefix: "models/joyai_llm_flash/{prefix}"
 """
 
 
+SDAR = dict(
+    # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat config.json: widths as
+    # published; depth, experts held and vocabulary are one chip's share,
+    # block length and noise schedule the configuration's `assumed`
+    # (benchmarks/configs/sdar_30b_a3b.json)
+    seq=8192, vocab=18992, dim=2048, heads=32, kv_heads=4, head_dim=128,
+    rope_theta=1e6, layers=5, experts=128, experts_held=16, top_k=8,
+    expert_width=768, eps=1e-6, block_length=4, mask_id=18991, t_min=1e-3)
+# the size of tests/test_sdar.py and of the benchmark's CPU rehearsal: every
+# mechanism, no width (32 experts in 16 shares of 2)
+SDAR_TINY = dict(
+    seq=32, vocab=64, dim=64, heads=4, kv_heads=2, head_dim=16,
+    rope_theta=1e6, layers=3, experts=32, experts_held=2, top_k=4,
+    expert_width=32, eps=1e-6, block_length=4, mask_id=63, t_min=1e-3)
+SDAR_IGNORE = -1
+# layers computed again in the backward pass (LayerParameter.remat): as
+# written the step needs 15.55e9 bytes, with the attention layers' remat
+# (the flash kernel's output kept) 13.96e9 (deviceless compile)
+SDAR_REMAT = ("attn",)
+# the frozen router starts as `experts / experts_held` copies of one matrix
+# of experts_held columns (filler `tile`): every row's top_k logits are then
+# one column's copies, one expert on each of the chips that share the layer,
+# and this chip receives exactly its share, 2 x seq rows a layer, on every
+# seed and at every step. With independent columns the share is a draw of
+# the seed: to a fresh router every masked row, a quarter of the rows, is
+# the same row through every layer (near-uniform attention adds nothing a
+# row could be told by), so they pick the same top_k experts, and how many
+# of those are among the held decides the load: 11,400 to 26,000 rows a
+# layer over 16 seeds on the chip, 3 of 16 past the expert layer's row
+# bound in some layer, and a rate that spread 0.7 % (PERF.md section 6,
+# PR 33). A deployment balances its chips by a loss; this is that balance
+# taken to its end
+def sdar_router(experts: int, experts_held: int) -> dict:
+    return dict(type="gaussian", std=0.02, tile=experts // experts_held)
+
+
+def sdar(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim, rope_theta,
+         layers, experts, experts_held, top_k, expert_width, eps,
+         block_length, mask_id, t_min, first_expert=0, use_flash=True,
+         remat=(), name="sdar_30b_a3b"):
+    """SDAR-30B-A3B-Chat (arXiv:2510.06303), one chip's share, as it is
+    trained: by block diffusion (arXiv:2503.09573). The noise layer turns
+    `seq` clean tokens into the 2 x seq ids [noisy | clean], which go
+    through every block together under the block-diffusion mask; the head
+    and the 1/t-weighted loss read the noisy half alone, with no shift. A
+    block is pre-norm: grouped-head attention with an RMSNorm on each query
+    and key head and rotary positions i mod seq, then a dropless top-k
+    expert layer (softmax over the chosen logits, SiLU-gated experts) whose
+    router reads the experts' own normed input. No biases, no shared
+    expert, untied embedding and head. The equations are written out in
+    benchmarks/reference/sdar_ref.py. `remat`: layer-name suffixes whose
+    layers are computed again in the backward pass."""
+    filler = dict(type="gaussian", std=0.02)
+    n = NetSpec(name)
+    n.tokens = L.Input(input_param=dict(shape=[dict(dim=[batch, seq])]))
+    n.ids, n.label, n.weight, n.masked = L.BlockDiffusionNoise(
+        n.tokens, ntop=4,
+        block_diffusion_param=dict(block_length=block_length,
+                                   mask_id=mask_id, t_min=t_min,
+                                   ignore_label=SDAR_IGNORE))
+    again = lambda layer: dict(remat=True) \
+        if any(layer.endswith(suffix) for suffix in remat) else {}
+    # unit normal, for smallthinker()'s reason
+    n.embed = L.Embed(n.ids, input_dim=vocab, num_output=dim,
+                      bias_term=False,
+                      weight_filler=dict(type="gaussian", std=1.0))
+    x = n.embed
+    for b in range(layers):
+        ln1 = L.RMSNorm(x, eps=eps)
+        setattr(n, f"blk{b}/ln1", ln1)
+        attn = L.Attention(
+            ln1, num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+            use_flash=use_flash, bias_term=False, rope_theta=rope_theta,
+            block_diffusion=block_length, qk_norm=True, norm_eps=eps,
+            weight_filler=filler, **again(f"blk{b}/attn"))
+        setattr(n, f"blk{b}/attn", attn)
+        res1 = L.Eltwise(x, attn)
+        setattr(n, f"blk{b}/res1", res1)
+        ln2 = L.RMSNorm(res1, eps=eps)
+        setattr(n, f"blk{b}/ln2", ln2)
+        # the router scores the experts' own input (the same blob, twice);
+        # routing is a constant of the step, as in smallthinker(): the
+        # router matrix is frozen and nothing trains through the scores.
+        # Second top: rows each held expert received
+        moe, rows = L.MoE(ln2, ln2, ntop=2, loss_weight=[0.0, 0.0],
+                          param=[dict(lr_mult=0, decay_mult=0)],
+                          propagate_down=[True, False],
+                          moe_param=dict(
+                              num_experts=experts, hidden_dim=expert_width,
+                              top_k=top_k, dropless=True,
+                              experts_held=experts_held,
+                              first_expert=first_expert, activation="silu",
+                              gate_filler=sdar_router(experts, experts_held),
+                              weight_filler=filler))
+        setattr(n, f"blk{b}/moe", moe)
+        setattr(n, f"blk{b}/moe_rows", rows)
+        res2 = L.Eltwise(res1, moe)
+        setattr(n, f"blk{b}/res2", res2)
+        x = res2
+    # the loss reads the noisy half; the clean half ends here
+    n.noisy, n.clean = L.Slice(x, ntop=2, axis=1, slice_point=[seq])
+    n.drop_clean = L.Silence(n.clean, ntop=0)
+    n.ln_f = L.RMSNorm(n.noisy, eps=eps)
+    n.logits = L.InnerProduct(n.ln_f, num_output=vocab, axis=2,
+                              bias_term=False, weight_filler=filler)
+    # FULL: 1 / (N seq), whatever the draw masked
+    n.loss = L.SoftmaxWithLoss(
+        n.logits, n.label, n.weight, softmax_param=dict(axis=2),
+        loss_param=dict(ignore_label=SDAR_IGNORE, normalization="FULL"))
+    n.accuracy = L.Accuracy(n.logits, n.label, axis=2,
+                            ignore_label=SDAR_IGNORE,
+                            include=dict(phase="TEST"))
+    return n
+
+
+def sdar_solver(net: str, prefix: str) -> str:
+    return f"""# SDAR-30B-A3B-Chat, one chip's share, trained by block
+# diffusion: Adam (this system's coupled L2, none set), fixed 3e-4,
+# global-norm clip 1; static loss scale for the reason
+# models/smallthinker_21b_a3b/solver.prototxt gives
+net: "models/sdar_30b_a3b/{net}"
+base_lr: 0.0003
+lr_policy: "fixed"
+display: 10
+max_iter: 10000
+momentum: 0.9
+momentum2: 0.95
+type: "Adam"
+clip_gradients: 1.0
+loss_scale: 1.0
+snapshot: 10000
+snapshot_prefix: "models/sdar_30b_a3b/{prefix}"
+"""
+
+
 def transformer_lm_pp_prototxt(batch=8, seq=64, vocab=256, dim=128, heads=4,
                                n_stages=4, micro_batches=4, ffn_hidden=256):
     """Pipeline-parallel transformer_lm variant: the trunk is ONE Pipeline
@@ -1516,14 +1651,18 @@ def main():
 
     # the language-model configurations of the benchmark: the recipe at the
     # published widths and the tiny one its CPU rehearsal and tests run
-    # (tests/test_smallthinker.py, tests/test_joyai.py). Train only: no
+    # (tests/test_smallthinker.py, tests/test_joyai.py, tests/test_sdar.py).
+    # Train only: no
     # deploy net, the serving path has no key/value cache yet
     for family, build, solver_text, real, tiny, prefix in (
             ("smallthinker_21b_a3b", smallthinker, smallthinker_solver,
              SMALLTHINKER, SMALLTHINKER_TINY, "smallthinker"),
             ("joyai_llm_flash", joyai_llm_flash, joyai_solver,
              dict(JOYAI, remat=JOYAI_REMAT),
-             dict(JOYAI_TINY, remat=JOYAI_REMAT), "joyai")):
+             dict(JOYAI_TINY, remat=JOYAI_REMAT), "joyai"),
+            ("sdar_30b_a3b", sdar, sdar_solver,
+             dict(SDAR, remat=SDAR_REMAT),
+             dict(SDAR_TINY, remat=SDAR_REMAT), "sdar")):
         d = os.path.join(out_root, family)
         os.makedirs(d, exist_ok=True)
         for net, solver, sizes in (

@@ -16,6 +16,16 @@ projection (C, C) weights in Caffe's (num_output, K) convention; MoE
 declares gate/w1/b1/w2/b2 expert banks — shard them over a mesh axis via
 Solver(param_shardings={"moe": {"w1": ("model",), ...}}) for EP.
 
+Inside Attention the heads are produced by products over reshaped VIEWS
+of those blobs (rows h*D..(h+1)*D of a blob are head h), (N, H, S, D)
+where the layer hands them to the flash kernels on one device — the
+order the kernels read, so nothing is re-laid out between a projection
+and a kernel — and (N, S, H, D) for the ring paths and the jnp path. The
+per-head norm and the rotary turn are lane-local (ops/attention.py
+`lane_partner`, `turn_lanes`): no slice into halves, no concatenation,
+no float32 tensor of q's size in HBM (PERF.md section 6, PR 34;
+tools/attention_glue_bytes.py counts it).
+
 EP scope note: the dict rules shard the expert WEIGHT banks; the (E, C, *)
 dispatched-activation shardings then follow from GSPMD operand propagation
 through the batched expert einsums. For explicit activation constraints
@@ -124,12 +134,13 @@ def latent_dims(p) -> tuple[int, int, int]:
 
 @register("Attention")
 class AttentionLayer(Layer):
-    """attention_param. Two forms: fused QKV heads (grouped; windowed or
-    under the block-diffusion mask; an RMSNorm on each query and key head;
-    rotary over the whole head) and, with kv_lora_rank > 0, latent
-    attention (`_setup_latent`, `_latent_qkv`): low-rank query and
-    key/value projections, a head of unequal query/key and value widths,
-    rotary over a part of it."""
+    """attention_param. Two forms: fused QKV heads (`_grouped_qkv`:
+    grouped; windowed or under the block-diffusion mask; an RMSNorm on
+    each query and key head; rotary over the whole head) and, with
+    kv_lora_rank > 0, latent attention (`_setup_latent`, `_latent_qkv`):
+    low-rank query and key/value projections, a head of unequal query/key
+    and value widths, rotary over a part of it. Both produce their heads
+    in the order `_head_major` says."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         from ..proto.config import AttentionParameter
@@ -187,59 +198,100 @@ class AttentionLayer(Layer):
                                      p.kv_lora_rank), filler)
         self.declare("proj_weight", (c, self.nq), filler)
 
-    def _latent_qkv(self, params, x):
-        """q, k (N, S, H, nope + rot) and v (N, S, H, vd): the rotary lanes
-        last, the one rotary key head repeated under every query head."""
-        from ..ops.attention import rope, rope_pairs
+    def _ring(self) -> bool:
+        """Whether the sequence is sharded over the mesh's 'model' axis
+        (prototxt-declared sequence parallelism on a mesh that has one)."""
+        mp = self.mesh_plan
+        return bool(self.p.sequence_parallel and mp is not None
+                    and mp.mesh.shape.get("model", 1) > 1)
+
+    def _head_major(self) -> bool:
+        """Whether q, k and v go to the flash kernels on one device: they
+        are then produced (B, H, S, D), the order the kernels read, and the
+        output projection contracts the kernels' own result. The ring paths
+        and the jnp path keep (B, S, H, D)."""
+        return bool(self.p.use_flash) and not self._ring()
+
+    def _latent_qkv(self, params, x, hm: bool = False):
+        """q, k (N, S, H, nope + rot) and v (N, S, H, vd), or with `hm`
+        all three (N, H, S, .): the rotary lanes last, the one rotary key
+        head under every query head. Each comes from a product over a view
+        of its declared blob in the order asked for; q is turned where it
+        lies (tables that are cos 1, sin 0 on the lanes without
+        positions)."""
+        from ..ops.attention import lane_partner, rope_tables, turn_lanes
         p = self.p
         nope, rot, vd = self.latent
-        n, s, _ = x.shape
+        s, pairs = x.shape[1], bool(p.rope_interleave)
         w = lambda name: self.f(params[name])
         rms = lambda t, scale: rms_normalize(t, p.norm_eps) * scale
-        turn = rope_pairs if p.rope_interleave else rope
-        q = rms(x @ w("q_a_weight").T, w("q_norm")) @ w("q_b_weight").T
-        q = q.reshape(n, s, self.heads, nope + rot)
+        heads = lambda t, wide: jnp.einsum(
+            "nsr,hdr->nhsd" if hm else "nsr,hdr->nshd", t, wide)
+        cos, sin = rope_tables(s, rot, p.rope_theta, pairs=pairs, lead=nope)
+        turn = lambda t, lead: turn_lanes(
+            t, lane_partner(t, rot, pairs), cos[:, nope - lead:],
+            sin[:, nope - lead:], head_major=hm)
+        q = rms(x @ w("q_a_weight").T, w("q_norm"))
+        q = turn(heads(q, w("q_b_weight").reshape(
+            self.heads, nope + rot, p.q_lora_rank)), nope)
         kv = x @ w("kv_a_weight").T
-        k_r = turn(kv[..., None, p.kv_lora_rank:], p.rope_theta)
-        kv = rms(kv[..., :p.kv_lora_rank], w("kv_norm")) \
-            @ w("kv_b_weight").T
-        kv = kv.reshape(n, s, self.heads, nope + vd)
-        q = jnp.concatenate(
-            [q[..., :nope], turn(q[..., nope:], p.rope_theta)], axis=-1)
+        k_r = kv[..., p.kv_lora_rank:]
+        k_r = turn(k_r[:, None] if hm else k_r[:, :, None], 0)
+        kv = rms(kv[..., :p.kv_lora_rank], w("kv_norm"))
+        kv_b = w("kv_b_weight").reshape(self.heads, nope + vd,
+                                        p.kv_lora_rank)
+        k = heads(kv, kv_b[:, :nope])
         k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_r, (n, s, self.heads, rot))], axis=-1)
-        return q, k, kv[..., nope:]
+            [k, jnp.broadcast_to(k_r, (*k.shape[:-1], rot))], axis=-1)
+        return q, k, heads(kv, kv_b[:, nope:])
+
+    def _grouped_qkv(self, params, x, hm: bool = False):
+        """q (N, S, H, D), k and v (N, S, Hkv, D), or with `hm` (N, H, S,
+        D): a product each over its rows of the fused blob viewed as
+        heads, in the order asked for (apart, the per-head norm's statistic
+        fuses into the product that feeds it); the norm and the rotary
+        turn are lane-local, so a head goes from its product to the kernel
+        in one more pass."""
+        from ..ops.attention import lane_partner, rope_tables, turn_lanes
+        p = self.p
+        s, d = x.shape[1], self.head_dim
+        weight = self.f(params["qkv_weight"])
+        bias = self.f(params["qkv_bias"]) if p.bias_term else None
+
+        def heads(lo, n):
+            t = jnp.einsum("nsc,hdc->nhsd" if hm else "nsc,hdc->nshd", x,
+                           weight[lo:lo + n * d].reshape(n, d, -1))
+            if bias is None:
+                return t
+            b = bias[lo:lo + n * d].reshape(n, d)
+            return t + (b[:, None] if hm else b)
+        q, k, v = (heads(0, self.heads), heads(self.nq, self.kv_heads),
+                   heads(self.nq + self.nkv, self.kv_heads))
+        # the two halves of a block-diffusion sequence sit at the same
+        # positions
+        tables = rope_tables(
+            s, d, p.rope_theta, period=s // 2 if p.block_diffusion else 0
+        ) if p.rope_theta else None
+
+        def positioned(t, norm):
+            if p.qk_norm:
+                t = rms_normalize(t, p.norm_eps) * self.f(params[norm])
+            return turn_lanes(t, lane_partner(t, d), *tables,
+                              head_major=hm) if tables else t
+        return positioned(q, "q_norm"), positioned(k, "k_norm"), v
 
     def apply(self, params, state, bottoms, *, train, rng):
-        from ..ops.attention import (attention, rope,
-                                     sequence_parallel_attention)
+        from ..ops.attention import attention, sequence_parallel_attention
+        from ..ops.flash_attention import flash_attention_heads
         p = self.p
         x = self.f(bottoms[0])
-        n, s, c = x.shape
-        if p.kv_lora_rank:
-            q, k, v = self._latent_qkv(params, x)
-        else:
-            qkv = x @ self.f(params["qkv_weight"]).T
-            if p.bias_term:
-                qkv = qkv + self.f(params["qkv_bias"])
-            nq, nkv = self.nq, self.nkv
-            q, k, v = jnp.split(qkv, [nq, nq + nkv], axis=-1)
-            q = q.reshape(n, s, self.heads, self.head_dim)
-            k = k.reshape(n, s, self.kv_heads, self.head_dim)
-            v = v.reshape(n, s, self.kv_heads, self.head_dim)
-            if p.qk_norm:
-                q = rms_normalize(q, p.norm_eps) * self.f(params["q_norm"])
-                k = rms_normalize(k, p.norm_eps) * self.f(params["k_norm"])
-            if p.rope_theta:
-                # the two halves of a block-diffusion sequence sit at the
-                # same positions
-                period = s // 2 if p.block_diffusion else 0
-                q = rope(q, p.rope_theta, period)
-                k = rope(k, p.rope_theta, period)
+        hm = self._head_major()
+        q, k, v = (self._latent_qkv if p.kv_lora_rank
+                   else self._grouped_qkv)(params, x, hm)
         mp = self.mesh_plan
-        if (p.sequence_parallel and mp is not None
-                and mp.mesh.shape.get("model", 1) > 1):
+        mask = dict(causal=bool(p.causal), window=p.window,
+                    block_diffusion=p.block_diffusion)
+        if self._ring():
             # prototxt-declared SP: the sequence dim shards over 'model'
             # and K/V ride the ICI ring (ops/attention.py ring_attention);
             # the batch dim stays on 'data' so DPxSP composes. use_flash
@@ -249,20 +301,19 @@ class AttentionLayer(Layer):
                 q, k, v, mp.mesh, seq_axis="model", causal=bool(p.causal),
                 batch_axis="data" if mp.mesh.shape.get("data", 1) > 1
                 else None, use_flash=bool(p.use_flash))
-        elif p.use_flash and mp is not None:
+        elif hm:
+            flash = lambda q, k, v: flash_attention_heads(q, k, v, **mask)
             # the flash kernels are Mosaic calls, which GSPMD cannot
             # partition: split the batch by hand (attention never mixes
             # samples)
-            out = mp.per_batch_shard(
-                lambda q, k, v: attention(
-                    q, k, v, causal=bool(p.causal), use_flash=True,
-                    window=p.window, block_diffusion=p.block_diffusion),
-                q, k, v)
+            out = (flash(q, k, v) if mp is None
+                   else mp.per_batch_shard(flash, q, k, v))
         else:
-            out = attention(q, k, v, causal=bool(p.causal),
-                            use_flash=bool(p.use_flash), window=p.window,
-                            block_diffusion=p.block_diffusion)
-        y = out.reshape(n, s, self.nq) @ self.f(params["proj_weight"]).T
+            out = attention(q, k, v, **mask)
+        y = jnp.einsum(
+            "nhsd,chd->nsc" if hm else "nshd,chd->nsc", out,
+            self.f(params["proj_weight"]).reshape(x.shape[-1], self.heads,
+                                                  -1))
         if p.bias_term:
             y = y + self.f(params["proj_bias"])
         return [y], state

@@ -51,27 +51,80 @@ def _block_attn(q, k, v, *, scale, mask=None):
     return out, m_safe, l
 
 
+def rope_tables(s: int, d: int, theta: float, *, period: int = 0,
+                pairs: bool = False, lead: int = 0):
+    """(cos, sin) float32 (S, lead + d) of the rotary turn `turn_lanes`
+    applies over the last `d` lanes of a head: positions 0..S-1 (row i at
+    i mod `period` where one is given: the two halves of a block-diffusion
+    sequence sit at the same positions), no scaling. Rotate-half
+    convention: lane i of the first half and lane i of the second share
+    the angle a_i = pos * theta^(-i / (d/2)); `pairs` (`rope_interleave`):
+    lanes 2i and 2i+1 share a_i = pos * theta^(-2i / d). The sign of the
+    partner's term is folded into `sin` (minus on the lane whose partner
+    lies above it); the first `lead` lanes are not turned: cos 1, sin 0.
+    Built once a layer call and handed to every tensor it turns."""
+    half = d // 2
+    pos = jnp.arange(s)
+    if period:
+        pos = pos % period
+    ang = (pos.astype(jnp.float32)[:, None]
+           * theta ** (-jnp.arange(half, dtype=jnp.float32) / half)[None, :])
+    both = ((lambda t: jnp.repeat(t, 2, axis=-1)) if pairs
+            else (lambda t: jnp.concatenate([t, t], -1)))
+    cos, sin = both(jnp.cos(ang)), both(jnp.sin(ang))
+    sin = jnp.where(_partner_above(d, d, pairs), -sin, sin)
+    if lead:
+        cos = jnp.concatenate([jnp.ones((s, lead), jnp.float32), cos], -1)
+        sin = jnp.concatenate([jnp.zeros((s, lead), jnp.float32), sin], -1)
+    return cos, sin
+
+
+def _partner_above(width: int, d: int, pairs: bool):
+    """(width,) bool: the lanes, of the last `d`, whose partner in the
+    rotary turn is a higher lane (the even one of a pair, the first half)."""
+    lane = jnp.arange(width) - (width - d)
+    return lane % 2 == 0 if pairs else lane < d // 2
+
+
+def lane_partner(x: jnp.ndarray, d: int, pairs: bool = False):
+    """x with each of its last `d` lanes replaced by its partner in the
+    rotary turn (x_i+d/2 and x_i-d/2, or the other lane of an adjacent
+    pair), zero on the lanes before them (their `sin` is 0). A product
+    with a 0/1 matrix: exact in any type (one term a sum), and the one form
+    of a lane permutation that XLA:TPU fuses with what reads it, forward
+    and backward; `jnp.roll`'s slices and concatenation cost a pass of
+    their own over the tensor each way (PERF.md section 6, PR 34)."""
+    width = x.shape[-1]
+    lane = jnp.arange(width)
+    shift = 1 if pairs else d // 2
+    partner = jnp.where(_partner_above(width, d, pairs), lane + shift,
+                        lane - shift)
+    swap = ((lane[:, None] == partner[None, :])
+            & (lane >= width - d)[None, :]).astype(x.dtype)
+    return jnp.einsum("...d,de->...e", x, swap,
+                      precision=lax.Precision.HIGHEST)
+
+
+def turn_lanes(x: jnp.ndarray, partner: jnp.ndarray, cos, sin, *,
+               head_major: bool = False) -> jnp.ndarray:
+    """x * cos + partner * sin: the rotary turn of x (B, S, H, D), or (B,
+    H, S, D) with `head_major`, by `rope_tables`' (S, D) tables; `partner`
+    is `lane_partner(x, ...)`. The multiply-add in float32, one rounding
+    to x's type at the end."""
+    if not head_major:
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    out = x.astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
+    return out.astype(x.dtype)
+
+
 def rope(x: jnp.ndarray, theta: float, period: int = 0) -> jnp.ndarray:
     """Rotary positions over the whole head, rotate-half convention:
-    x (B, S, H, D) -> the same, positions 0..S-1 in each sequence (row i
-    at i mod `period` where one is given: the two halves of a block-
-    diffusion sequence sit at the same positions), no scaling. Angles and
-    the rotation in float32, the result in x's type.
+    x (B, S, H, D) -> the same (`rope_tables`, `turn_lanes`).
     With x = [x1, x2] (halves of D) and a_i = pos * theta^(-i / (D/2)):
     [x1 cos a - x2 sin a, x2 cos a + x1 sin a]."""
     s, d = x.shape[1], x.shape[-1]
-    half = d // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    pos = jnp.arange(s, dtype=jnp.float32)
-    if period:
-        pos = (jnp.arange(s) % period).astype(jnp.float32)
-    ang = pos[:, None] * inv[None, :]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return out.astype(x.dtype)
+    return turn_lanes(x, lane_partner(x, d),
+                      *rope_tables(s, d, theta, period=period))
 
 
 def rope_pairs(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -79,20 +132,10 @@ def rope_pairs(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     convention (`rope_interleave`): (x_2i, x_2i+1) turned by the angle
     a_i = pos * theta^(-2i / D), positions 0..S-1, no scaling:
     [x_2i cos a_i - x_2i+1 sin a_i, x_2i+1 cos a_i + x_2i sin a_i], each in
-    its own lane. x (B, S, H, D). The pair's other lane comes by two lane
-    rotations and a select, so nothing is re-laid out. Angles and the
-    rotation in float32, the result in x's type."""
+    its own lane. x (B, S, H, D)."""
     s, d = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.repeat(jnp.arange(s, dtype=jnp.float32)[:, None]
-                     * inv[None, :], 2, axis=-1)            # (S, D)
-    even = jnp.arange(d) % 2 == 0
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.where(even, -jnp.sin(ang), jnp.sin(ang))[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    other = jnp.where(even, jnp.roll(x32, -1, axis=-1),
-                      jnp.roll(x32, 1, axis=-1))
-    return (x32 * cos + other * sin).astype(x.dtype)
+    return turn_lanes(x, lane_partner(x, d, pairs=True),
+                      *rope_tables(s, d, theta, pairs=True))
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,

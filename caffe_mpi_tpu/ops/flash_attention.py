@@ -6,8 +6,12 @@ CUDA kernels next to cuDNN ops). Attention is the canonical case — naive
 attention materializes the (Sq, Sk) score matrix in HBM; these kernels keep
 it in VMEM tiles with an online softmax, O(S) memory instead of O(S^2).
 
-Layout: (B, H, S, D) inside the kernels (sequence-minor tiles). The public
-entry accepts the framework's (B, S, H, D) and transposes at the edges.
+Layout: (B, H, S, D) inside the kernels (sequence-minor tiles), which is
+what `flash_attention_heads` takes and the attention layer produces
+straight from its projections; `flash_attention` accepts the framework's
+(B, S, H, D) and turns it on the way in and out (XLA folds those turns
+into its layout choice: they were never the cost, PERF.md section 6, PR
+34).
 Two head widths: queries and keys are `d` wide, values (and so the output)
 `dv` wide; they differ in latent attention, whose scores run over 128 +
 64 rotary lanes and whose values are 128 wide. A width that is no multiple
@@ -769,13 +773,16 @@ def check_block_diffusion(block: int, sq: int, sk: int, causal, window):
             f"{bool(causal)}, window {window}, lengths {sq} and {sk})")
 
 
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = False, interpret: bool | None = None,
-                    window: int = 0, block_diffusion: int = 0
-                    ) -> jnp.ndarray:
-    """q (B, S, H, D), k (B, S, Hkv, D), v (B, S, Hkv, Dv) -> (B, S, H,
-    Dv); scores are scaled by 1 / sqrt(D). Differentiable: jax.grad hits
-    the Pallas backward kernels via custom_vjp.
+def flash_attention_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                          causal: bool = False,
+                          interpret: bool | None = None, window: int = 0,
+                          block_diffusion: int = 0) -> jnp.ndarray:
+    """The kernels' own order: q (B, H, S, D), k (B, Hkv, S, D), v (B,
+    Hkv, S, Dv) -> (B, H, S, Dv); scores are scaled by 1 / sqrt(D). What a
+    layer that produces its heads in this order (layers/sequence.py) calls,
+    so that nothing is re-laid out between its projections and the
+    kernels: folding B into H is a view. Differentiable: jax.grad hits the
+    Pallas backward kernels via custom_vjp.
 
     Grouped heads (Hkv < H): query head n reads key/value head
     n // (H / Hkv) through the kernels' block index maps, K and V are not
@@ -790,8 +797,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     key columns are masked out of the in-kernel softmax, padded query rows
     are sliced off the output (their gradients vanish through the zero
     cotangent)."""
-    b, sq, h, d = q.shape
-    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
+    b, h, sq, d = q.shape
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     if h % hkv:
         raise ValueError(f"{h} query heads over {hkv} key/value heads")
     if window and not causal:
@@ -800,14 +807,23 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     bd = (sq // 2, block_diffusion) if block_diffusion else None
     sq_p, sk_p = _pad_len(sq, BQ), _pad_len(sk, BK)
     if sq_p != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     if sk_p != sk:
-        k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk_p, dv)
-    out = _flash(qt, kt, vt, causal, interpret,
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+    out = _flash(q.reshape(b * h, sq_p, d), k.reshape(b * hkv, sk_p, d),
+                 v.reshape(b * hkv, sk_p, dv), causal, interpret,
                  sk if sk_p != sk else None, window, bd)
-    out = out.reshape(b, h, sq_p, dv).transpose(0, 2, 1, 3)
-    return out[:, :sq] if sq_p != sq else out
+    out = out.reshape(b, h, sq_p, dv)
+    return out[:, :, :sq] if sq_p != sq else out
+
+
+def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    **kw) -> jnp.ndarray:
+    """`flash_attention_heads` for the framework's order: q (B, S, H, D),
+    k (B, S, Hkv, D), v (B, S, Hkv, Dv) -> (B, S, H, Dv), turned head-major
+    on the way in and back on the way out (what
+    `ops.attention.attention(use_flash=True)` calls)."""
+    out = flash_attention_heads(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                                **kw)
+    return out.transpose(0, 2, 1, 3)

@@ -470,6 +470,52 @@ class TestSdarCell:
         assert fallback.count("ragged-dot-none") == 5 * 11, fallback
 
 
+# (model, layer, glue GB, all three classes GB, rows x heads x head_dim) as
+# PR 34 left them (PERF.md section 5); before it 11.92 / 15.44, 7.11 /
+# 10.65, 3.25 / 4.88, 1.41 / 3.04
+_GLUE_CASES = {
+    "sdar_block_mask_qk_norm": ("sdar_30b_a3b", "blk0/attn", 3.48, 8.89,
+                                16384 * 32 * 128),
+    "joyai_latent": ("joyai_llm_flash", "blk0/attn", 2.67, 7.01,
+                     8192 * 32 * 128),
+    "smallthinker_window_rotary": ("smallthinker_21b_a3b", "blk1/attn",
+                                   1.13, 3.33, 8192 * 28 * 128),
+    "smallthinker_full": ("smallthinker_21b_a3b", "blk0/attn", 1.28, 3.18,
+                          8192 * 28 * 128),
+}
+
+
+class TestAttentionGlueBytes:
+    """What one attention layer of each language-model cell moves through
+    HBM beside its kernels and its matrix products
+    (`tools/attention_glue_bytes.py`: operand + result bytes of the
+    compiled forward + backward pass, under the layer's own `remat`). A
+    count from the compiler's text, held at what PR 34 reached plus a
+    tenth: a float32 tensor of q's size, a second layout of it or a lane
+    rotation that became a pass of its own again shows here, at no chip
+    time."""
+
+    @pytest.mark.parametrize("case", list(_GLUE_CASES))
+    def test_one_layer_forward_and_backward(self, case):
+        from pathlib import Path
+        root = Path(__file__).resolve().parent.parent
+        spec = importlib.util.spec_from_file_location(
+            "attention_glue_bytes", root / "tools/attention_glue_bytes.py")
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        model, name, glue_gb, all_gb, q_elements = _GLUE_CASES[case]
+        records = tool.layer_records(
+            str(root / "models" / model / "train_val.prototxt"), name,
+            "bf16", v5e_devices()[0])
+        totals = tool.totals(records)
+        assert sum(r["kind"] == "mosaic" for r in records) == 3
+        assert totals["glue"] <= 1.1 * glue_gb * 1e9, totals
+        assert sum(totals.values()) <= 1.1 * all_gb * 1e9, totals
+        # no glue operation writes a float32 tensor of q's size (the
+        # widest left is a parameter's gradient)
+        assert tool.widest_f32_glue_result(records) < q_elements
+
+
 _ALEXNET_HEAD = """
 name: "alexnet_head"
 layer { name: "data" type: "Input" top: "data"

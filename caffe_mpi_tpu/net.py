@@ -34,6 +34,7 @@ from .layers.base import Layer, create_layer
 from .layers.data_layers import InputLayerBase
 from .proto.config import NetParameter, NetState
 from .proto.upgrade import filter_net, normalize_net
+from .utils import compile_cache, spans
 from .utils.spans import layer_scope
 
 log = logging.getLogger(__name__)
@@ -75,6 +76,18 @@ class Net:
         TPU) — the one-knob spelling of NVCaffe's fp16 prototxt variants
         — while per-layer forward_type/backward_type overrides still
         win, exactly as they do against the prototxt net defaults."""
+        # the start-up ledger listens from the first Net on, where no
+        # entry point enabled a compile cache before (utils/spans.py)
+        compile_cache.install_ledger()
+        with spans.phase("net/build", phase=phase) as built:
+            self._build(param, phase, level, stages, batch_divisor,
+                        data_shape_probe, model_dir, solver_storage,
+                        device_transform, precision)
+            built.stats["layers"] = len(self.layers)
+
+    def _build(self, param, phase, level, stages, batch_divisor,
+               data_shape_probe, model_dir, solver_storage, device_transform,
+               precision) -> None:
         self.model_dir = model_dir
         param = normalize_net(param)
         state = NetState(phase=phase, level=level, stage=list(stages))
@@ -310,19 +323,24 @@ class Net:
         reference's learnable-param ownership (net.cpp AppendParam)."""
         params: Params = {}
         state: State = {}
-        for i, layer in enumerate(self.layers):
-            lkey = jax.random.fold_in(key, i)
-            p = {}
-            inited = layer.init_params(lkey)
-            for pname, arr in inited.items():
-                if (layer.name, pname) in self.param_aliases:
-                    continue  # owner holds it
-                p[pname] = arr
-            if p:
-                params[layer.name] = p
-            s = layer.init_state()
-            if s:
-                state[layer.name] = s
+        # host seconds: the fillers dispatch asynchronously, so what the
+        # device still owes when the phase closes is in no phase
+        with spans.phase("net/fill", layers=len(self.layers)) as filled:
+            for i, layer in enumerate(self.layers):
+                lkey = jax.random.fold_in(key, i)
+                p = {}
+                inited = layer.init_params(lkey)
+                for pname, arr in inited.items():
+                    if (layer.name, pname) in self.param_aliases:
+                        continue  # owner holds it
+                    p[pname] = arr
+                if p:
+                    params[layer.name] = p
+                s = layer.init_state()
+                if s:
+                    state[layer.name] = s
+            filled.stats["parameters"] = sum(
+                a.size for p in params.values() for a in p.values())
         return params, state
 
     def _layer_params(self, layer: Layer, params: Params, train: bool) -> dict:

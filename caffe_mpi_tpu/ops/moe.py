@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..utils import spans
 from ..utils.spans import (MOE_COMBINE as COMBINE, MOE_DISPATCH as DISPATCH,
                            MOE_EXPERTS as EXPERTS, MOE_FALLBACK as FALLBACK,
                            MOE_ROUTE as ROUTE, MOE_SHARED as SHARED)
@@ -214,8 +215,9 @@ def _megablox():
 def _gmm_fwd(rows, bank, sizes):
     backend = _megablox()
     m, k = rows.shape
-    out = backend.gmm(rows, bank, sizes, rows.dtype,
-                      _gmm_tiles(m, k, bank.shape[2]))
+    with spans.phase(spans.KERNEL, kernel="gmm", branch="default"):
+        out = backend.gmm(rows, bank, sizes, rows.dtype,
+                          _gmm_tiles(m, k, bank.shape[2]))
     return out, (rows, bank, sizes)
 
 
@@ -224,10 +226,12 @@ def _gmm_bwd(res, grad):
     rows, bank, sizes = res
     m, k = rows.shape
     n = bank.shape[2]
-    d_rows = backend.gmm(grad, bank, sizes, rows.dtype,
-                         _gmm_tiles(m, n, k), transpose_rhs=True)
-    d_bank = backend.tgmm(rows.swapaxes(0, 1), grad, sizes, bank.dtype,
-                          _gmm_tiles(m, k, n))
+    with spans.phase(spans.KERNEL, kernel="gmm", branch="default"):
+        d_rows = backend.gmm(grad, bank, sizes, rows.dtype,
+                             _gmm_tiles(m, n, k), transpose_rhs=True)
+    with spans.phase(spans.KERNEL, kernel="tgmm", branch="default"):
+        d_bank = backend.tgmm(rows.swapaxes(0, 1), grad, sizes, bank.dtype,
+                              _gmm_tiles(m, k, n))
     return d_rows, d_bank, None
 
 

@@ -20,6 +20,8 @@ from __future__ import annotations
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..utils import spans
+
 
 def pallas_call(kernel, *, interpret: bool | None = None, **kwargs):
     """`pl.pallas_call` with `interpret=None` meaning "by lowering
@@ -28,8 +30,24 @@ def pallas_call(kernel, *, interpret: bool | None = None, **kwargs):
     parity check pins `False`)."""
     if interpret is not None:
         return pl.pallas_call(kernel, interpret=interpret, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
-    compiled = pl.pallas_call(kernel, interpret=False, **kwargs)
+    name = kwargs.get("name") or getattr(
+        getattr(kernel, "func", kernel), "__name__", "kernel")
+
+    def arm(branch: str, interpret: bool):
+        built = pl.pallas_call(kernel, interpret=interpret, **kwargs)
+
+        def traced(*args):
+            # jax traces BOTH arms of `platform_dependent` wherever the
+            # lowering platform is not known yet: each trace is a phase of
+            # the start-up ledger, named by kernel and arm (utils/spans.py)
+            with spans.phase(spans.KERNEL, kernel=name, branch=branch):
+                return built(*args)
+        return traced
+
+    # built once a `pallas_call`, not once a call: a memoized builder
+    # (ops/flash_attention.py) hands out one `call`, and jax's own caches
+    # key on the arms' identity
+    interpreted, compiled = arm("cpu", True), arm("default", False)
 
     def call(*args):
         return lax.platform_dependent(*args, cpu=interpreted,

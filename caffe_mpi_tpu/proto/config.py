@@ -21,7 +21,8 @@ import typing
 from dataclasses import dataclass, field as dc_field
 from typing import Any, get_args, get_origin
 
-from .text_format import PbEnum, PbNode, parse, parse_file
+from ..utils import spans
+from .text_format import PbEnum, PbNode, parse
 
 
 # ---------------------------------------------------------------------------
@@ -115,11 +116,13 @@ class Message:
 
     @classmethod
     def from_text(cls, text: str):
-        return cls.from_node(parse(text))
+        with spans.phase("parse", message=cls.__name__, bytes=len(text)):
+            return cls.from_node(parse(text))
 
     @classmethod
     def from_file(cls, path: str):
-        return cls.from_node(parse_file(path))
+        with open(path, "r", encoding="utf-8") as f:
+            return cls.from_text(f.read())
 
     @property
     def unknown_fields(self) -> list[str]:

@@ -114,6 +114,12 @@ class Solver:
         way (tools/caffe.cpp:223-225 hands the solver to P2PManager::Run);
         mutually exclusive with mesh/zero_stage, and iter_size must be 1
         (microbatches already carry the accumulation semantics)."""
+        with spans.phase("solver/build"):
+            self._build(sp, model_dir, batch_divisor, grad_transform,
+                        data_shape_probe, rank, mesh, param_shardings, gpipe)
+
+    def _build(self, sp, model_dir, batch_divisor, grad_transform,
+               data_shape_probe, rank, mesh, param_shardings, gpipe) -> None:
         self.sp = sp
         self.type = solver_type(sp)
         if self.type not in UPDATE_FNS:
@@ -195,7 +201,8 @@ class Solver:
         seed = sp.random_seed if sp.random_seed >= 0 else 0
         self.base_rng = jax.random.PRNGKey(seed)
         self.params, self.net_state = self.net.init(self.base_rng)
-        self.opt_state = self._init_opt_state()
+        with spans.phase("solver/opt state"):
+            self.opt_state = self._init_opt_state()
         self.mesh = mesh
         if param_shardings is None and mesh is not None:
             param_shardings = self._prototxt_shardings() or None
@@ -223,8 +230,9 @@ class Solver:
         if mesh is not None:
             # startup weight broadcast (reference parallel.cpp:208-227) —
             # replicated by default, or tensor-parallel-sharded per rules
-            self.net_state = mesh.replicate(self.net_state)
-            self._place_params_opt()
+            with spans.phase("solver/place"):
+                self.net_state = mesh.replicate(self.net_state)
+                self._place_params_opt()
             self.net.bind_mesh(mesh)
             for tnet in self.test_nets:
                 tnet.bind_mesh(mesh)
@@ -252,7 +260,8 @@ class Solver:
             self._gpipe_owned = [
                 self.gpipe.owned_param_layers(s, self.params)
                 for s in range(self.gpipe.n_stages)]
-            self._place_params_opt()
+            with spans.phase("solver/place"):
+                self._place_params_opt()
         # overlapped bucketed gradient reduction (ISSUE 6,
         # parallel/reduction.py — reference ReduceAndUpdate,
         # net.cpp:757-913): knob validation always runs (an explicit
@@ -977,8 +986,9 @@ class Solver:
         # the guard carry (5 scalars) is NOT donated: the deferred
         # divergence check reads the previous dispatch's gstate after
         # the next one launches, so its buffer must stay valid
-        return jax.jit(self._iteration_fn(),
-                       donate_argnums=self._train_donate_argnums())
+        with spans.phase("solver/jit"):
+            return jax.jit(self._iteration_fn(),
+                           donate_argnums=self._train_donate_argnums())
 
     def _build_multi_step(self):
         """K-step fused training program: ONE jitted `lax.scan` runs K
@@ -992,7 +1002,10 @@ class Solver:
         per-iteration losses and learning rates back as [K] device
         arrays — the whole-loop-on-TPU strategy (arXiv:1810.09868) in
         place of the reference's overlap-by-threads (parallel.cpp)."""
-        body = self._iteration_fn()
+        with spans.phase("solver/jit"):
+            return self._jit_multi_step(self._iteration_fn())
+
+    def _jit_multi_step(self, body):
 
         if self._guard_on:
             # guard mode: the 5-scalar guard state rides in the scan
@@ -2485,6 +2498,11 @@ class Solver:
         are loaded; corruption raises SnapshotCorruptError (use
         restore_auto for the fall-back-to-older behavior). Manifest-less
         snapshots load unverified, as before."""
+        with spans.phase("solver/restore", source=os.path.basename(
+                path.rstrip("/"))):
+            self._restore(path, verify)
+
+    def _restore(self, path: str, verify: bool) -> None:
         if verify:
             # .orbax dirs share the manifest scheme since ISSUE 11
             # (per-shard crc entries) — verify them the same way
@@ -2561,10 +2579,11 @@ class Solver:
     def load_weights(self, path: str) -> None:
         """Finetune-style weight load (reference `caffe train -weights`)."""
         from .. import io as caffe_io
-        weights = caffe_io.load_weights(path)
-        self.params, self.net_state = self.net.import_weights(
-            self.params, self.net_state, weights)
-        if self.mesh is not None:
-            self.net_state = self.mesh.replicate(self.net_state)
-        self._place_params_opt()
+        with spans.phase("solver/restore", source=os.path.basename(path)):
+            weights = caffe_io.load_weights(path)
+            self.params, self.net_state = self.net.import_weights(
+                self.params, self.net_state, weights)
+            if self.mesh is not None:
+                self.net_state = self.mesh.replicate(self.net_state)
+            self._place_params_opt()
         log.info("Loaded weights from %s (%d layers)", path, len(weights))

@@ -16,6 +16,7 @@ Usage (gflags-compatible single-dash long flags accepted):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import logging
 import os
 import signal
@@ -655,6 +656,44 @@ def _cluster_exit(prefix: str, rank: int, reason: str, error: str) -> int:
     return resilience.EXIT_CLUSTER
 
 
+def _train_feeds(solver, sp, synthetic: bool):
+    """(train feed, test feeds or None) of `cmd_train`; the train feed is
+    None for an Input net without -synthetic."""
+    # multi-host: each process reads its stripe of the global batch
+    # (reference CursorManager record striping, data_reader.hpp:28-53)
+    import jax as _jax
+    feed_fn = _build_feeders(solver.net, "TRAIN",
+                             rank=_jax.process_index(),
+                             world=_jax.process_count(),
+                             solver_param=sp)
+    if feed_fn is None:
+        if not synthetic:
+            return None, None
+        feeds = _synthetic_feed(solver.net)
+        feed_fn = lambda it: feeds
+
+    test_feed_fns = None
+    if solver.test_nets:
+        tf = []
+        for tnet in solver.test_nets:
+            # TEST feeders stripe per host exactly like TRAIN: the
+            # eval path assembles each host's batch as a process-local
+            # SHARD of the global test batch (shard_feeds), so
+            # unstriped feeders would evaluate duplicate copies of
+            # stripe 0 and never see the other hosts' records
+            f = _build_feeders(tnet, "TEST",
+                               rank=_jax.process_index(),
+                               world=_jax.process_count(),
+                               solver_param=sp)
+            if f is None:
+                feeds_t = _synthetic_feed(tnet, seed=1)
+                tf.append(lambda it, feeds_t=feeds_t: feeds_t)
+            else:
+                tf.append(f)
+        test_feed_fns = tf
+    return feed_fn, test_feed_fns
+
+
 def cmd_train(args) -> int:
     from ..proto import SolverParameter
     from ..utils import resilience
@@ -670,6 +709,7 @@ def cmd_train(args) -> int:
         return _supervised_train(args)
     from ..data.feeder import data_shape_probe
     from ..solver import Solver
+    from ..utils import spans
     from ..utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     sp = SolverParameter.from_file(args.solver)
@@ -876,42 +916,12 @@ def cmd_train(args) -> int:
     if hasattr(signal, "SIGHUP"):
         signal.signal(signal.SIGHUP, on_signal(args.sighup_effect))
 
-    # multi-host: each process reads its stripe of the global batch
-    # (reference CursorManager record striping, data_reader.hpp:28-53)
-    import jax as _jax
-    feeder = _build_feeders(solver.net, "TRAIN",
-                            rank=_jax.process_index(),
-                            world=_jax.process_count(),
-                            solver_param=sp)
-    if feeder is None:
-        if not args.synthetic:
-            log.error("net has no Data layer; pass -synthetic to train on "
-                      "random data or use a Data/ImageData net")
-            return 1
-        feeds = _synthetic_feed(solver.net)
-        feed_fn = lambda it: feeds
-    else:
-        feed_fn = feeder
-
-    test_feed_fns = None
-    if solver.test_nets:
-        tf = []
-        for tnet in solver.test_nets:
-            # TEST feeders stripe per host exactly like TRAIN: the
-            # eval path assembles each host's batch as a process-local
-            # SHARD of the global test batch (shard_feeds), so
-            # unstriped feeders would evaluate duplicate copies of
-            # stripe 0 and never see the other hosts' records
-            f = _build_feeders(tnet, "TEST",
-                               rank=_jax.process_index(),
-                               world=_jax.process_count(),
-                               solver_param=sp)
-            if f is None:
-                feeds_t = _synthetic_feed(tnet, seed=1)
-                tf.append(lambda it, feeds_t=feeds_t: feeds_t)
-            else:
-                tf.append(f)
-        test_feed_fns = tf
+    with spans.phase("cli/feeders"):
+        feed_fn, test_feed_fns = _train_feeds(solver, sp, args.synthetic)
+    if feed_fn is None:
+        log.error("net has no Data layer; pass -synthetic to train on "
+                  "random data or use a Data/ImageData net")
+        return 1
 
     # bind the quarantine journal next to the snapshots: corrupt
     # records the feeder substitutes during this run are audited in
@@ -931,10 +941,17 @@ def cmd_train(args) -> int:
     t0 = time.time()
     start_iter = solver.iter
     profile = _ProfileSlice(args.profile, solver, sp)
+    # the first chunk traces, lowers and builds the step: the last phase
+    # of start-up, after which the ledger's table is logged, once
+    first = spans.phase("cli/first step")
     try:
         while solver.iter < sp.max_iter and not state["stop"]:
             chunk = profile.clip(min(100, sp.max_iter - solver.iter))
-            solver.step(chunk, feed_fn, test_feed_fns)
+            with first or contextlib.nullcontext():
+                solver.step(chunk, feed_fn, test_feed_fns)
+            if first is not None:
+                first = None
+                log.info("%s", spans.ledger.table())
             if state["snap"]:
                 state["snap"] = False
                 solver.snapshot()

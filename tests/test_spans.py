@@ -109,8 +109,9 @@ def test_no_layer_scope_parses_to_none(op_name):
 
 
 def test_every_name_in_the_source_is_in_the_table():
-    """utils/spans.py is the single spelling: each label a `_guard(...)` or
-    `spans.span(...)` call site uses is a row of its table."""
+    """utils/spans.py is the single spelling: each label a `_guard(...)`,
+    `spans.span(...)` or `spans.phase(...)` call site uses is a row of its
+    table."""
     text = open(os.path.join(ROOT, "caffe_mpi_tpu", "solver",
                              "solver.py")).read()
     labels = set(re.findall(r'_guard\("([^"]+)"\)', text))
@@ -121,6 +122,20 @@ def test_every_name_in_the_source_is_in_the_table():
         assert f"| `caffe/solver/{label}` |" in spans.__doc__, label
     for name in (spans.UPDATE, spans.REDUCE, "caffe/" + spans.ITER):
         assert f"| `{name}` |" in spans.__doc__
+    # the start-up ledger's phases, wherever they are opened
+    phases = set()
+    for sub in ("net.py", "solver/solver.py", "tools/cli.py",
+                "proto/config.py", "ops/pallas_call.py", "ops/moe.py"):
+        text = open(os.path.join(ROOT, "caffe_mpi_tpu", sub)).read()
+        phases |= set(re.findall(r'spans\.phase\(\s*"([^"]+)"', text))
+        if "spans.phase(spans.KERNEL" in text:
+            phases.add(spans.KERNEL)
+    assert phases == {"parse", "net/build", "net/fill", "solver/build",
+                      "solver/opt state", "solver/place", "solver/restore",
+                      "solver/jit", "cli/feeders", "cli/first step",
+                      "trace/kernel"}
+    for name in phases:
+        assert f"| `caffe/{name}` | phase |" in spans.__doc__, name
 
 
 # -- device scopes in the compiled step --------------------------------------

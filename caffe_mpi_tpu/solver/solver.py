@@ -612,20 +612,32 @@ class Solver:
         reduction.collective_stats (per-step collective counts) and
         for chip_smoke.py's Mosaic/all-reduce checks. Compiles but never
         executes; per-call cost is one XLA compile."""
-        iter_size = max(self.sp.iter_size, 1)
-        feeds_stack = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                jnp.asarray(x)[None],
-                (iter_size,) + jnp.shape(jnp.asarray(x))), feeds)
-        if self.mesh is not None:
-            feeds_stack = self.mesh.shard_feeds(feeds_stack, batch_axis=1)
-        args = [self.params, self.net_state, self.opt_state, feeds_stack,
-                jnp.int32(self.iter), self.base_rng]
+        args = [self.params, self.net_state, self.opt_state,
+                self._place_feeds([feeds] * max(self.sp.iter_size, 1)),
+                np.int32(self.iter), self.base_rng]
         if self._guard_on:
             if self._gstate is None:
                 self._gstate = self._guard_state0()
             args.append(self._gstate)
         return self._build_step().lower(*args).compile().as_text()
+
+    def _place_feeds(self, micro_feeds: list):
+        """The plain step's feed argument from the iteration's iter_size
+        feed trees. At iter_size 1 the tree goes in as `feed_fn` returned
+        it, leaves (B, ...): `jnp.asarray` is the transfer of a host
+        array and hands a device array back untouched, so nothing is
+        launched on a batch already on the device. Above 1 the trees are
+        stacked on a leading axis for the step's scan."""
+        if len(micro_feeds) == 1:
+            feeds, batch_axis = jax.tree.map(jnp.asarray, micro_feeds[0]), 0
+        else:
+            feeds, batch_axis = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                             *micro_feeds), 1
+        if self.mesh is not None:
+            # global batch sharded over the 'data' mesh axis
+            # (divide_batch_size semantics, parallel.cpp:295-348)
+            feeds = self.mesh.shard_feeds(feeds, batch_axis=batch_axis)
+        return feeds
 
     # ------------------------------------------------------------------
     def _init_opt_state(self):
@@ -638,16 +650,26 @@ class Solver:
         return opt
 
     # ------------------------------------------------------------------
-    def _iteration_fn(self):
+    def _iteration_fn(self, plain: bool = False):
         """The pure single-iteration training body
             (params, net_state, opt_state, feeds_stack, it, rng)
               -> (params, net_state, opt_state, loss, rate)
-        traced in BOTH entry points: jitted directly for the classic
+        traced in BOTH entry points: as the whole program of the classic
         one-dispatch-per-iteration path (_build_step) and as the
         `lax.scan` body of the K-step fused program (_build_multi_step).
         One definition means the two modes are numerically the same
         computation — the equivalence suite (tests/test_multistep.py)
         holds them to f32 tolerance.
+
+        The default form is the scan's: `feeds_stack` leaves are
+        [iter_size, B, ...] and `rng` is the iteration's key. With
+        `plain` it is everything an iteration needs besides the feed, so
+        the host launches that one program and nothing else: `rng` is
+        the solver's base key, folded with `it + 1` inside exactly as
+        the scan body folds it, and at iter_size 1 the feeds are what
+        `feed_fn` returned, leaves (B, ...). The leading axis is added
+        only when iter_size > 1, where a scan runs over it: on a device
+        array `x[None]` is no view but a program that copies the batch.
 
         With `train_guard` on (ISSUE 4) the signature grows a trailing
         guard-carry dict and return: after the update is computed, an
@@ -712,7 +734,7 @@ class Solver:
                     local_loss_fn, mesh, reduction_plan)
             return jax.value_and_grad(loss_fn, has_aux=True)
 
-        def step(params, net_state, opt_state, feeds_stack, it, rng,
+        def body(params, net_state, opt_state, feeds, it, rng,
                  gstate=None):
             net_state0 = net_state
             # dynamic loss scaling: the scale is part of the guard carry
@@ -721,8 +743,8 @@ class Solver:
             # discards an overflowed step and backs the scale off
             eff_scale = grad_scale * gstate["scale"] if dyn else grad_scale
             value_and_grad = make_value_and_grad(eff_scale)
-            # iter_size accumulation: feeds_stack pytree has leading
-            # iter_size dim on every leaf (solver.cpp:277-288)
+            # iter_size accumulation: above 1 the feeds pytree has a
+            # leading iter_size dim on every leaf (solver.cpp:277-288)
             def micro(carry, feeds_rng):
                 acc, net_state = carry
                 feeds, mrng = feeds_rng
@@ -736,14 +758,13 @@ class Solver:
                                   params)
             rngs = jax.random.split(rng, iter_size)
             if iter_size == 1:
-                feeds = jax.tree.map(lambda x: x[0], feeds_stack)
                 (_, (net_state, loss)), grads = value_and_grad(
                     params, net_state, feeds, rngs[0])
                 total_loss = loss
             else:
                 ((grads, total_loss), net_state), _ = jax.lax.scan(
                     micro, ((zero_g, jnp.float32(0.0)), net_state),
-                    (feeds_stack, rngs))
+                    (feeds, rngs))
             with jax.named_scope(spans.UPDATE):
                 # normalize: 1/(iter_size * loss scale) (SGDSolver::Normalize
                 # + net.cpp:815-818 loss-scale unwind) — the unwind happens
@@ -960,6 +981,21 @@ class Solver:
             return (new_params, net_state, new_opt, loss_out, rate,
                     new_gstate)
 
+        if plain:
+            def step(params, net_state, opt_state, feeds, it, base_rng,
+                     gstate=None):
+                rng = jax.random.fold_in(base_rng, it + 1)
+                return body(params, net_state, opt_state, feeds, it, rng,
+                            gstate)
+        elif iter_size == 1:
+            def step(params, net_state, opt_state, feeds_stack, it, rng,
+                     gstate=None):
+                # the scan's slice of [K, 1, B, ...] keeps its unit axis
+                feeds = jax.tree.map(lambda x: x[0], feeds_stack)
+                return body(params, net_state, opt_state, feeds, it, rng,
+                            gstate)
+        else:
+            step = body
         return step
 
     def _train_donate_argnums(self) -> tuple[int, ...]:
@@ -987,7 +1023,7 @@ class Solver:
         # divergence check reads the previous dispatch's gstate after
         # the next one launches, so its buffer must stay valid
         with spans.phase("solver/jit"):
-            return jax.jit(self._iteration_fn(),
+            return jax.jit(self._iteration_fn(plain=True),
                            donate_argnums=self._train_donate_argnums())
 
     def _build_multi_step(self):
@@ -997,8 +1033,8 @@ class Solver:
         [K, iter_size, B, ...]. Params/optimizer/net state are donated
         into the program and carried through the scan entirely in HBM;
         per-iteration RNG keys fold_in from the carried iteration counter
-        exactly like the host does at K=1. The host pays one dispatch
-        per K iterations, and gets the
+        exactly like the plain step's program at K=1. The host pays one
+        dispatch per K iterations, and gets the
         per-iteration losses and learning rates back as [K] device
         arrays — the whole-loop-on-TPU strategy (arXiv:1810.09868) in
         place of the reference's overlap-by-threads (parallel.cpp)."""
@@ -1557,42 +1593,28 @@ class Solver:
                         # dispatch (the fused path guards queue.get the same
                         # way)
                         with self._guard("feed wait"):
-                            micro_feeds = [feed_fn(self.iter * iter_size + k)
-                                           for k in range(iter_size)]
-                            if iter_size == 1:
-                                # view, not copy: the common path skips the
-                                # host-side stack
-                                feeds_stack = jax.tree.map(
-                                    lambda x: jnp.asarray(x)[None],
-                                    micro_feeds[0])
-                            else:
-                                feeds_stack = jax.tree.map(
-                                    lambda *xs: jnp.stack(xs), *micro_feeds)
-                            if self.mesh is not None:
-                                # global batch sharded over the 'data' mesh
-                                # axis (divide_batch_size semantics,
-                                # parallel.cpp:295-348)
-                                feeds_stack = self.mesh.shard_feeds(
-                                    feeds_stack, batch_axis=1)
+                            feeds = self._place_feeds(
+                                [feed_fn(self.iter * iter_size + k)
+                                 for k in range(iter_size)])
                         with self._guard("train dispatch"):
-                            # the step's scalar arguments are device
-                            # programs of their own (`fold_in`, a cast):
-                            # launches like the step's, in its section
-                            rng = jax.random.fold_in(self.base_rng,
-                                                     self.iter + 1)
-                            it = jnp.int32(self.iter)
+                            # the one launch of the iteration: its key is
+                            # folded inside the program, and the counter
+                            # goes in as a host scalar (4 bytes moved, no
+                            # program of its own)
+                            it = np.int32(self.iter)
                             FAULTS.maybe_stall("dispatch_stall")
                             if self._guard_on:
                                 (self.params, self.net_state, self.opt_state,
                                  loss, rate, self._gstate) = self._step_jit(
                                     self.params, self.net_state,
-                                    self.opt_state, feeds_stack, it, rng,
-                                    self._gstate)
+                                    self.opt_state, feeds, it,
+                                    self.base_rng, self._gstate)
                             else:
                                 (self.params, self.net_state, self.opt_state,
                                  loss, rate) = self._step_jit(
                                     self.params, self.net_state,
-                                    self.opt_state, feeds_stack, it, rng)
+                                    self.opt_state, feeds, it,
+                                    self.base_rng)
                         self.dispatch_count += 1
                 # feed any in-flight eval pass the chunks whose super-batches
                 # the worker finished while this train chunk dispatched —
@@ -1618,8 +1640,10 @@ class Solver:
                 if (sp.display and last_iter % sp.display == 0
                         and self.rank == 0):
                     with self._guard("display sync"):
+                        # one transfer of the window's scalars, summed on
+                        # the host: the read launches no program either
                         smoothed = float(sum(  # host-sync: ok (display)
-                            jnp.asarray(l) for l in self._loss_window)) / len(
+                            jax.device_get(list(self._loss_window)))) / len(
                                 self._loss_window)
                     self.host_sync_count += 1
                     elapsed = time.time() - t0

@@ -49,7 +49,7 @@ to tell them apart.
 | `solver.reduce` | scope | the bucketed gradient psums of `reduce_overlap` (parallel/reduction.py) |
 | `caffe/solver/iter` | step span | one pass of `Solver.step`'s loop (`step_num` = its first iteration) |
 | `caffe/solver/feed wait` | span | batch assembly, re-layout and host-to-device placement |
-| `caffe/solver/train dispatch` | span | launching the train program (one step, a fused chunk, a GPipe wavefront) and its scalar arguments (`fold_in`, the iteration's cast) |
+| `caffe/solver/train dispatch` | span | launching the train program: one step, and nothing else (its key is folded inside it); a fused chunk with the counter's cast; a GPipe wavefront with its `fold_in` |
 | `caffe/solver/step sync` | span | per-program sync of host-callback nets on the CPU backend |
 | `caffe/solver/display sync` | span | device-to-host read of the smoothed loss at a display boundary |
 | `caffe/solver/guard check` | span | read of the skip-step guard's counters |

@@ -273,17 +273,17 @@ class TestSmallThinkerCell:
         try:
             assert not solver._guard_on   # static loss scale: one state
             rep = SingleDeviceSharding(v5e_devices()[0])
-            feeds = {k: jax.ShapeDtypeStruct((1, *shape), jnp.int32,
+            feeds = {k: jax.ShapeDtypeStruct(shape, jnp.int32,
                                              sharding=rep)
                      for k, (shape, _) in solver.net.feed_specs.items()}
             assert {k: v.shape for k, v in feeds.items()} == {
-                "tokens": (1, 1, 8192), "label": (1, 1, 8192)}
+                "tokens": (1, 8192), "label": (1, 8192)}
             args = [abstract(solver.params, rep),
                     abstract(solver.net_state, rep),
                     abstract(solver.opt_state, rep), feeds,
                     abstract(jnp.int32(0), rep),
                     abstract(solver.base_rng, rep)]
-            compiled = (jax.jit(solver._iteration_fn(),
+            compiled = (jax.jit(solver._iteration_fn(plain=True),
                                 donate_argnums=(0, 1, 2))
                         .trace(*args).lower(lowering_platforms=("tpu",))
                         .compile())
@@ -354,17 +354,17 @@ class TestJoyAICell:
         try:
             assert not solver._guard_on   # static loss scale: one state
             rep = SingleDeviceSharding(v5e_devices()[0])
-            feeds = {k: jax.ShapeDtypeStruct((1, *shape), jnp.int32,
+            feeds = {k: jax.ShapeDtypeStruct(shape, jnp.int32,
                                              sharding=rep)
                      for k, (shape, _) in solver.net.feed_specs.items()}
             assert {k: v.shape for k, v in feeds.items()} == {
-                k: (1, 1, 8192) for k in ("tokens", "label", "label_mtp")}
+                k: (1, 8192) for k in ("tokens", "label", "label_mtp")}
             args = [abstract(solver.params, rep),
                     abstract(solver.net_state, rep),
                     abstract(solver.opt_state, rep), feeds,
                     abstract(jnp.int32(0), rep),
                     abstract(solver.base_rng, rep)]
-            compiled = (jax.jit(solver._iteration_fn(),
+            compiled = (jax.jit(solver._iteration_fn(plain=True),
                                 donate_argnums=(0, 1, 2))
                         .trace(*args).lower(lowering_platforms=("tpu",))
                         .compile())
@@ -435,17 +435,17 @@ class TestSdarCell:
         try:
             assert not solver._guard_on   # static loss scale: one state
             rep = SingleDeviceSharding(v5e_devices()[0])
-            feeds = {k: jax.ShapeDtypeStruct((1, *shape), jnp.int32,
+            feeds = {k: jax.ShapeDtypeStruct(shape, jnp.int32,
                                              sharding=rep)
                      for k, (shape, _) in solver.net.feed_specs.items()}
             assert {k: v.shape for k, v in feeds.items()} == {
-                "tokens": (1, 1, 8192)}
+                "tokens": (1, 8192)}
             args = [abstract(solver.params, rep),
                     abstract(solver.net_state, rep),
                     abstract(solver.opt_state, rep), feeds,
                     abstract(jnp.int32(0), rep),
                     abstract(solver.base_rng, rep)]
-            compiled = (jax.jit(solver._iteration_fn(),
+            compiled = (jax.jit(solver._iteration_fn(plain=True),
                                 donate_argnums=(0, 1, 2))
                         .trace(*args).lower(lowering_platforms=("tpu",))
                         .compile())
@@ -606,18 +606,17 @@ def train_step_text(net_text: str, precision: str, n_data: int) -> str:
     plan = MeshPlan(mesh=tpu_mesh(n_data, 1))
     solver.net.bind_mesh(plan)  # layers specialize on the TPU mesh
     feeds = synthetic_feeds(input_shapes(npar), npar=npar)
-    feeds_stack = jax.tree.map(lambda x: jnp.asarray(x)[None], feeds)
     rep = plan.replicated()
     args = [abstract(solver.params, rep), abstract(solver.net_state, rep),
             abstract(solver.opt_state, rep),
             jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=plan.batch_sharded(x.ndim, 1)),
-                feeds_stack),
+                x.shape, x.dtype, sharding=plan.batch_sharded(x.ndim, 0)),
+                jax.tree.map(jnp.asarray, feeds)),
             abstract(jnp.int32(0), rep), abstract(solver.base_rng, rep)]
     if solver._guard_on:  # bf16's dynamic loss scale rides the guard carry
         args.append(abstract(solver._guard_state0(), rep))
     try:
-        return compile_tpu(solver._iteration_fn(), *args)
+        return compile_tpu(solver._iteration_fn(plain=True), *args)
     finally:
         solver.close()
 

@@ -69,8 +69,11 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "y" bottom: "t"
         top: "l" }
 """
 
-MAX_ITER = 4000
-SNAP_EVERY = 500  # first snapshot ~0.5 s in: well before the kill beat
+# Sized by the CPU's iteration rate, ~3,300 a second since the plain
+# step is the one program an iteration launches (750 before): the run
+# has to outlast the kill beat plus the deadline, ~3 s in.
+MAX_ITER = 16000
+SNAP_EVERY = 2000  # first snapshot ~0.6 s in: well before the kill beat
 # The deadline MUST undercut the killed worker's restart latency
 # (supervisor backoff 1 s + interpreter/jax start ~1.3 s): the survivor
 # has to detect the silence and exit 87 BEFORE the dead host's
@@ -89,7 +92,7 @@ PERMA_DARK_S = 5.0
 # --degrade trains longer: the degraded generation must still be
 # mid-run (with snapshot boundaries ahead) when host 1 revives, or
 # there is no grow-back to observe.
-DEGRADE_MAX_ITER = 8000
+DEGRADE_MAX_ITER = 32000
 
 
 def free_port() -> int:

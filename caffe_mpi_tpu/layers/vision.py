@@ -203,14 +203,14 @@ class LRNLayer(Layer):
         # direction) whenever the layer COMPUTES in bf16 — keyed on the
         # input dtype, so both the `precision: bf16` solver knob and the
         # pre-existing FLOAT16 prototxt variants (solver_fp16 recipes)
-        # take the kernels: any bf16 LRN is the same bandwidth-bound
-        # layer, and neither bf16 spelling ever had a bitwise contract
-        # (in-kernel math is f32, so the kernels are if anything closer
-        # to the f32 reference than the lax-bf16 lowering they replace).
-        # The f32 default keeps the stock lax path below, bitwise.
-        # CAFFE_LRN_PALLAS=0 restores the old lax lowering for any
-        # dtype; =1 forces the kernels for any float dtype (chip_smoke.py
-        # and benchmarks/run.py refuse to run with it set).
+        # take the kernels (in-kernel math is f32). Float32 takes the lax
+        # path of ops/lrn_lax.py: the same mathematics, no Pallas import.
+        # Not bitwise the pad / shifted-add / jnp.power expression that
+        # stood here before PR 39: a reassociated window sum and one
+        # exp/log pair for `power`, a few ulp; tests/test_layers.py holds
+        # it to 1e-5 relative of that expression, forward and gradient.
+        # CAFFE_LRN_PALLAS=0 sends any dtype down the lax path; =1 forces
+        # the kernels (chip_smoke.py and the benchmark refuse to run so).
         knob = os.environ.get("CAFFE_LRN_PALLAS", "")
         use_pallas = (self.region != "WITHIN_CHANNEL" and x.ndim == 4
                       and knob != "0"
@@ -226,34 +226,19 @@ class LRNLayer(Layer):
                 # partitioned by hand; LRN is per-sample
                 return [self.mesh_plan.per_batch_shard(kernel, x)], state
             return [kernel(x)], state
-        sq = jnp.square(x)
+        if self.region != "WITHIN_CHANNEL":
+            from ..ops.lrn_lax import lrn_across_channels
+            return [lrn_across_channels(x, p.local_size, p.alpha, p.beta,
+                                        p.k)], state
+        # spatial window, divisor is the full window size (lrn pads with 0)
         half = (p.local_size - 1) // 2
-        if self.region == "WITHIN_CHANNEL":
-            # spatial window, divisor is the full window size (lrn pads with 0)
-            window_sum = lax.reduce_window(
-                sq, np.zeros((), np.dtype(x.dtype))[()], lax.add,
-                window_dimensions=(1, 1, p.local_size, p.local_size),
-                window_strides=(1, 1, 1, 1),
-                padding=((0, 0), (0, 0), (half, half), (half, half)),
-            )
-            scale = p.k + window_sum * (p.alpha / (p.local_size * p.local_size))
-        else:
-            # across channels: 1-D window over C, as local_size shifted
-            # adds over a zero-padded copy (the same sum ops/lrn.py's
-            # kernels take). NOT a padded lax.reduce_window over the
-            # channel axis: XLA:TPU (libtpu 0.0.34) refuses the AlexNet
-            # deploy net at batch 1 and 4 with it — "INVALID_ARGUMENT:
-            # during context [post-optimization]: Binary op with
-            # incompatible shapes: f32[55,8,8,96] and f32[55,8,8,92]" —
-            # the window's padding is lost somewhere after it fuses
-            # behind conv1 (batch 10 and 256 compile; an explicit
-            # jnp.pad + VALID window is folded back and fails alike).
-            padded = jnp.pad(sq, ((0, 0), (half, half), (0, 0), (0, 0)))
-            c = x.shape[1]
-            window_sum = padded[:, 0:c]
-            for off in range(1, p.local_size):
-                window_sum = window_sum + padded[:, off:off + c]
-            scale = p.k + window_sum * (p.alpha / p.local_size)
+        window_sum = lax.reduce_window(
+            jnp.square(x), np.zeros((), np.dtype(x.dtype))[()], lax.add,
+            window_dimensions=(1, 1, p.local_size, p.local_size),
+            window_strides=(1, 1, 1, 1),
+            padding=((0, 0), (0, 0), (half, half), (half, half)),
+        )
+        scale = p.k + window_sum * (p.alpha / (p.local_size * p.local_size))
         return [x * jnp.power(scale, -p.beta)], state
 
 

@@ -1,14 +1,13 @@
 """Pallas LRN kernels (TPU): across-channels forward + backward.
 
-Replaces the lax path of `layers/vision.py LRNLayer` (reference
+Takes the place of the lax path (`ops/lrn_lax.py`; reference
 src/caffe/layers/lrn_layer.cpp + lrn_layer.cu: LRNFillScale /
-LRNComputeOutput / LRNComputeDiff) for the bf16 roofline offender case
-(ISSUE 9). LRN is pure bandwidth: ~zero MACs over N*C*H*W elements,
-and the stock lowering (reduce_window for the channel-window sum, a
-power, and reverse-mode AD re-materializing the scale) makes several
-full HBM passes over the activation per direction
-(`benchmarks/layer_metrics/lrn_ms_per_step.py` and `pallas_ms_per_step.py`
-read what it costs in the AlexNet cells; PERF.md section 5).
+LRNComputeOutput / LRNComputeDiff) where the layer computes in bf16
+(ISSUE 9). LRN is pure bandwidth: ~zero MACs over N*C*H*W elements, and
+a bf16 operand halves the bytes, so what XLA spends around them weighs
+twice (`benchmarks/layer_metrics/lrn_ms_per_step.py` and
+`pallas_ms_per_step.py` read what the layer costs in the AlexNet cells;
+PERF.md section 5).
 
 Each direction is one kernel that reads its operands once and writes
 its result once: forward reads x and writes y; backward reads x and dy,
@@ -52,10 +51,11 @@ at 455-515 GB/s, bound by its ~45 f32 VPU operations an element, not by
 HBM.
 
 Math is f32 in-kernel regardless of the I/O dtype (bf16 under
-`precision: bf16`); outputs cast back at the tile edge. The jnp path in
-vision.py remains the numerical reference and the f32 default. On the
-`cpu` platform the same kernels run in Pallas interpreter mode (the
-CPU test suite); every other platform compiles them through Mosaic or
+`precision: bf16`); outputs cast back at the tile edge. Float32 operands
+stay on `ops/lrn_lax.py`: the same two lines in jnp, the window a product
+with the 0/1 band, no Pallas import (PERF.md section 6, PR 39). On the
+`cpu` platform the same kernels run in Pallas interpreter mode (the CPU
+test suite); every other platform compiles them through Mosaic or
 fails — ops/pallas_call.py owns that choice."""
 
 from __future__ import annotations

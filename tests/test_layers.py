@@ -251,6 +251,52 @@ class TestLRN:
         )
         check_gradients(layer, params, state, [rand((1, 4, 3, 3), rng)])
 
+    @staticmethod
+    def _oracle(x, size, alpha, beta, k=1.0):
+        """The expression LRNLayer.apply held before PR 39: pad, `size`
+        shifted adds, jnp.power; its gradient is reverse-mode AD's."""
+        half = (size - 1) // 2
+        padded = jnp.pad(jnp.square(x), ((0, 0), (half, half), (0, 0), (0, 0)))
+        c = x.shape[1]
+        window_sum = padded[:, 0:c]
+        for off in range(1, size):
+            window_sum = window_sum + padded[:, off:off + c]
+        return x * jnp.power(k + window_sum * (alpha / size), -beta)
+
+    # C of 3 is smaller than the window, 100 no sublane multiple; alpha
+    # 0.1 puts the scale far from k. Relative 1e-5; the absolute 1e-6 is
+    # for gradient elements whose two terms cancel (values are of order 1)
+    @pytest.mark.parametrize("beta", [0.75, 0.5])
+    @pytest.mark.parametrize("alpha", [1e-4, 0.1])
+    @pytest.mark.parametrize("batch", [1, 4, 10, 128])
+    @pytest.mark.parametrize("channels", [3, 6, 96, 100])
+    @pytest.mark.parametrize("size", [3, 5])
+    def test_lax_path_against_the_shifted_adds(self, size, channels, batch,
+                                               alpha, beta):
+        from caffe_mpi_tpu.ops.lrn_lax import lrn_across_channels
+        rng = np.random.RandomState(size * 1000 + channels * 10 + batch)
+        x = rand((batch, channels, 2, 3), rng, scale=3.0)
+        dy = rand((batch, channels, 2, 3), rng)
+        y, vjp = jax.vjp(
+            lambda x: lrn_across_channels(x, size, alpha, beta, 1.0), x)
+        ref, ref_vjp = jax.vjp(
+            lambda x: self._oracle(x, size, alpha, beta), x)
+        assert y.dtype == jnp.float32
+        np.testing.assert_allclose(np.array(y), np.array(ref), rtol=1e-5,
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.array(vjp(dy)[0]),
+                                   np.array(ref_vjp(dy)[0]), rtol=1e-5,
+                                   atol=1e-6)
+
+    @pytest.mark.parametrize("size,channels", [(3, 3), (5, 8)])
+    def test_lax_path_custom_vjp_against_finite_differences(self, size,
+                                                            channels, rng):
+        from jax.test_util import check_grads
+        from caffe_mpi_tpu.ops.lrn_lax import lrn_across_channels
+        x = rand((2, channels, 2, 2), rng)
+        check_grads(lambda x: lrn_across_channels(x, size, 0.1, 0.75, 1.0),
+                    (x,), order=1, modes=("rev",), atol=2e-2, rtol=2e-2)
+
 
 class TestInnerProduct:
     def test_forward_and_transpose(self, rng):

@@ -266,6 +266,10 @@ def solver_file(tmp_path):
 def test_a_solver_build_leaves_its_phases_in_order(ledgers, solver_file):
     from caffe_mpi_tpu.proto import SolverParameter
     ledger, programs = ledgers
+    # the fillers' programs must be built here, not found in this
+    # process's jit cache: another test file on the same xdist worker
+    # (test_spans.py's toy net has the same shapes) may have built them
+    jax.clear_caches()
     sp = SolverParameter.from_file(solver_file)
     solver = Solver(sp)
     solver.close()

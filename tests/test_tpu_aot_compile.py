@@ -534,6 +534,80 @@ layer { name: "pool1" type: "Pooling" bottom: "norm1" top: "pool1"
 """
 
 
+_ALEXNET_NORM2 = """
+name: "alexnet_norm2"
+layer { name: "data" type: "Input" top: "pool1"
+        input_param { shape { dim: %d dim: 96 dim: 27 dim: 27 } } }
+layer { name: "conv2" type: "Convolution" bottom: "pool1" top: "conv2"
+        convolution_param { num_output: 256 pad: 2 kernel_size: 5 group: 2
+                            weight_filler { type: "gaussian" std: 0.01 } } }
+layer { name: "relu2" type: "ReLU" bottom: "conv2" top: "conv2" }
+layer { name: "norm2" type: "LRN" bottom: "conv2" top: "norm2"
+        lrn_param { local_size: 5 alpha: 0.0001 beta: 0.75 } }
+layer { name: "pool2" type: "Pooling" bottom: "norm2" top: "pool2"
+        pooling_param { pool: MAX kernel_size: 3 stride: 2 } }
+"""
+
+
+class TestLaxLRN:
+    """ops/lrn_lax.py, the f32 across-channels LRN: conv -> relu -> LRN
+    -> max-pool and its gradient at AlexNet's two shapes, batch 1024 (the
+    benchmark's `alexnet_f32` cells). A count, never a time: the times
+    are PERF.md section 6's (PR 39)."""
+
+    # scoped_ops: device operations of the entry computation filed under
+    # the layer's scope by the form that shipped (PR 39): the forward's
+    # fusion and the backward's window product (dx rides in the
+    # convolution's backward fusions); the form before it had three
+    @pytest.mark.parametrize("net_text,feed,in_shape,top,scoped_ops", [
+        (_ALEXNET_HEAD, "data", (1024, 3, 227, 227), "pool1", 2),
+        (_ALEXNET_NORM2, "pool1", (1024, 96, 27, 27), "pool2", 2),
+    ], ids=["norm1", "norm2"])
+    def test_f32_fwd_bwd_is_fusions_around_a_product(self, net_text, feed,
+                                                     in_shape, top,
+                                                     scoped_ops):
+        from caffe_mpi_tpu.net import Net
+        from caffe_mpi_tpu.proto import NetParameter
+        net = Net(NetParameter.from_text(net_text % 1024), phase="TRAIN")
+        params, state = net.init(jax.random.PRNGKey(0))
+
+        def grads(p, s, x):
+            return jax.grad(lambda p, x: jnp.sum(net.apply(
+                p, s, {feed: x}, train=True,
+                rng=jax.random.PRNGKey(0))[0][top] ** 2), (0, 1))(p, x)
+        sh = SingleDeviceSharding(v5e_devices()[0])
+        text = compile_tpu(grads, abstract(params, sh), abstract(state, sh),
+                           on_chip(in_shape, jnp.float32))
+        assert mosaic_calls(text) == 0
+        scoped = [line for line in text.splitlines() if "caffe.LRN." in line]
+        assert scoped
+        assert not [line for line in scoped if " power(" in line]
+        entry = text[text.index("\nENTRY "):].splitlines()
+        filed = [line.split("=")[0].strip() for line in entry
+                 if "caffe.LRN." in line
+                 and not re.search(r" (get-tuple-element|bitcast)\(", line)]
+        assert len(filed) <= scoped_ops, filed
+
+    def test_conv_lrn_weight_gradient_compiles_at_an_odd_batch(self):
+        """conv1 -> relu -> LRN and conv1's weight gradient at batch 4,
+        where the band product spelled as an einsum (a dot_general) kept
+        XLA:TPU compiling for 210 s (3, 5, 6, 10, 12: 94-207 s; PERF.md
+        section 6, PR 39). As a 1x1 convolution it is seconds; no time is
+        asserted here, only the spelling and that it compiles."""
+        from caffe_mpi_tpu.ops.lrn_lax import lrn_across_channels
+        lrn = lambda x: lrn_across_channels(x, 5, 1e-4, 0.75, 1.0)
+        lowered = jax.jit(lrn).lower(
+            jax.ShapeDtypeStruct((4, 96, 55, 55), jnp.float32)).as_text()
+        assert "stablehlo.convolution" in lowered
+        assert "dot_general" not in lowered
+
+        def dw(w, x):
+            y = jax.lax.conv_general_dilated(x, w, (4, 4), "VALID")
+            return jnp.sum(lrn(jnp.maximum(y, 0.0)) ** 2)
+        compile_tpu(jax.grad(dw), on_chip((96, 3, 11, 11), jnp.float32),
+                    on_chip((4, 3, 227, 227), jnp.float32))
+
+
 class TestServingBuckets:
     """The f32 deploy path holds no Pallas call, and still met a
     compiler refusal: with the across-channels LRN written as a padded
@@ -543,7 +617,7 @@ class TestServingBuckets:
     step compiled). chip_smoke.py's serve leg found it on the chip; this
     is its deviceless regression."""
 
-    @pytest.mark.parametrize("batch", [1, 4])
+    @pytest.mark.parametrize("batch", [1, 4, 10, 256])
     def test_alexnet_head_compiles_at_small_batches(self, batch):
         from caffe_mpi_tpu.net import Net
         from caffe_mpi_tpu.proto import NetParameter
@@ -633,6 +707,12 @@ class TestDataParallelStepWithMosaicKernels:
         text = train_step_text(_CONV_LRN_NET, "bf16", 4)
         assert mosaic_calls(text) >= 2      # LRN forward + backward
         assert "all-reduce" in text         # gradient mean over 'data'
+
+    def test_f32_conv_lrn_step_on_four_chips_is_plain_xla(self):
+        # ops/lrn_lax.py is per-sample jnp: GSPMD partitions it by itself
+        text = train_step_text(_CONV_LRN_NET, "f32", 4)
+        assert mosaic_calls(text) == 0
+        assert "all-reduce" in text
 
     def test_bf16_conv_lrn_step_on_one_chip(self):
         assert mosaic_calls(train_step_text(_CONV_LRN_NET, "bf16", 1)) >= 2

@@ -77,6 +77,18 @@ def fill(filler: FillerParameter | None, key: jax.Array, shape: tuple[int, ...],
             mask = jax.random.bernoulli(jax.random.fold_in(key, 1), prob, shape)
             out = jnp.where(mask, out, 0.0)
         return out.astype(dtype)
+    if ftype == "log_arange":
+        # ln(i + 1) along the last axis (a state-space mixer's A_log)
+        return jnp.broadcast_to(jnp.log(jnp.arange(
+            1, shape[-1] + 1, dtype=jnp.float32)), shape).astype(dtype)
+    if ftype == "softplus_inverse_log_uniform":
+        # v with softplus(v) log-uniform in [min, max], floored at `value`
+        # (a state-space mixer's dt_bias): v = t + ln(1 - exp(-t))
+        t = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                       math.log(filler.min),
+                                       math.log(filler.max)))
+        t = jnp.maximum(t, filler.value)
+        return (t + jnp.log(-jnp.expm1(-t))).astype(dtype)
     if ftype == "xavier":
         scale = math.sqrt(3.0 / _scale_n(filler, shape))
         return jax.random.uniform(key, shape, jnp.float32, -scale,

@@ -1,4 +1,4 @@
-"""Sequence layers: Attention, MoE and the block-diffusion noise —
+"""Sequence layers: Attention, Mamba2, MoE and the block-diffusion noise —
 TPU-native layer types with NO
 reference analogue (SURVEY §5.7: the reference is a CNN-era framework
 with no attention op; §2.7: no MoE/EP). They make the framework's
@@ -489,19 +489,92 @@ class BlockDiffusionNoiseLayer(Layer):
         return list(tops[:len(self.lp.top)]), state
 
 
+@register("Mamba2")
+class Mamba2Layer(Layer):
+    """mamba2_param: a Mamba-2 mixer over (N, S, C) (proto/config.py
+    Mamba2Parameter has the equations; ops/ssd.py the chunked scan). Five
+    scopes: `ssm.project` the input product, `ssm.conv` the causal
+    convolution a channel with its SiLU (shifted sums, `shift_rows`),
+    `ssm.scan` softplus, decays and the recurrence, `ssm.gate` the gate
+    and the grouped norm, `ssm.out` the output product."""
+
+    def setup(self, in_shapes: list[Shape]) -> list[Shape]:
+        from ..proto.netshape import mamba2_problem, mamba2_widths
+        p = self.lp.mamba2_param
+        if len(in_shapes[0]) != 3:
+            raise ValueError(
+                f"Mamba2 expects (N, S, C) bottom, got {in_shapes[0]}")
+        problem = mamba2_problem(p, in_shapes[0][1])
+        if problem:
+            raise ValueError(f"mamba2_param: {problem}")
+        self.p = p
+        c = in_shapes[0][2]
+        self.inner, conv, wide = mamba2_widths(p)
+        filler = p.weight_filler or FillerParameter(type="xavier")
+        bound = p.conv_kernel ** -0.5
+        const = lambda v: FillerParameter(type="constant", value=v)
+        self.declare("in_weight", (wide, c), filler)
+        self.declare("conv_weight", (conv, p.conv_kernel), FillerParameter(
+            type="uniform", min=-bound, max=bound))
+        self.declare("conv_bias", (conv,), const(0.0))
+        self.declare("dt_bias", (p.num_heads,), FillerParameter(
+            type="softplus_inverse_log_uniform", min=p.dt_min, max=p.dt_max,
+            value=p.dt_floor))
+        self.declare("A_log", (p.num_heads,),
+                     FillerParameter(type="log_arange"))
+        self.declare("D", (p.num_heads,), const(1.0))
+        self.declare("norm_scale", (self.inner,), const(1.0))
+        self.declare("out_weight", (c, self.inner), filler)
+        return [in_shapes[0]]
+
+    def apply(self, params, state, bottoms, *, train, rng):
+        from ..ops.ssd import ssd
+        from ..utils.spans import (SSM_CONV, SSM_GATE, SSM_OUT, SSM_PROJECT,
+                                   SSM_SCAN)
+        p = self.p
+        u = self.f(bottoms[0])
+        n, s, _ = u.shape
+        w = lambda name: self.f(params[name])
+        inner, bc = self.inner, p.groups * p.state_size
+        with jax.named_scope(SSM_PROJECT):
+            zxbcdt = u @ w("in_weight").T
+            z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], -1)
+        with jax.named_scope(SSM_CONV):
+            taps = w("conv_weight")
+            xbc = jax.nn.silu(w("conv_bias") + sum(
+                taps[:, i] * shift_rows(xbc, 1, p.conv_kernel - 1 - i)
+                for i in range(p.conv_kernel)))
+            x, b, c = jnp.split(xbc, [inner, inner + bc], -1)
+        with jax.named_scope(SSM_SCAN):
+            # the vectors a head stay in the master type: the scan's decay
+            # arithmetic is float32 whatever the compute type
+            y = ssd(x.reshape(n, s, p.num_heads, p.head_dim), dt,
+                    params["A_log"],
+                    b.reshape(n, s, p.groups, p.state_size),
+                    c.reshape(n, s, p.groups, p.state_size),
+                    params["D"], params["dt_bias"], p.chunk)
+        with jax.named_scope(SSM_GATE):
+            y = y.reshape(n, s, inner) * jax.nn.silu(z)
+            y = rms_normalize(y.reshape(n, s, p.groups, -1), p.eps)
+            y = y.reshape(n, s, inner) * w("norm_scale")
+        with jax.named_scope(SSM_OUT):
+            return [y @ w("out_weight").T], state
+
+
 @register("MoE")
 class MoELayer(Layer):
     """moe_param. Two formulations (ops/moe.py): the capacity one (GShard
     dispatch/combine tensors, softmax then top-k, tokens past capacity
     dropped, two biased matrices an expert; second top = the auxiliary
     load-balancing loss) and, with `dropless: true`, rows sorted by expert
-    through grouped matrix products over gated experts of three unbiased
-    matrices (second top = the rows each held expert received). Of the
-    dropless path `scoring` (softmax over the top-k logits | sigmoid
-    scores chosen under the `select_bias` blob, renormalised, times
-    `routed_scaling_factor`), the gate's `activation` (relu | silu) and
-    `shared_experts` (a gated unit every token passes through) are
-    parameters; their defaults are the layer as it was; "softmax_all"
+    through grouped matrix products over experts of unbiased matrices,
+    gated units of three or, with `gated: false`, ungated ones of two
+    (second top = the rows each held expert received). Of the dropless
+    path `scoring` (softmax over the top-k logits | sigmoid scores chosen
+    under the `select_bias` blob, renormalised, times
+    `routed_scaling_factor`), the `activation` (relu | silu | relu2),
+    `gated` and `shared_experts` (a unit of the experts' form every token
+    passes through) are parameters; their defaults are the layer as it was; "softmax_all"
     scores by the softmax over every expert and weighs by the chosen
     expert's own probability. A second bottom, when given, is what the
     router scores instead of the tensor the experts transform or, with
@@ -527,17 +600,10 @@ class MoELayer(Layer):
         problem = moe_router_problem(p, in_shapes)
         if problem:
             raise ValueError(f"moe_param: {problem}")
-        plain = (p.scoring == "softmax" and p.activation == "relu"
-                 and p.routed_scaling_factor == 1.0 and not p.shared_experts)
-        if not p.dropless and not plain:
-            raise ValueError("moe_param: scoring, routed_scaling_factor, "
-                             "activation and shared_experts need "
-                             "dropless: true")
-        if p.scoring not in ("softmax", "sigmoid", "softmax_all") \
-                or p.activation not in ("relu", "silu"):
-            raise ValueError(f"moe_param: scoring {p.scoring!r} (softmax | "
-                             f"sigmoid | softmax_all), activation "
-                             f"{p.activation!r} (relu | silu)")
+        from ..proto.netshape import moe_form_problem
+        problem = moe_form_problem(p)
+        if problem:
+            raise ValueError(f"moe_param: {problem}")
         filler = p.weight_filler or FillerParameter(type="xavier")
         gate_filler = p.gate_filler or FillerParameter(type="gaussian",
                                                        std=0.02)
@@ -551,14 +617,15 @@ class MoELayer(Layer):
         if not p.dropless:
             self.declare("b1", (held, p.hidden_dim), zero)
         self.declare("w2", (held, p.hidden_dim, c), filler)
-        if p.dropless:
-            self.declare("w3", (held, c, p.hidden_dim), filler)
-        else:
+        if not p.dropless:
             self.declare("b2", (held, c), zero)
+        elif p.gated:
+            self.declare("w3", (held, c, p.hidden_dim), filler)
         if p.shared_experts:
             wide = p.shared_experts * p.hidden_dim
             self.declare("shared_w1", (c, wide), filler)
-            self.declare("shared_w3", (c, wide), filler)
+            if p.gated:
+                self.declare("shared_w3", (c, wide), filler)
             self.declare("shared_w2", (wide, c), filler)
         tops = [in_shapes[0]]
         if len(self.lp.top) > 1:  # aux loss / rows per held expert
@@ -578,7 +645,8 @@ class MoELayer(Layer):
             y, extra = moe_dropless(
                 cast, flat, scored, top_k=max(p.top_k, 1),
                 first_expert=p.first_expert, scoring=p.scoring,
-                scale=p.routed_scaling_factor, activation=p.activation)
+                scale=p.routed_scaling_factor, activation=p.activation,
+                row_bound=p.row_bound)
         else:
             y, extra = moe_ffn(cast, flat, top_k=max(p.top_k, 1),
                                capacity_factor=p.capacity_factor)

@@ -22,7 +22,7 @@ _PARAM_FIELD = {
     "Convolution": "convolution_param", "Deconvolution": "convolution_param",
     "Crop": "crop_param", "Data": "data_param", "Dropout": "dropout_param",
     "Attention": "attention_param", "LayerNorm": "layer_norm_param",
-    "MoE": "moe_param", "Parameter": "parameter_param",
+    "MoE": "moe_param", "Mamba2": "mamba2_param", "Parameter": "parameter_param",
     "BlockDiffusionNoise": "block_diffusion_param",
     "RMSNorm": "rms_norm_param",
     "DummyData": "dummy_data_param", "Eltwise": "eltwise_param",

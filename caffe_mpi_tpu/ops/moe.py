@@ -23,14 +23,16 @@ tokens are both unaffordable: the (T, k) choices are flattened and sorted
 by expert, the rows gathered, the experts run as grouped matrix products
 over the sorted rows (megablox's Pallas kernels; `lax.ragged_dot` on the
 CPU), and the results summed back per token under the router's weights.
-No capacity, no dropped token, static shapes. Experts are gated units of
-three unbiased matrices. Parameters (`moe_param`, three recipes use them):
-the scoring (`route`: top-k then softmax, sigmoid scores chosen under a
-selection bias and renormalised, or the softmax over every expert chosen
-under the bias and not renormalised, times a scaling factor), whether the
-router is the layer's own matrix or logits handed in, the gate's
-activation (relu | silu), and shared experts, one gated unit every token
-passes through (scope `moe.shared`). It is told which experts it holds (an
+No capacity, no dropped token, static shapes. An expert is unbiased
+matrices: a gated unit of three, (act(x w1) * (x w3)) w2, or, where the
+layer holds no `w3`, an ungated one of two, act(x w1) w2. Parameters
+(`moe_param`, five recipes use them): the scoring (`route`: top-k then
+softmax, sigmoid scores chosen under a selection bias and renormalised, or
+the softmax over every expert chosen under the bias and not renormalised,
+times a scaling factor), whether the router is the layer's own matrix or
+logits handed in, the activation (relu | silu | relu2, relu squared),
+whether the experts are gated, and shared experts, one unit of the same
+form every token passes through (scope `moe.shared`). It is told which experts it holds (an
 expert-parallel share): the router still scores all of them, and the
 result is the held experts' part plus, computed here for this chip's own
 tokens, the shared experts'. Rows routed to absent experts sort last. They
@@ -55,6 +57,8 @@ with DP/TP sharding on the same mesh.
 from __future__ import annotations
 
 import functools
+import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +69,8 @@ from ..utils.spans import (MOE_COMBINE as COMBINE, MOE_DISPATCH as DISPATCH,
                            MOE_EXPERTS as EXPERTS, MOE_FALLBACK as FALLBACK,
                            MOE_ROUTE as ROUTE, MOE_SHARED as SHARED)
 
-ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+               "relu2": lambda t: jnp.square(jax.nn.relu(t))}
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,
@@ -347,35 +352,44 @@ def route(logits: jnp.ndarray, top_k: int, scoring: str = "softmax",
     return scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20), ids
 
 
-def _row_bound(pairs: int, held: int, experts: int) -> int:
+def _row_bound(pairs: int, held: int, experts: int,
+               shares: float = 1.5) -> int:
     """Rows of the sorted buffer for `pairs` (token, choice) pairs of which
     the `held` of `experts` experts receive their share on average: the
-    smallest multiple of the row tile at or over 1.5 x that share (the
-    held experts of the two recipes received 0.79-1.17 x theirs over every
-    seed read on the chip, PERF.md section 6, PR 32), and `pairs` where
-    that is no fewer."""
-    tiles = -(-3 * pairs * held // (2 * experts * ROW_TILE))
+    smallest multiple of the row tile at or over `shares` x that share, and
+    `pairs` where that is no fewer. 1.5 by default (the held experts of
+    the first two recipes received 0.79-1.17 x theirs over every seed read
+    on the chip, PERF.md section 6, PR 32); `moe_param.row_bound` says
+    otherwise where a fresh router's skew is wider (8 held of 128 behind
+    ungated relu^2 experts: 0.50-1.78 x over 116 layers read, PR 40)."""
+    tiles = math.ceil(shares * pairs * held / (experts * ROW_TILE))
     return min(tiles * ROW_TILE, pairs)
+
+
+def _unit(act, a, b=None):
+    """What lies between an expert's products: act(x w1), times x w3 where
+    the expert is gated."""
+    return act(a) if b is None else act(a) * b
 
 
 def _sorted_rows(dot, k, act, x, w, banks, order, inv, sizes):
     """The held experts over the sorted buffer's first R = len(order) rows,
     which have to hold every live one: x (T, F), w (k * T,) the pairs'
-    weights, banks (w1, w3, w2), `dot` the grouped product -> (y (T, F),
-    what the backward pass reads again: xs (R, F), the two products
-    (R, H), ys (R, F))."""
-    w1, w3, w2 = banks
+    weights, banks (w1, w3, w2) of gated experts or (w1, w2) of ungated
+    ones, `dot` the grouped product -> (y (T, F), what the backward pass
+    reads again: xs (R, F), the products from xs (R, H) (two, or one), ys
+    (R, F))."""
+    *ups, w2 = banks
     live = _live(order, sizes)
     with jax.named_scope(DISPATCH):
         xs = jnp.where(live, _dispatch(x, order, inv, k), 0)
     with jax.named_scope(EXPERTS):
-        a = dot(xs, w1, sizes)
-        b = dot(xs, w3, sizes)
-        ys = dot(act(a) * b, w2, sizes)
+        pre = tuple(dot(xs, up, sizes) for up in ups)
+        ys = dot(_unit(act, *pre), w2, sizes)
     with jax.named_scope(COMBINE):
         y = _combine(_weigh(ys, _permute(w, order, inv), live),
                      order, inv, k)
-    return y, (xs, a, b, ys)
+    return y, (xs, pre, ys)
 
 
 def _live(order, sizes):
@@ -393,8 +407,8 @@ def _weigh(ys, w, live):
 def _sorted_rows_bwd(k, act, w, banks, order, inv, sizes, saved, g):
     """Gradients of `_sorted_rows`' y with respect to (x, w, banks) from
     what it saved: each stage's own transpose, in the stages' scopes."""
-    w1, w3, w2 = banks
-    xs, a, b, ys = saved
+    *ups, w2 = banks
+    xs, pre, ys = saved
     live = _live(order, sizes)
     with jax.named_scope(COMBINE):
         d_ys, d_w = jax.vjp(lambda ys, w: _weigh(ys, w, live), ys,
@@ -402,14 +416,14 @@ def _sorted_rows_bwd(k, act, w, banks, order, inv, sizes, saved, g):
                             )[1](_dispatch(g, order, inv, k))
         d_w = _unsort(d_w, inv)
     with jax.named_scope(EXPERTS):
-        h, gate_bwd = jax.vjp(lambda a, b: act(a) * b, a, b)
+        h, unit_bwd = jax.vjp(functools.partial(_unit, act), *pre)
         d_h, d_w2 = grouped_dot_t(h, w2, sizes, d_ys)
-        d_a, d_b = gate_bwd(d_h)
-        d_xs1, d_w1 = grouped_dot_t(xs, w1, sizes, d_a)
-        d_xs3, d_w3 = grouped_dot_t(xs, w3, sizes, d_b)
+        d_xs, d_ups = zip(*(grouped_dot_t(xs, up, sizes, d)
+                            for up, d in zip(ups, unit_bwd(d_h))))
     with jax.named_scope(DISPATCH):
-        d_x = _combine(jnp.where(live, d_xs1 + d_xs3, 0), order, inv, k)
-    return d_x, d_w, (d_w1, d_w3, d_w2)
+        d_x = _combine(jnp.where(live, functools.reduce(operator.add, d_xs),
+                                 0), order, inv, k)
+    return d_x, d_w, (*d_ups, d_w2)
 
 
 # Fewer rows than pairs: the bound holds every live row, and a dead one
@@ -444,11 +458,12 @@ def _bounded_rows_fwd(k, act, bound, x, w, banks, order, inv, sizes):
     bounded = lambda: _sorted_rows(grouped_dot, k, act, x, w, banks,
                                    order[:bound], inv, sizes)
     wide, narrow = (bound, x.shape[1]), (bound, banks[0].shape[2])
+    zeros = lambda shape: jnp.zeros(shape, x.dtype)
     y, saved = jax.lax.cond(
         jnp.sum(sizes) < bound, bounded,
         lambda: (_fallback(k, act, x, w, banks, order, inv, sizes),
-                 tuple(jnp.zeros(shape, x.dtype)
-                       for shape in (wide, narrow, narrow, wide))))
+                 (zeros(wide), tuple(zeros(narrow) for _ in banks[:-1]),
+                  zeros(wide))))
     return y, (x, w, banks, order, inv, sizes, saved)
 
 
@@ -469,7 +484,7 @@ _bounded_rows.defvjp(_bounded_rows_fwd, _bounded_rows_bwd)
 def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
                  top_k: int, first_expert: int = 0,
                  scoring: str = "softmax", scale: float = 1.0,
-                 activation: str = "relu"):
+                 activation: str = "relu", row_bound: float = 1.5):
     """x, router_in: (T, F) -> (y (T, F), rows (E_held,) float32).
 
     params: `gate` (F, E) scores every expert (`route`; `select_bias` (E,)
@@ -477,18 +492,20 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
     `router_in` (T, E) is the logits themselves; the banks `w1`, `w3`
     (E_held, F, H) and `w2` (E_held, H, F) are experts first_expert ..
     first_expert + E_held - 1, gated units without biases: (act(x w1) * (x
-    w3)) w2, act = relu | silu. y is the sum over a token's chosen experts
+    w3)) w2, act = relu | silu | relu2; without `w3` the experts are
+    ungated, act(x w1) w2. y is the sum over a token's chosen experts
     THAT ARE HELD of weight * expert(x): what absent experts would add is
     left out and the weights are not renormalised over the held ones.
     With `shared_w1`, `shared_w3` (F, Hs) and `shared_w2` (Hs, F), the
     shared experts' (act(x shared_w1) * (x shared_w3)) shared_w2 is added
-    for every token, unweighted.
+    for every token, unweighted (act(x shared_w1) shared_w2 without
+    `shared_w3`).
     rows[e] counts the (token, choice) pairs held expert e received.
 
     The (token, choice) pairs are sorted by expert, those of absent
     experts last, and the sorted buffer holds the first R rows of that
     order: R = `_row_bound`, static, top_k * T when every expert is held.
-    With fewer, R is 1.5 x the held experts' average share, and the device
+    With fewer, R is `row_bound` x the held experts' average share, and the device
     takes the buffer of all top_k * T rows instead (`_bounded_rows`)
     whenever the live rows reach it: nothing is dropped either way."""
     held = params["w1"].shape[0]
@@ -506,15 +523,18 @@ def moe_dropless(params: dict, x: jnp.ndarray, router_in: jnp.ndarray, *,
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
         sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
-    bound = _row_bound(order.shape[0], held, logits.shape[1])
-    routed = (x, weights.T.reshape(-1),
-              (params["w1"], params["w3"], params["w2"]), order, inv, sizes)
+    bound = _row_bound(order.shape[0], held, logits.shape[1], row_bound)
+    banks = tuple(params[name] for name in ("w1", "w3", "w2")
+                  if name in params)
+    routed = (x, weights.T.reshape(-1), banks, order, inv, sizes)
     if bound < order.shape[0]:
         y = _bounded_rows(top_k, act, bound, *routed)
     else:
         y = _sorted_rows(grouped_dot, top_k, act, *routed)[0]
     if "shared_w1" in params:
         with jax.named_scope(SHARED):
-            y = y + (act(x @ params["shared_w1"])
-                     * (x @ params["shared_w3"])) @ params["shared_w2"]
+            h = act(x @ params["shared_w1"])
+            if "shared_w3" in params:
+                h = h * (x @ params["shared_w3"])
+            y = y + h @ params["shared_w2"]
     return y, sizes.astype(jnp.float32)

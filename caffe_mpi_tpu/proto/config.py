@@ -214,6 +214,10 @@ def _rep() -> Any:
 
 @dataclass
 class FillerParameter(Message):
+    # beside the reference's types, two TPU-native ones, a state-space
+    # mixer's starts: "log_arange" fills ln(i + 1) along the last axis;
+    # "softplus_inverse_log_uniform" fills v with softplus(v) log-uniform
+    # in [min, max], floored at `value`
     type: str = "constant"
     value: float = 0.0
     min: float = 0.0
@@ -580,8 +584,9 @@ class MoEParameter(Message):
     weight_filler: FillerParameter | None = None
     # no capacity and no dropped token: the top_k largest router logits,
     # softmax over those, rows sorted by expert, grouped matrix products
-    # over gated ReLU experts of three unbiased matrices, (relu(x w1) *
-    # (x w3)) w2 (ops/moe.py moe_dropless). A second bottom, when given,
+    # over experts of unbiased matrices: gated units of three, (act(x w1)
+    # * (x w3)) w2, or with `gated: false` ungated ones of two, act(x w1)
+    # w2 (ops/moe.py moe_dropless). A second bottom, when given,
     # is what the router scores; the second top is the rows each held
     # expert received
     dropless: bool = False
@@ -591,6 +596,14 @@ class MoEParameter(Message):
     # part. 0 = all of them
     experts_held: int = 0
     first_expert: int = 0
+    # a share's sorted buffer holds this many times the held experts'
+    # average share of the (token, choice) pairs, in whole row tiles; the
+    # device takes the buffer of every pair whenever the live rows reach it
+    # (ops/moe.py _row_bound, scope `moe.fallback`): a router's skew costs
+    # time, never a row. A debt, not a knob to tune a recipe: it exists for
+    # a frozen fresh router whose selection bias nothing updates, and goes
+    # when that update rule lands (ROADMAP Speed 13)
+    row_bound: float = 1.5
     # the rest is the dropless path's. scoring "softmax": the top_k largest
     # logits, softmax over those. "sigmoid" (arXiv:2412.19437): s =
     # sigmoid(logits); the top_k largest of s + select_bias (a blob after
@@ -606,18 +619,58 @@ class MoEParameter(Message):
     bias_filler: FillerParameter | None = None
     gate_filler: FillerParameter | None = None
     routed_scaling_factor: float = 1.0
-    # the gate's activation in an expert, act(x w1) * (x w3): relu | silu
+    # the activation in an expert, act(x w1): relu | silu | relu2 (relu
+    # squared)
     activation: str = "relu"
+    # whether an expert has a gate matrix: (act(x w1) * (x w3)) w2, or
+    # without it act(x w1) w2 and no `w3` (nor `shared_w3`) blob
+    gated: bool = True
     # what the second bottom is. "input": a tensor like the first, which
     # the router matrix `gate` multiplies. "logits": the router's logits
     # themselves, (..., num_experts), from layers of the net's own (a
     # router that is a network); no `gate` blob is declared
     router: str = "input"
-    # shared experts: one gated unit of shared_experts * hidden_dim that
-    # every token passes through, added to the routed experts' part (blobs
+    # shared experts: one unit of the experts' form (gated or not) of
+    # shared_experts * hidden_dim that every token passes through, added to the routed experts' part (blobs
     # shared_w1, shared_w3 (C, n h), shared_w2 (n h, C)). Under expert
     # parallelism every chip computes it for its own tokens
     shared_experts: int = 0
+
+
+@dataclass
+class Mamba2Parameter(Message):
+    """TPU-native extension: a Mamba-2 mixer (SSD, arXiv:2405.21060;
+    layers/sequence.py Mamba2, ops/ssd.py). Over a bottom (N, S, C): one
+    input product to [z | x B C | dt] of widths inner | inner + 2 groups
+    state | heads, inner = num_heads * head_dim (NOT a multiple of C); a
+    depthwise causal convolution of `conv_kernel` taps and a bias over x B
+    C, then SiLU; the selective recurrence S_t = exp(delta_t A) S_{t-1} +
+    delta_t x_t (x) B_t, y_t = S_t C_t + D x_t a head, delta = softplus(dt
+    + dt_bias), A = -exp(A_log), head h reading B and C of group h //
+    (num_heads / groups), computed in chunks of `chunk` positions with the
+    decays and the carried state in float32; y * silu(z), then an RMS norm
+    over each of the `groups` groups of inner / groups channels apart,
+    times a learned scale; one output product. No bias on the products.
+    Blobs: in_weight, conv_weight (channels, taps: the LAST tap reads the
+    current position), conv_bias, dt_bias, A_log, D, norm_scale,
+    out_weight."""
+    num_heads: int = 0
+    head_dim: int = 0
+    state_size: int = 0
+    groups: int = 1
+    conv_kernel: int = 4
+    chunk: int = 128
+    eps: float = 1e-5
+    # the two products' filler (default xavier). The rest starts as the
+    # published model's does: A_log_h = ln(h + 1), D = 1, the norm's scale
+    # 1, the convolution's taps uniform in +- 1 / sqrt(conv_kernel) and its
+    # bias 0, and dt_bias such that softplus(dt_bias) is log-uniform in
+    # [dt_min, dt_max], floored at dt_floor (a published config's
+    # time_step_min, time_step_max, time_step_floor)
+    weight_filler: FillerParameter | None = None
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
 
 
 @dataclass
@@ -971,6 +1024,7 @@ class LayerParameter(Message):
     dummy_data_param: DummyDataParameter | None = None
     eltwise_param: EltwiseParameter | None = None
     moe_param: MoEParameter | None = None
+    mamba2_param: Mamba2Parameter | None = None
     block_diffusion_param: BlockDiffusionParameter | None = None
     layer_norm_param: LayerNormParameter | None = None
     rms_norm_param: RMSNormParameter | None = None

@@ -73,7 +73,7 @@ BF16_ELIGIBLE = frozenset({
     "Eltwise", "Embed", "EuclideanLoss", "Exp", "Filter", "Flatten",
     "GELU", "HDF5Data", "HingeLoss", "Im2col", "ImageData", "InfogainLoss",
     "InnerProduct", "Input", "L1Loss", "LRN", "LayerNorm", "Log", "MVN",
-    "MemoryData", "MoE", "MultinomialLogisticLoss", "PReLU", "Parameter",
+    "Mamba2", "MemoryData", "MoE", "MultinomialLogisticLoss", "PReLU", "Parameter",
     "Pipeline", "Pooling", "Power", "RMSNorm", "ReLU", "Reduction",
     "Reshape", "SPP", "Scale", "Sigmoid", "SigmoidCrossEntropyLoss",
     "Silence", "Slice", "Softmax", "SoftmaxWithLoss", "Split", "TanH",
@@ -1207,6 +1207,24 @@ def moe_router_problem(p, in_shapes) -> "str | None":
     return None
 
 
+def moe_form_problem(p) -> "str | None":
+    """What is wrong with a moe_param's scoring, activation and expert
+    form, or None. The one spelling: layers/sequence.py raises what this
+    returns."""
+    plain = (p.scoring == "softmax" and p.activation == "relu" and p.gated
+             and p.routed_scaling_factor == 1.0 and not p.shared_experts)
+    if not p.dropless and not plain:
+        return ("scoring, routed_scaling_factor, activation, gated and "
+                "shared_experts need dropless: true")
+    if not p.row_bound >= 1.0:
+        return f"row_bound {p.row_bound}: at least 1, the held experts' share"
+    if p.scoring not in ("softmax", "sigmoid", "softmax_all") \
+            or p.activation not in ("relu", "silu", "relu2"):
+        return (f"scoring {p.scoring!r} (softmax | sigmoid | softmax_all), "
+                f"activation {p.activation!r} (relu | silu | relu2)")
+    return None
+
+
 @rule("Attention")
 def _attention(ctx):
     from .config import AttentionParameter
@@ -1298,6 +1316,61 @@ def _attention(ctx):
     return [s]
 
 
+def mamba2_problem(p, s) -> "str | None":
+    """What is wrong with a mamba2_param over a bottom of `s` positions
+    (None: not known), or None. The one spelling: layers/sequence.py raises
+    what this returns, and ops/ssd.py counts on it."""
+    if p is None or min(p.num_heads, p.head_dim, p.state_size,
+                        p.conv_kernel) < 1:
+        return ("mamba2_param needs num_heads, head_dim, state_size and a "
+                "conv_kernel of at least 1")
+    if (p.num_heads * p.head_dim) % max(p.groups, 1):
+        return (f"the inner width {p.num_heads} x {p.head_dim} does not "
+                f"divide into {p.groups} groups for the gated norm")
+    if min(p.groups, p.chunk) < 1 or p.num_heads % p.groups:
+        return (f"{p.num_heads} heads do not divide into {p.groups} groups "
+                f"of B and C")
+    if s is not None and s > p.chunk and s % p.chunk:
+        return (f"a sequence of {s} positions is not whole chunks of "
+                f"{p.chunk}")
+    return None
+
+
+def mamba2_widths(p) -> tuple:
+    """(inner, channels the convolution mixes, the input product's
+    outputs) of a mamba2_param: z | x B C | dt."""
+    inner = p.num_heads * p.head_dim
+    conv = inner + 2 * p.groups * p.state_size
+    return inner, conv, inner + conv + p.num_heads
+
+
+@rule("Mamba2")
+def _mamba2(ctx):
+    p = ctx.lp.mamba2_param
+    s = ctx.in_shapes[0]
+    if s is None:
+        return [None]
+    if len(s) != 3:
+        ctx.problem("shape", f"Mamba2 expects (N, S, C) bottom, got "
+                             f"{_fmt(s)}")
+        return [None]
+    problem = mamba2_problem(p, s[1])
+    if problem:
+        ctx.problem("shape", f"mamba2_param: {problem}")
+        return [None]
+    c = s[2]
+    inner, conv, wide = mamba2_widths(p)
+    ctx.declare("in_weight", (wide, c))
+    ctx.declare("conv_weight", (conv, p.conv_kernel))
+    ctx.declare("conv_bias", (conv,))
+    ctx.declare("dt_bias", (p.num_heads,))
+    ctx.declare("A_log", (p.num_heads,))
+    ctx.declare("D", (p.num_heads,))
+    ctx.declare("norm_scale", (inner,))
+    ctx.declare("out_weight", (c, inner))
+    return [s]
+
+
 @rule("MoE")
 def _moe(ctx):
     p = ctx.lp.moe_param
@@ -1319,17 +1392,9 @@ def _moe(ctx):
     problem = moe_router_problem(p, ctx.in_shapes)
     if problem:
         ctx.problem("shape", f"moe_param: {problem}")
-    plain = (p.scoring == "softmax" and p.activation == "relu"
-             and p.routed_scaling_factor == 1.0 and not p.shared_experts)
-    if not p.dropless and not plain:
-        ctx.problem("shape", "moe_param: scoring, routed_scaling_factor, "
-                             "activation and shared_experts need "
-                             "dropless: true")
-    if p.scoring not in ("softmax", "sigmoid", "softmax_all") \
-            or p.activation not in ("relu", "silu"):
-        ctx.problem("shape", f"moe_param: scoring {p.scoring!r} (softmax | "
-                             f"sigmoid | softmax_all), activation "
-                             f"{p.activation!r} (relu | silu)")
+    problem = moe_form_problem(p)
+    if problem:
+        ctx.problem("shape", f"moe_param: {problem}")
     if p.router != "logits":
         ctx.declare("gate", (c, p.num_experts))
     if p.scoring != "softmax":
@@ -1338,14 +1403,15 @@ def _moe(ctx):
     if not p.dropless:
         ctx.declare("b1", (held, p.hidden_dim))
     ctx.declare("w2", (held, p.hidden_dim, c))
-    if p.dropless:
-        ctx.declare("w3", (held, c, p.hidden_dim))
-    else:
+    if not p.dropless:
         ctx.declare("b2", (held, c))
+    elif p.gated:
+        ctx.declare("w3", (held, c, p.hidden_dim))
     if p.shared_experts:
         wide = p.shared_experts * p.hidden_dim
         ctx.declare("shared_w1", (c, wide))
-        ctx.declare("shared_w3", (c, wide))
+        if p.gated:
+            ctx.declare("shared_w3", (c, wide))
         ctx.declare("shared_w2", (wide, c))
     tops = [s]
     if len(ctx.lp.top) > 1:
@@ -1537,6 +1603,20 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
                                "weight") and name != "conv0_weight") \
                 + 2 * pairs * heads * hd
         return s * c * (2 * heads + 2 * kv) * hd + 2 * pairs * heads * hd
+    if type_name == "Mamba2":
+        s0 = in_shapes[0] if in_shapes else None
+        p = getattr(lp, "mamba2_param", None) if lp is not None else None
+        if s0 is None or len(s0) != 3 or p is None or not _known(*s0[1:]):
+            return None
+        _, s, c = s0
+        # the two products, and the recurrence by its definition: a
+        # position's state update and its read-out, 2 H P N, whatever the
+        # chunk (the chunked form's four products are more, 1.70 M a
+        # position for 1.05 M at 64 x 64 x 128 and chunks of 128: work of
+        # the method, which a utilization does not credit; the
+        # convolution a channel is no product)
+        inner, _, wide = mamba2_widths(p)
+        return s * (c * wide + inner * c + 2 * inner * p.state_size)
     if type_name == "MoE":
         s0 = in_shapes[0] if in_shapes else None
         w1 = param_shapes.get("w1")
@@ -1551,11 +1631,13 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
             return None
         if p is not None and p.dropless:
             # a token's k choices fall on the held experts with
-            # probability held / num_experts each; three matrices
-            # plus the shared experts, which every token passes through
+            # probability held / num_experts each; three matrices an
+            # expert, two where it is ungated, plus the shared experts,
+            # which every token passes through
+            mats = 3 if "w3" in param_shapes else 2
             return tokens * c * p.num_experts * ("gate" in param_shapes) + (
-                tokens * k * e * 3 * c * h // p.num_experts) \
-                + tokens * p.shared_experts * 3 * c * h
+                tokens * k * e * mats * c * h // p.num_experts) \
+                + tokens * p.shared_experts * mats * c * h
         return tokens * (c * e + k * 2 * c * h)
     return 0
 

@@ -42,6 +42,11 @@ to tell them apart.
 | `cca.project` | scope | inside an `Attention` layer with `cca`: the products from the bottom (queries and keys down into the latent, the two value halves) (layers/sequence.py `_cca_qkv`) |
 | `cca.mix` | scope | what that attention adds to a grouped head: the shifts along the sequence, the convolution a channel and the one a head, the q-k mean, the normalisation under the temperature, the rotary turn over part of the head |
 | `cca.out` | scope | the output product, latent back up to the channels |
+| `ssm.project` | scope | inside a `Mamba2` layer (layers/sequence.py): the one input product to z, x B C and dt |
+| `ssm.conv` | scope | the depthwise causal convolution over x B C (shifted sums), its bias and SiLU, the split |
+| `ssm.scan` | scope | softplus, the decays and the selective recurrence in chunks (ops/ssd.py): four products and the carried states; its backward pass computes the chunks' matrices again under the same scope |
+| `ssm.gate` | scope | y * silu(z), the RMS norm a group, the learned scale |
+| `ssm.out` | scope | the output product |
 | `moe.route` | scope | inside a dropless `MoE` layer: router product (none where the layer is handed logits), top-k, softmax (ops/moe.py) |
 | `moe.dispatch` | scope | sort of the (token, choice) pairs by expert, group sizes, gather of the rows |
 | `moe.experts` | scope | the grouped matrix products over the held experts' rows and the activation between them |
@@ -88,6 +93,11 @@ UPDATE = "solver.update"
 CCA_PROJECT = "cca.project"
 CCA_MIX = "cca.mix"
 CCA_OUT = "cca.out"
+SSM_PROJECT = "ssm.project"
+SSM_CONV = "ssm.conv"
+SSM_SCAN = "ssm.scan"
+SSM_GATE = "ssm.gate"
+SSM_OUT = "ssm.out"
 MOE_ROUTE = "moe.route"
 MOE_DISPATCH = "moe.dispatch"
 MOE_EXPERTS = "moe.experts"

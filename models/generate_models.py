@@ -1438,6 +1438,143 @@ snapshot_prefix: "models/zaya1_8b/{prefix}"
 """
 
 
+NEMOTRON = dict(
+    # https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+    # config.json: widths as published; depth (the pattern's first nine
+    # layers), experts held and vocabulary are one chip's share
+    # (benchmarks/configs/nemotron3_nano_30b_a3b.json)
+    seq=8192, vocab=16384, dim=2688, pattern="MEMEM*EME", ssm_heads=64,
+    ssm_head_dim=64, ssm_state=128, ssm_groups=8, conv_kernel=4, chunk=128,
+    time_step=(0.001, 0.1, 1e-4),   # time_step_min, _max, _floor
+    heads=32, kv_heads=2, head_dim=128, experts=128, experts_held=8, top_k=6,
+    expert_width=1856, shared_width=3712, scaling=2.5, eps=1e-5)
+# the size of tests/test_nemotron.py and of the benchmark's CPU rehearsal:
+# every mechanism, no width (32 experts in 16 shares of 2; 4 chunks of 8)
+NEMOTRON_TINY = dict(
+    seq=32, vocab=64, dim=48, pattern="MEM*E", ssm_heads=4, ssm_head_dim=8,
+    ssm_state=16, ssm_groups=2, conv_kernel=4, chunk=8,
+    time_step=(0.001, 0.1, 1e-4), heads=4, kv_heads=2, head_dim=16,
+    experts=32, experts_held=2, top_k=4, expert_width=32, shared_width=64,
+    scaling=2.5, eps=1e-5)
+# The expert layers' sorted buffers hold 2.5 x the held experts' share
+# (`moe_param.row_bound`; 7,680 rows a layer) where the other recipes' hold
+# 1.5 x. An ungated relu(z)^2 expert on fresh gaussian weights adds one
+# common vector to every token (relu^2's mean, 0.45 the size of what it adds
+# token by token), so by the third expert layer a fresh router favours the
+# same experts for most tokens, and whether those are among the 8 held is a
+# draw of the seed: read on the chip (PERF.md section 6, PR 40, 29 seeds x 4
+# layers), a layer's held experts receive 1,543-5,473 rows where 3,072 are
+# their share (deviation 269 in the first expert layer, 727, 832 and 1,005
+# in the next three); at 1.5 x (4,608) 6 of 29 seeds started a layer past
+# the bound and one stayed there all window: the step 297 ms for 266,
+# XLA's `ragged-dot` kernels in the count, `correct` false
+NEMOTRON_ROW_BOUND = 2.5
+# layers computed again in the backward pass (LayerParameter.remat): as
+# written the step needs 15.45e9 bytes, with the Mamba-2 layers' remat too
+# (the depth rule of benchmarks/configs/nemotron3_nano_30b_a3b.json)
+NEMOTRON_REMAT = ("attn", "ssm")
+
+
+def nemotron_h(batch=1, *, seq, vocab, dim, pattern, ssm_heads, ssm_head_dim,
+               ssm_state, ssm_groups, conv_kernel, chunk, time_step, heads,
+               kv_heads, head_dim, experts, experts_held, top_k, expert_width,
+               shared_width, scaling, eps, first_expert=0, use_flash=True,
+               remat=(), name="nemotron3_nano_30b_a3b"):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (Nemotron-H, arXiv:2504.03624), one
+    chip's share. Every layer is ONE mixer under a pre-norm residual, its
+    kind read off `pattern`, one letter a layer: `M` a Mamba-2 mixer
+    (arXiv:2405.21060), `E` a dropless expert layer (sigmoid scores chosen
+    under a selection bias, renormalised, times a scaling factor; ungated
+    relu^2 experts and one shared expert of the same form), `*` grouped
+    attention with no positions. No biases but the convolution's, untied
+    embedding and head. `time_step`: the ends and the floor of the
+    log-uniform start of softplus(dt_bias). The layer equations are written out in
+    benchmarks/reference/nemotron_ref.py. `remat`: layer-name suffixes whose
+    layers are computed again in the backward pass."""
+    filler = dict(type="gaussian", std=0.02)
+    n = NetSpec(name)
+    n.tokens, n.label = L.Input(ntop=2, input_param=dict(
+        shape=[dict(dim=[batch, seq]), dict(dim=[batch, seq])]))
+    again = lambda layer: dict(remat=True) \
+        if any(layer.endswith(suffix) for suffix in remat) else {}
+
+    def put(layer, top):
+        setattr(n, layer, top)
+        return top
+
+    def mixer(b, kind, u):
+        if kind == "M":
+            return put(f"{b}/ssm", L.Mamba2(
+                u, num_heads=ssm_heads, head_dim=ssm_head_dim,
+                state_size=ssm_state, groups=ssm_groups,
+                conv_kernel=conv_kernel, chunk=chunk, eps=eps,
+                weight_filler=filler, dt_min=time_step[0],
+                dt_max=time_step[1], dt_floor=time_step[2],
+                **again(f"{b}/ssm")))
+        if kind == "*":
+            return put(f"{b}/attn", L.Attention(
+                u, num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+                causal=True, use_flash=use_flash, bias_term=False,
+                weight_filler=filler, **again(f"{b}/attn")))
+        if kind != "E":
+            raise ValueError(f"pattern letter {kind!r}: M, E or *")
+        # the router scores the experts' own input (the same blob, twice).
+        # Routing is a constant of the step, as in smallthinker(): the
+        # router matrix and the selection bias (the first two blobs) are
+        # frozen and nothing trains through the scores. Second top: rows
+        # each held expert received
+        out, rows = L.MoE(
+            u, u, ntop=2, loss_weight=[0.0, 0.0],
+            param=[dict(lr_mult=0, decay_mult=0)] * 2,
+            propagate_down=[True, False],
+            moe_param=dict(
+                num_experts=experts, hidden_dim=expert_width, top_k=top_k,
+                dropless=True, experts_held=experts_held,
+                first_expert=first_expert, row_bound=NEMOTRON_ROW_BOUND,
+                scoring="sigmoid",
+                routed_scaling_factor=scaling, activation="relu2",
+                gated=False, shared_experts=shared_width // expert_width,
+                weight_filler=filler, bias_filler=JOYAI_SELECT_BIAS))
+        put(f"{b}/moe_rows", rows)
+        return put(f"{b}/moe", out)
+
+    # unit normal, for smallthinker()'s reason
+    n.embed = L.Embed(n.tokens, input_dim=vocab, num_output=dim,
+                      bias_term=False,
+                      weight_filler=dict(type="gaussian", std=1.0))
+    x = n.embed
+    for l, kind in enumerate(pattern):
+        u = put(f"blk{l}/norm", L.RMSNorm(x, eps=eps))
+        x = put(f"blk{l}/res", L.Eltwise(x, mixer(f"blk{l}", kind, u)))
+    n.ln_f = L.RMSNorm(x, eps=eps)
+    n.logits = L.InnerProduct(n.ln_f, num_output=vocab, axis=2,
+                              bias_term=False, weight_filler=filler)
+    n.loss = L.SoftmaxWithLoss(n.logits, n.label,
+                               softmax_param=dict(axis=2))
+    n.accuracy = L.Accuracy(n.logits, n.label, axis=2,
+                            include=dict(phase="TEST"))
+    return n
+
+
+def nemotron_solver(net: str, prefix: str) -> str:
+    return f"""# NVIDIA-Nemotron-3-Nano-30B-A3B, one chip's share: Adam (this
+# system's coupled L2, none set), fixed 3e-4, global-norm clip 1; static
+# loss scale for the reason models/smallthinker_21b_a3b/solver.prototxt gives
+net: "models/nemotron3_nano_30b_a3b/{net}"
+base_lr: 0.0003
+lr_policy: "fixed"
+display: 10
+max_iter: 10000
+momentum: 0.9
+momentum2: 0.95
+type: "Adam"
+clip_gradients: 1.0
+loss_scale: 1.0
+snapshot: 10000
+snapshot_prefix: "models/nemotron3_nano_30b_a3b/{prefix}"
+"""
+
+
 def transformer_lm_pp_prototxt(batch=8, seq=64, vocab=256, dim=128, heads=4,
                                n_stages=4, micro_batches=4, ffn_hidden=256):
     """Pipeline-parallel transformer_lm variant: the trunk is ONE Pipeline
@@ -1821,7 +1958,7 @@ def main():
     # the language-model configurations of the benchmark: the recipe at the
     # published widths and the tiny one its CPU rehearsal and tests run
     # (tests/test_smallthinker.py, tests/test_joyai.py, tests/test_sdar.py,
-    # tests/test_zaya.py). Train only: no
+    # tests/test_zaya.py, tests/test_nemotron.py). Train only: no
     # deploy net, the serving path has no key/value cache yet
     for family, build, solver_text, real, tiny, prefix in (
             ("smallthinker_21b_a3b", smallthinker, smallthinker_solver,
@@ -1834,7 +1971,10 @@ def main():
              dict(SDAR_TINY, remat=SDAR_REMAT), "sdar"),
             ("zaya1_8b", zaya, zaya_solver,
              dict(ZAYA, remat=ZAYA_REMAT),
-             dict(ZAYA_TINY, remat=ZAYA_REMAT), "zaya")):
+             dict(ZAYA_TINY, remat=ZAYA_REMAT), "zaya"),
+            ("nemotron3_nano_30b_a3b", nemotron_h, nemotron_solver,
+             dict(NEMOTRON, remat=NEMOTRON_REMAT),
+             dict(NEMOTRON_TINY, remat=NEMOTRON_REMAT), "nemotron")):
         d = os.path.join(out_root, family)
         os.makedirs(d, exist_ok=True)
         for net, solver, sizes in (

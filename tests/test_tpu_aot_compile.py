@@ -78,6 +78,18 @@ def kernel_calls(text: str) -> list[tuple[str, list[str]]]:
                 r'custom_call_target="tpu_custom_call"', text)]
 
 
+def bench_harness():
+    """`benchmarks/run.py`, loaded by its path: under the bare name `run`
+    an example's `run.py` may already stand in `sys.modules` (whichever
+    test file the worker imported first decided it)."""
+    from pathlib import Path
+    path = Path(__file__).resolve().parent.parent / "benchmarks" / "run.py"
+    spec = importlib.util.spec_from_file_location("bench_run", path)
+    harness = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(harness)
+    return harness
+
+
 def step_calls(text: str) -> tuple[list[str], list[str]]:
     """Kernel names of a compiled step's Mosaic calls: this tree's own,
     which a step runs, and XLA:TPU's `ragged-dot` kernels, which only the
@@ -263,7 +275,7 @@ class TestSmallThinkerCell:
         root = Path(__file__).resolve().parent.parent
         bench = root / "benchmarks"
         sys.path[:0] = [p for p in (str(bench),) if p not in sys.path]
-        import run as harness
+        harness = bench_harness()
         driver = harness.load_module(bench / "drivers" / "train_lm.py")
         cell = harness.load_cell("smallthinker_bf16_s8k_ep4share",
                                  rehearse=False)
@@ -344,7 +356,7 @@ class TestJoyAICell:
         root = Path(__file__).resolve().parent.parent
         bench = root / "benchmarks"
         sys.path[:0] = [p for p in (str(bench),) if p not in sys.path]
-        import run as harness
+        harness = bench_harness()
         driver = harness.load_module(bench / "drivers" / "train_mla_lm.py")
         cell = harness.load_cell("joyai_flash_bf16_s8k_epshare",
                                  rehearse=False)
@@ -425,7 +437,7 @@ class TestSdarCell:
         root = Path(__file__).resolve().parent.parent
         bench = root / "benchmarks"
         sys.path[:0] = [p for p in (str(bench),) if p not in sys.path]
-        import run as harness
+        harness = bench_harness()
         driver = harness.load_module(bench / "drivers" / "train_bd_lm.py")
         cell = harness.load_cell("sdar_bf16_s8k_bd4_ep8share",
                                  rehearse=False)

@@ -13,8 +13,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from test_tpu_aot_compile import (abstract, compile_tpu, kernel_calls,
-                                  on_chip, step_calls, v5e_devices)
+from test_tpu_aot_compile import (abstract, bench_harness, compile_tpu,
+                                  kernel_calls, on_chip, step_calls,
+                                  v5e_devices)
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -38,7 +39,7 @@ def test_flash_at_the_cell_s_grouped_heads():
 def test_whole_step_compiles_and_fits_the_chip():
     bench = ROOT / "benchmarks"
     sys.path[:0] = [p for p in (str(bench),) if p not in sys.path]
-    import run as harness
+    harness = bench_harness()
     driver = harness.load_module(bench / "drivers" / "train_cca_lm.py")
     cell = harness.load_cell("zaya1_bf16_s8k_ep2share", rehearse=False)
     blocks = cell["config"]["num_hidden_layers"]

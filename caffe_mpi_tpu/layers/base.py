@@ -48,6 +48,10 @@ class Layer:
     """Base class. Subclasses set `type_name` and implement setup/apply."""
 
     type_name: str = ""
+    # the `checkpoint_name`s of what this layer's backward pass reads that
+    # a `remat: true` keeps (Net.apply_range): everything else of the
+    # layer's forward is computed again in the backward pass, these are not
+    kept_under_remat: tuple[str, ...] = ()
 
     def __init__(self, lp: LayerParameter, policy: DtypePolicy, phase: str = "TRAIN"):
         self.lp = lp

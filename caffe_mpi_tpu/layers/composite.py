@@ -197,3 +197,9 @@ class PipelineLayer(Layer):
             # stage dim of the very same stacked params
             y, _ = lax.scan(lambda h, p_s: (stage(p_s, h), None), x, params)
         return [y], state
+
+    @property
+    def kept_under_remat(self) -> tuple[str, ...]:
+        """Under the Pipeline layer's own `remat`, what its block's layers
+        keep under theirs."""
+        return tuple(name for il in self.block for name in il.kept_under_remat)

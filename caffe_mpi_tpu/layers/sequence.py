@@ -435,6 +435,13 @@ class AttentionLayer(Layer):
             y = y + self.f(params["proj_bias"])
         return [y], state
 
+    @property
+    def kept_under_remat(self) -> tuple[str, ...]:
+        """The flash kernel's output and its row statistics: what is
+        computed again is what leads up to q, k and v."""
+        from ..ops.flash_attention import KEPT_UNDER_REMAT
+        return KEPT_UNDER_REMAT
+
 
 def block_diffusion_noise(key, tokens, block_length: int, mask_id: int,
                           t_min: float, ignore_label: int):
@@ -489,6 +496,14 @@ class BlockDiffusionNoiseLayer(Layer):
         return list(tops[:len(self.lp.top)]), state
 
 
+# what a Mamba2 layer's backward pass reads of its forward: the input
+# product's result and the scan's output (with ops/ssd.py's carried states,
+# `ssd.KEPT`), kept under `remat: true` so that neither the input product
+# nor the scan's forward pass runs again in the backward pass
+MAMBA2_INPUT = "mamba2.zxbcdt"
+MAMBA2_SCAN = "mamba2.scan_out"
+
+
 @register("Mamba2")
 class Mamba2Layer(Layer):
     """mamba2_param: a Mamba-2 mixer over (N, S, C) (proto/config.py
@@ -496,7 +511,10 @@ class Mamba2Layer(Layer):
     scopes: `ssm.project` the input product, `ssm.conv` the causal
     convolution a channel with its SiLU (shifted sums, `shift_rows`),
     `ssm.scan` softplus, decays and the recurrence, `ssm.gate` the gate
-    and the grouped norm, `ssm.out` the output product."""
+    and the grouped norm, `ssm.out` the output product. Under `remat` the
+    input product's result, the scan's output and its carried states are
+    kept (`kept_under_remat`); the convolution and the gate are computed
+    again."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         from ..proto.netshape import mamba2_problem, mamba2_widths
@@ -527,7 +545,14 @@ class Mamba2Layer(Layer):
         self.declare("out_weight", (c, self.inner), filler)
         return [in_shapes[0]]
 
+    @property
+    def kept_under_remat(self) -> tuple[str, ...]:
+        from ..ops.ssd import KEPT
+        return MAMBA2_INPUT, MAMBA2_SCAN, KEPT
+
     def apply(self, params, state, bottoms, *, train, rng):
+        from jax.ad_checkpoint import checkpoint_name
+
         from ..ops.ssd import ssd
         from ..utils.spans import (SSM_CONV, SSM_GATE, SSM_OUT, SSM_PROJECT,
                                    SSM_SCAN)
@@ -537,7 +562,7 @@ class Mamba2Layer(Layer):
         w = lambda name: self.f(params[name])
         inner, bc = self.inner, p.groups * p.state_size
         with jax.named_scope(SSM_PROJECT):
-            zxbcdt = u @ w("in_weight").T
+            zxbcdt = checkpoint_name(u @ w("in_weight").T, MAMBA2_INPUT)
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], -1)
         with jax.named_scope(SSM_CONV):
             taps = w("conv_weight")
@@ -548,11 +573,11 @@ class Mamba2Layer(Layer):
         with jax.named_scope(SSM_SCAN):
             # the vectors a head stay in the master type: the scan's decay
             # arithmetic is float32 whatever the compute type
-            y = ssd(x.reshape(n, s, p.num_heads, p.head_dim), dt,
-                    params["A_log"],
-                    b.reshape(n, s, p.groups, p.state_size),
-                    c.reshape(n, s, p.groups, p.state_size),
-                    params["D"], params["dt_bias"], p.chunk)
+            y = checkpoint_name(ssd(
+                x.reshape(n, s, p.num_heads, p.head_dim), dt,
+                params["A_log"], b.reshape(n, s, p.groups, p.state_size),
+                c.reshape(n, s, p.groups, p.state_size), params["D"],
+                params["dt_bias"], p.chunk), MAMBA2_SCAN)
         with jax.named_scope(SSM_GATE):
             y = y.reshape(n, s, inner) * jax.nn.silu(z)
             y = rms_normalize(y.reshape(n, s, p.groups, -1), p.eps)

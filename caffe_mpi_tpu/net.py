@@ -404,15 +404,15 @@ class Net:
                 if layer.lp.remat and train:
                     # recompute this layer's forward during backward
                     # instead of keeping its activations in HBM
-                    # (layer-level remat); of an attention layer the
-                    # flash kernel's output is kept, so that only what
-                    # leads up to the kernel is computed again
-                    from .ops.flash_attention import KEPT_UNDER_REMAT
+                    # (layer-level remat), all but what the layer's type
+                    # names as read by its backward pass
+                    # (`Layer.kept_under_remat`): the flash kernel's
+                    # output, a Mamba2 layer's input product and scan
                     apply_fn = jax.checkpoint(
                         lambda p, s, b, layer=layer, lrng=lrng: layer.apply(
                             p, s, b, train=True, rng=lrng),
                         policy=jax.checkpoint_policies.save_only_these_names(
-                            *KEPT_UNDER_REMAT))
+                            *layer.kept_under_remat))
                     tops, lstate_new = apply_fn(lparams, lstate, bottoms)
                 else:
                     tops, lstate_new = apply_fn(lparams, lstate, bottoms,

@@ -734,10 +734,10 @@ def _flash(q, k, v, causal, interpret, sk_valid, window, bd):
     return out
 
 
-# Under a layer's `remat: true` (net.py) these two residuals are kept and
-# the forward kernel is not run again in the backward pass: what is
-# computed again is what leads up to q, k and v. Without a checkpoint
-# around the call the names do nothing.
+# Under an Attention layer's `remat: true` these two residuals are kept
+# (`AttentionLayer.kept_under_remat`) and the forward kernel is not run
+# again in the backward pass: what is computed again is what leads up to
+# q, k and v. Without a checkpoint around the call the names do nothing.
 KEPT_UNDER_REMAT = ("flash.out", "flash.lse")
 
 

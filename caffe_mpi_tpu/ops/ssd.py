@@ -34,7 +34,9 @@ sum of negatives taken later-minus-earlier, so it is at most nought and no
 The backward pass is jax's own of this function under a `jax.checkpoint`
 that keeps the carried states `S_in` alone (`KEPT`): the Q x Q matrices (S
 Q H float32 values a layer, 268 MB at 8,192 x 128 x 64) are computed again,
-not kept.
+not kept. Under a `Mamba2` layer's `remat` the layer's checkpoint keeps
+`KEPT` and this function's result as well (`Mamba2Layer.kept_under_remat`),
+so the backward pass runs the forward once, inside this checkpoint.
 """
 
 from __future__ import annotations

@@ -995,8 +995,10 @@ class LayerParameter(Message):
     backward_math: str = ""
     debug: bool = False
     # TPU-native extension: rematerialize this layer's activations in the
-    # backward pass (jax.checkpoint) instead of storing them — the
-    # HBM-for-FLOPs trade the reference cannot express
+    # backward pass (jax.checkpoint) instead of storing them, all but what
+    # the layer's type keeps (`Layer.kept_under_remat`: the flash kernel's
+    # output, a Mamba2 layer's input product and scan) — the HBM-for-FLOPs
+    # trade the reference cannot express
     remat: bool = False
     # TPU-native extension: tensor-parallel placement of this layer's
     # weights over the mesh 'model' axis. "rows" shards the output dim

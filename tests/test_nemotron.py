@@ -28,9 +28,12 @@ sys.path[:0] = [p for p in (os.path.join(ROOT, "benchmarks"),
 
 from reference import nemotron_ref  # noqa: E402
 
+from caffe_mpi_tpu.layers.sequence import (MAMBA2_INPUT,  # noqa: E402
+                                           MAMBA2_SCAN)
 from caffe_mpi_tpu.net import Net  # noqa: E402
 from caffe_mpi_tpu.ops import moe as moe_ops  # noqa: E402
-from caffe_mpi_tpu.ops.ssd import ssd  # noqa: E402
+from caffe_mpi_tpu.ops.flash_attention import KEPT_UNDER_REMAT  # noqa: E402
+from caffe_mpi_tpu.ops.ssd import KEPT, ssd  # noqa: E402
 from caffe_mpi_tpu.proto import NetParameter  # noqa: E402
 
 CONFIG = json.load(open(os.path.join(
@@ -118,6 +121,74 @@ def test_the_layer_is_the_reference_s_recurrence(seq, chunk):
         "norm_scale", "out_weight"}
     for blob, grad in w_p["ssm"].items():
         assert rel(g_p["ssm"][blob], grad) < 2e-4, blob
+
+
+@pytest.mark.parametrize("chunk", [8, 32])
+def test_remat_changes_no_number_of_the_layer(chunk):
+    """Under `remat: true` a Mamba2 layer keeps its input product, its
+    scan's output and the scan's carried states (`kept_under_remat`) and
+    computes the rest again: the value and the gradient with respect to
+    every blob and the bottom are the layer's without it."""
+    params, x, cot = ssm_case(S)
+    text = SSM % (S, chunk, "")
+    nets = [net_from(text),
+            net_from(text.replace('top: "y"', 'top: "y" remat: true'))]
+    assert nets[1]._layer_by_name("ssm").lp.remat
+    (y0, (p0, x0)), (y1, (p1, x1)) = (
+        (y, vjp(cot)) for y, vjp in (
+            jax.vjp(lambda p, x, net=net: layer_out(net, p, x), params, x)
+            for net in nets))
+    assert rel(y1, y0) < 1e-6
+    assert rel(x1, x0) < 1e-6
+    for blob, grad in p0["ssm"].items():
+        assert rel(p1["ssm"][blob], grad) < 1e-6, blob
+
+
+DENSE = 'type: "InnerProduct" inner_product_param { num_output: 48 axis: 2 }'
+ATTENTION = 'type: "Attention" attention_param { num_heads: 4 causal: true }'
+MAMBA2 = ('type: "Mamba2" mamba2_param { num_heads: 4 head_dim: 8 '
+          'state_size: 16 groups: 2 conv_kernel: 4 chunk: 8 }')
+
+
+def one_layer_net(layer: str) -> Net:
+    return net_from(f"""
+layer {{ name: "in" type: "Input" top: "x"
+        input_param {{ shape {{ dim: 2 dim: {S} dim: 48 }} }} }}
+layer {{ name: "l" bottom: "x" top: "y" remat: true {layer} }}""")
+
+
+@pytest.mark.parametrize("layer,kept", [
+    (DENSE, ()),
+    (ATTENTION, KEPT_UNDER_REMAT),
+    (MAMBA2, (MAMBA2_INPUT, MAMBA2_SCAN, KEPT)),
+    # a block that holds an attention layer keeps what that layer keeps
+    ('type: "Pipeline" pipeline_param { num_stages: 2 layer { name: "a" '
+     f'bottom: "x" top: "a" {ATTENTION} }} }}', KEPT_UNDER_REMAT)])
+def test_each_type_keeps_what_its_backward_pass_reads(layer, kept):
+    assert one_layer_net(layer)._layer_by_name("l").kept_under_remat == kept
+
+
+@pytest.mark.parametrize("layer,keeps", [
+    (DENSE, False), ('type: "RMSNorm"', False),
+    # the jnp path writes no flash name, though the type keeps them
+    (ATTENTION, False), (MAMBA2, True)])
+def test_a_layer_that_writes_no_kept_name_is_remat_that_keeps_nothing(
+        layer, keeps):
+    """`remat: true` on a layer that writes none of the names its type keeps
+    lowers to the text of a checkpoint that keeps nothing: the policy
+    changes the program only where a name is written (a Mamba2 layer)."""
+    net = one_layer_net(layer)
+    params, _ = net.init(jax.random.PRNGKey(0))
+    one = net._layer_by_name("l")
+    nothing = jax.checkpoint(
+        lambda p, b: one.apply(p, {}, b, train=True, rng=None)[0],
+        policy=jax.checkpoint_policies.save_only_these_names())
+    x = jnp.zeros((2, S, 48))
+    texts = [jax.jit(lambda p, x, f=f: jax.vjp(f, p, x)[1](x)).lower(
+        params, x).as_text() for f in (
+            lambda p, x: net.apply(p, {}, {"x": x}, train=True)[0]["y"],
+            lambda p, x: nothing(p["l"], [x])[0])]
+    assert (texts[0] != texts[1]) == keeps
 
 
 def test_the_reference_s_time_blocks_are_its_scan():

@@ -94,8 +94,10 @@ def test_whole_step_compiles_and_fits_the_chip():
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     # the pattern's first nine layers fit under the configuration's limit
-    # with the Mamba-2 layers' `remat: true`: 12.07e9 with it, 15.45e9
-    # without (PERF.md section 4, PR 40)
+    # with the Mamba-2 layers' `remat: true`: 13.69e9 with it, keeping
+    # each layer's input product, scan output and carried states (12.30e9
+    # keeping none, PR 40's tree with `row_bound` 2.5; 12.07e9 with 1.5),
+    # 15.61e9 with no layer's `remat` (PERF.md section 4, PR 41)
     assert pattern == "MEMEM*EME" and live < 14.5e9, live
     calls, fallback = step_calls(compiled.as_text())
     flash = [c for c in calls if c.startswith("flash_")]
@@ -107,3 +109,70 @@ def test_whole_step_compiles_and_fits_the_chip():
     assert len(calls) == expected == 3 + 6 * experts, (len(calls), expected)
     # and in the branch a balanced router never takes, XLA's own
     assert fallback.count("ragged-dot-none") == experts * 7, fallback
+
+
+def products_by_scope(text: str) -> dict[tuple[str, str], int]:
+    """`dot` and `convolution` instructions of a compiled module, by the
+    `ssm.*` scope their op_name names ("" outside one) and by pass: a
+    backward instruction's op_name holds `transpose(`."""
+    counts: dict[tuple[str, str], int] = {}
+    for line in text.splitlines():
+        if not re.search(r"= \S+ (convolution|dot)\(", line):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        name = name.group(1) if name else ""
+        scope = re.search(r"ssm\.\w+", name)
+        key = (scope.group(0) if scope else "",
+               "backward" if "transpose(" in name else "forward")
+        counts[key] = counts.get(key, 0) + 1
+    return counts
+
+
+def test_remat_keeps_what_a_mamba2_layer_s_backward_pass_reads():
+    """The tiny recipe's forward and backward pass, bf16, compiled for one
+    described v5e: under `remat: true` a Mamba2 layer keeps its input
+    product's result, its scan's output and the scan's carried states
+    (`Mamba2Layer.kept_under_remat`). So the input product runs once
+    forward and twice backward (dX, dW), not again to recompute the layer;
+    the scan's forward products run once in the backward pass, inside
+    ops/ssd.py's own checkpoint, not a second time for the layer's. The
+    attention layer's `remat` keeps the flash kernel's output as before.
+    Take any one name off the policy and a count here moves."""
+    from caffe_mpi_tpu.net import Net
+    from caffe_mpi_tpu.ops.ssd import ssd
+    from caffe_mpi_tpu.proto import NetParameter
+    recipe = ROOT / "models" / "nemotron3_nano_30b_a3b" / "tiny_train_val.prototxt"
+    net = Net(NetParameter.from_text(recipe.read_text()), phase="TRAIN",
+              precision="bf16")
+    mixers = [l for l in net.layers if l.type_name == "Mamba2"]
+    assert mixers and all(l.lp.remat for l in mixers)
+    rep = SingleDeviceSharding(v5e_devices()[0])
+    params, state = net.init(jax.random.PRNGKey(0))
+    feeds = {k: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+             for k, (shape, _) in net.feed_specs.items()}
+
+    def step(p, feeds):
+        return jax.value_and_grad(lambda p: net.apply(
+            p, state, feeds, train=True, rng=None)[2])(p)
+    text = compile_tpu(step, abstract(params, rep), feeds)
+    got = products_by_scope(text)
+
+    # the scan alone at the layers' sizes, under its own checkpoint
+    p, (n, s, _) = mixers[0].p, mixers[0].in_shapes[0]
+    x = on_chip((n, s, p.num_heads, p.head_dim), jnp.bfloat16)
+    dt = on_chip((n, s, p.num_heads), jnp.bfloat16)
+    bc = on_chip((n, s, p.groups, p.state_size), jnp.bfloat16)
+    head = on_chip((p.num_heads,), jnp.float32)
+
+    def scan(*args):
+        out, vjp = jax.vjp(lambda *a: ssd(*a, p.chunk), *args)
+        return vjp(out)
+    alone = products_by_scope(compile_tpu(scan, x, dt, head, bc, bc, head,
+                                          head))
+    layers = len(mixers)
+    assert got[("ssm.project", "forward")] == layers
+    assert got[("ssm.project", "backward")] == 2 * layers
+    assert got[("ssm.scan", "forward")] == layers * alone[("", "forward")]
+    assert got[("ssm.scan", "backward")] == layers * alone[("", "backward")]
+    flash = [c for c in step_calls(text)[0] if c.startswith("flash_")]
+    assert sorted(flash) == ["flash_dkv", "flash_dq", "flash_fwd"]

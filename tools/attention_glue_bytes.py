@@ -115,7 +115,6 @@ def layer_vjp(net, layer_name: str):
     `Net.apply_range` applies the layer."""
     import jax
 
-    from caffe_mpi_tpu.ops.flash_attention import KEPT_UNDER_REMAT
     layer = next(l for l in net.layers if l.name == layer_name)
     if layer.type_name != "Attention":
         raise SystemExit(f"{layer_name!r} is a {layer.type_name} layer")
@@ -123,7 +122,7 @@ def layer_vjp(net, layer_name: str):
     if layer.lp.remat:
         apply = jax.checkpoint(
             apply, policy=jax.checkpoint_policies.save_only_these_names(
-                *KEPT_UNDER_REMAT))
+                *layer.kept_under_remat))
 
     def fn(params, x, dy):
         y, vjp = jax.vjp(apply, params, x)

@@ -159,7 +159,14 @@ class AttentionLayer(Layer):
     summed with a mean of each other, normalised under a temperature,
     turned over a part of the head, value heads that read the current and
     the previous token. All produce their heads in the order `_head_major`
-    says."""
+    says.
+
+    Under the block-diffusion mask the fused form takes one bottom, the
+    `[noisy | clean]` sequence (N, 2 L, C), or two: the query rows (N, L,
+    C), the noisy half, and the key/value rows (N, 2 L, C), the whole. The
+    output then has the queries' L rows, which are the one-bottom layer's
+    first L rows of the same sequence: where nothing reads the clean half's
+    output, nothing computes it."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         from ..proto.config import AttentionParameter
@@ -169,6 +176,10 @@ class AttentionLayer(Layer):
             raise ValueError(
                 f"Attention expects (N, S, C) bottom, got {in_shapes[0]}")
         n, s, c = in_shapes[0]
+        from ..proto.netshape import attention_bottoms_problem
+        problem = attention_bottoms_problem(p, in_shapes)
+        if problem:
+            raise ValueError(f"Attention: {problem}")
         if p.kv_lora_rank:
             self._setup_latent(c)
             return [in_shapes[0]]
@@ -185,7 +196,7 @@ class AttentionLayer(Layer):
             raise ValueError(f"rotary positions over an odd head size "
                              f"{self.head_dim}")
         from ..proto.netshape import block_diffusion_problem
-        problem = block_diffusion_problem(p, s)
+        problem = block_diffusion_problem(p, in_shapes[-1][1])
         if problem:
             raise ValueError(f"attention_param: {problem}")
         nq, nkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
@@ -289,40 +300,46 @@ class AttentionLayer(Layer):
             [k, jnp.broadcast_to(k_r, (*k.shape[:-1], rot))], axis=-1)
         return q, k, heads(kv, kv_b[:, nope:])
 
-    def _grouped_qkv(self, params, x, hm: bool = False):
+    def _grouped_qkv(self, params, x, hm: bool = False, kv=None):
         """q (N, S, H, D), k and v (N, S, Hkv, D), or with `hm` (N, H, S,
         D): a product each over its rows of the fused blob viewed as
         heads, in the order asked for (apart, the per-head norm's statistic
         fuses into the product that feeds it); the norm and the rotary
         turn are lane-local, so a head goes from its product to the kernel
-        in one more pass."""
+        in one more pass. `kv`: the rows k and v are taken from, where
+        they are not x's (x then the first of them)."""
         from ..ops.attention import lane_partner, rope_tables, turn_lanes
         p = self.p
-        s, d = x.shape[1], self.head_dim
+        kv = x if kv is None else kv
+        s, d = kv.shape[1], self.head_dim
         weight = self.f(params["qkv_weight"])
         bias = self.f(params["qkv_bias"]) if p.bias_term else None
 
-        def heads(lo, n):
-            t = jnp.einsum("nsc,hdc->nhsd" if hm else "nsc,hdc->nshd", x,
+        def heads(rows, lo, n):
+            t = jnp.einsum("nsc,hdc->nhsd" if hm else "nsc,hdc->nshd", rows,
                            weight[lo:lo + n * d].reshape(n, d, -1))
             if bias is None:
                 return t
             b = bias[lo:lo + n * d].reshape(n, d)
             return t + (b[:, None] if hm else b)
-        q, k, v = (heads(0, self.heads), heads(self.nq, self.kv_heads),
-                   heads(self.nq + self.nkv, self.kv_heads))
+        q, k, v = (heads(x, 0, self.heads), heads(kv, self.nq, self.kv_heads),
+                   heads(kv, self.nq + self.nkv, self.kv_heads))
         # the two halves of a block-diffusion sequence sit at the same
         # positions
         tables = rope_tables(
             s, d, p.rope_theta, period=s // 2 if p.block_diffusion else 0
         ) if p.rope_theta else None
 
-        def positioned(t, norm):
+        def positioned(t, norm, tables):
             if p.qk_norm:
                 t = rms_normalize(t, p.norm_eps) * self.f(params[norm])
             return turn_lanes(t, lane_partner(t, d), *tables,
                               head_major=hm) if tables else t
-        return positioned(q, "q_norm"), positioned(k, "k_norm"), v
+        # the queries are the first rows of the keys' sequence
+        q_tables = [t[:x.shape[1]] for t in tables] \
+            if tables and x.shape[1] < s else tables
+        return (positioned(q, "q_norm", q_tables),
+                positioned(k, "k_norm", tables), v)
 
     def _cca_qkv(self, params, x, hm: bool = False):
         """q (N, S, H, D), k and v (N, S, G, D), or with `hm` (N, H, S, D),
@@ -400,9 +417,12 @@ class AttentionLayer(Layer):
         p = self.p
         x = self.f(bottoms[0])
         hm = self._head_major()
-        q, k, v = (self._latent_qkv if p.kv_lora_rank
-                   else self._cca_qkv if p.cca
-                   else self._grouped_qkv)(params, x, hm)
+        if len(bottoms) > 1:
+            q, k, v = self._grouped_qkv(params, x, hm, self.f(bottoms[1]))
+        else:
+            q, k, v = (self._latent_qkv if p.kv_lora_rank
+                       else self._cca_qkv if p.cca
+                       else self._grouped_qkv)(params, x, hm)
         mp = self.mesh_plan
         mask = dict(causal=bool(p.causal), window=p.window,
                     block_diffusion=p.block_diffusion)

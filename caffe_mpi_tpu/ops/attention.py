@@ -157,11 +157,12 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     Grouped heads: with Hkv < H (H a multiple of it) query head n reads
     key/value head n // (H / Hkv). window > 0 (with causal): key j is
     visible to query i iff i - window < j <= i. block_diffusion > 0
-    (neither causal nor a window): q, k and v are one `[noisy | clean]`
-    sequence, two halves of S / 2 in blocks of that length, and row i sees
-    column j under the block-diffusion mask (flash_attention.py
-    `_bd_valid`: inside its block among the noisy, the blocks before its
-    own among the clean; a clean row the clean blocks up to its own).
+    (neither causal nor a window): k and v are one `[noisy | clean]`
+    sequence, two halves of Sk / 2 in blocks of that length, q the same
+    sequence or its noisy half alone, and row i sees column j under the
+    block-diffusion mask (flash_attention.py `_bd_valid`: inside its block
+    among the noisy, the blocks before its own among the clean; a clean
+    row the clean blocks up to its own).
 
     use_flash: route through the Pallas flash-attention kernels
     (ops/flash_attention.py) — O(S) memory VMEM-tiled online softmax,
@@ -192,9 +193,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             mask &= ~jnp.tril(jnp.ones((sq, sk), bool), -window)
         mask = mask[None, None]
     if block_diffusion:
-        at = jnp.arange(q.shape[1], dtype=jnp.int32)
-        mask = flash._bd_valid(at[:, None], at[None, :], q.shape[1] // 2,
-                               block_diffusion)[None, None]
+        at = jnp.arange(k.shape[1], dtype=jnp.int32)
+        mask = flash._bd_valid(at[:q.shape[1], None], at[None, :],
+                               k.shape[1] // 2, block_diffusion)[None, None]
     out, m, l = _block_attn(q, k, v, scale=scale, mask=mask)
     return out / jnp.maximum(l, 1e-30)[..., None].swapaxes(1, 2)
 

@@ -182,7 +182,9 @@ def _tile_runs(g, bg, bt, n_t, n_full, lo, hi):
 # pos(i) // block. Row i sees column j iff
 #   both noisy: blk(j) == blk(i);    noisy row, clean column: blk(j) < blk(i);
 #   both clean: blk(j) <= blk(i);    clean row, noisy column: never.
-# Positions past the two halves (padding) count as clean ones.
+# Positions past the two halves (padding) count as clean ones. The queries
+# may be the noisy half alone (rows 0..half-1 against all 2 x half keys):
+# its rows are the same rows, and no query tile holds a clean row.
 
 def _bd_valid(rows, cols, half, block):
     """The block-diffusion mask of integer positions `rows` against `cols`
@@ -207,13 +209,19 @@ def _bd_valid(rows, cols, half, block):
 def _span_tiles(span, bt, start, n_t, n_full):
     """(first, in_lo, in_hi, end) as `_tile_runs` gives them, of the loop
     positions [vis_lo, vis_hi) a grid tile sees, of which every position of
-    the tile sees [all_lo, all_hi); no tile before `start`."""
+    the tile sees [all_lo, all_hi); no tile before `start`, and an empty
+    span ends where it starts, so that it hides no tile from the next.
+    Positions are never negative, so `lax.div` divides them: `//` on
+    signed integers adds a sign correction that Mosaic lowers at a cost of
+    its own, dozens of times a kernel (set-up time, not step time)."""
+    _div = jax.lax.div
     vis_lo, vis_hi, all_lo, all_hi = span
-    end = jnp.clip((vis_hi + bt - 1) // bt, start, n_t)
-    first = jnp.where(vis_hi > vis_lo, jnp.clip(vis_lo // bt, start, end),
-                      end)
-    in_lo = jnp.clip((all_lo + bt - 1) // bt, first, end)
-    in_hi = jnp.clip(jnp.minimum(all_hi // bt, n_full), in_lo, end)
+    seen = vis_hi > vis_lo
+    end = jnp.where(seen, jnp.clip(_div(vis_hi + bt - 1, bt), start, n_t),
+                    start)
+    first = jnp.where(seen, jnp.clip(_div(vis_lo, bt), start, end), end)
+    in_lo = jnp.clip(_div(all_lo + bt - 1, bt), first, end)
+    in_hi = jnp.clip(jnp.minimum(_div(all_hi, bt), n_full), in_lo, end)
     return first, in_lo, in_hi, end
 
 
@@ -226,14 +234,17 @@ def _bd_runs(g, bg, bt, n_t, n_full, half, block, keys_on_grid):
     cut tiles again (`_span_tiles`); the spans' ends come from the blocks
     of the tile's first and last position in each half (blk is monotone
     inside a half). A run that the shapes alone leave empty is left out: a
-    tile lies inside one block only if the block is that long, and a span
-    that ends where the half does ends on a tile's edge if the half does."""
+    tile lies inside one block only if the block is that long, a span that
+    ends where the half does ends on a tile's edge if the half does, and
+    queries that end with the noisy half hold no clean row."""
     L, B = half, block
+    _div = jax.lax.div      # of positions, as in `_span_tiles`
     x0 = g * bg
     x1 = x0 + bg - 1
     noisy, clean = x0 < L, x1 >= L          # the halves the tile touches
-    n0, n1 = x0 // B, jnp.minimum(x1, L - 1) // B        # its noisy blocks
-    c0, c1 = (jnp.maximum(x0, L) - L) // B, (x1 - L) // B  # its clean ones
+    n0, n1 = _div(x0, B), _div(jnp.minimum(x1, L - 1), B)  # noisy blocks
+    # its clean blocks (0 where it touches no clean position)
+    c0, c1 = _div(jnp.maximum(x0, L) - L, B), _div(jnp.maximum(x1, L) - L, B)
     one_block = noisy & ~clean & (n0 == n1) if B >= bt else False
     own_lo, own_hi = n0 * B, jnp.minimum((n1 + 1) * B, L)
     aligned = L % bt == 0
@@ -252,21 +263,28 @@ def _bd_runs(g, bg, bt, n_t, n_full, half, block, keys_on_grid):
     else:
         # noisy rows: the block of a noisy column, the blocks after a clean
         # column's. Clean rows: a clean column's block and every later one.
-        # A tile that touches both halves is cut everywhere
+        # A tile that touches both halves is seen whole only by the rows of
+        # its noisy columns' one block, where that block comes after its
+        # clean columns' blocks, and by no clean row
         end = n_t * bt
         lo = jnp.minimum(jnp.minimum(jnp.where(noisy, own_lo, L),
                                      jnp.where(clean, (c0 + 1) * B, L)), L)
         hi = jnp.where(clean, L, own_hi)
-        all_lo = jnp.where(noisy & clean, hi, jnp.where(
-            one_block, lo,
-            jnp.where(clean, jnp.minimum((c1 + 1) * B, L), hi)))
+        all_lo = jnp.where(
+            noisy & clean, jnp.where((n0 == n1) & (c1 < n1), own_lo, hi),
+            jnp.where(one_block, lo,
+                      jnp.where(clean, jnp.minimum((c1 + 1) * B, L), hi)))
         only_clean = clean & ~noisy
         spans = [((lo, hi, all_lo, hi),
-                  (True, True, not (aligned and (B < bt or B % bt == 0)))),
-                 ((jnp.where(clean, L + c0 * B, 0), jnp.where(clean, end, 0),
-                   jnp.where(only_clean, L + c1 * B, jnp.where(clean, end,
-                                                               0)),
-                   jnp.where(clean, end, 0)), (True, True, False))]
+                  (True, True, not (aligned and (B < bt or B % bt == 0))))]
+        if end > L:
+            # queries that hold clean rows (with keys, or past the noisy
+            # half where they are padding)
+            spans.append(((jnp.where(clean, L + c0 * B, 0),
+                           jnp.where(clean, end, 0),
+                           jnp.where(only_clean, L + c1 * B,
+                                     jnp.where(clean, end, 0)),
+                           jnp.where(clean, end, 0)), (True, True, False)))
     runs, start = [], 0
     for span, (lead, inside, tail) in spans:
         first, in_lo, in_hi, end = _span_tiles(span, bt, start, n_t, n_full)
@@ -765,11 +783,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def check_block_diffusion(block: int, sq: int, sk: int, causal, window):
     """Refuse what the block-diffusion mask has no meaning with."""
-    if block and (causal or window or block < 0 or sq != sk or sq % 2):
+    if block and (causal or window or block < 0 or sk % 2
+                  or sq not in (sk, sk // 2)):
         raise ValueError(
             f"block diffusion (block length {block}) is a mask of its own "
             f"over one [noisy | clean] sequence of even length: neither "
-            f"causal nor window, and queries and keys alike (got causal "
+            f"causal nor window, the keys the whole sequence and the "
+            f"queries the whole or its noisy half (got causal "
             f"{bool(causal)}, window {window}, lengths {sq} and {sk})")
 
 
@@ -788,9 +808,11 @@ def flash_attention_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     n // (H / Hkv) through the kernels' block index maps, K and V are not
     repeated. window > 0 (causal only): key j visible to query i iff
     i - window < j <= i; tiles wholly outside are not visited.
-    block_diffusion > 0 (neither causal nor a window): the sequence is
-    `[noisy | clean]`, two halves of S / 2 in blocks of that length, under
-    the block-diffusion mask (`_bd_valid`).
+    block_diffusion > 0 (neither causal nor a window): the keys are
+    `[noisy | clean]`, two halves of Sk / 2 in blocks of that length, under
+    the block-diffusion mask (`_bd_valid`); the queries are the same
+    sequence or its noisy half alone (Sq = Sk / 2), whose rows then see
+    what they see in the whole.
 
     Arbitrary sequence lengths: a length over 128 is padded up to a
     multiple of 128 and walked in tiles of 128 to 512 (`_tile`) — padded
@@ -804,7 +826,7 @@ def flash_attention_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if window and not causal:
         raise ValueError("a sliding window needs causal attention")
     check_block_diffusion(block_diffusion, sq, sk, causal, window)
-    bd = (sq // 2, block_diffusion) if block_diffusion else None
+    bd = (sk // 2, block_diffusion) if block_diffusion else None
     sq_p, sk_p = _pad_len(sq, BQ), _pad_len(sk, BK)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))

@@ -1166,6 +1166,28 @@ def block_diffusion_problem(p, s) -> "str | None":
     return None
 
 
+def attention_bottoms_problem(p, in_shapes) -> "str | None":
+    """What is wrong with an Attention layer's bottoms, or None (shapes
+    not known are not judged): one, or under block_diffusion the query
+    rows (N, L, C), the noisy half, and the key/value rows (N, 2 L, C),
+    the whole `[noisy | clean]` sequence. The one spelling:
+    layers/sequence.py raises what this returns."""
+    if len(in_shapes) == 1:
+        return None
+    if len(in_shapes) > 2 or not p.block_diffusion or p.kv_lora_rank \
+            or p.cca:
+        return ("a second bottom is the key/value rows of block_diffusion's "
+                "[noisy | clean] sequence, in the grouped form: two bottoms "
+                "at most, neither kv_lora_rank nor cca")
+    q, kv = in_shapes
+    if q is None or kv is None:
+        return None
+    if len(kv) != 3 or (kv[0], 2 * q[1], kv[2]) != (q[0], kv[1], q[2]):
+        return (f"the query rows {_fmt(q)} are the noisy half of the "
+                f"key/value rows {_fmt(kv)}: (N, L, C) and (N, 2 L, C)")
+    return None
+
+
 def cca_problem(p) -> "str | None":
     """What is wrong with an attention_param's `cca`, or None. The one
     spelling: layers/sequence.py raises what this returns."""
@@ -1238,6 +1260,10 @@ def _attention(ctx):
         return [None]
     c = s[2]
     heads = max(p.num_heads, 1)
+    problem = attention_bottoms_problem(p, ctx.in_shapes)
+    if problem:
+        ctx.problem("shape", problem)
+        return [None]
     if p.kv_lora_rank:
         # latent attention (layers/sequence.py latent_dims)
         nope, rot, vd = p.qk_nope_head_dim, p.qk_rope_head_dim, p.v_head_dim
@@ -1300,7 +1326,8 @@ def _attention(ctx):
     if p.rope_theta and hd is not None and hd % 2:
         ctx.problem("shape",
                     f"rotary positions over an odd head size {hd}")
-    problem = block_diffusion_problem(p, s[1])
+    rows = ctx.in_shapes[-1]
+    problem = block_diffusion_problem(p, None if rows is None else rows[1])
     if problem:
         ctx.problem("shape", problem)
     nq = None if hd is None else heads * hd
@@ -1575,6 +1602,12 @@ def macs_per_image(type_name: str, in_shapes: list, out_shapes: list,
         heads = max(getattr(p, "num_heads", 1), 1)
         kv = getattr(p, "num_kv_heads", 0) or heads
         hd = getattr(p, "head_dim", 0) or c // heads
+        if len(in_shapes) > 1:
+            # queries of the noisy half against the whole sequence: k and v
+            # over 2 s rows, q and the output over s, the noisy rows' half
+            # of the pairs
+            return s * c * 2 * (heads + 2 * kv) * hd \
+                + heads * hd * s * (s + p.block_diffusion)
         # the four projections, then scores and values over the pairs the
         # mask leaves (a causal window of w: w keys a query, fewer at the
         # start)

@@ -1177,9 +1177,11 @@ def sdar(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim, rope_theta,
     """SDAR-30B-A3B-Chat (arXiv:2510.06303), one chip's share, as it is
     trained: by block diffusion (arXiv:2503.09573). The noise layer turns
     `seq` clean tokens into the 2 x seq ids [noisy | clean], which go
-    through every block together under the block-diffusion mask; the head
-    and the 1/t-weighted loss read the noisy half alone, with no shift. A
-    block is pre-norm: grouped-head attention with an RMSNorm on each query
+    through every block but the last together under the block-diffusion
+    mask; the head and the 1/t-weighted loss read the noisy half alone,
+    with no shift, so the last block's attention takes its queries from
+    the noisy half and its keys and values from both, and what follows it
+    runs on the noisy half. A block is pre-norm: grouped-head attention with an RMSNorm on each query
     and key head and rotary positions i mod seq, then a dropless top-k
     expert layer (softmax over the chosen logits, SiLU-gated experts) whose
     router reads the experts' own normed input. No biases, no shared
@@ -1204,8 +1206,22 @@ def sdar(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim, rope_theta,
     for b in range(layers):
         ln1 = L.RMSNorm(x, eps=eps)
         setattr(n, f"blk{b}/ln1", ln1)
+        rows = (ln1,)
+        if b == layers - 1:
+            # nothing reads the clean half's output of the last block: its
+            # queries and all that follows them run on the noisy half
+            halves = lambda t: L.Slice(t, ntop=2, axis=1, slice_point=[seq])
+            x, x_clean = halves(x)
+            setattr(n, f"blk{b}/noisy", x)
+            setattr(n, f"blk{b}/clean", x_clean)
+            ln1_noisy, ln1_clean = halves(ln1)
+            setattr(n, f"blk{b}/ln1_noisy", ln1_noisy)
+            setattr(n, f"blk{b}/ln1_clean", ln1_clean)
+            setattr(n, f"blk{b}/drop_clean",
+                    L.Silence(x_clean, ln1_clean, ntop=0))
+            rows = (ln1_noisy, ln1)
         attn = L.Attention(
-            ln1, num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
+            *rows, num_heads=heads, num_kv_heads=kv_heads, head_dim=head_dim,
             use_flash=use_flash, bias_term=False, rope_theta=rope_theta,
             block_diffusion=block_length, qk_norm=True, norm_eps=eps,
             weight_filler=filler, **again(f"blk{b}/attn"))
@@ -1217,7 +1233,13 @@ def sdar(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim, rope_theta,
         # the router scores the experts' own input (the same blob, twice);
         # routing is a constant of the step, as in smallthinker(): the
         # router matrix is frozen and nothing trains through the scores.
-        # Second top: rows each held expert received
+        # Second top: rows each held expert received. The last block's
+        # expert layer sees half the rows: twice the default row bound
+        # keeps its sorted buffer at the other layers' rows, so that the
+        # step holds one set of grouped-product kernels, each traced and
+        # lowered once a program: a buffer sized to the half is a little
+        # faster a step and seconds slower to set up (PERF.md section 6)
+        bound = dict(row_bound=3.0) if b == layers - 1 else {}
         moe, rows = L.MoE(ln2, ln2, ntop=2, loss_weight=[0.0, 0.0],
                           param=[dict(lr_mult=0, decay_mult=0)],
                           propagate_down=[True, False],
@@ -1227,16 +1249,13 @@ def sdar(batch=1, *, seq, vocab, dim, heads, kv_heads, head_dim, rope_theta,
                               experts_held=experts_held,
                               first_expert=first_expert, activation="silu",
                               gate_filler=sdar_router(experts, experts_held),
-                              weight_filler=filler))
+                              weight_filler=filler, **bound))
         setattr(n, f"blk{b}/moe", moe)
         setattr(n, f"blk{b}/moe_rows", rows)
         res2 = L.Eltwise(res1, moe)
         setattr(n, f"blk{b}/res2", res2)
         x = res2
-    # the loss reads the noisy half; the clean half ends here
-    n.noisy, n.clean = L.Slice(x, ntop=2, axis=1, slice_point=[seq])
-    n.drop_clean = L.Silence(n.clean, ntop=0)
-    n.ln_f = L.RMSNorm(n.noisy, eps=eps)
+    n.ln_f = L.RMSNorm(x, eps=eps)
     n.logits = L.InnerProduct(n.ln_f, num_output=vocab, axis=2,
                               bias_term=False, weight_filler=filler)
     # FULL: 1 / (N seq), whatever the draw masked

@@ -110,31 +110,33 @@ def widest_f32_glue_result(records: list[dict]) -> int:
 
 def layer_vjp(net, layer_name: str):
     """(fn, its arguments as ShapeDtypeStructs) of one layer's forward +
-    backward pass: fn(params, bottom, cotangent) -> (top, gradients), under
-    `jax.checkpoint` where the prototxt says `remat: true`, as
-    `Net.apply_range` applies the layer."""
+    backward pass: fn(params, bottoms, cotangent) -> (top, gradients),
+    under `jax.checkpoint` where the prototxt says `remat: true`, as
+    `Net.apply_range` applies the layer. The top has the first bottom's
+    shape."""
     import jax
 
     layer = next(l for l in net.layers if l.name == layer_name)
     if layer.type_name != "Attention":
         raise SystemExit(f"{layer_name!r} is a {layer.type_name} layer")
-    apply = lambda p, x: layer.apply(p, {}, [x], train=True, rng=None)[0][0]
+    apply = lambda p, xs: layer.apply(p, {}, list(xs), train=True,
+                                      rng=None)[0][0]
     if layer.lp.remat:
         apply = jax.checkpoint(
             apply, policy=jax.checkpoint_policies.save_only_these_names(
                 *layer.kept_under_remat))
 
-    def fn(params, x, dy):
-        y, vjp = jax.vjp(apply, params, x)
+    def fn(params, xs, dy):
+        y, vjp = jax.vjp(apply, params, xs)
         return y, vjp(dy)
 
     master, compute = layer.policy.master, layer.policy.cast_in
-    bottom = jax.eval_shape(
-        compute, jax.ShapeDtypeStruct(layer.in_shapes[0], master))
+    bottoms = tuple(jax.eval_shape(compute, jax.ShapeDtypeStruct(s, master))
+                    for s in layer.in_shapes)
     params = {name: jax.ShapeDtypeStruct(
         decl.shape, decl.dtype if decl.dtype is not None else master)
         for name, decl in layer.params.items()}
-    return fn, (params, bottom, bottom)
+    return fn, (params, bottoms, bottoms[0])
 
 
 def compiled_text(fn, args, device=None) -> str:

@@ -701,30 +701,29 @@ class Feeder:
 
 class DeviceFeedQueue:
     """Double-buffered DEVICE-resident super-batch queue — the feed side
-    of the K-step fused train loop (solver.step_chunk > 1).
+    of fused evaluation (solver._start_eval / _continue_eval).
 
     The host-side pipeline above (Feeder) overlaps batch ASSEMBLY with
-    the train step but still hands the solver one host batch per
-    iteration, costing one dispatch each. This queue extends the
-    lookahead to the device: `get(it0, k)` returns a stacked feeds
-    pytree with leaves [k, iter_size, ...] already `device_put` (or
+    the train step but hands over one host batch at a time. This queue
+    extends the lookahead to the device: `get(it0, k)` returns a stacked
+    feeds pytree with leaves [k, 1, B, ...] already `device_put` (or
     mesh-sharded), and a single worker thread assembles + transfers the
-    NEXT super-batch (the `hint`) while the current k-iteration scan
-    chunk runs on the chip — so host->HBM transfer hides behind compute,
-    the way the reference hides its NCCL allreduce behind backprop
-    (parallel.cpp:166-169), but for the input stream.
+    NEXT super-batch (the `hint`) while the current k-batch eval scan
+    runs on the chip — so host->HBM transfer hides behind compute, the
+    way the reference hides its NCCL allreduce behind backprop
+    (parallel.cpp:166-169), but for the input stream. The unit axis is
+    what the eval scan's body slices off (solver._build_eval_scan).
 
     Super-batches are pure functions of (it0, k) — the underlying
     feed_fn is indexed (Feeder's deterministic record striping) — so a
     mispredicted hint is dropped and rebuilt with no correctness cost.
     """
 
-    def __init__(self, feed_fn, *, iter_size: int = 1, place=None):
+    def __init__(self, feed_fn, *, place=None):
         """place: optional callable(stacked_pytree) -> device pytree
         (e.g. MeshPlan.shard_feeds at batch_axis=2); default is a plain
         jax.device_put."""
         self.feed_fn = feed_fn
-        self.iter_size = max(iter_size, 1)
         self.place = place
         self._pool = ThreadPoolExecutor(max_workers=1,
                                         thread_name_prefix="device-feed")
@@ -733,9 +732,7 @@ class DeviceFeedQueue:
     def _build(self, it0: int, k: int):
         import jax
         import jax.numpy as jnp
-        isz = self.iter_size
-        micros = [self.feed_fn(m)
-                  for m in range(it0 * isz, (it0 + k) * isz)]
+        micros = [self.feed_fn(m) for m in range(it0, it0 + k)]
 
         def stack(*leaves):
             if all(isinstance(x, np.ndarray) for x in leaves):
@@ -744,7 +741,7 @@ class DeviceFeedQueue:
                 # device-resident feeds (synthetic benches): stack on
                 # device, never pulling them back to host
                 arr = jnp.stack([jnp.asarray(x) for x in leaves])
-            return arr.reshape((k, isz) + arr.shape[1:])
+            return arr.reshape((k, 1) + arr.shape[1:])
 
         tree = jax.tree.map(stack, *micros)
         if self.place is not None:
@@ -755,7 +752,7 @@ class DeviceFeedQueue:
         """Schedule (it0, k) on the worker WITHOUT blocking — the
         test-boundary warmup path (solver._prefetch_test_feeds): the
         eval pass's first super-batch assembles and device_puts while
-        the train chunk that ends at the boundary is still computing,
+        the train step that ends at the boundary is still computing,
         so the boundary itself only pays the dispatch."""
         if (it0, k) not in self._pending:
             self._pending[(it0, k)] = self._pool.submit(self._build, it0, k)
@@ -763,7 +760,7 @@ class DeviceFeedQueue:
     def ready(self, it0: int, k: int) -> bool:
         """True when (it0, k) is assembled and a get() would not block
         — the solver's opportunistic eval-chunk dispatch asks this
-        between train chunks. Schedules the build if it wasn't pending,
+        between train steps. Schedules the build if it wasn't pending,
         so polling converges."""
         self.prefetch(it0, k)
         return self._pending[(it0, k)].done()

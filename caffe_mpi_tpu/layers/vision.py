@@ -195,7 +195,6 @@ class LRNLayer(Layer):
         return [in_shapes[0]]
 
     def apply(self, params, state, bottoms, *, train, rng):
-        import os
         x = self.f(bottoms[0])
         p = self.p
         # ISSUE 9: the across-channels case routes through the Pallas
@@ -209,12 +208,8 @@ class LRNLayer(Layer):
         # stood here before PR 39: a reassociated window sum and one
         # exp/log pair for `power`, a few ulp; tests/test_layers.py holds
         # it to 1e-5 relative of that expression, forward and gradient.
-        # CAFFE_LRN_PALLAS=0 sends any dtype down the lax path; =1 forces
-        # the kernels (chip_smoke.py and the benchmark refuse to run so).
-        knob = os.environ.get("CAFFE_LRN_PALLAS", "")
         use_pallas = (self.region != "WITHIN_CHANNEL" and x.ndim == 4
-                      and knob != "0"
-                      and (knob == "1" or x.dtype == jnp.bfloat16))
+                      and x.dtype == jnp.bfloat16)
         if use_pallas:
             from ..ops.lrn import lrn_across_channels
 

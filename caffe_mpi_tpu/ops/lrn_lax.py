@@ -1,6 +1,6 @@
 """Across-channels LRN in plain jnp/lax: the path every operand takes
-that does not go to the Pallas kernels of `ops/lrn.py` (float32 always,
-any dtype under `CAFFE_LRN_PALLAS=0`). This module imports no Pallas:
+that does not go to the Pallas kernels of `ops/lrn.py` (every dtype but
+bfloat16). This module imports no Pallas:
 `jax.experimental.pallas` costs 1.2 s at its first import, and an f32
 net never needs it (PERF.md section 5).
 

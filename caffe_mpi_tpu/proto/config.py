@@ -1180,13 +1180,6 @@ class SolverParameter(Message):
     # updates compute on 1/N of each param, new params all-gather; slot
     # memory drops to 1/N per chip. 0 = replicated (reference behavior).
     zero_stage: int = 0
-    # TPU-native extension: fuse up to K consecutive iterations into ONE
-    # jitted lax.scan program fed by a device-resident super-batch — the
-    # host pays one dispatch per K iterations instead of
-    # per iteration. Chunks auto-shrink to land exactly on display /
-    # test_interval / snapshot boundaries. 1 (default) = classic
-    # one-dispatch-per-iteration behavior.
-    step_chunk: int = 1
     # TPU-native extension (ISSUE 2): test batches fused into ONE
     # evaluation dispatch — the test pass runs as a jitted lax.scan over
     # a [T, B, ...] super-batch carrying the per-blob score accumulators

@@ -56,10 +56,9 @@ def learning_rate(p: SolverParameter, it: jnp.ndarray) -> jnp.ndarray:
 def schedule(p: SolverParameter, it: jnp.ndarray
              ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(lr, momentum) at iteration `it` — both pure jnp functions of a
-    traced scalar, so the whole schedule evaluates INSIDE a jitted step
-    and inside the K-step `lax.scan` train loop (the carried iteration
-    counter feeds straight in; no host round-trip, no recompiles as the
-    iteration advances)."""
+    traced scalar, so the whole schedule evaluates INSIDE the jitted
+    step (the iteration counter feeds straight in as a host scalar; no
+    host round-trip, no recompiles as the iteration advances)."""
     return learning_rate(p, it), momentum(p, it)
 
 

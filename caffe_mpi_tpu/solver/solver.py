@@ -290,18 +290,14 @@ class Solver:
         self._sync_test = on_cpu and any(map(_has_cb, self.test_nets))
         self._loss_window = deque(maxlen=max(sp.average_loss, 1))
         self._step_jit = None
-        self._multi_step_jit = None
-        self._feed_queue = None
-        self._compiled_chunks: set[int] = set()
         self._gpipe_clip_scale = None
         # host-dispatch telemetry: dispatch_count = train-step program
-        # launches (what the K-step fused mode exists to shrink — each
-        # dispatch costs host time the device may sit idle for);
-        # host_sync_count = display-boundary host materializations (one
-        # per display line; the smoothed-loss and rate float()s block on
-        # the same chunk). The benchmark reads both deltas over its timed
-        # window (benchmarks/layer_metrics/dispatches_per_100_iters.py,
-        # host_syncs_per_100_iters.py).
+        # launches (one an iteration; each costs host time the device may
+        # sit idle for); host_sync_count = display-boundary host
+        # materializations (one per display line; the smoothed-loss and
+        # rate float()s block on the same iteration). The benchmark reads
+        # both deltas over its timed window (benchmarks/layer_metrics/
+        # dispatches_per_100_iters.py, host_syncs_per_100_iters.py).
         self.dispatch_count = 0
         self.host_sync_count = 0
         # evaluation telemetry (ISSUE 2): test_dispatch_count = eval
@@ -341,13 +337,12 @@ class Solver:
         # self-healing state (ISSUE 4): the on-device non-finite guard.
         # _gstate is the guard carry (skip counter, consecutive-skip
         # counter, longest-burst-this-dispatch, last-bad-iteration,
-        # loss EMA) — five device scalars threaded through both train
-        # entry points when train_guard is on; _guard_prev defers the
-        # host-side divergence check by one
-        # dispatch so the async pipeline never blocks on the chunk it
-        # just launched. skipped_steps / guard_sync_count are host
-        # counters the benchmark reads (benchmarks/drivers/train.py:
-        # skipped steps count as `failed`).
+        # loss EMA) — five device scalars threaded through the train step
+        # when train_guard is on; _guard_prev defers the host-side
+        # divergence check by one dispatch so the async pipeline never
+        # blocks on the iteration it just launched. skipped_steps /
+        # guard_sync_count are host counters the benchmark reads
+        # (benchmarks/drivers/train.py: skipped steps count as `failed`).
         # dynamic loss scaling (ISSUE 9) reuses the guard machinery: the
         # skip-step select is how an overflowed step is discarded, and
         # the scale/clean-window counters ride the same carry — so a
@@ -373,7 +368,6 @@ class Solver:
         self.overflow_steps = 0
         self.loss_scale_value = (_LS_INIT if self._dyn_scale
                                  else float(self._static_scale))
-        self._fault_feed_cache: tuple | None = None
         self._grad_transform = grad_transform
         # decls (lr_mult/decay_mult per param) in pytree-congruent form
         self._decls = {
@@ -652,24 +646,20 @@ class Solver:
     # ------------------------------------------------------------------
     def _iteration_fn(self, plain: bool = False):
         """The pure single-iteration training body
-            (params, net_state, opt_state, feeds_stack, it, rng)
+            (params, net_state, opt_state, feeds, it, base_rng)
               -> (params, net_state, opt_state, loss, rate)
-        traced in BOTH entry points: as the whole program of the classic
-        one-dispatch-per-iteration path (_build_step) and as the
-        `lax.scan` body of the K-step fused program (_build_multi_step).
-        One definition means the two modes are numerically the same
-        computation — the equivalence suite (tests/test_multistep.py)
-        holds them to f32 tolerance.
+        that `_build_step` jits: with `plain` it is everything an
+        iteration needs besides the feed, so the host launches that one
+        program and nothing else. `base_rng` is the solver's base key,
+        folded with `it + 1` inside, and at iter_size 1 the feeds are
+        what `feed_fn` returned, leaves (B, ...). The leading axis is
+        added only when iter_size > 1, where a scan runs over it: on a
+        device array `x[None]` is no view but a program that copies the
+        batch.
 
-        The default form is the scan's: `feeds_stack` leaves are
-        [iter_size, B, ...] and `rng` is the iteration's key. With
-        `plain` it is everything an iteration needs besides the feed, so
-        the host launches that one program and nothing else: `rng` is
-        the solver's base key, folded with `it + 1` inside exactly as
-        the scan body folds it, and at iter_size 1 the feeds are what
-        `feed_fn` returned, leaves (B, ...). The leading axis is added
-        only when iter_size > 1, where a scan runs over it: on a device
-        array `x[None]` is no view but a program that copies the batch.
+        The default form takes feeds [iter_size, B, ...] and the
+        iteration's key already folded; only benchmarks/aot_check.py and
+        tests/test_solver_step_programs.py lower it.
 
         With `train_guard` on (ISSUE 4) the signature grows a trailing
         guard-carry dict and return: after the update is computed, an
@@ -787,9 +777,9 @@ class Solver:
                                       sp.clip_gradients / gnorm, 1.0)
                     grads = jax.tree.map(lambda g: g * scale, grads)
 
-                # iteration-dependent LR/momentum from the (possibly carried)
-                # iteration scalar — the whole schedule lives on device, so a
-                # K-step chunk can cross an lr_policy step boundary mid-scan
+                # iteration-dependent LR/momentum from the iteration
+                # scalar — the whole schedule lives on device, so an
+                # lr_policy step boundary compiles nothing new
                 rate, mom = lr_policy.schedule(sp, it)
                 hyper = Hyper(rate=rate, momentum=mom, momentum2=sp.momentum2,
                               delta=sp.delta, weight_decay=sp.weight_decay,
@@ -913,7 +903,7 @@ class Solver:
                     # is safe because reaching M always exits — there
                     # is no "after" in which a stale maximum could
                     # re-trip — and it lets the host check lazily
-                    # (rate-limited at K=1) without missing bursts.
+                    # (rate-limited) without missing bursts.
                     "max_consec": jnp.maximum(gs["max_consec"], consec),
                     "last_bad": jnp.where(ok, gs["last_bad"],
                                           it_b).astype(jnp.int32),
@@ -988,9 +978,9 @@ class Solver:
                 return body(params, net_state, opt_state, feeds, it, rng,
                             gstate)
         elif iter_size == 1:
+            # the stacked form, read by benchmarks/aot_check.py
             def step(params, net_state, opt_state, feeds_stack, it, rng,
                      gstate=None):
-                # the scan's slice of [K, 1, B, ...] keeps its unit axis
                 feeds = jax.tree.map(lambda x: x[0], feeds_stack)
                 return body(params, net_state, opt_state, feeds, it, rng,
                             gstate)
@@ -1025,144 +1015,6 @@ class Solver:
         with spans.phase("solver/jit"):
             return jax.jit(self._iteration_fn(plain=True),
                            donate_argnums=self._train_donate_argnums())
-
-    def _build_multi_step(self):
-        """K-step fused training program: ONE jitted `lax.scan` runs K
-        full iterations — forward, backward, update, LR policy, gradient
-        clipping — over a device-resident super-batch whose leaves are
-        [K, iter_size, B, ...]. Params/optimizer/net state are donated
-        into the program and carried through the scan entirely in HBM;
-        per-iteration RNG keys fold_in from the carried iteration counter
-        exactly like the plain step's program at K=1. The host pays one
-        dispatch per K iterations, and gets the
-        per-iteration losses and learning rates back as [K] device
-        arrays — the whole-loop-on-TPU strategy (arXiv:1810.09868) in
-        place of the reference's overlap-by-threads (parallel.cpp)."""
-        with spans.phase("solver/jit"):
-            return self._jit_multi_step(self._iteration_fn())
-
-    def _jit_multi_step(self, body):
-
-        if self._guard_on:
-            # guard mode: the 5-scalar guard state rides in the scan
-            # carry exactly like params — zero extra dispatches, and the
-            # per-step skip decision never leaves HBM
-            def multi_step(params, net_state, opt_state, feeds_super, it0,
-                           base_rng, gstate):
-                def scan_body(carry, feeds_stack):
-                    p, s, o, it, gs = carry
-                    rng = jax.random.fold_in(base_rng, it + 1)
-                    p, s, o, loss, rate, gs = body(p, s, o, feeds_stack,
-                                                   it, rng, gs)
-                    return (p, s, o, it + 1, gs), (loss, rate)
-
-                ((params, net_state, opt_state, _, gstate),
-                 (losses, rates)) = jax.lax.scan(
-                    scan_body, (params, net_state, opt_state, it0, gstate),
-                    feeds_super)
-                return params, net_state, opt_state, losses, rates, gstate
-
-            return jax.jit(multi_step,
-                           donate_argnums=self._train_donate_argnums())
-
-        def multi_step(params, net_state, opt_state, feeds_super, it0,
-                       base_rng):
-            def scan_body(carry, feeds_stack):
-                p, s, o, it = carry
-                rng = jax.random.fold_in(base_rng, it + 1)
-                p, s, o, loss, rate = body(p, s, o, feeds_stack, it, rng)
-                return (p, s, o, it + 1), (loss, rate)
-
-            (params, net_state, opt_state, _), (losses, rates) = jax.lax.scan(
-                scan_body, (params, net_state, opt_state, it0), feeds_super)
-            return params, net_state, opt_state, losses, rates
-
-        return jax.jit(multi_step,
-                       donate_argnums=self._train_donate_argnums())
-
-    # ------------------------------------------------------------------
-    def _chunk_at(self, it: int, n: int, testing: bool = True) -> int:
-        """Fused-chunk length starting at iteration `it` with `n` left:
-        min(step_chunk, distance to the next host-visible event). Display
-        fires AFTER its iteration (the chunk may end ON it), a test pass
-        runs BEFORE its iteration (the chunk must stop just short), and a
-        snapshot fires after the iteration preceding a multiple (the
-        chunk ends exactly there, so snapshot/resume round-trips at chunk
-        boundaries are byte-identical to K=1). testing=False (no test
-        feeds supplied to step()) lifts the test_interval cap — a
-        configured-but-unused interval must not silently clip fusion."""
-        sp = self.sp
-        k = max(int(getattr(sp, "step_chunk", 1) or 1), 1)
-        if k <= 1 or self.gpipe is not None or self._sync_steps:
-            # gpipe owns its own MPMD wavefront; host-callback nets on the
-            # CPU backend must sync every program (see __init__) — both
-            # keep the classic per-iteration dispatch
-            return 1
-        c = min(n, k)
-        if sp.display:
-            c = min(c, (-it) % sp.display + 1)
-        if sp.test_interval and testing:
-            c = min(c, sp.test_interval - it % sp.test_interval)
-        if sp.snapshot:
-            c = min(c, sp.snapshot - it % sp.snapshot)
-        return max(c, 1)
-
-    def _scan_chunk(self, feed_fn, c: int, n: int, testing: bool = True):
-        """Dispatch one fused c-iteration chunk; returns ([c] losses,
-        [c] rates) as device arrays. The device feed queue assembles and
-        device_puts the NEXT super-batch in a worker thread while this
-        chunk computes (double buffering), hinted with the next chunk
-        length so prefetch follows the event-boundary schedule."""
-        if self._multi_step_jit is None:
-            self._multi_step_jit = self._build_multi_step()
-        if c not in self._compiled_chunks:
-            # scan length is static: each DISTINCT chunk length is its
-            # own XLA program. The length set is small and cyclic (K plus
-            # the event-boundary remainders), so compiles amortize — but
-            # announce them, or a mid-training compile stall looks
-            # like a hang. Pick K dividing display/test_interval/
-            # snapshot to avoid the extras entirely.
-            self._compiled_chunks.add(c)
-            log.info("compiling fused %d-step train program (distinct "
-                     "chunk lengths so far: %s)", c,
-                     sorted(self._compiled_chunks))
-        queue = self._feed_queue
-        if queue is None or queue.feed_fn is not feed_fn:
-            if queue is not None:
-                queue.close()
-            from ..data.feeder import DeviceFeedQueue
-            place = None
-            if self.mesh is not None:
-                # super-batch leaves are [K, iter_size, B, ...]: the
-                # global batch axis (2) shards over 'data', K/iter_size
-                # stay replicated scan/accumulation dims
-                place = lambda t: self.mesh.shard_feeds(t, batch_axis=2)
-            queue = DeviceFeedQueue(feed_fn,
-                                    iter_size=max(self.sp.iter_size, 1),
-                                    place=place)
-            self._feed_queue = queue
-        hint = None
-        if n - c > 0:
-            c2 = self._chunk_at(self.iter + c, n - c, testing)
-            if c2 > 1:
-                hint = (self.iter + c, c2)
-        with self._guard("feed wait"):
-            feeds_super = queue.get(self.iter, c, hint=hint)
-        with self._guard("train dispatch"):
-            it0 = jnp.int32(self.iter)
-            FAULTS.maybe_stall("dispatch_stall")
-            if self._guard_on:
-                (self.params, self.net_state, self.opt_state, losses,
-                 rates, self._gstate) = self._multi_step_jit(
-                    self.params, self.net_state, self.opt_state,
-                    feeds_super, it0, self.base_rng, self._gstate)
-            else:
-                (self.params, self.net_state, self.opt_state, losses,
-                 rates) = self._multi_step_jit(
-                    self.params, self.net_state, self.opt_state,
-                    feeds_super, it0, self.base_rng)
-        self.dispatch_count += 1
-        return losses, rates
 
     # ------------------------------------------------------------------
     # GPipe mode: the train step is the MPMD wavefront in
@@ -1365,25 +1217,10 @@ class Solver:
     # ------------------------------------------------------------------
     # Self-healing training (ISSUE 4): host side of the on-device guard.
 
-    # classic K=1 mode checks the guard counters every Nth dispatch
-    # (each check is a device_get = one host sync); fused chunks check
-    # every boundary. Detection latency is bounded by N iterations.
+    # the guard counters are checked every Nth dispatch (each check is
+    # a device_get = one host sync). Detection latency is bounded by N
+    # iterations.
     _GUARD_CHECK_EVERY = 16
-
-    def _fault_feed(self, feed_fn):
-        """Identity-cached FAULTS.wrap_feeds: one tuple check per
-        step() call when faults are off, and a stable wrapper identity
-        when they are on (the device feed queue re-keys on feed_fn).
-        Keyed on FAULTS.generation too, so reconfiguring the fault
-        plane between step() calls invalidates the cache instead of
-        silently returning the unwrapped (or stale-wrapped) fn."""
-        cached = self._fault_feed_cache
-        if cached is not None and cached[0] is feed_fn \
-                and cached[1] == FAULTS.generation:
-            return cached[2]
-        wrapped = FAULTS.wrap_feeds(feed_fn)
-        self._fault_feed_cache = (feed_fn, FAULTS.generation, wrapped)
-        return wrapped
 
     def _guard_state0(self) -> dict:
         """Fresh guard carry: no skips, no consecutive run, no bad
@@ -1404,7 +1241,7 @@ class Solver:
 
     def _check_guard(self, boundary_iter: int, gstate) -> None:
         """Materialize the guard counters of the dispatch that ended at
-        `boundary_iter` (a chunk-boundary host read — the only host
+        `boundary_iter` (a rate-limited host read — the only host
         traffic the guard adds) and apply the divergence policy:
         guard_max_skips consecutive skips journals the anomaly to
         `<prefix>.run.json` and raises NumericAnomalyError, which the
@@ -1412,7 +1249,7 @@ class Solver:
         if gstate is None:
             return
         with self._guard("guard check"):
-            # chunk boundary: 5 scalars, one transfer — not per-iteration
+            # 5 scalars, one transfer — not per-iteration
             vals = jax.device_get(gstate)
         # max_consec = longest burst seen over the RUN (monotone in the
         # carry; reset only by restore()): a >=M run that recovered
@@ -1432,15 +1269,15 @@ class Solver:
             scale = float(vals["scale"])
             if overflows > self.overflow_steps and self.rank == 0:
                 log.warning(
-                    "loss scale: %d overflow step(s) so far (+%d this "
-                    "chunk), skipped and rescaled — scale now %g",
+                    "loss scale: %d overflow step(s) so far (+%d since "
+                    "the last check), skipped and rescaled — scale now %g",
                     overflows, overflows - self.overflow_steps, scale)
             self.overflow_steps = overflows
             self.loss_scale_value = scale
         if skips > self.skipped_steps and self.rank == 0:
             log.warning(
-                "train guard: %d skipped step(s) so far (+%d this chunk, "
-                "last bad iteration %d, %d consecutive)", skips,
+                "train guard: %d skipped step(s) so far (+%d since the "
+                "last check, last bad iteration %d, %d consecutive)", skips,
                 skips - self.skipped_steps, last_bad, consec)
         self.skipped_steps = skips
         m = int(getattr(self.sp, "guard_max_skips", 0) or 0)
@@ -1544,11 +1381,8 @@ class Solver:
             self._step_jit = self._build_step()
         self._ensure_watchdog()
         # ISSUE 4 fault sites nan_grad/loss_spike poison feed batches;
-        # wrap_feeds returns feed_fn UNCHANGED when neither is
-        # configured, and the wrapper is cached so its identity is
-        # stable across step() calls (the device feed queue keys its
-        # worker on feed_fn identity)
-        feed_fn = self._fault_feed(feed_fn)
+        # wrap_feeds returns feed_fn UNCHANGED when neither is configured
+        feed_fn = FAULTS.wrap_feeds(feed_fn)
         if self._guard_on and self._gstate is None:
             self._gstate = self._guard_state0()
         sp = self.sp
@@ -1567,57 +1401,46 @@ class Solver:
                         and test_feed_fns):
                     # asynchronous evaluation: drain the previous pass (its
                     # scores are certainly computed by now — its programs
-                    # preceded a full test_interval of train chunks), then
+                    # preceded a full test_interval of train steps), then
                     # dispatch this one and resume training immediately; the
-                    # device runs the eval between train chunks
+                    # device runs the eval between train steps
                     self._harvest_eval()
                     with spans.span("solver/eval dispatch"):
                         self._start_eval(test_feed_fns)
-                c = 1
                 if self.gpipe is not None:
                     with self._guard("train dispatch"):
                         loss, rate = self._gpipe_iteration(feed_fn)
-                    self.dispatch_count += 1
                 else:
-                    testing = bool(test_feed_fns)
-                    c = self._chunk_at(self.iter, n, testing)
-                    if c > 1:
-                        # K-step fused path: one dispatch covers c iterations
-                        losses, rates = self._scan_chunk(feed_fn, c, n,
-                                                         testing)
-                        loss, rate = losses[-1], rates[-1]
-                    else:
-                        # feed assembly + host->device transfer are watchdog
-                        # sections too: a hung runtime blocks inside the
-                        # jnp.asarray/shard_feeds C++ transfer exactly like a
-                        # dispatch (the fused path guards queue.get the same
-                        # way)
-                        with self._guard("feed wait"):
-                            feeds = self._place_feeds(
-                                [feed_fn(self.iter * iter_size + k)
-                                 for k in range(iter_size)])
-                        with self._guard("train dispatch"):
-                            # the one launch of the iteration: its key is
-                            # folded inside the program, and the counter
-                            # goes in as a host scalar (4 bytes moved, no
-                            # program of its own)
-                            it = np.int32(self.iter)
-                            FAULTS.maybe_stall("dispatch_stall")
-                            if self._guard_on:
-                                (self.params, self.net_state, self.opt_state,
-                                 loss, rate, self._gstate) = self._step_jit(
-                                    self.params, self.net_state,
-                                    self.opt_state, feeds, it,
-                                    self.base_rng, self._gstate)
-                            else:
-                                (self.params, self.net_state, self.opt_state,
-                                 loss, rate) = self._step_jit(
-                                    self.params, self.net_state,
-                                    self.opt_state, feeds, it,
-                                    self.base_rng)
-                        self.dispatch_count += 1
+                    # feed assembly + host->device transfer are watchdog
+                    # sections too: a hung runtime blocks inside the
+                    # jnp.asarray/shard_feeds C++ transfer exactly like a
+                    # dispatch
+                    with self._guard("feed wait"):
+                        feeds = self._place_feeds(
+                            [feed_fn(self.iter * iter_size + k)
+                             for k in range(iter_size)])
+                    with self._guard("train dispatch"):
+                        # the one launch of the iteration: its key is
+                        # folded inside the program, and the counter goes
+                        # in as a host scalar (4 bytes moved, no program
+                        # of its own)
+                        it = np.int32(self.iter)
+                        FAULTS.maybe_stall("dispatch_stall")
+                        if self._guard_on:
+                            (self.params, self.net_state, self.opt_state,
+                             loss, rate, self._gstate) = self._step_jit(
+                                self.params, self.net_state,
+                                self.opt_state, feeds, it,
+                                self.base_rng, self._gstate)
+                        else:
+                            (self.params, self.net_state, self.opt_state,
+                             loss, rate) = self._step_jit(
+                                self.params, self.net_state,
+                                self.opt_state, feeds, it,
+                                self.base_rng)
+                self.dispatch_count += 1
                 # feed any in-flight eval pass the chunks whose super-batches
-                # the worker finished while this train chunk dispatched —
+                # the worker finished while this iteration dispatched —
                 # non-blocking, so eval assembly never stalls training
                 self._continue_eval()
                 if self._sync_steps:
@@ -1628,16 +1451,8 @@ class Solver:
                 # PCIe; over a remote TPU link it would serialize the
                 # pipeline). Materialize only at display boundaries.
                 last_loss = loss
-                if c == 1:
-                    self._loss_window.append(loss)
-                else:
-                    # only the slices that can survive the window are worth a
-                    # (lazy, async) device gather op
-                    w = self._loss_window.maxlen or 1
-                    for k in range(max(0, c - w), c):
-                        self._loss_window.append(losses[k])
-                last_iter = self.iter + c - 1  # chunk ends ON display iters
-                if (sp.display and last_iter % sp.display == 0
+                self._loss_window.append(loss)
+                if (sp.display and self.iter % sp.display == 0
                         and self.rank == 0):
                     with self._guard("display sync"):
                         # one transfer of the window's scalars, summed on
@@ -1647,22 +1462,22 @@ class Solver:
                                 self._loss_window)
                     self.host_sync_count += 1
                     elapsed = time.time() - t0
-                    ips = ((last_iter - it0 + 1) * imgs_per_iter / elapsed
+                    done = self.iter - it0 + 1
+                    ips = (done * imgs_per_iter / elapsed
                            if elapsed > 0 else 0.0)
                     log.info("Iteration %d (%.4g iter/s, %.1f img/s), "
                              "loss = %.6g, "
-                             "lr = %.6g", last_iter,  # host-sync: ok (display)
-                             (last_iter - it0 + 1) / max(elapsed, 1e-9), ips,
+                             "lr = %.6g", self.iter,  # host-sync: ok (display)
+                             done / max(elapsed, 1e-9), ips,
                              smoothed, float(rate))
-                self.iter += c
-                n -= c
+                self.iter += 1
+                n -= 1
                 if self._guard_on:
                     # deferred divergence check: materialize a PREVIOUS
                     # dispatch's guard counters now that this one is in
                     # flight — the read blocks on a program that has almost
-                    # certainly retired, so the pipeline stays full. At
-                    # K>1 every chunk boundary checks; at K=1 a per-
-                    # iteration device_get would cost one host sync per
+                    # certainly retired, so the pipeline stays full. A
+                    # per-iteration device_get would cost one host sync per
                     # iteration, so checks rate-limit to every
                     # _GUARD_CHECK_EVERY dispatches — safe, because the
                     # carried counters (skips, consec, monotone max_consec)
@@ -1671,8 +1486,7 @@ class Solver:
                     prev, self._guard_prev = (self._guard_prev,
                                               (self.iter - 1, self._gstate))
                     self._guard_unchecked += 1
-                    if prev is not None and (
-                            c > 1 or self._guard_unchecked
+                    if (prev is not None and self._guard_unchecked
                             >= self._GUARD_CHECK_EVERY):
                         self._guard_unchecked = 0
                         self._check_guard(*prev)
@@ -1682,17 +1496,17 @@ class Solver:
                         and (n > 0 or self.iter < sp.max_iter)):
                     # the next loop pass (or next step() call) starts an
                     # eval here: warm its first test super-batch while the
-                    # chunk that just dispatched computes. At max_iter no
+                    # iteration that just dispatched computes. At max_iter no
                     # eval can follow — don't assemble a super-batch nobody
                     # will consume (it would pin HBM until close())
                     self._prefetch_test_feeds(test_feed_fns)
                 if sp.snapshot and self.iter % sp.snapshot == 0:
                     if self._guard_on and self._guard_prev is not None:
                         # the snapshot at this boundary becomes the rewind
-                        # target: the chunk that just ended must pass its
-                        # divergence check FIRST, or a >=M burst inside it
-                        # gets sealed into a verified snapshot that the
-                        # supervisor then rewinds to — skipping the
+                        # target: the dispatches since the last check must
+                        # pass their divergence check FIRST, or a >=M burst
+                        # among them gets sealed into a verified snapshot
+                        # that the supervisor then rewinds to — skipping the
                         # divergent region instead of replaying it
                         # (iteration-exactness lost). The extra host read
                         # is snapshot-rate, and snapshot() blocks on this
@@ -1709,21 +1523,21 @@ class Solver:
                     # writer swallows raises into _snapshot_error.
                     self._maybe_admit_rejoin()
         if self._guard_on and self._guard_prev is not None:
-            # drain the deferred check so a divergence inside THIS call's
-            # final chunk surfaces before step() returns
+            # drain the deferred check so a divergence in THIS call's
+            # final iterations surfaces before step() returns
             prev, self._guard_prev = self._guard_prev, None
             self._check_guard(*prev)
         # a pass dispatched at the final boundary must land before step()
         # returns (step's contract is "n iterations ran, events fired");
-        # by now the eval programs sit ahead of the last train chunks in
+        # by now the eval programs sit ahead of the last train steps in
         # device order, so this wait is dispatch drain, not the pass
         self._harvest_eval()
         return float(last_loss) if last_loss is not None else float("nan")
 
     def close(self) -> None:
         """Release host-side training resources: joins in-flight async
-        snapshots and shuts down the device feed queue's worker thread
-        (harmless if the fused path never ran). Long-lived processes that
+        snapshots and shuts down the test feed queues' worker threads
+        (harmless if no evaluation ran). Long-lived processes that
         construct many Solvers should call this; training results are
         unaffected either way. A failed async snapshot still re-raises
         (wait_snapshots), but worker threads and the watchdog are
@@ -1740,9 +1554,6 @@ class Solver:
                 self._pending_eval = None
                 log.warning("dropping un-harvested evaluation pass at "
                             "close")
-            if self._feed_queue is not None:
-                self._feed_queue.close()
-                self._feed_queue = None
             for q in self._test_feed_queues.values():
                 q.close()
             self._test_feed_queues.clear()
@@ -1784,14 +1595,14 @@ class Solver:
     # training stalled for the whole pass. Now one jitted `lax.scan`
     # consumes a [T, B, ...] test super-batch and carries the per-blob
     # sum accumulators in HBM — ceil(test_iter/T) dispatches per pass —
-    # fed by the same DeviceFeedQueue double-buffering as the fused
-    # train loop, and because the accumulator is the scan carry AND the
+    # fed by DeviceFeedQueue double-buffering, and because the
+    # accumulator is the scan carry AND the
     # program's acc0 input, chunks chain across dispatches with zero
     # extra combine work, in exactly the classic loop's addition order
     # (CPU-bitwise; tests/test_fused_eval.py). At an in-training test
     # boundary the solver takes a cheap on-device copy of the shared
-    # param view (the fused train step DONATES those buffers), dispatches
-    # the eval scan, and resumes dispatching train chunks immediately;
+    # param view (the train step DONATES those buffers), dispatches
+    # the eval scan, and resumes dispatching train steps immediately;
     # the single device->host sync happens at harvest time and the
     # scores log tagged with the iteration they evaluate — the
     # whole-loop-on-accelerator strategy (arXiv:1810.09868) applied to
@@ -1866,7 +1677,7 @@ class Solver:
             elif self.gpipe is not None:
                 dev0 = self.gpipe.devices[0]
                 place = lambda t: jax.device_put(t, dev0)
-            queue = DeviceFeedQueue(feed_fn, iter_size=1, place=place)
+            queue = DeviceFeedQueue(feed_fn, place=place)
             self._test_feed_queues[ti] = queue
         return queue
 
@@ -1921,7 +1732,7 @@ class Solver:
         WITHOUT the device->host sync. On return `self._pending_eval`
         holds per-net continuation records; training dispatch resumes
         immediately, `_continue_eval()` feeds the remaining eval chunks
-        opportunistically between train chunks (dispatching only when
+        opportunistically between train steps (dispatching only when
         the worker thread has their super-batch ready, so the train
         loop never blocks on eval feed assembly), and `_harvest_eval`
         drains + materializes the scores later. The host time spent
@@ -1967,16 +1778,16 @@ class Solver:
                 self.test_pass_count += 1
                 continue
             if not settled:
-                # the boundary train chunk may still be in flight with
+                # the boundary train step may still be in flight with
                 # these buffers mid-donation-handoff; dispatching copies
                 # against that state intermittently SIGABRTs the CPU
                 # client (same hazard, same fix as the async snapshot
                 # capture in snapshot()): settle first. Costs
-                # the tail of one chunk, which the eval had to wait out
+                # the tail of one step, which the eval had to wait out
                 # on device anyway.
                 jax.block_until_ready((tparams, tstate))
                 settled = True
-            # point-in-time copy (HBM->HBM, async): the next train chunk
+            # point-in-time copy (HBM->HBM, async): the next train step
             # donates the live params/state the moment it dispatches
             copy = lambda a: jnp.copy(a) if isinstance(a, jax.Array) else a
             tparams = jax.tree.map(copy, tparams)
@@ -1995,8 +1806,8 @@ class Solver:
                      "iters": iters, "next": 0, "T": T, "queue": queue,
                      "jit": jit, "tparams": tparams, "tstate": tstate}
             # chunk 0 dispatches AT the boundary (its super-batch was
-            # prefetched while the boundary train chunk computed); the
-            # rest follow from _continue_eval between train chunks
+            # prefetched while the boundary train step computed); the
+            # rest follow from _continue_eval between train steps
             self._dispatch_eval_chunk(entry)
             entries.append(entry)
             self.test_pass_count += 1
@@ -2019,10 +1830,10 @@ class Solver:
 
     def _continue_eval(self, block: bool = False) -> None:
         """Advance an in-flight evaluation pass. Non-blocking mode (the
-        per-train-chunk call in step()) dispatches every chunk whose
+        per-iteration call in step()) dispatches every chunk whose
         super-batch the worker thread has ALREADY assembled — eval feed
         assembly hides behind train compute and the dispatches
-        interleave with train chunks. block=True (harvest) drains the
+        interleave with train steps. block=True (harvest) drains the
         rest unconditionally."""
         pending = self._pending_eval
         if pending is None:
@@ -2047,7 +1858,7 @@ class Solver:
         logged tagged with the iteration they evaluate. Returns the
         results list, or None when nothing is pending. Any wait here
         counts as eval stall — it is ~0 when the pass's chunks already
-        dispatched between train chunks, because the eval programs
+        dispatched between train steps, because the eval programs
         precede the later train work in device order."""
         if self._pending_eval is None:
             return None
@@ -2077,9 +1888,10 @@ class Solver:
 
     def _prefetch_test_feeds(self, test_feed_fns) -> None:
         """Warm each test net's first eval super-batch in the feed
-        queue's worker thread — called when the chunk just dispatched
-        ends at a test boundary, so assembly + device_put overlap the
-        chunk's compute and the boundary itself only pays dispatches."""
+        queue's worker thread — called when the iteration just
+        dispatched ends at a test boundary, so assembly + device_put
+        overlap its compute and the boundary itself only pays
+        dispatches."""
         if self._sync_test:
             return
         for ti, tnet in enumerate(self.test_nets):

@@ -72,13 +72,6 @@ def _parser() -> argparse.ArgumentParser:
                            help="override solver test_iter (0 = prototxt)")),
     ]:
         p.add_argument(f"-{flag}", f"--{flag}", **kw)
-    p.add_argument("-step_chunk", "--step_chunk", "--step-chunk",
-                   dest="step_chunk", type=int, default=0,
-                   help="fuse K iterations into ONE on-device lax.scan "
-                   "dispatch (train only; overrides solver step_chunk; "
-                   "0 = prototxt value, which defaults to 1). Chunks "
-                   "auto-align to display/test_interval/snapshot "
-                   "boundaries, so observable behavior is unchanged")
     p.add_argument("-test_chunk", "--test_chunk", "--test-chunk",
                    dest="test_chunk", type=int, default=0,
                    help="fuse T test batches into one evaluation "
@@ -717,8 +710,6 @@ def cmd_train(args) -> int:
         sp.max_iter = args.max_iter
     if args.test_iter:
         sp.test_iter = [args.test_iter] * max(len(sp.test_iter), 1)
-    if args.step_chunk:
-        sp.step_chunk = args.step_chunk
     if args.test_chunk:
         sp.test_chunk = args.test_chunk
     if args.snapshot_prefix:
@@ -941,8 +932,9 @@ def cmd_train(args) -> int:
     t0 = time.time()
     start_iter = solver.iter
     profile = _ProfileSlice(args.profile, solver, sp)
-    # the first chunk traces, lowers and builds the step: the last phase
-    # of start-up, after which the ledger's table is logged, once
+    # the first solver.step call traces, lowers and builds the step: the
+    # last phase of start-up, after which the ledger's table is logged,
+    # once
     first = spans.phase("cli/first step")
     try:
         while solver.iter < sp.max_iter and not state["stop"]:

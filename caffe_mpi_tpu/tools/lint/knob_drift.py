@@ -43,7 +43,6 @@ from . import FileContext, Finding, LintPass, iter_py_files, register
 # required to satisfy all four legs. Extend this tuple when adding a
 # knob (the docs/benchmarks.md section for it is then enforced too).
 KNOBS = (
-    "step_chunk",       # ISSUE 1: K-step fused training
     "test_chunk",       # ISSUE 2: fused async evaluation
     "reduce_overlap",   # ISSUE 6: overlapped bucketed reduction
     "reduce_buckets",   # ISSUE 6: bucket count
@@ -129,7 +128,7 @@ def _reads(attrs, calls) -> set[str]:
 @register
 class KnobDriftPass(LintPass):
     name = "knob-drift"
-    description = ("perf knobs (step_chunk/test_chunk/reduce_*) must be "
+    description = ("perf knobs (test_chunk/reduce_*/serve_*) must be "
                    "declared, CLI-flagged, documented, and CONSUMED — "
                    "no accept-and-ignore")
     self_waiving = True   # applies registry-line waivers itself

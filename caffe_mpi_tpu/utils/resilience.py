@@ -199,10 +199,6 @@ class FaultPlane:
         self._sites: dict[str, dict] = {}
         self._dir = ""
         self._lock = threading.Lock()
-        # bumped on every (re)configure — consumers that cache derived
-        # state (the solver's wrapped feed_fn) key on it so a
-        # reconfiguration mid-run invalidates their cache
-        self.generation = 0
 
     def load_env(self) -> None:
         self.configure(os.environ.get("CAFFE_TPU_FAULTS", ""),
@@ -211,7 +207,6 @@ class FaultPlane:
     def configure(self, spec: str, once_dir: str = "") -> None:
         self._dir = once_dir
         self._sites = {}
-        self.generation += 1
         for entry in (spec or "").split(","):
             entry = entry.strip()
             if not entry:
@@ -314,9 +309,7 @@ class FaultPlane:
         sites (ISSUE 4): float leaves of the batch for micro-iterations
         [arg, arg+count) are overwritten with NaN (nan_grad) or scaled
         1e3x (loss_spike). Returns `feed_fn` UNCHANGED when neither
-        site is configured — the zero-cost-when-off contract (the
-        solver caches the wrapper, so identity matters: a fresh wrapper
-        per step() would churn the device feed queue)."""
+        site is configured — the zero-cost-when-off contract."""
         if not (self.active("nan_grad") or self.active("loss_spike")):
             return feed_fn
         import numpy as np  # deferred: resilience imports at startup

@@ -16,7 +16,7 @@ written only while programs are being built. It holds the phases opened by
 program jax traced, lowered and built, is kept by the listeners of
 utils/compile_cache.py (`programs`). Both stamp `time.perf_counter()`.
 `ledger.table()` prints it; `caffe train` logs that once, after its first
-chunk of steps (docs/profiling.md, "Where start-up time goes"). A phase is
+`solver.step` call (docs/profiling.md, "Where start-up time goes"). A phase is
 for work done once a program or once a run: inside the loop of
 `Solver.step` `span` stays the only tool (tests/test_startup_ledger.py
 holds that).
@@ -57,7 +57,7 @@ to tell them apart.
 | `solver.reduce` | scope | the bucketed gradient psums of `reduce_overlap` (parallel/reduction.py) |
 | `caffe/solver/iter` | step span | one pass of `Solver.step`'s loop (`step_num` = its first iteration) |
 | `caffe/solver/feed wait` | span | batch assembly, re-layout and host-to-device placement |
-| `caffe/solver/train dispatch` | span | launching the train program: one step, and nothing else (its key is folded inside it); a fused chunk with the counter's cast; a GPipe wavefront with its `fold_in` |
+| `caffe/solver/train dispatch` | span | launching the train program: one step, and nothing else (its key is folded inside it); a GPipe wavefront with its `fold_in` |
 | `caffe/solver/step sync` | span | per-program sync of host-callback nets on the CPU backend |
 | `caffe/solver/display sync` | span | device-to-host read of the smoothed loss at a display boundary |
 | `caffe/solver/guard check` | span | read of the skip-step guard's counters |
@@ -73,9 +73,9 @@ to tell them apart.
 | `caffe/solver/opt state` | phase | the optimizer's zeroed slots, inside `solver/build` |
 | `caffe/solver/place` | phase | `Solver._place_params_opt` and the state's replication: mesh replicate / shardings, stage placement |
 | `caffe/solver/restore` | phase | `Solver.restore`, `restore_native`, `load_weights`: a snapshot or weights read and placed |
-| `caffe/solver/jit` | phase | `Solver._build_step` / `_build_multi_step`: building the step's function and its `jax.jit` wrapper (tracing waits for the first call) |
+| `caffe/solver/jit` | phase | `Solver._build_step`: building the step's function and its `jax.jit` wrapper (tracing waits for the first call) |
 | `caffe/cli/feeders` | phase | `cmd_train`: the train and test feeders (or the synthetic batch) |
-| `caffe/cli/first step` | phase | `cmd_train`: its first `solver.step` chunk, which traces, lowers and builds the step |
+| `caffe/cli/first step` | phase | `cmd_train`: its first `solver.step` call, which traces, lowers and builds the step |
 | `caffe/trace/kernel` | phase | ops/pallas_call.py `call`, ops/moe.py: one trace of a Pallas kernel's caller; `kernel`, and `branch`, the arm of `lax.platform_dependent` being traced (`cpu`: the interpreter, `default`: Mosaic). Trace time only: a compiled step never reaches it |
 """
 
@@ -107,7 +107,7 @@ MOE_FALLBACK = "moe.fallback"
 REDUCE = "solver.reduce"
 ITER = "solver/iter"
 KERNEL = "trace/kernel"
-STEP_PROGRAMS = ("step", "multi_step")   # `Solver`'s two, by their rows' names
+STEP_PROGRAM = "step"   # `Solver`'s, by its row's name
 PHASE_CAP = 4096
 _SCOPE = re.compile(r"caffe\.([A-Za-z0-9_]+)\.([A-Za-z0-9_.~%-]*)")
 _clock = time.perf_counter
@@ -308,15 +308,14 @@ class Ledger:
         for name in ("solver/restore", "cli/feeders", "cli/first step"):
             if self.outermost(name):
                 line(name, self.seconds(name))
-        for name in STEP_PROGRAMS:
-            row = programs.rows.get(name)
-            if row is not None:
-                how = (f"{row.retrieval_s:.3f} of it the load from the "
-                       f"cache" if row.hits else "the compile")
-                line(f"program `{name}`",
-                     row.trace_s + row.lower_s + row.backend_s,
-                     f"trace {row.trace_s:.3f}, lower {row.lower_s:.3f}, "
-                     f"backend {row.backend_s:.3f} ({how})")
+        row = programs.rows.get(STEP_PROGRAM)
+        if row is not None:
+            how = (f"{row.retrieval_s:.3f} of it the load from the "
+                   f"cache" if row.hits else "the compile")
+            line(f"program `{STEP_PROGRAM}`",
+                 row.trace_s + row.lower_s + row.backend_s,
+                 f"trace {row.trace_s:.3f}, lower {row.lower_s:.3f}, "
+                 f"backend {row.backend_s:.3f} ({how})")
         sums = programs.sums()
         line("programs", sums["trace_s"] + sums["lower_s"]
              + sums["backend_s"],

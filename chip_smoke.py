@@ -106,8 +106,6 @@ def leg_train(*, precision: str = "", gpu_all: bool = False,
     n_dev = len(jax.devices())
     require(not gpu_all or n_dev >= DP_DEVICES,
             f"this leg needs >= {DP_DEVICES} devices, jax found {n_dev}")
-    require("CAFFE_LRN_PALLAS" not in os.environ,
-            "CAFFE_LRN_PALLAS is set: the smoke runs the shipped routing")
     cache_dir = enable_compile_cache()
     cache_before = _cache_entries(cache_dir)
 

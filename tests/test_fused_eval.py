@@ -178,12 +178,10 @@ class TestAsyncInTraining:
         assert [a_[2] for a_ in logged] == want  # bitwise
 
     def test_async_eval_does_not_perturb_training(self):
-        """With step_chunk dividing test_interval the chunk schedule is
-        identical with and without test feeds — so params must be
-        BITWISE identical: the async eval copies its param view and
-        never touches train state."""
-        cfg = "test_iter: 3 test_interval: 4 step_chunk: 2 " \
-              "test_initialization: false "
+        """Training dispatches the same programs with and without test
+        feeds — so params must be BITWISE identical: the async eval
+        copies its param view and never touches train state."""
+        cfg = "test_iter: 3 test_interval: 4 test_initialization: false "
         a = make_solver(cfg)
         b = make_solver(cfg)
         train, test = cls_feed(0), cls_feed(5000)
@@ -201,7 +199,7 @@ class TestAsyncInTraining:
 
     def test_boundary_dispatches_only_first_chunk(self):
         """_start_eval returns after chunk 0: the remaining chunks
-        dispatch from _continue_eval between train chunks (or at
+        dispatch from _continue_eval between train steps (or at
         harvest), so the boundary stall is one dispatch + the param
         copy, not the pass."""
         s = make_solver("test_iter: 6 test_chunk: 2")
@@ -250,11 +248,11 @@ class TestAsyncInTraining:
         assert s.eval_stall_ms > 0.0
 
     def test_snapshot_resume_across_test_boundary(self, tmp_path):
-        """snapshot at 6 with a test boundary at 4 and step_chunk 4:
-        resuming must continue the uninterrupted trajectory and the
-        post-resume evals must match the classic oracle."""
+        """snapshot at 6 with a test boundary at 4: resuming must
+        continue the uninterrupted trajectory and the post-resume evals
+        must match the classic oracle."""
         cfg = ('type: "Adam" test_iter: 2 test_interval: 4 snapshot: 6 '
-               'test_initialization: false step_chunk: 4 ')
+               'test_initialization: false ')
         train, test = cls_feed(0), cls_feed(5000)
         a = make_solver(cfg)
         a.sp.snapshot_prefix = str(tmp_path / "fe")
@@ -366,7 +364,7 @@ class TestFeedQueuePrefetch:
             calls.append(it)
             return {"x": np.full((4, 3), it, np.float32)}
 
-        q = DeviceFeedQueue(feed, iter_size=1)
+        q = DeviceFeedQueue(feed)
         try:
             q.prefetch(0, 3)
             q._pending[(0, 3)].result()  # worker built it

@@ -1,18 +1,8 @@
-"""K-step fused training (solver step_chunk > 1): one jitted lax.scan
-program runs K full iterations over a device-resident super-batch, one
-host dispatch per chunk instead of per iteration.
-
-The contract under test: `step_chunk=K` is an EXECUTION-SCHEDULE knob,
-not a semantics knob — params, optimizer state, per-iteration losses,
-LR schedule evaluation, event timing (display/test/snapshot), and
-snapshot/resume trajectories must all match the classic K=1 path within
-f32 tolerance. Covers the ISSUE-1 acceptance matrix: chunk boundaries
-straddling display/test_interval/snapshot, lr_policy step changes
-mid-chunk, clip_gradients active, iter_size accumulation, and the
-dispatch-count reduction itself.
+"""The train loop's host-visible schedule: one `jit_step` dispatch an
+iteration, display lines, test passes and snapshots at their iterations,
+a resumed run on the uninterrupted trajectory, `step()`'s return value;
+plus the device feed queue fused evaluation reads from.
 """
-
-import os
 
 import numpy as np
 import jax
@@ -63,10 +53,6 @@ def lsq_data(rng, n=32):
     return out
 
 
-def window_losses(solver) -> np.ndarray:
-    return np.array([float(jnp.asarray(l)) for l in solver._loss_window])
-
-
 def assert_same_training(a: Solver, b: Solver, rtol=1e-5, atol=1e-6):
     assert a.iter == b.iter
     for ln in a.params:
@@ -83,191 +69,92 @@ def assert_same_training(a: Solver, b: Solver, rtol=1e-5, atol=1e-6):
                     err_msg=f"opt {ln}/{pn}[{si}]")
 
 
-class TestEquivalence:
-    """step_chunk=K training == step_chunk=1 training, f32 tolerance."""
-
-    def test_sgd_lr_step_mid_chunk(self, rng):
-        """lr_policy 'step' drops the rate at iters 6 and 13 — inside a
-        K=7 chunk, never on its boundary — so the carried iteration
-        counter must drive the schedule correctly from INSIDE the scan."""
-        data = lsq_data(rng)
-        cfg = ('type: "SGD" momentum: 0.9 lr_policy: "multistep" '
-               'gamma: 0.5 stepvalue: 6 stepvalue: 13 average_loss: 20')
-        a = make_solver(cfg)
-        b = make_solver(cfg + " step_chunk: 7")
-        a.step(20, lambda it: data[it % 32])
-        b.step(20, lambda it: data[it % 32])
-        assert a.dispatch_count == 20
-        assert b.dispatch_count == 3  # chunks 7 + 7 + 6
-        assert_same_training(a, b)
-        # per-iteration losses agree (window sized to hold all 20)
-        np.testing.assert_allclose(window_losses(a), window_losses(b),
-                                   rtol=1e-5, atol=1e-6)
-
-    def test_clip_gradients_and_iter_size(self, rng):
-        """grad-norm clipping is a device scalar inside the scan; the
-        iter_size micro-accumulation nests inside each scanned step."""
-        data = lsq_data(rng, 64)
-        cfg = ('type: "SGD" momentum: 0.9 clip_gradients: 0.05 '
-               'iter_size: 2 average_loss: 12')
-        a = make_solver(cfg)
-        b = make_solver(cfg + " step_chunk: 4")
-        a.step(12, lambda it: data[it % 64])
-        b.step(12, lambda it: data[it % 64])
-        assert b.dispatch_count == 3
-        assert_same_training(a, b)
-        np.testing.assert_allclose(window_losses(a), window_losses(b),
-                                   rtol=1e-5, atol=1e-6)
-
-    def test_adam_long_run(self, rng):
-        data = lsq_data(rng)
-        cfg = 'type: "Adam" momentum: 0.9 momentum2: 0.999 average_loss: 5'
-        a = make_solver(cfg)
-        b = make_solver(cfg + " step_chunk: 16")
-        a.step(33, lambda it: data[it % 32])
-        b.step(33, lambda it: data[it % 32])
-        assert b.dispatch_count == 3  # 16 + 16 + 1
-        assert_same_training(a, b)
-
-    def test_mesh_data_parallel(self, rng):
-        """K-step scan under the SPMD mesh: super-batch sharded over
-        'data' at axis 2, params carried replicated through the scan."""
-        from caffe_mpi_tpu.parallel import MeshPlan
-        net = LSQ_NET.replace("dim: 4 dim: 3", "dim: 8 dim: 3") \
-                     .replace("dim: 4 dim: 1", "dim: 8 dim: 1")
-        data = []
-        for _ in range(32):
-            x = rng.randn(8, 3).astype(np.float32)
-            t = (x @ np.array([[1.0], [-2.0], [0.5]]) + 0.3).astype(np.float32)
-            data.append({"x": x, "t": t})
-        cfg = 'type: "SGD" momentum: 0.9'
-        sp1 = SolverParameter.from_text(
-            f'base_lr: 0.1 max_iter: 1000 lr_policy: "fixed" display: 0 '
-            f'random_seed: 7\n{cfg}')
-        sp1.net_param = NetParameter.from_text(net)
-        a = Solver(sp1)
-        sp2 = SolverParameter.from_text(
-            f'base_lr: 0.1 max_iter: 1000 lr_policy: "fixed" display: 0 '
-            f'random_seed: 7\n{cfg} step_chunk: 5')
-        sp2.net_param = NetParameter.from_text(net)
-        b = Solver(sp2, mesh=MeshPlan.data_parallel())
-        a.step(10, lambda it: data[it % 32])
-        b.step(10, lambda it: data[it % 32])
-        assert b.dispatch_count == 2
-        assert_same_training(a, b, rtol=1e-4, atol=1e-5)
-
-
 class TestEventBoundaries:
-    """Chunks auto-shrink so display/test/snapshot land exactly where
-    the K=1 schedule puts them."""
-
-    def test_chunk_at_schedule(self):
-        s = make_solver("step_chunk: 64 display: 4 test_interval: 6 "
-                        "snapshot: 10")
-        # display fires AFTER iters 0,4,8..; test BEFORE iters 6,12..;
-        # snapshot after iters 9,19.. (when iter becomes a multiple of 10)
-        assert s._chunk_at(0, 100) == 1    # display after iter 0
-        assert s._chunk_at(1, 100) == 4    # iters 1..4 (display at 4)
-        assert s._chunk_at(5, 100) == 1    # test pass due at iter 6
-        assert s._chunk_at(6, 100) == 3    # iters 6..8 (display at 8)
-        assert s._chunk_at(9, 100) == 1    # snapshot after iter 9
-        assert s._chunk_at(13, 2) == 2     # n caps the chunk
-        s2 = make_solver("step_chunk: 64")
-        assert s2._chunk_at(0, 1000) == 64
-        s3 = make_solver()  # default step_chunk preserves K=1
-        assert s3._chunk_at(0, 1000) == 1
+    """Display, test and snapshot events fire at the iterations the
+    reference's Solver::Step puts them, one dispatch an iteration."""
 
     def test_display_parity(self, rng, caplog):
-        """Smoothed-loss display lines appear at the same iterations with
-        the same values in both modes."""
+        """Display lines appear after iterations 0, 5, 10 and 15, each
+        with the mean of the last `average_loss` iterations' losses."""
         import logging
         data = lsq_data(rng)
         cfg = 'type: "SGD" momentum: 0.9 display: 5 average_loss: 3'
-        records = {}
-        for name, extra in (("k1", ""), ("k8", " step_chunk: 8")):
-            s = make_solver(cfg + extra)
-            with caplog.at_level(logging.INFO, "caffe_mpi_tpu.solver"):
-                caplog.clear()
-                s.step(17, lambda it: data[it % 32])
-            records[name] = [
-                (r.args[0], float(r.args[3]))  # (iteration, smoothed loss)
-                for r in caplog.records if "Iteration" in r.msg]
-        assert [i for i, _ in records["k1"]] == [0, 5, 10, 15]
-        assert [i for i, _ in records["k8"]] == [0, 5, 10, 15]
-        for (i1, l1), (i2, l2) in zip(records["k1"], records["k8"]):
-            assert l1 == pytest.approx(l2, rel=1e-5), f"iter {i1}"
+        s = make_solver(cfg)
+        with caplog.at_level(logging.INFO, "caffe_mpi_tpu.solver"):
+            caplog.clear()
+            s.step(17, lambda it: data[it % 32])
+        shown = [(r.args[0], float(r.args[3]))  # (iteration, smoothed loss)
+                 for r in caplog.records if "Iteration" in r.msg]
+        assert [i for i, _ in shown] == [0, 5, 10, 15]
+        assert s.dispatch_count == 17
+        assert s.host_sync_count == 4
+        # each iteration's own loss, from a twin stepped one at a time
+        twin = make_solver(cfg.replace("display: 5", "display: 0"))
+        losses = [twin.step(1, lambda it: data[it % 32]) for _ in range(17)]
+        for i, smoothed in shown:
+            window = losses[max(0, i - 2):i + 1]
+            assert smoothed == pytest.approx(sum(window) / len(window),
+                                             rel=1e-5), f"iter {i}"
 
     def test_test_interval_parity(self, rng):
-        """test_all fires at identical iterations; chunk never straddles
-        a test boundary."""
+        """Test passes start before iterations 6, 12 and 18."""
         data = lsq_data(rng)
         cfg = ('type: "SGD" momentum: 0.9 test_interval: 6 test_iter: 2 '
                'test_initialization: false')
-        for extra, want_dispatch in (("", 20), (" step_chunk: 50", 4)):
-            s = make_solver(cfg + extra, net=LSQ_NET)
-            fired = []
-            # the in-training boundary now dispatches the ASYNC eval
-            # entrypoint (ISSUE 2); hook it to observe firing iterations
-            orig = s._start_eval
-            s._start_eval = lambda fns: fired.append(s.iter) or orig(fns)
-            s.step(20, lambda it: data[it % 32],
-                   test_feed_fns=[lambda k: data[(7 + k) % 32]])
-            assert fired == [6, 12, 18], extra
-            assert s.dispatch_count == want_dispatch, extra
-            # chunks: 6 (iters 0-5), then 6, 6, 2
+        s = make_solver(cfg, net=LSQ_NET)
+        fired = []
+        # the in-training boundary dispatches the ASYNC eval
+        # entrypoint (ISSUE 2); hook it to observe firing iterations
+        orig = s._start_eval
+        s._start_eval = lambda fns: fired.append(s.iter) or orig(fns)
+        s.step(20, lambda it: data[it % 32],
+               test_feed_fns=[lambda k: data[(7 + k) % 32]])
+        assert fired == [6, 12, 18]
+        assert s.dispatch_count == 20
 
     def test_test_interval_without_feeds_does_not_clip(self, rng):
         """A configured-but-unused test_interval (no test feeds passed to
-        step()) must not clip fusion — the test pass cannot fire, so the
-        chunk schedule ignores it."""
+        step()) starts no test pass and dispatches nothing but the
+        iterations."""
         data = lsq_data(rng)
-        s = make_solver('type: "SGD" momentum: 0.9 test_interval: 2 '
-                        'step_chunk: 10')
+        s = make_solver('type: "SGD" momentum: 0.9 test_interval: 2')
         s.step(20, lambda it: data[it % 32])  # no test_feed_fns
-        assert s.dispatch_count == 2
+        assert s.dispatch_count == 20
+        assert s.test_pass_count == 0 and s.test_dispatch_count == 0
 
     def test_snapshot_boundary_and_resume(self, rng, tmp_path):
-        """Interval snapshots land at identical iterations with
-        identical bytes, and a resume from a chunk-boundary state
-        continues on the uninterrupted trajectory."""
+        """Interval snapshots land after iterations 5 and 11, and a
+        resume from the iteration-6 state continues on the uninterrupted
+        trajectory."""
         data = lsq_data(rng)
         cfg = 'type: "Adam" momentum: 0.9 snapshot: 6 average_loss: 2'
         a = make_solver(cfg)
-        a.sp.snapshot_prefix = str(tmp_path / "k1")
-        b = make_solver(cfg + " step_chunk: 9")
-        b.sp.snapshot_prefix = str(tmp_path / "k9")
+        a.sp.snapshot_prefix = str(tmp_path / "a")
         a.step(14, lambda it: data[it % 32])
         a.wait_snapshots()
-        b.step(14, lambda it: data[it % 32])
-        b.wait_snapshots()
         for it in (6, 12):
-            pa = tmp_path / f"k1_iter_{it}.caffemodel"
-            pb = tmp_path / f"k9_iter_{it}.caffemodel"
-            assert pa.exists() and pb.exists()
-            wa = np.frombuffer(pa.read_bytes(), np.uint8)
-            wb = np.frombuffer(pb.read_bytes(), np.uint8)
-            # identical shapes; values within f32 tolerance — compare the
-            # restored weights, not raw bytes (low-order bits may differ
-            # between the scanned and unscanned XLA programs)
-            assert wa.size == wb.size
-        # resume from the K-mode iter-6 snapshot and run to 14 at K=9:
-        # trajectory must match the uninterrupted K=1 run
-        c = make_solver(cfg + " step_chunk: 9")
+            assert (tmp_path / f"a_iter_{it}.caffemodel").exists()
+            assert (tmp_path / f"a_iter_{it}.solverstate").exists()
+        c = make_solver(cfg)
         # prefix pinned to tmp: the resumed run crosses the iter-12
         # snapshot boundary, and the default prefix litters the repo root
         c.sp.snapshot_prefix = str(tmp_path / "resume")
-        c.restore(str(tmp_path / "k9_iter_6.solverstate"))
+        c.restore(str(tmp_path / "a_iter_6.solverstate"))
         assert c.iter == 6
         c.step(8, lambda it: data[it % 32])
+        c.wait_snapshots()
+        assert (tmp_path / "resume_iter_12.caffemodel").exists()
         assert_same_training(a, c)
 
     def test_solver_step_returns_final_loss(self, rng):
+        """step(n) returns the loss of its last iteration."""
         data = lsq_data(rng)
         a = make_solver('type: "SGD" momentum: 0.9')
-        b = make_solver('type: "SGD" momentum: 0.9 step_chunk: 5')
+        b = make_solver('type: "SGD" momentum: 0.9')
         la = a.step(11, lambda it: data[it % 32])
-        lb = b.step(11, lambda it: data[it % 32])
-        assert la == pytest.approx(lb, rel=1e-5)
+        b.step(10, lambda it: data[it % 32])
+        lb = b.step(1, lambda it: data[it % 32])
+        assert la == lb == float(a._loss_window[-1])
 
 
 class TestDeviceFeedQueue:
@@ -280,14 +167,14 @@ class TestDeviceFeedQueue:
             return {"x": np.full((4, 3), it, np.float32),
                     "t": np.full((4,), it, np.int32)}
 
-        q = DeviceFeedQueue(feed, iter_size=2)
+        q = DeviceFeedQueue(feed)
         try:
             out = q.get(0, 3, hint=(3, 2))
-            assert out["x"].shape == (3, 2, 4, 3)
-            assert out["t"].shape == (3, 2, 4)
-            # micro-iteration striping: iteration j, micro m -> j*2+m
+            assert out["x"].shape == (3, 1, 4, 3)
+            assert out["t"].shape == (3, 1, 4)
+            # batch j of the super-batch is feed_fn(it0 + j)
             got = np.asarray(out["x"])[:, :, 0, 0]
-            np.testing.assert_array_equal(got, [[0, 1], [2, 3], [4, 5]])
+            np.testing.assert_array_equal(got, [[0], [1], [2]])
             assert np.asarray(out["t"]).dtype == np.int32
             # the hint was prefetched on the worker; get() must not
             # rebuild it
@@ -296,7 +183,7 @@ class TestDeviceFeedQueue:
             out2 = q.get(3, 2)
             assert len(calls) == n_before  # served from prefetch
             np.testing.assert_array_equal(
-                np.asarray(out2["x"])[:, :, 0, 0], [[6, 7], [8, 9]])
+                np.asarray(out2["x"])[:, :, 0, 0], [[3], [4]])
         finally:
             q.close()
 
@@ -305,7 +192,7 @@ class TestDeviceFeedQueue:
         host round-trip path error."""
         from caffe_mpi_tpu.data.feeder import DeviceFeedQueue
         feeds = {"x": jnp.ones((4, 3))}
-        q = DeviceFeedQueue(lambda it: feeds, iter_size=1)
+        q = DeviceFeedQueue(lambda it: feeds)
         try:
             out = q.get(5, 2)
             assert out["x"].shape == (2, 1, 4, 3)

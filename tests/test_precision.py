@@ -6,8 +6,7 @@ scaling) as rebuilt for TPU:
 
 1. The f32 path is UNTOUCHED: a solver that spells `precision: "f32"`
    (+ loss-scale knobs, which are bf16-only) trains bitwise-identically
-   to one that predates the knob, across step_chunk {1,K} x train_guard
-   x reduce_overlap.
+   to one that predates the knob, across train_guard x reduce_overlap.
 2. Under `precision: bf16`, activations/gradients compute in bfloat16
    while params and momentum stay f32 MASTER copies updated in f32 —
    held against a torch-amp-style oracle (torch is the independent
@@ -85,13 +84,9 @@ def _assert_trees_equal(a, b):
 class TestF32Bitwise:
     """Spelling the knobs at their f32 defaults must not move a bit."""
 
-    @pytest.mark.parametrize("variant", ["plain", "chunk", "guard",
-                                         "chunk_guard"])
+    @pytest.mark.parametrize("variant", ["plain", "guard"])
     def test_f32_knob_is_bitwise_noop(self, variant):
-        extra = {"plain": "",
-                 "chunk": "step_chunk: 3",
-                 "guard": "train_guard: true",
-                 "chunk_guard": "step_chunk: 3 train_guard: true"}[variant]
+        extra = {"plain": "", "guard": "train_guard: true"}[variant]
         base = _solver(extra)
         base.step(7, _feed())
         knob = _solver(extra + ' precision: "f32" loss_scale: 128 '
@@ -202,7 +197,7 @@ class TestBF16MasterWeights:
                     np.float32)),
                 "label": jnp.asarray(lab)}
 
-        s = _solver('precision: "bf16" step_chunk: 5')
+        s = _solver('precision: "bf16"')
         assert s._dyn_scale and s._guard_on
         l0 = s.step(5, feed)
         lN = s.step(35, feed)
@@ -254,8 +249,7 @@ class TestDynamicLossScale:
         # a run that is ACTUALLY divergent (every step non-finite)
         # halves to the floor and then the exit-88 policy fires — the
         # self-healing contract survives under bf16
-        s = _solver('precision: "bf16" guard_max_skips: 2 '
-                    'step_chunk: 5')
+        s = _solver('precision: "bf16" guard_max_skips: 2')
         bad = self._burst_feed(set(range(100)))
         with pytest.raises(resilience.NumericAnomalyError):
             s.step(30, bad)
@@ -405,8 +399,7 @@ class TestPallasLRN:
         np.testing.assert_allclose(y.astype(jnp.float32), y_ref, **fwd)
         np.testing.assert_allclose(dx.astype(jnp.float32), dx_ref, **bwd)
 
-    def test_bf16_routes_through_pallas_f32_does_not(self, monkeypatch):
-        monkeypatch.delenv("CAFFE_LRN_PALLAS", raising=False)
+    def test_bf16_routes_through_pallas_f32_does_not(self):
         for precision, expect_pallas in (("bf16", True), ("", False)):
             s = _solver('precision: "bf16"' if precision else "",
                         net=LRN_NET)
@@ -416,37 +409,12 @@ class TestPallasLRN:
                 s.params, s.net_state, self._feed())
             has_pallas = "pallas" in str(jaxpr)
             assert has_pallas == expect_pallas, (precision, has_pallas)
-        # CAFFE_LRN_PALLAS=0 opts the bf16 path back out
-        monkeypatch.setenv("CAFFE_LRN_PALLAS", "0")
-        s = _solver('precision: "bf16"', net=LRN_NET)
-        jaxpr = jax.make_jaxpr(
-            lambda p, st, f: s.net.apply(p, st, f, train=True,
-                                         rng=jax.random.PRNGKey(0)))(
-            s.params, s.net_state, self._feed())
-        assert "pallas" not in str(jaxpr)
 
-    def test_bf16_lrn_net_trains(self, monkeypatch):
-        monkeypatch.delenv("CAFFE_LRN_PALLAS", raising=False)
-        s = _solver('precision: "bf16" step_chunk: 3', net=LRN_NET)
+    def test_bf16_lrn_net_trains(self):
+        s = _solver('precision: "bf16"', net=LRN_NET)
         loss = s.step(6, lambda it: self._feed())
         assert np.isfinite(loss)
         assert s.skipped_steps == 0
-
-    def test_forced_pallas_matches_stock_f32_training(self, monkeypatch):
-        # CAFFE_LRN_PALLAS=1: the kernels under the plain f32 path must
-        # track the stock lax program to f32 tolerance over real steps
-        monkeypatch.setenv("CAFFE_LRN_PALLAS", "0")
-        a = _solver("", net=LRN_NET)
-        a.step(4, lambda it: self._feed())
-        monkeypatch.setenv("CAFFE_LRN_PALLAS", "1")
-        b = _solver("", net=LRN_NET)
-        b.step(4, lambda it: self._feed())
-        for ln in a.params:
-            for pn in a.params[ln]:
-                np.testing.assert_allclose(
-                    np.asarray(a.params[ln][pn]),
-                    np.asarray(b.params[ln][pn]), rtol=1e-4, atol=1e-6,
-                    err_msg=f"{ln}/{pn}")
 
 
 class TestBF16Serving:

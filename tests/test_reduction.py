@@ -5,8 +5,8 @@ src/caffe/net.cpp:757-913).
 The contract under test: `reduce_overlap` is an EXECUTION-SCHEDULE
 knob, not a semantics knob — the shard_map step with per-bucket psums
 must land on BITWISE-identical params and optimizer state (CPU
-backend) vs the implicit GSPMD reduction, across step_chunk {1, K},
-iter_size accumulation, global-norm clipping, and train_guard. Plus:
+backend) vs the implicit GSPMD reduction, across iter_size
+accumulation, global-norm clipping, and train_guard. Plus:
 the bucket planner's ordering/sizing rules, the knob validation that
 replaces the old accept-and-ignore, the net-compatibility fallback,
 and the per-step collective count the MULTICHIP dryrun reports.
@@ -240,10 +240,9 @@ class TestEquivalence:
     @pytest.mark.parametrize("cfg", [
         "",
         "clip_gradients: 0.7",
-        "step_chunk: 4 clip_gradients: 0.7",
-        "step_chunk: 4 train_guard: true clip_gradients: 0.7",
+        "train_guard: true clip_gradients: 0.7",
         "iter_size: 2 clip_gradients: 0.7",
-        "iter_size: 2 step_chunk: 3 train_guard: true",
+        "iter_size: 2 train_guard: true",
     ])
     def test_bitwise_vs_implicit(self, rng, cfg):
         data = mlp_data(rng)

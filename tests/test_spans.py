@@ -177,12 +177,11 @@ def test_step_hlo_names_every_layer_forward_and_backward(extra):
 @pytest.mark.parametrize("extra", ["", "train_guard: true"],
                          ids=["plain", "guard"])
 def test_jitted_programs_have_fixed_names(extra):
-    """A trace names a program after its function: `jit_step` and
-    `jit_multi_step`, with the guard's carry or without."""
-    solver = toy_solver("step_chunk: 2\n" + extra)
+    """A trace names a program after its function: `jit_step`, with the
+    guard's carry or without."""
+    solver = toy_solver(extra)
     try:
         assert solver._build_step().__name__ == "step"
-        assert solver._build_multi_step().__name__ == "multi_step"
     finally:
         solver.close()
 
@@ -250,10 +249,8 @@ def profiled(fn, trace_dir):
     return host_spans(trace_dir)
 
 
-@pytest.mark.parametrize("extra,passes", [("", 3), ("step_chunk: 2", 2)],
-                         ids=["k1", "fused"])
-def test_profiled_step_holds_one_iter_span_per_pass(tmp_path, extra, passes):
-    solver = toy_solver("display: 2\n" + extra)
+def test_profiled_step_holds_one_iter_span_per_pass(tmp_path):
+    solver = toy_solver("display: 2\n")
     feeds = toy_feeds()
     try:
         solver.step(1, lambda it: feeds)   # compile outside the session
@@ -262,9 +259,7 @@ def test_profiled_step_holds_one_iter_span_per_pass(tmp_path, extra, passes):
     finally:
         solver.close()
     iters = [e for e in events if e[0] == "caffe/solver/iter"]
-    assert len(iters) == passes
-    assert [e[3]["step_num"] for e in iters] == ([1, 2, 3] if passes == 3
-                                                 else [1, 3])
+    assert [e[3]["step_num"] for e in iters] == [1, 2, 3]
     for child in ("caffe/solver/feed wait", "caffe/solver/train dispatch"):
         for _, start, end, _ in iters:
             inside = [e for e in events

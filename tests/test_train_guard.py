@@ -4,10 +4,9 @@ divergence rewind, and the data-integrity plane.
 The acceptance bars:
 - guard OFF (default): nothing changes — covered implicitly by every
   pre-existing solver test;
-- guard ON, clean data: training equals guard-off on CPU — bitwise for
-  step_chunk 1 and under a mesh, to a few float32 ulp for the scan-fused
-  K-step program (two different programs share no rounding contract;
-  the guard lives in a lax.cond branch, see solver._iteration_fn);
+- guard ON, clean data: training equals guard-off on CPU — bitwise, on
+  one device and under a mesh (the guard lives in a lax.cond branch,
+  see solver._iteration_fn);
 - injected NaNs: the bad step is skipped on device (params/momentum
   unchanged), M consecutive skips exit 88, and the supervised rewind
   resumes iteration-exact vs an uninterrupted clean run;
@@ -60,24 +59,10 @@ def lsq_data(n=32):
     return out
 
 
-def assert_bitwise_state(a: Solver, b: Solver, maxulp: int = 0):
-    """Params and optimizer slots of `a` and `b` agree: bitwise, or to
-    within `maxulp` float32 units in the last place of the largest
-    weight (a momentum slot is a weight increment, so the weights' scale
-    is the slots' too)."""
-    atol = 0.0
-    if maxulp:
-        top = max(float(np.abs(np.asarray(w)).max())
-                  for lp in a.params.values() for w in lp.values())
-        atol = maxulp * float(np.spacing(np.float32(top)))
-
+def assert_bitwise_state(a: Solver, b: Solver):
+    """Params and optimizer slots of `a` and `b` agree bitwise."""
     def same(x, y, what):
-        x, y = np.asarray(x), np.asarray(y)
-        if maxulp == 0:
-            assert np.array_equal(x, y), f"{what} differ"
-        else:
-            np.testing.assert_allclose(x, y, rtol=0, atol=atol,
-                                       err_msg=what)
+        assert np.array_equal(np.asarray(x), np.asarray(y)), f"{what} differ"
     for ln in a.params:
         for pn in a.params[ln]:
             same(a.params[ln][pn], b.params[ln][pn], f"params {ln}/{pn}")
@@ -99,23 +84,18 @@ def _clean_faults():
 # ---------------------------------------------------------------------------
 
 class TestGuardEquivalence:
-    @pytest.mark.parametrize("chunk", [1, 5])
-    def test_bitwise_equal_clean_data(self, chunk):
+    def test_bitwise_equal_clean_data(self):
         """Guard on against guard off are two different programs and
         share no rounding contract: bitwise is for one program run twice
         or resumed. The one-iteration program happens to round alike on
-        this jax's CPU backend and is held to it; the scan-fused one
-        (XLA fuses the update differently around the guard's carry) is
-        held to 4 float32 ulp of the largest weight after 33 iterations
-        (measured: 2), where a guard that dropped or altered one update
-        would be off by orders of magnitude more."""
+        this jax's CPU backend and is held to it."""
         data = lsq_data()
         feed = lambda it: data[it % 32]
-        a = make_solver(f"step_chunk: {chunk}")
-        b = make_solver(f"step_chunk: {chunk} train_guard: true")
+        a = make_solver()
+        b = make_solver("train_guard: true")
         a.step(33, feed)
         b.step(33, feed)
-        assert_bitwise_state(a, b, maxulp=0 if chunk == 1 else 4)
+        assert_bitwise_state(a, b)
         assert b.skipped_steps == 0
         # zero extra dispatches: the guard rides inside the programs
         assert b.dispatch_count == a.dispatch_count
@@ -125,9 +105,8 @@ class TestGuardEquivalence:
         from caffe_mpi_tpu.parallel import MeshPlan
         data = lsq_data()
         feed = lambda it: data[it % 32]
-        a = make_solver("step_chunk: 4", mesh=MeshPlan.data_parallel())
-        b = make_solver("step_chunk: 4 train_guard: true",
-                        mesh=MeshPlan.data_parallel())
+        a = make_solver(mesh=MeshPlan.data_parallel())
+        b = make_solver("train_guard: true", mesh=MeshPlan.data_parallel())
         a.step(9, feed)
         b.step(9, feed)
         assert_bitwise_state(a, b)
@@ -171,8 +150,8 @@ class TestSkipStep:
         data = lsq_data()
         feed = lambda it: data[it % 32]
         resilience.FAULTS.configure("nan_grad:1:0:3")
-        g = make_solver("train_guard: true step_chunk: 4")
-        g.step(4, feed)  # iterations 0..3; 3 skipped inside the chunk
+        g = make_solver("train_guard: true")
+        g.step(4, feed)  # iterations 0..3; 3 skipped on device
         assert g.skipped_steps == 1
         resilience.FAULTS.configure("")
         clean = make_solver("train_guard: true")
@@ -194,19 +173,18 @@ class TestSkipStep:
         assert run["exit_code"] == resilience.EXIT_NUMERIC == 88
 
     def test_mid_chunk_burst_still_trips_policy(self, tmp_path):
-        """A >=M consecutive burst that RECOVERS before the chunk
-        boundary must still exit 88: `consec` has reset by the time the
-        host looks, so the carry also tracks the longest burst seen
+        """A >=M consecutive burst that RECOVERS before the host's
+        rate-limited check must still exit 88: `consec` has reset by the
+        time the host looks, so the carry also tracks the longest burst seen
         (max_consec, monotone over the run — sound because reaching M
         always exits)."""
         data = lsq_data()
         feed = lambda it: data[it % 32]
         resilience.FAULTS.configure("nan_grad:3:0:2")  # iters 2,3,4 bad
-        s = make_solver("train_guard: true guard_max_skips: 3 "
-                        "step_chunk: 10")
+        s = make_solver("train_guard: true guard_max_skips: 3")
         s.sp.snapshot_prefix = str(tmp_path / "s")
         with pytest.raises(resilience.NumericAnomalyError) as ei:
-            s.step(10, feed)  # one chunk; burst ends at iter 5
+            s.step(10, feed)  # one check, at the end; burst ends at iter 5
         assert ei.value.consec == 3
 
     def test_divergence_blocks_snapshot_at_its_boundary(self, tmp_path):

@@ -84,11 +84,6 @@ def main() -> int:
     p.add_argument("--iters", type=int, default=30)
     p.add_argument("--records", type=int, default=1024)
     p.add_argument("--workdir", default="/tmp/caffe_e2e_lmdb")
-    p.add_argument("--step-chunk", type=int, default=6,
-                   help="iterations fused per lax.scan dispatch; the "
-                   "Feeder-built super-batch device_puts in a background "
-                   "thread while the previous chunk trains (1 = classic "
-                   "per-iteration dispatch)")
     p.add_argument("--test-iters", type=int, default=8,
                    help="test batches per fused-eval telemetry pass")
     p.add_argument("--test-chunk", type=int, default=4,
@@ -201,7 +196,6 @@ def main() -> int:
         'base_lr: 0.01 momentum: 0.9 lr_policy: "fixed" max_iter: 1000000 '
         'display: 0 random_seed: 3')
     sp.net_param = npar
-    sp.step_chunk = max(args.step_chunk, 1)
     # fused-eval telemetry (ISSUE 2): a TEST-phase twin of the same net
     # reads the same LMDB; after the timed region two async eval passes
     # run overlapped with training to measure test_dispatches_per_pass
@@ -218,7 +212,7 @@ def main() -> int:
         sp.snapshot = snap_every
     sp.snapshot_keep = max(args.snapshot_keep, 0)
     sp.watchdog_deadline = max(args.watchdog_deadline, 0.0)
-    # self-healing (ISSUE 4): non-finite guard in the fused scan; a
+    # self-healing (ISSUE 4): non-finite guard in the train step; a
     # corrupt LMDB record would quarantine via the crc sidecar the
     # build_db writer published (journal next to the snapshots)
     sp.train_guard = bool(args.train_guard)
@@ -241,10 +235,9 @@ def main() -> int:
 
     eval_line = ""
     try:
-        # with K-step fusion, warm one full chunk so the timed region
-        # reuses the compiled scan program
-        warmup = max(3, sp.step_chunk if sp.step_chunk > 1 else 0)
-        solver.step(warmup, feeder)
+        # the first iteration compiles the step: the timed region
+        # reuses it
+        solver.step(3, feeder)
         jax.block_until_ready(solver.params)
         d0, g0 = solver.dispatch_count, solver.guard_sync_count
         t0 = time.perf_counter()
@@ -255,7 +248,7 @@ def main() -> int:
         guard_syncs = solver.guard_sync_count - g0
 
         # untimed fused-eval phase: boundaries fire during 6 more train
-        # iters; the eval scan runs between train chunks and the stall
+        # iters; the eval scan runs between train steps and the stall
         # counter records what the train loop actually lost
         solver.sp.test_interval = 3
         solver.test_all([test_feeder])  # compile eval programs off-clock
@@ -309,11 +302,11 @@ def main() -> int:
                       f"{guard_syncs} guard_syncs")
     print(f"e2e-lmdb-train: {img_s:.1f} img/s (b{args.batch}, "
           f"{args.iters} iters, {device.device_kind}, MFU {mfu}, "
-          f"step_chunk {sp.step_chunk}: {dispatches} dispatches for "
+          f"{dispatches} dispatches for "
           f"{args.iters} iters{eval_line}{guard_line}) — full host "
           "pipeline: LMDB read -> crc verify -> decode -> "
-          "transform/staging -> device super-batch (prefetched in a "
-          "worker thread) -> fused K-step scan with non-finite guard; "
+          "transform/staging (prefetched in worker threads) -> one "
+          "train step an iteration with non-finite guard; "
           "eval passes fused+async (ISSUE 2)")
 
     # ISSUE 10 ingest report: decode-plane counters + both sides of the

@@ -10,6 +10,7 @@ from .base import LAYER_REGISTRY, Layer, ParamDecl, create_layer, register, regi
 from . import activations  # noqa: F401
 from . import composite  # noqa: F401
 from . import extension  # noqa: F401
+from . import hyper_connection  # noqa: F401
 from . import data_layers  # noqa: F401
 from . import dense  # noqa: F401
 from . import detection  # noqa: F401

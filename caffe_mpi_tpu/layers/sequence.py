@@ -38,6 +38,7 @@ expert_axis=...) directly.
 from __future__ import annotations
 
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -133,6 +134,10 @@ def latent_dims(p) -> tuple[int, int, int]:
     if p.block_diffusion or p.qk_norm:
         raise ValueError("latent attention has neither block_diffusion "
                          "nor qk_norm")
+    from ..proto.netshape import yarn_problem
+    problem = yarn_problem(p)
+    if problem:
+        raise ValueError(problem)
     return nope, rot, vd
 
 
@@ -184,6 +189,9 @@ class AttentionLayer(Layer):
             self._setup_latent(c)
             return [in_shapes[0]]
         self.heads, self.kv_heads, self.head_dim = attention_dims(p, c)
+        if p.rope_scaling_factor:
+            raise ValueError("rope_scaling_factor (YaRN) is latent "
+                             "attention's alone")
         if p.cca:
             self._setup_cca(c)
             return [in_shapes[0]]
@@ -274,7 +282,8 @@ class AttentionLayer(Layer):
         of its declared blob in the order asked for; q is turned where it
         lies (tables that are cos 1, sin 0 on the lanes without
         positions)."""
-        from ..ops.attention import lane_partner, rope_tables, turn_lanes
+        from ..ops.attention import (Yarn, lane_partner, rope_tables,
+                                     turn_lanes)
         p = self.p
         nope, rot, vd = self.latent
         s, pairs = x.shape[1], bool(p.rope_interleave)
@@ -282,7 +291,8 @@ class AttentionLayer(Layer):
         rms = lambda t, scale: rms_normalize(t, p.norm_eps) * scale
         heads = lambda t, wide: jnp.einsum(
             "nsr,hdr->nhsd" if hm else "nsr,hdr->nshd", t, wide)
-        cos, sin = rope_tables(s, rot, p.rope_theta, pairs=pairs, lead=nope)
+        cos, sin = rope_tables(s, rot, p.rope_theta, pairs=pairs, lead=nope,
+                               yarn=Yarn.of(p))
         turn = lambda t, lead: turn_lanes(
             t, lane_partner(t, rot, pairs), cos[:, nope - lead:],
             sin[:, nope - lead:], head_major=hm)
@@ -426,6 +436,11 @@ class AttentionLayer(Layer):
         mp = self.mesh_plan
         mask = dict(causal=bool(p.causal), window=p.window,
                     block_diffusion=p.block_diffusion)
+        if p.rope_scaling_factor:
+            # YaRN's factor on the scores, a constant of the kernels
+            from ..ops.attention import Yarn
+            mask["scale"] = Yarn.of(p).score_factor / math.sqrt(
+                sum(self.latent[:2]))
         if self._ring():
             # prototxt-declared SP: the sequence dim shards over 'model'
             # and K/V ride the ICI ring (ops/attention.py ring_attention);

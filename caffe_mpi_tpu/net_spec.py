@@ -25,6 +25,8 @@ _PARAM_FIELD = {
     "MoE": "moe_param", "Mamba2": "mamba2_param", "Parameter": "parameter_param",
     "BlockDiffusionNoise": "block_diffusion_param",
     "RMSNorm": "rms_norm_param",
+    "HyperConnectionPre": "hyper_connection_param",
+    "HyperConnectionPost": "hyper_connection_param",
     "DummyData": "dummy_data_param", "Eltwise": "eltwise_param",
     "ELU": "elu_param", "Embed": "embed_param", "Exp": "exp_param",
     "Flatten": "flatten_param", "HDF5Data": "hdf5_data_param",

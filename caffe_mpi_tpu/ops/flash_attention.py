@@ -579,10 +579,11 @@ class _Static(typing.NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(st: _Static, causal, window, has_bias, interpret, bd=None):
+def _fwd_call(st: _Static, causal, window, has_bias, interpret, bd=None,
+              scale=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
-    kernel = functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
+    kernel = functools.partial(_fwd_kernel, scale=scale or 1.0 / math.sqrt(d),
                                causal=causal, sk=sk, bq=bq, bk=bk,
                                sk_valid=st.sk_valid, has_bias=has_bias,
                                window=window, bd=bd)
@@ -615,14 +616,14 @@ def _fwd_call(st: _Static, causal, window, has_bias, interpret, bd=None):
 
 
 def _fwd_impl(q, k, v, causal, interpret, sk_valid=None, k_bias=None,
-              window=0, bd=None):
+              window=0, bd=None, scale=None):
     """(B*H, S, D) q and (B*Hkv, S, D) k, v -> (out, lse); query row i of
     the leading axis reads key/value row i // (H / Hkv). k_bias: optional
     (1, Sk) f32 additive score bias shared by every row/head (0 live,
-    -inf masked)."""
+    -inf masked). scale: of the scores, 1 / sqrt(D) where None."""
     bias = () if k_bias is None else (k_bias,)
     return _fwd_call(_Static.of(q, k, v, sk_valid), causal, window,
-                     bool(bias), interpret, bd)(q, k, v, *bias)
+                     bool(bias), interpret, bd, scale)(q, k, v, *bias)
 
 
 def _delta(do, out):
@@ -633,7 +634,8 @@ def _delta(do, out):
 
 
 @functools.lru_cache(maxsize=None)
-def _dq_call(st: _Static, causal, window, has_bias, interpret, bd=None):
+def _dq_call(st: _Static, causal, window, has_bias, interpret, bd=None,
+             scale=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
@@ -647,7 +649,7 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret, bd=None):
     if has_bias:
         in_specs.append(pl.BlockSpec((1, sk), lambda i, j: (0, 0)))
     return pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=1.0 / math.sqrt(d),
+        functools.partial(_bwd_dq_kernel, scale=scale or 1.0 / math.sqrt(d),
                           causal=causal, sk=sk, bq=bq, bk=bk,
                           sk_valid=st.sk_valid, has_bias=has_bias,
                           window=window, bd=bd),
@@ -663,7 +665,8 @@ def _dq_call(st: _Static, causal, window, has_bias, interpret, bd=None):
 
 
 @functools.lru_cache(maxsize=None)
-def _dkv_call(st: _Static, causal, window, has_bias, interpret, bd=None):
+def _dkv_call(st: _Static, causal, window, has_bias, interpret, bd=None,
+              scale=None):
     bh, group, sq, sk, d, dv = st.bh, st.group, st.sq, st.sk, st.d, st.dv
     bq, bk = _check_tiles(sq, sk)
     in_specs = [
@@ -681,7 +684,8 @@ def _dkv_call(st: _Static, causal, window, has_bias, interpret, bd=None):
     # across a head's K tiles
     kv_dtype = (st.k_dtype, st.v_dtype) if group == 1 else (jnp.float32,) * 2
     return pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=1.0 / math.sqrt(d),
+        functools.partial(_bwd_dkv_kernel,
+                          scale=scale or 1.0 / math.sqrt(d),
                           causal=causal, sq=sq, bq=bq, bk=bk,
                           has_bias=has_bias, window=window, bd=bd),
         grid=(bh, sk // bk),
@@ -703,7 +707,7 @@ def _dkv_call(st: _Static, causal, window, has_bias, interpret, bd=None):
 
 
 def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
-              k_bias=None, delta=None, window=0, bd=None):
+              k_bias=None, delta=None, window=0, bd=None, scale=None):
     """out/lse are the GLOBAL attention output/logsumexp for these q rows
     (for plain flash that's this call's own forward; for ring attention
     each per-block call passes the ring-merged values, which makes the
@@ -712,7 +716,7 @@ def _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid=None,
         delta = _delta(do, out)
     st = _Static.of(q, k, v, sk_valid)
     args = (q, k, v, do, lse, delta, *(() if k_bias is None else (k_bias,)))
-    flags = (causal, window, k_bias is not None, interpret, bd)
+    flags = (causal, window, k_bias is not None, interpret, bd, scale)
     dq = _dq_call(st, *flags)(*args)
     dk, dv = _dkv_call(st, *flags)(*args)
     if st.group > 1:
@@ -745,10 +749,10 @@ def flash_block_bwd(q, k, v, out, lse, do, *, causal=False, k_bias=None,
                      k_bias=k_bias, delta=delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, interpret, sk_valid, window, bd):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, interpret, sk_valid, window, bd, scale):
     out, _ = _fwd_impl(q, k, v, causal, interpret, sk_valid, window=window,
-                       bd=bd)
+                       bd=bd, scale=scale)
     return out
 
 
@@ -759,15 +763,15 @@ def _flash(q, k, v, causal, interpret, sk_valid, window, bd):
 KEPT_UNDER_REMAT = ("flash.out", "flash.lse")
 
 
-def _flash_fwd(q, k, v, causal, interpret, sk_valid, window, bd):
+def _flash_fwd(q, k, v, causal, interpret, sk_valid, window, bd, scale):
     out, lse = _fwd_impl(q, k, v, causal, interpret, sk_valid,
-                         window=window, bd=bd)
+                         window=window, bd=bd, scale=scale)
     out, lse = (checkpoint_name(x, name)
                 for x, name in zip((out, lse), KEPT_UNDER_REMAT))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, interpret, sk_valid, window, bd, res, do):
+def _flash_bwd(causal, interpret, sk_valid, window, bd, scale, res, do):
     # sk_valid reaches the dQ kernel (p at padded columns can overflow to
     # inf when lse < -88 and must be zeroed before ds @ k). The dK/dV
     # kernel needs no mask: padded Q rows carry do = 0 (the output
@@ -775,7 +779,7 @@ def _flash_bwd(causal, interpret, sk_valid, window, bd, res, do):
     # rows the wrapper slices off.
     q, k, v, out, lse = res
     return _bwd_impl(q, k, v, out, lse, do, causal, interpret, sk_valid,
-                     window=window, bd=bd)
+                     window=window, bd=bd, scale=scale)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -796,9 +800,11 @@ def check_block_diffusion(block: int, sq: int, sk: int, causal, window):
 def flash_attention_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                           causal: bool = False,
                           interpret: bool | None = None, window: int = 0,
-                          block_diffusion: int = 0) -> jnp.ndarray:
+                          block_diffusion: int = 0,
+                          scale: float | None = None) -> jnp.ndarray:
     """The kernels' own order: q (B, H, S, D), k (B, Hkv, S, D), v (B,
-    Hkv, S, Dv) -> (B, H, S, Dv); scores are scaled by 1 / sqrt(D). What a
+    Hkv, S, Dv) -> (B, H, S, Dv); scores are scaled by `scale`, a constant
+    of the kernels, 1 / sqrt(D) where None. What a
     layer that produces its heads in this order (layers/sequence.py) calls,
     so that nothing is re-laid out between its projections and the
     kernels: folding B into H is a view. Differentiable: jax.grad hits the
@@ -835,7 +841,7 @@ def flash_attention_heads(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
     out = _flash(q.reshape(b * h, sq_p, d), k.reshape(b * hkv, sk_p, d),
                  v.reshape(b * hkv, sk_p, dv), causal, interpret,
-                 sk if sk_p != sk else None, window, bd)
+                 sk if sk_p != sk else None, window, bd, scale)
     out = out.reshape(b, h, sq_p, dv)
     return out[:, :, :sq] if sq_p != sq else out
 

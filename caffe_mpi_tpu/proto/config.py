@@ -508,6 +508,22 @@ class AttentionParameter(Message):
     # the rotary lanes turn in adjacent pairs (x_2i, x_2i+1) instead of
     # rotate-half; latent attention only
     rope_interleave: bool = False
+    # YaRN (arXiv:2309.00071; the DeepSeek-V3 family's `rope_scaling` of
+    # type "yarn"), latent attention only; rope_scaling_factor 0 = none.
+    # Rotary lane pair i of d has inv_freq_i = f_i (1 - m_i) / factor + f_i
+    # m_i, f_i = rope_theta^(-2i/d), m_i = 1 - clip((i - low) / (high -
+    # low), 0, 1), [low, high] the lanes that turn beta_fast and beta_slow
+    # times over rope_original_max_position positions; cos and sin are
+    # scaled by mscale(rope_mscale) / mscale(rope_mscale_all_dim) and,
+    # with rope_mscale_all_dim, the scores by mscale(rope_mscale_all_dim)^2
+    # over sqrt(qk_nope_head_dim + qk_rope_head_dim), mscale(m) = 0.1 m
+    # ln(factor) + 1 (ops/attention.py yarn_inv_freq, yarn_mscale)
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # eps of the two RMSNorms inside the latent path, and of qk_norm's
     norm_eps: float = 1e-6
     # block diffusion (arXiv:2503.09573), the block length; 0 = off. The
@@ -635,6 +651,32 @@ class MoEParameter(Message):
     # shared_w1, shared_w3 (C, n h), shared_w2 (n h, C)). Under expert
     # parallelism every chip computes it for its own tokens
     shared_experts: int = 0
+
+
+@dataclass
+class HyperConnectionParameter(Message):
+    """TPU-native extension: manifold-constrained hyper-connections (mHC,
+    arXiv:2512.24880, on hyper-connections arXiv:2409.19606;
+    layers/hyper_connection.py). The residual is `streams` copies of the
+    channels, a stream blob (N, S, n, C). Around a sub-layer F, with x' =
+    RMSNorm(vec(x)) over the n C lanes (eps, no scale) a token:
+    H_pre = sigmoid(a_pre x' phi_pre + b_pre) (n), H_post = 2
+    sigmoid(a_post x' phi_post + b_post) (n), H_res = Sinkhorn-Knopp of
+    exp(clip(a_res mat(x' phi_res) + b_res, -clamp, clamp)) (n x n:
+    `sinkhorn_iters` times rows then columns divided by their sums +
+    sinkhorn_eps); F reads H_pre x and the stream goes on as H_res x +
+    H_post^T F(H_pre x). `HyperConnectionPre` holds phi (2 n + n^2, n C:
+    [pre | post | res], res row-major), bias (2 n + n^2) and alpha (3:
+    a_pre, a_post, a_res), and computes the coefficients in float32;
+    `HyperConnectionPost` applies the update."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    sinkhorn_eps: float = 1e-6
+    clamp: float = 30.0
+    eps: float = 1e-6
+    phi_filler: FillerParameter | None = None
+    bias_filler: FillerParameter | None = None
+    alpha_filler: FillerParameter | None = None
 
 
 @dataclass
@@ -1027,6 +1069,7 @@ class LayerParameter(Message):
     eltwise_param: EltwiseParameter | None = None
     moe_param: MoEParameter | None = None
     mamba2_param: Mamba2Parameter | None = None
+    hyper_connection_param: HyperConnectionParameter | None = None
     block_diffusion_param: BlockDiffusionParameter | None = None
     layer_norm_param: LayerNormParameter | None = None
     rms_norm_param: RMSNormParameter | None = None

@@ -167,6 +167,8 @@ def _tokenize(text: str) -> list[_Token]:
 
 def _unquote(s: str) -> str:
     body = s[1:-1]
+    if "\\" not in body:
+        return body
     out: list[str] = []
     i = 0
     while i < len(body):

@@ -65,8 +65,8 @@ import ast
 import os
 from typing import Iterator
 
-from . import (DEFAULT_SCAN, FileContext, Finding, LintPass, dotted_name,
-               iter_py_files, register)
+from . import (DEFAULT_SCAN, FileContext, Finding, LintPass, children,
+               dotted_name, iter_py_files, register)
 
 REGISTRY_FILE = os.path.join("caffe_mpi_tpu", "serving", "locks.py")
 
@@ -504,7 +504,7 @@ class _FuncWalk:
             self.m.entries.add((self.fn.cls, node.attr))
         if isinstance(node, (ast.Assign, ast.AugAssign, ast.Delete)):
             self._mutation(node, held, stmt)
-        for child in ast.iter_child_nodes(node):
+        for child in children(node):
             self._walk(child, held, stmt)
 
     def _nesting(self, outer: str, inner: str, stmt, via: str) -> None:

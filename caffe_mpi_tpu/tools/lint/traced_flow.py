@@ -38,7 +38,8 @@ from __future__ import annotations
 import ast
 from typing import Iterator
 
-from . import Finding, FileContext, LintPass, attr_root, dotted_name, register
+from . import (Finding, FileContext, LintPass, attr_root, children,
+               dotted_name, register)
 
 # transforms whose function-valued arguments are traced
 _TRANSFORMS = {"jit", "pjit", "grad", "value_and_grad", "vmap", "pmap",
@@ -120,7 +121,7 @@ class TracedControlFlowPass(LintPass):
 
         def collect(node: ast.AST, current: dict | None,
                     stmt: ast.stmt | None = None) -> None:
-            for child in ast.iter_child_nodes(node):
+            for child in children(node):
                 s = child if isinstance(child, ast.stmt) else stmt
                 if isinstance(child, _FUNC_DEFS + (ast.Lambda,)):
                     # `stmt` anchors waivers for lambda-body findings:
@@ -224,7 +225,7 @@ class TracedControlFlowPass(LintPass):
                 flag(node.args[0], f"`{node.func.id}()`", s)
 
         def scan_body(node: ast.AST, stmt: ast.stmt | None) -> None:
-            for child in ast.iter_child_nodes(node):
+            for child in children(node):
                 if isinstance(child, _FUNC_DEFS + (ast.Lambda,)):
                     continue  # nested scopes judged by their own entry
                 s = child if isinstance(child, ast.stmt) else stmt

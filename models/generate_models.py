@@ -1134,6 +1134,171 @@ snapshot_prefix: "models/joyai_llm_flash/{prefix}"
 """
 
 
+XING = dict(
+    # https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B config.json:
+    # widths as published; depth, experts held, vocabulary and the MTP
+    # module are one chip's share (benchmarks/configs/xing4_29b_a4b.json);
+    # the sequence is the model's original_max_position_embeddings
+    seq=4096, vocab=16384, dim=3584, heads=32, q_lora=768, kv_lora=512,
+    nope=128, rot=64, vd=128, rope_theta=1e4,
+    yarn=dict(rope_scaling_factor=64.0, rope_original_max_position=4096,
+              rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+              rope_mscale_all_dim=1.0),
+    dense_layers=1, dense_width=9216, expert_layers=4, experts=64,
+    experts_held=8, top_k=4, expert_width=1024, shared_experts=1,
+    scaling=2.0, eps=1e-6, streams=4, sinkhorn_iters=20, hc_eps=1e-6,
+    clamp=30.0)
+# the size of tests/test_xing.py and of the benchmark's CPU rehearsal:
+# every mechanism, no width (8 experts in 4 shares of 2); the published
+# YaRN, whose ramp over 4 rotary pairs runs from pair 1 to pair 3
+XING_TINY = dict(
+    seq=32, vocab=64, dim=64, heads=4, q_lora=48, kv_lora=32, nope=16,
+    rot=8, vd=16, rope_theta=1e4, yarn=XING["yarn"], dense_layers=1, dense_width=96, expert_layers=2, experts=8,
+    experts_held=2, top_k=4, expert_width=32, shared_experts=1,
+    scaling=2.0, eps=1e-6, streams=4, sinkhorn_iters=20, hc_eps=1e-6,
+    clamp=30.0)
+# layers computed again in the backward pass (LayerParameter.remat)
+XING_REMAT = ("attn", "hc1", "hc2", "hc1_out", "hc2_out")
+# the hyper-connection's coefficients: phi of unit-variance products on the
+# normed stream (std 1 / sqrt(n C)) and a bias of unit spread, so that H_res
+# is neither the identity nor symmetric and moves with the token
+XING_HC_BIAS = dict(type="gaussian", std=1.0)
+
+
+def xing4_29b_a4b(batch=1, *, seq, vocab, dim, heads, q_lora, kv_lora, nope,
+                  rot, vd, rope_theta, yarn, dense_layers, dense_width,
+                  expert_layers, experts, experts_held, top_k, expert_width,
+                  shared_experts, scaling, eps, streams, sinkhorn_iters,
+                  hc_eps, clamp, first_expert=0, use_flash=True, remat=(),
+                  name="xing4_29b_a4b"):
+    """Xing4.0-29B-A4B (the DeepSeek-V3 block, arXiv:2412.19437, with
+    manifold-constrained hyper-connections, arXiv:2512.24880), one chip's
+    share: the embedding copied into `streams` residual streams; blocks of
+    latent attention under YaRN and a feed-forward (dense gated-SiLU in the
+    leading blocks, then joyai_llm_flash()'s expert layer), each sub-layer
+    read through H_pre and written back through H_res and H_post
+    (HyperConnectionPre / HyperConnectionPost); the streams summed, the
+    last norm and an untied head. No MTP module (reduced). The layer
+    equations are written out in benchmarks/reference/xing_ref.py.
+    `remat`: layer-name suffixes whose layers are computed again in the
+    backward pass."""
+    filler = dict(type="gaussian", std=0.02)
+    n = NetSpec(name)
+    n.tokens, n.label = L.Input(ntop=2, input_param=dict(
+        shape=[dict(dim=[batch, seq])] * 2))
+    again = lambda layer: dict(remat=True) \
+        if any(layer.endswith(suffix) for suffix in remat) else {}
+    hc = dict(streams=streams, sinkhorn_iters=sinkhorn_iters,
+              sinkhorn_eps=hc_eps, clamp=clamp, eps=eps,
+              phi_filler=dict(type="gaussian", std=(streams * dim) ** -0.5),
+              bias_filler=XING_HC_BIAS,
+              alpha_filler=dict(type="constant", value=1.0))
+
+    def put(layer, top):
+        setattr(n, layer, top)
+        return top
+
+    def wrapped(b, x, sub):
+        """x -> H_res x + H_post^T sub(H_pre x): the sub-layer `sub`
+        between a pair of hyper-connection layers named b."""
+        h, coef = L.HyperConnectionPre(x, ntop=2, hyper_connection_param=hc,
+                                       **again(b))
+        put(b, h)
+        put(f"{b}_coef", coef)
+        return put(f"{b}_out", L.HyperConnectionPost(
+            x, sub(h), coef, hyper_connection_param=hc, **again(f"{b}_out")))
+
+    def attention(b):
+        def sub(h):
+            ln1 = put(f"{b}/ln1", L.RMSNorm(h, eps=eps))
+            return put(f"{b}/attn", L.Attention(
+                ln1, num_heads=heads, causal=True, use_flash=use_flash,
+                bias_term=False, rope_theta=rope_theta, q_lora_rank=q_lora,
+                kv_lora_rank=kv_lora, qk_nope_head_dim=nope,
+                qk_rope_head_dim=rot, v_head_dim=vd, rope_interleave=True,
+                norm_eps=eps, weight_filler=filler, **yarn,
+                **again(f"{b}/attn")))
+        return sub
+
+    def feed_forward(b, dense):
+        def sub(h):
+            ln2 = put(f"{b}/ln2", L.RMSNorm(h, eps=eps))
+            if dense:
+                product = dict(num_output=dense_width, axis=2,
+                               bias_term=False, weight_filler=filler)
+                gate = put(f"{b}/gate", L.InnerProduct(ln2, **product))
+                up = put(f"{b}/up", L.InnerProduct(ln2, **product))
+                sig = put(f"{b}/sig", L.Sigmoid(gate))
+                act = put(f"{b}/act", L.Eltwise(gate, sig, up,
+                                                 operation="PROD"))
+                return put(f"{b}/down", L.InnerProduct(
+                    act, num_output=dim, axis=2, bias_term=False,
+                    weight_filler=filler))
+            # joyai_llm_flash()'s expert layer: routing a constant of the
+            # step (router and selection bias frozen, no gradient through
+            # the scores); second top: rows each held expert received
+            ffn, rows = L.MoE(
+                ln2, ln2, ntop=2, loss_weight=[0.0, 0.0],
+                param=[dict(lr_mult=0, decay_mult=0)] * 2,
+                propagate_down=[True, False],
+                moe_param=dict(
+                    num_experts=experts, hidden_dim=expert_width,
+                    top_k=top_k, dropless=True, experts_held=experts_held,
+                    first_expert=first_expert, scoring="sigmoid",
+                    routed_scaling_factor=scaling, activation="silu",
+                    shared_experts=shared_experts, weight_filler=filler,
+                    bias_filler=JOYAI_SELECT_BIAS))
+            put(f"{b}/moe_rows", rows)
+            return put(f"{b}/moe", ffn)
+        return sub
+
+    n.embed = L.Embed(n.tokens, input_dim=vocab, num_output=dim,
+                      bias_term=False,
+                      weight_filler=dict(type="gaussian", std=1.0))
+    # the embedding copied into every stream (assumed: the hyper-connection
+    # paper's start)
+    one = put("streams/one", L.Reshape(n.embed, reshape_param=dict(
+        shape=dict(dim=[0, 0, 1, -1]))))
+    x = put("streams", L.Tile(one, tile_param=dict(axis=2, tiles=streams)))
+    for l in range(dense_layers + expert_layers):
+        b = f"blk{l}"
+        x = wrapped(f"{b}/hc1", x, attention(b))
+        x = wrapped(f"{b}/hc2", x, feed_forward(b, dense=l < dense_layers))
+    # the streams summed before the last norm (assumed, as their start)
+    parts = L.Slice(x, ntop=streams, slice_param=dict(axis=2))
+    for i, part in enumerate(parts):
+        put(f"merge/part{i}", part)
+    total = put("merge/sum", L.Eltwise(*parts))
+    n.merge = L.Reshape(total, reshape_param=dict(shape=dict(dim=[0, 0, -1])))
+    n.ln_f = L.RMSNorm(n.merge, eps=eps)
+    n.logits = L.InnerProduct(n.ln_f, num_output=vocab, axis=2,
+                              bias_term=False, weight_filler=filler)
+    n.loss = L.SoftmaxWithLoss(n.logits, n.label,
+                               softmax_param=dict(axis=2))
+    n.accuracy = L.Accuracy(n.logits, n.label, axis=2,
+                            include=dict(phase="TEST"))
+    return n
+
+
+def xing_solver(net: str, prefix: str) -> str:
+    return f"""# Xing4.0-29B-A4B, one chip's share: Adam (this system's coupled
+# L2, none set), fixed 3e-4, global-norm clip 1; static loss scale for the
+# reason models/smallthinker_21b_a3b/solver.prototxt gives
+net: "models/xing4_29b_a4b/{net}"
+base_lr: 0.0003
+lr_policy: "fixed"
+display: 10
+max_iter: 10000
+momentum: 0.9
+momentum2: 0.95
+type: "Adam"
+clip_gradients: 1.0
+loss_scale: 1.0
+snapshot: 10000
+snapshot_prefix: "models/xing4_29b_a4b/{prefix}"
+"""
+
+
 SDAR = dict(
     # https://huggingface.co/JetLM/SDAR-30B-A3B-Chat config.json: widths as
     # published; depth, experts held and vocabulary are one chip's share,
@@ -1977,8 +2142,9 @@ def main():
     # the language-model configurations of the benchmark: the recipe at the
     # published widths and the tiny one its CPU rehearsal and tests run
     # (tests/test_smallthinker.py, tests/test_joyai.py, tests/test_sdar.py,
-    # tests/test_zaya.py, tests/test_nemotron.py). Train only: no
-    # deploy net, the serving path has no key/value cache yet
+    # tests/test_zaya.py, tests/test_nemotron.py, tests/test_xing.py).
+    # Train only: no deploy net, the serving path has no key/value cache
+    # yet
     for family, build, solver_text, real, tiny, prefix in (
             ("smallthinker_21b_a3b", smallthinker, smallthinker_solver,
              SMALLTHINKER, SMALLTHINKER_TINY, "smallthinker"),
@@ -1993,7 +2159,10 @@ def main():
              dict(ZAYA_TINY, remat=ZAYA_REMAT), "zaya"),
             ("nemotron3_nano_30b_a3b", nemotron_h, nemotron_solver,
              dict(NEMOTRON, remat=NEMOTRON_REMAT),
-             dict(NEMOTRON_TINY, remat=NEMOTRON_REMAT), "nemotron")):
+             dict(NEMOTRON_TINY, remat=NEMOTRON_REMAT), "nemotron"),
+            ("xing4_29b_a4b", xing4_29b_a4b, xing_solver,
+             dict(XING, remat=XING_REMAT),
+             dict(XING_TINY, remat=XING_REMAT), "xing")):
         d = os.path.join(out_root, family)
         os.makedirs(d, exist_ok=True)
         for net, solver, sizes in (

@@ -9,10 +9,10 @@ one seeding the same pattern waived with
 
 import json
 import os
+import resource
 import subprocess
 import sys
 import textwrap
-import time
 
 from caffe_mpi_tpu.tools import lint
 
@@ -62,9 +62,12 @@ def test_all_tentpole_passes_registered():
 
 def test_shipped_tree_is_clean_fast_and_jax_free():
     """`python -m caffe_mpi_tpu.tools.lint` exits 0 on the shipped
-    tree, in under 5 s, with jax imports poisoned — the whole suite
-    needs no device."""
-    t0 = time.monotonic()
+    tree, in under 5 s of the child's CPU time (user + system: the wall
+    clock also counts the other test processes a loaded host runs beside
+    it), with jax imports poisoned — the whole suite needs no device."""
+    cpu = lambda: (lambda u: u.ru_utime + u.ru_stime)(
+        resource.getrusage(resource.RUSAGE_CHILDREN))
+    t0 = cpu()
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys\n"
@@ -74,9 +77,9 @@ def test_shipped_tree_is_clean_fast_and_jax_free():
          "raise SystemExit(main([]))"],
         cwd=_ROOT, capture_output=True, text=True, timeout=60,
         env=dict(os.environ, PYTHONPATH=_ROOT))
-    elapsed = time.monotonic() - t0
+    elapsed = cpu() - t0
     assert r.returncode == 0, r.stdout + r.stderr
-    assert elapsed < 5.0, f"lint took {elapsed:.1f}s (budget 5s)"
+    assert elapsed < 5.0, f"lint took {elapsed:.1f}s of CPU (budget 5s)"
 
 
 def test_cli_select_unknown_pass_is_usage_error():

@@ -17,6 +17,7 @@ chip_smoke.py's `kernels` leg on the chip.
 import functools
 import importlib.util
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
+
+_ROOT = Path(__file__).resolve().parent.parent
 
 pytestmark = pytest.mark.skipif(
     importlib.util.find_spec("libtpu") is None,
@@ -556,6 +559,14 @@ _GLUE_CASES = {
 }
 
 
+def _glue_bytes_tool():
+    spec = importlib.util.spec_from_file_location(
+        "attention_glue_bytes", _ROOT / "tools/attention_glue_bytes.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
 class TestAttentionGlueBytes:
     """What one attention layer of each language-model cell moves through
     HBM beside its kernels and its matrix products
@@ -568,15 +579,10 @@ class TestAttentionGlueBytes:
 
     @pytest.mark.parametrize("case", list(_GLUE_CASES))
     def test_one_layer_forward_and_backward(self, case):
-        from pathlib import Path
-        root = Path(__file__).resolve().parent.parent
-        spec = importlib.util.spec_from_file_location(
-            "attention_glue_bytes", root / "tools/attention_glue_bytes.py")
-        tool = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(tool)
+        tool = _glue_bytes_tool()
         model, name, glue_gb, all_gb, q_elements = _GLUE_CASES[case]
         records = tool.layer_records(
-            str(root / "models" / model / "train_val.prototxt"), name,
+            str(_ROOT / "models" / model / "train_val.prototxt"), name,
             "bf16", v5e_devices()[0])
         totals = tool.totals(records)
         assert sum(r["kind"] == "mosaic" for r in records) == 3
@@ -585,6 +591,32 @@ class TestAttentionGlueBytes:
         # no glue operation writes a float32 tensor of q's size (the
         # widest left is a parameter's gradient)
         assert tool.widest_f32_glue_result(records) < q_elements
+
+
+class TestHyperConnectionBytes:
+    """One hyper-connection of the xing cell's recipe (`blk1/hc1` +
+    `blk1/hc1_out`: 4,096 tokens, a stream of 4 x 3,584, an elementwise
+    stand-in for the sub-layer between them), forward + backward under each
+    layer's own `remat` (`tools/attention_glue_bytes.py`'s pair mode). Held
+    at what the hand-written backward passes reached plus a tenth (9.50 GB
+    with jax's own backward passes over the (N, S, n, C) view): a float32
+    copy of the stream, a second layout of it or a broadcast mix that
+    became a pass of its own again shows here, at no chip time."""
+
+    ALL_GB = 4.56
+
+    def test_one_pair_forward_and_backward(self):
+        tool = _glue_bytes_tool()
+        records = tool.layer_records(
+            str(_ROOT / "models/xing4_29b_a4b/train_val.prototxt"),
+            "blk1/hc1", "bf16", v5e_devices()[0], post="blk1/hc1_out")
+        assert sum(r["kind"] == "mosaic" for r in records) == 0
+        totals = tool.totals(records)
+        assert sum(totals.values()) <= 1.1 * self.ALL_GB * 1e9, totals
+        # no glue operation writes a float32 array of one stream's size,
+        # let alone of the four (the widest left: the Sinkhorn iterations'
+        # residuals, 20 x 4 x 4 floats a token)
+        assert tool.widest_f32_glue_result(records) < 4096 * 3584
 
 
 _ALEXNET_HEAD = """

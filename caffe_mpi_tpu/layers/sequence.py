@@ -532,9 +532,10 @@ class BlockDiffusionNoiseLayer(Layer):
 
 
 # what a Mamba2 layer's backward pass reads of its forward: the input
-# product's result and the scan's output (with ops/ssd.py's carried states,
-# `ssd.KEPT`), kept under `remat: true` so that neither the input product
-# nor the scan's forward pass runs again in the backward pass
+# product's result and the scan's output (with what ops/ssd.py's backward
+# rule reads, `ssd.KEPT`: the carried states and two (S, H) vectors), kept
+# under `remat: true` so that neither the input product nor the scan's
+# forward pass runs again in the backward pass
 MAMBA2_INPUT = "mamba2.zxbcdt"
 MAMBA2_SCAN = "mamba2.scan_out"
 
@@ -547,9 +548,9 @@ class Mamba2Layer(Layer):
     convolution a channel with its SiLU (shifted sums, `shift_rows`),
     `ssm.scan` softplus, decays and the recurrence, `ssm.gate` the gate
     and the grouped norm, `ssm.out` the output product. Under `remat` the
-    input product's result, the scan's output and its carried states are
-    kept (`kept_under_remat`); the convolution and the gate are computed
-    again."""
+    input product's result, the scan's output and what its backward rule
+    reads are kept (`kept_under_remat`); the convolution and the gate are
+    computed again."""
 
     def setup(self, in_shapes: list[Shape]) -> list[Shape]:
         from ..proto.netshape import mamba2_problem, mamba2_widths
@@ -612,9 +613,9 @@ class Mamba2Layer(Layer):
                 x.reshape(n, s, p.num_heads, p.head_dim), dt,
                 params["A_log"], b.reshape(n, s, p.groups, p.state_size),
                 c.reshape(n, s, p.groups, p.state_size), params["D"],
-                params["dt_bias"], p.chunk), MAMBA2_SCAN)
+                params["dt_bias"], p.chunk).reshape(n, s, inner), MAMBA2_SCAN)
         with jax.named_scope(SSM_GATE):
-            y = y.reshape(n, s, inner) * jax.nn.silu(z)
+            y = y * jax.nn.silu(z)
             y = rms_normalize(y.reshape(n, s, p.groups, -1), p.eps)
             y = y.reshape(n, s, inner) * w("norm_scale")
         with jax.named_scope(SSM_OUT):

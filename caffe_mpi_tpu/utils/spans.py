@@ -44,7 +44,7 @@ to tell them apart.
 | `cca.out` | scope | the output product, latent back up to the channels |
 | `ssm.project` | scope | inside a `Mamba2` layer (layers/sequence.py): the one input product to z, x B C and dt |
 | `ssm.conv` | scope | the depthwise causal convolution over x B C (shifted sums), its bias and SiLU, the split |
-| `ssm.scan` | scope | softplus, the decays and the selective recurrence in chunks (ops/ssd.py): four products and the carried states; its backward pass computes the chunks' matrices again under the same scope |
+| `ssm.scan` | scope | softplus, the decays and the selective recurrence in chunks (ops/ssd.py): four products and the carried states; its backward pass, written by hand, opens the same scope and builds the chunks' matrices again from the kept running sums |
 | `ssm.gate` | scope | y * silu(z), the RMS norm a group, the learned scale |
 | `ssm.out` | scope | the output product |
 | `mhc.sinkhorn` | scope | inside a `HyperConnectionPre` layer (layers/hyper_connection.py): the Sinkhorn-Knopp iterations that make a token's residual mix doubly stochastic, rows then columns divided by their sums, float32 |

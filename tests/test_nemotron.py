@@ -33,6 +33,7 @@ from caffe_mpi_tpu.layers.sequence import (MAMBA2_INPUT,  # noqa: E402
 from caffe_mpi_tpu.net import Net  # noqa: E402
 from caffe_mpi_tpu.ops import moe as moe_ops  # noqa: E402
 from caffe_mpi_tpu.ops.flash_attention import KEPT_UNDER_REMAT  # noqa: E402
+from caffe_mpi_tpu.ops import ssd as ssd_ops  # noqa: E402
 from caffe_mpi_tpu.ops.ssd import KEPT, ssd  # noqa: E402
 from caffe_mpi_tpu.proto import NetParameter  # noqa: E402
 
@@ -222,6 +223,139 @@ def test_the_scan_alone_is_the_reference_s_on_the_probe_s_inputs(seq, chunk):
                                     carry_state=False)
     assert rel(got, want) < 2e-5
     assert (rel(dropped, want) > 0.1) == (seq > chunk)
+
+
+# the scan's seven inputs, in `ssd`'s order
+SCAN_INPUTS = ("x", "dt", "A_log", "B", "C", "D", "dt_bias")
+# one chunk, several, chunks that differ, one shorter than a chunk; lanes
+# a head that are no multiple of 8
+SCAN_CASES = [(8, 8, 8), (32, 8, 8), (32, 4, 8), (64, 16, 8), (24, 128, 8),
+              (32, 8, 6)]
+
+
+def scan_case(seq: int, p: int = 8):
+    """The scan's inputs at the tiny layer's widths (`p` lanes a head), with
+    decays neither all 1 nor all 0, and a cotangent."""
+    k = jax.random.split(jax.random.PRNGKey(seq + 11), 8)
+    n, h, g, st = 2, 4, 2, 16
+    args = (jax.random.normal(k[0], (n, seq, h, p)),
+            jax.random.normal(k[1], (n, seq, h)) - 1.0,
+            0.5 * jax.random.normal(k[2], (h,)),
+            jax.random.normal(k[3], (n, seq, g, st)),
+            jax.random.normal(k[4], (n, seq, g, st)),
+            1.0 + 0.5 * jax.random.normal(k[5], (h,)),
+            jax.random.normal(k[6], (h,)) - 1.0)
+    return args, jax.random.normal(k[7], (n, seq, h, p))
+
+
+def scan_cotangents(fn, args, dy):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda args, dy: jax.vjp(fn, *args)[1](dy))(args, dy)
+
+
+def recurrence_cotangents(args, dy, chunk: int):
+    """jax.grad through equations M 4-5 one position a step
+    (`nemotron_ref.scan`, with the D term), in float32."""
+    args = tuple(a.astype(jnp.float32) for a in args)
+    dy = dy.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(dy * (nemotron_ref.scan(
+            a[0], a[1], a[6], a[2], a[3], a[4], chunk)
+            + a[5][:, None] * a[0])), argnums=tuple(range(7))))(*args)
+
+
+def with_decays_rounded(chunk, res, dy):
+    """`ssd_ops._ssd_bwd` with the decays it reads (the running sums and
+    the carry between chunks) rounded to bf16, the forward pass untouched."""
+    low = lambda v: jax.lax.reduce_precision(v, 8, 7)
+    *inputs, (s_in, lt, carry) = res
+    return ssd_ops._ssd_bwd(chunk, (*inputs, (s_in, low(lt), low(carry))), dy)
+
+
+planted_scan = jax.custom_vjp(ssd_ops._ssd, nondiff_argnums=(7,))
+planted_scan.defvjp(ssd_ops._ssd_fwd, with_decays_rounded)
+
+
+def bf16_case(seq: int, p: int = 8):
+    """`scan_case` at the cell's precision: x, dt, B, C and the cotangent
+    bf16, the head vectors float32 as the layer hands them over."""
+    (x, dt, a_log, b, c, d, dt_bias), dy = scan_case(seq, p)
+    low = lambda v: v.astype(jnp.bfloat16)
+    return (low(x), low(dt), a_log, low(b), low(c), d, dt_bias), low(dy)
+
+
+@pytest.mark.parametrize("seq,chunk,p", SCAN_CASES)
+def test_the_scan_s_backward_rule_is_autodiff_and_the_recurrence(seq, chunk,
+                                                                 p):
+    """`ops/ssd.py`'s hand-written backward rule, in float32, against jax's
+    autodiff of the same chunked forward pass (`_ssd`, undecorated) and
+    against jax.grad through equations M 4-5 one position a step
+    (`nemotron_ref.scan`, with the D term): all seven cotangents."""
+    args, dy = scan_case(seq, p)
+    got = scan_cotangents(lambda *a: ssd(*a, chunk), args, dy)
+    auto = scan_cotangents(lambda *a: ssd_ops._ssd(*a, chunk), args, dy)
+    ref = recurrence_cotangents(args, dy, chunk)
+    for name, g, a, r in zip(SCAN_INPUTS, got, auto, ref):
+        assert g.shape == a.shape and g.dtype == a.dtype, name
+        assert rel(g, a) < 1e-5, (name, rel(g, a))
+        assert rel(g, r) < 1e-5, (name, rel(g, r))
+
+
+@pytest.mark.parametrize("seq,chunk,p", SCAN_CASES)
+def test_decays_rounded_in_the_backward_rule_alone_are_seen(seq, chunk, p):
+    """The control of the comparison above: the same backward rule with the
+    decays it reads rounded to bf16 (`with_decays_rounded`) is refused on
+    the time step, its bias and A_log."""
+    args, dy = scan_case(seq, p)
+    got = dict(zip(SCAN_INPUTS, scan_cotangents(
+        lambda *a: planted_scan(*a, chunk), args, dy)))
+    auto = dict(zip(SCAN_INPUTS, scan_cotangents(
+        lambda *a: ssd_ops._ssd(*a, chunk), args, dy)))
+    for name in ("dt", "dt_bias", "A_log"):
+        assert not rel(got[name], auto[name]) < 1e-5, name
+
+
+# bf16 readings of every cotangent are at most 1.07e-2 (the rule against
+# the recurrence, A_log at 32 positions in chunks of 4) and 9.8e-3 (against
+# jax's autodiff, A_log at 32 in chunks of 8); jax's autodiff of `_ssd`
+# itself reads up to 8.0e-3 against the recurrence
+BF16_LIMIT = 1.5e-2
+# the cell's chunk of 128, two chunks
+CELL_CHUNKS = (256, 128, 8)
+
+
+@pytest.mark.parametrize("seq,chunk,p", SCAN_CASES + [CELL_CHUNKS])
+def test_the_scan_s_backward_rule_in_bf16(seq, chunk, p):
+    """The rule at the cell's precision (`bf16_case`) keeps the forward
+    pass's: all seven cotangents within `BF16_LIMIT` of jax's autodiff of
+    `_ssd` at the same precision and of the float32 recurrence on the same
+    rounded inputs, each in the type jax's autodiff gives it."""
+    args, dy = bf16_case(seq, p)
+    got = scan_cotangents(lambda *a: ssd(*a, chunk), args, dy)
+    auto = scan_cotangents(lambda *a: ssd_ops._ssd(*a, chunk), args, dy)
+    ref = recurrence_cotangents(args, dy, chunk)
+    for name, g, a, r in zip(SCAN_INPUTS, got, auto, ref):
+        assert g.shape == a.shape and g.dtype == a.dtype, name
+        g, a = g.astype(jnp.float32), a.astype(jnp.float32)
+        assert rel(g, a) < BF16_LIMIT, (name, rel(g, a))
+        assert rel(g, r) < BF16_LIMIT, (name, rel(g, r))
+
+
+def test_decays_rounded_in_the_backward_rule_alone_are_seen_in_bf16():
+    """The control of the bf16 comparison, at the cell's chunk of 128: the
+    rule with its decays rounded to bf16 reads 4.0e-2 (dt_bias) to 6.6e-2
+    (A_log) against the recurrence, refused by `BF16_LIMIT`. (In chunks of
+    8 to 64 the decays' rounding reads under the products' own bf16
+    rounding: 3e-3 to 2e-2.)"""
+    seq, chunk, p = CELL_CHUNKS
+    args, dy = bf16_case(seq, p)
+    got = dict(zip(SCAN_INPUTS, scan_cotangents(
+        lambda *a: planted_scan(*a, chunk), args, dy)))
+    ref = dict(zip(SCAN_INPUTS, recurrence_cotangents(args, dy, chunk)))
+    for name in ("dt", "dt_bias", "A_log"):
+        got_name = got[name].astype(jnp.float32)
+        assert not rel(got_name, ref[name]) < BF16_LIMIT, (
+            name, rel(got_name, ref[name]))
 
 
 SSM_FAULTS = ["carry_state", "softplus", "d_term", "gate_first",

@@ -8,15 +8,16 @@ the ungated grouped products, and the whole train step as the
 import os
 import re
 import sys
+from collections import Counter
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from test_tpu_aot_compile import (abstract, bench_harness, compile_tpu,
-                                  kernel_calls, on_chip, step_calls,
-                                  v5e_devices)
+from test_tpu_aot_compile import (_glue_bytes_tool, abstract, bench_harness,
+                                  compile_tpu, kernel_calls, on_chip,
+                                  step_calls, v5e_devices)
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -41,6 +42,28 @@ def test_the_chunked_scan_and_its_backward_pass_are_plain_xla():
     assert not kernel_calls(compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+
+
+def test_one_mamba2_layer_s_bytes():
+    """One Mamba-2 layer of the cell's recipe (`blk0/ssm`), forward +
+    backward under its `remat`, by its five scopes
+    (`tools/attention_glue_bytes.py`: operand + result bytes of the
+    compiled passes). Held at what ops/ssd.py's hand-written backward rule
+    and its tile-moving views reached plus a tenth (17.32 GB a layer, 6.46
+    of them under `ssm.scan`, with jax's own backward pass under a
+    checkpoint and plain transposes): a forward pass run again or a
+    float32 tensor of x's size moved between layouts shows here, at no
+    chip time."""
+    tool = _glue_bytes_tool()
+    records = tool.layer_records(
+        str(ROOT / "models/nemotron3_nano_30b_a3b/train_val.prototxt"),
+        "blk0/ssm", "bf16", v5e_devices()[0])
+    totals = tool.totals(records)
+    assert totals["ssm.scan"] <= 1.1 * 4.79e9, totals
+    assert sum(totals.values()) <= 1.1 * 13.29e9, totals
+    # under `ssm.scan` only a product writes float32 of x's size
+    scan = [r for r in records if r["kind"] == "ssm.scan"]
+    assert tool.widest_f32_glue_result(scan) < 8192 * 64 * 64
 
 
 def test_flash_at_the_cell_s_grouped_heads():
@@ -94,10 +117,12 @@ def test_whole_step_compiles_and_fits_the_chip():
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     # the pattern's first nine layers fit under the configuration's limit
-    # with the Mamba-2 layers' `remat: true`: 13.69e9 with it, keeping
-    # each layer's input product, scan output and carried states (12.30e9
-    # keeping none, PR 40's tree with `row_bound` 2.5; 12.07e9 with 1.5),
-    # 15.61e9 with no layer's `remat` (PERF.md section 4, PR 41)
+    # with the Mamba-2 layers' `remat: true`: 13.71e9 with it, keeping
+    # each layer's input product, scan output, and the carried states and
+    # two vectors its scan's backward rule reads (13.69e9 with jax's own
+    # backward pass of the scan; 12.30e9 keeping none with `row_bound`
+    # 2.5, 12.07e9 with 1.5), 15.61e9 with no layer's `remat` (PERF.md
+    # section 4)
     assert pattern == "MEMEM*EME" and live < 14.5e9, live
     calls, fallback = step_calls(compiled.as_text())
     flash = [c for c in calls if c.startswith("flash_")]
@@ -111,36 +136,56 @@ def test_whole_step_compiles_and_fits_the_chip():
     assert fallback.count("ragged-dot-none") == experts * 7, fallback
 
 
-def products_by_scope(text: str) -> dict[tuple[str, str], int]:
-    """`dot` and `convolution` instructions of a compiled module, by the
-    `ssm.*` scope their op_name names ("" outside one) and by pass: a
-    backward instruction's op_name holds `transpose(`."""
-    counts: dict[tuple[str, str], int] = {}
+def products(text: str) -> list[tuple[str, str, str]]:
+    """`dot` and `convolution` instructions of a compiled module, one
+    (scope, pass, label) record each: the `ssm.*` scope their op_name names
+    ("" outside one), the pass (a backward instruction's op_name holds
+    `transpose(`), and the op_name's last scope, which for a product
+    `jnp.einsum` wrote is its equation. XLA may merge two equal products of
+    one program into one instruction, never split one into two."""
+    out = []
     for line in text.splitlines():
         if not re.search(r"= \S+ (convolution|dot)\(", line):
             continue
         name = re.search(r'op_name="([^"]*)"', line)
         name = name.group(1) if name else ""
         scope = re.search(r"ssm\.\w+", name)
-        key = (scope.group(0) if scope else "",
-               "backward" if "transpose(" in name else "forward")
-        counts[key] = counts.get(key, 0) + 1
-    return counts
+        out.append((scope.group(0) if scope else "",
+                    "backward" if "transpose(" in name else "forward",
+                    name.rsplit("/", 2)[-2] if "/" in name else name))
+    return out
+
+
+def products_by_scope(text: str) -> Counter:
+    """`products` counted by scope and pass."""
+    return Counter((scope, side) for scope, side, _ in products(text))
+
+
+def einsums(text: str, scope: str, side: str) -> Counter:
+    """`products` under `scope` in the pass `side`, counted by label."""
+    return Counter(label for s, p, label in products(text)
+                   if (s, p) == (scope, side))
 
 
 def test_remat_keeps_what_a_mamba2_layer_s_backward_pass_reads():
     """The tiny recipe's forward and backward pass, bf16, compiled for one
     described v5e: under `remat: true` a Mamba2 layer keeps its input
-    product's result, its scan's output and the scan's carried states
-    (`Mamba2Layer.kept_under_remat`). So the input product runs once
-    forward and twice backward (dX, dW), not again to recompute the layer;
-    the scan's forward products run once in the backward pass, inside
-    ops/ssd.py's own checkpoint, not a second time for the layer's. The
+    product's result, its scan's output and what the scan's backward rule
+    reads (`Mamba2Layer.kept_under_remat`: the carried states and two
+    vectors, `ssd.KEPT`). So the input product runs once forward and twice
+    backward (dX, dW), not again to recompute the layer; the scan's
+    forward products run once a layer in the whole step, and its backward
+    pass runs ops/ssd.py's hand-written rule's products alone, each once a
+    layer (of the forward's, only C B^T and the read-out S_in C, which the
+    rule builds again by design), every operation of it under `ssm.scan`
+    and `transpose(`. The
     attention layer's `remat` keeps the flash kernel's output as before.
     Take any one name off the policy and a count here moves."""
     from caffe_mpi_tpu.net import Net
     from caffe_mpi_tpu.ops.ssd import ssd
     from caffe_mpi_tpu.proto import NetParameter
+    sys.path[:0] = [p for p in (str(ROOT / "tools"),) if p not in sys.path]
+    from attention_glue_bytes import SSM_SCOPES, classify
     recipe = ROOT / "models" / "nemotron3_nano_30b_a3b" / "tiny_train_val.prototxt"
     net = Net(NetParameter.from_text(recipe.read_text()), phase="TRAIN",
               precision="bf16")
@@ -155,24 +200,52 @@ def test_remat_keeps_what_a_mamba2_layer_s_backward_pass_reads():
         return jax.value_and_grad(lambda p: net.apply(
             p, state, feeds, train=True, rng=None)[2])(p)
     text = compile_tpu(step, abstract(params, rep), feeds)
-    got = products_by_scope(text)
 
-    # the scan alone at the layers' sizes, under its own checkpoint
+    # the scan alone at the layers' sizes: its forward pass, and its
+    # forward and backward passes (the backward rule opens `ssm.scan`)
     p, (n, s, _) = mixers[0].p, mixers[0].in_shapes[0]
     x = on_chip((n, s, p.num_heads, p.head_dim), jnp.bfloat16)
     dt = on_chip((n, s, p.num_heads), jnp.bfloat16)
     bc = on_chip((n, s, p.groups, p.state_size), jnp.bfloat16)
     head = on_chip((p.num_heads,), jnp.float32)
+    args = (x, dt, head, bc, bc, head, head)
 
-    def scan(*args):
+    def scan(dy, *args):
         out, vjp = jax.vjp(lambda *a: ssd(*a, p.chunk), *args)
-        return vjp(out)
-    alone = products_by_scope(compile_tpu(scan, x, dt, head, bc, bc, head,
-                                          head))
+        return out, vjp(dy)
+    forward_text = compile_tpu(lambda *a: ssd(*a, p.chunk), *args)
+    both_text = compile_tpu(scan, x, *args)
+    assert set(products_by_scope(forward_text)) == {("", "forward")}
+    assert set(products_by_scope(both_text)) == {("", "forward"),
+                                                 ("ssm.scan", "backward")}
+    forward = einsums(forward_text, "", "forward")
+    rule = einsums(both_text, "ssm.scan", "backward")
+    # no forward product of the scan runs again for its backward pass
+    assert einsums(both_text, "", "forward") == forward
+    ops = [r for r in classify(both_text, SSM_SCOPES)
+           if r["kind"] == "ssm.scan" or r["pass"] == "backward"]
+    assert ops and all(r["kind"] == "ssm.scan" and r["pass"] == "backward"
+                       for r in ops), ops
+    # of the forward's products the rule computes none again but C B^T
+    # (M is built again from it) and the read-out S_in C (its product with
+    # dy is the running sums' gradient from the read-out), which XLA takes
+    # from the forward pass where the two passes are one program: not the
+    # intra-chunk product, the chunks' end states, the carried states, and
+    # of the two running sums' triangles one runs backward, dl's transposed
+    cb, read_out = "ncgsi,ncgsj->ncgij", "ncgrps,ncgsi->ncgrpi"
+    assert forward[cb] == forward[read_out] == 1, forward
+    assert not {e for e in forward if e != "...j,ij->...i"} & set(rule), rule
+    assert (forward["...j,ij->...i"], rule["...j,ij->...i"]) == (2, 1), rule
+
     layers = len(mixers)
+    per_layer = lambda c: Counter({k: layers * v for k, v in c.items()})
+    got = products_by_scope(text)
     assert got[("ssm.project", "forward")] == layers
     assert got[("ssm.project", "backward")] == 2 * layers
-    assert got[("ssm.scan", "forward")] == layers * alone[("", "forward")]
-    assert got[("ssm.scan", "backward")] == layers * alone[("", "backward")]
+    # in the step, a layer's forward products once, and its backward pass
+    # the rule's products and those two, each once
+    assert einsums(text, "ssm.scan", "forward") == per_layer(forward)
+    assert einsums(text, "ssm.scan", "backward") == per_layer(
+        rule + Counter([cb, read_out]))
     flash = [c for c in step_calls(text)[0] if c.startswith("flash_")]
     assert sorted(flash) == ["flash_dkv", "flash_dq", "flash_fwd"]

@@ -1,13 +1,14 @@
 #!/usr/bin/env python3
-"""What one attention layer, or one hyper-connection, moves through HBM
-beside its kernels and its matrix products, counted without a chip.
+"""What one attention layer, one hyper-connection or one Mamba-2 layer moves
+through HBM beside its kernels and its matrix products, counted without a
+chip.
 
     JAX_PLATFORMS=cpu python3 tools/attention_glue_bytes.py <prototxt> <layer name> [--precision bf16]
     JAX_PLATFORMS=cpu python3 tools/attention_glue_bytes.py <prototxt> <HyperConnectionPre name> <its HyperConnectionPost name>
 
-Builds the net of `<prototxt>`, takes the one `Attention` layer named, and
-compiles its forward and backward pass (`jax.vjp` of `layer.apply` over the
-parameters and the bottom, under the layer's own `remat` as `Net` applies
+Builds the net of `<prototxt>`, takes the one `Attention` or `Mamba2`
+layer named, and compiles its forward and backward pass (`jax.vjp` of
+`layer.apply` over the parameters and the bottom, under the layer's own `remat` as `Net` applies
 it) for a described `v5e:2x2` with nothing attached. Given a
 `HyperConnectionPre` and its `HyperConnectionPost`, it compiles the pair
 the same way, each under its own `remat`, with an elementwise stand-in for
@@ -19,9 +20,16 @@ entry computation it sums operand bytes + result bytes in three classes:
   projections  the fusions that hold a convolution (XLA:TPU's matrix product)
   glue         everything else: loop fusions, copies, reductions
 
-and prints them with every glue operation over 32 MB. Bytes and counts from
+and prints them with every glue operation over 32 MB. A `Mamba2` layer's
+operations are classed by the layer's five scopes instead (`ssm.project`,
+`ssm.conv`, `ssm.scan`, `ssm.gate`, `ssm.out`; utils/spans.py), each
+operation given to the scope most of its instructions' `op_name`s carry,
+as `benchmarks/scope_reduce.py` gives its time ("other" where none holds
+a majority), and by pass (`backward` where most carry `transpose(`), with
+each class's products counted. Bytes and counts from
 the compiler's text, never a time: nothing runs. The count is static, so it
-is this script and a test (`tests/test_tpu_aot_compile.py`), not a span.
+is this script and tests (`tests/test_tpu_aot_compile.py`,
+`tests/test_nemotron_aot.py`), not a span.
 
 The xing cell's `blk1/hc1` + `blk1/hc1_out` (4,096 tokens, a stream of
 4 x 3,584 in bf16): 9.50 GB with jax's own backward passes over the
@@ -30,6 +38,12 @@ broadcast mix), 4.56 GB with the hand-written ones over the flat view
 (`layers/hyper_connection.py`), of which ~1.6 GB are the stream's relayouts
 at this harness's own argument and result; `xing_ref.mhc_cost` counts
 1.205 GB by the definition.
+
+The nemotron cell's `blk0/ssm` (8,192 tokens, 64 heads of 64 lanes, a state
+of 128 in 8 groups, chunks of 128): 17.32 GB, 6.46 of them under
+`ssm.scan`, with jax's own backward pass of the chunked form under a
+checkpoint; 13.29 and 4.79 with `ops/ssd.py`'s hand-written one and its
+tile-moving views (`tests/test_nemotron_aot.py` holds them).
 """
 
 from __future__ import annotations
@@ -53,6 +67,8 @@ _INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = (.*?) ([\w-]+)\((.*)$")
 # operations that move nothing: views, tuples, what the caller hands over
 _FREE = {"parameter", "tuple", "get-tuple-element", "bitcast", "constant",
          "after-all", "partition-id", "replica-id"}
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+SSM_SCOPES = ("ssm.project", "ssm.conv", "ssm.scan", "ssm.gate", "ssm.out")
 
 
 def _arrays(shape_text: str) -> list[tuple[str, int]]:
@@ -66,16 +82,22 @@ def _bytes(shape_text: str) -> int:
     return sum(_ITEM[dtype] * n for dtype, n in _arrays(shape_text))
 
 
-def classify(text: str) -> list[dict]:
+def _majority(names: list[str], needle: str) -> bool:
+    return bool(names) and 2 * sum(needle in n for n in names) > len(names)
+
+
+def classify(text: str, scopes: tuple[str, ...] = ()) -> list[dict]:
     """One record an operation of the entry computation of a compiled
     module's text that touches HBM: name, opcode, kind (mosaic |
-    projections | glue), bytes (operands + results), result (its shape's
-    text)."""
+    projections | glue; with `scopes`, the one of them that most of the
+    operation's instructions carry, or "other"), pass (forward |
+    backward), product (whether it holds a matrix product), bytes
+    (operands + results), result (its shape's text)."""
     computations = re.split(r"\n(?=(?:ENTRY )?%[\w.-]+ \()", text)
-    holds_product = {
-        re.match(r"(?:ENTRY )?%([\w.-]+)", c).group(1)
-        for c in computations
-        if re.match(r"(?:ENTRY )?%", c) and " convolution(" in c}
+    named = [c for c in computations if re.match(r"(?:ENTRY )?%", c)]
+    key = lambda c: re.match(r"(?:ENTRY )?%([\w.-]+)", c).group(1)
+    holds_product = {key(c) for c in named if " convolution(" in c}
+    op_names = {key(c): _OP_NAME.findall(c) for c in named}
     entry = next(c for c in computations if c.startswith("ENTRY "))
     shapes, records = {}, []
     for line in entry.splitlines():
@@ -94,28 +116,41 @@ def classify(text: str) -> list[dict]:
         if opcode.endswith("-done"):
             moved = 2 * _bytes(shape)       # read once, written once
         called = re.search(r"calls=%([\w.-]+)", rest)
+        product = opcode == "convolution" or bool(
+            called and called.group(1) in holds_product)
         if 'custom_call_target="tpu_custom_call"' in rest:
             kind = "mosaic"
-        elif opcode == "convolution" or (called
-                                         and called.group(1) in holds_product):
+        elif product:
             kind = "projections"
         else:
             kind = "glue"
+        names = (op_names.get(called.group(1), []) if called else []) \
+            or _OP_NAME.findall(rest)
+        if scopes:
+            kind = next((s for s in scopes if _majority(names, s)), "other")
         records.append({"name": name, "opcode": opcode, "kind": kind,
-                        "bytes": moved, "result": shape})
+                        "pass": ("backward" if _majority(names, "transpose(")
+                                 else "forward"),
+                        "product": product, "bytes": moved,
+                        "result": shape})
     return records
 
 
 def totals(records: list[dict]) -> dict[str, int]:
     out = {"mosaic": 0, "projections": 0, "glue": 0}
     for r in records:
-        out[r["kind"]] += r["bytes"]
+        out[r["kind"]] = out.get(r["kind"], 0) + r["bytes"]
     return out
+
+
+def _glue(r: dict) -> bool:
+    """Neither a Mosaic call nor a fusion that holds a product."""
+    return r["kind"] != "mosaic" and not r["product"]
 
 
 def widest_f32_glue_result(records: list[dict]) -> int:
     """Elements of the largest float32 array a glue operation writes."""
-    return max((n for r in records if r["kind"] == "glue"
+    return max((n for r in records if _glue(r)
                 for dtype, n in _arrays(r["result"]) if dtype == "f32"),
                default=0)
 
@@ -156,7 +191,7 @@ def layer_vjp(net, layer_name: str):
     import jax
 
     layer = next(l for l in net.layers if l.name == layer_name)
-    if layer.type_name != "Attention":
+    if layer.type_name not in ("Attention", "Mamba2"):
         raise SystemExit(f"{layer_name!r} is a {layer.type_name} layer")
     apply = _applied(layer)
 
@@ -214,8 +249,9 @@ def compiled_text(fn, args, device=None) -> str:
 
 def layer_records(prototxt: str, layer_name: str, precision: str = "bf16",
                   device=None, post: str | None = None) -> list[dict]:
-    """`classify` of one attention layer's compiled pass, or with `post`
-    of the hyper-connection pair `layer_name` / `post`."""
+    """`classify` of one attention or Mamba-2 layer's compiled pass (a
+    Mamba-2 layer's by its scopes), or with `post` of the hyper-connection
+    pair `layer_name` / `post`."""
     from caffe_mpi_tpu.net import Net
     from caffe_mpi_tpu.proto import NetParameter
     with open(prototxt) as f:
@@ -223,7 +259,10 @@ def layer_records(prototxt: str, layer_name: str, precision: str = "bf16",
                   model_dir=os.path.dirname(prototxt))
     fn, args = (pair_vjp(net, layer_name, post) if post
                 else layer_vjp(net, layer_name))
-    return classify(compiled_text(fn, args, device))
+    mamba2 = not post and next(
+        l for l in net.layers if l.name == layer_name).type_name == "Mamba2"
+    return classify(compiled_text(fn, args, device),
+                    SSM_SCOPES if mamba2 else ())
 
 
 def main(argv=None) -> int:
@@ -245,12 +284,19 @@ def main(argv=None) -> int:
           f"{args.precision}, one described v5e chip (a count, not a time)")
     by_kind = totals(records)
     for kind, n in by_kind.items():
-        ops = sum(r["kind"] == kind for r in records)
-        print(f"  {kind:12s} {gb(n):>9s}  in {ops} operations")
+        ops = [r for r in records if r["kind"] == kind]
+        if not ops:
+            continue
+        passes = ", ".join(
+            f"{side} {gb(sum(r['bytes'] for r in ops if r['pass'] == side))}"
+            f" / {sum(r['pass'] == side and r['product'] for r in ops)}"
+            f" products" for side in ("forward", "backward"))
+        print(f"  {kind:12s} {gb(n):>9s}  in {len(ops)} operations "
+              f"({passes})")
     print(f"  {'all':12s} {gb(sum(by_kind.values())):>9s}")
     by_opcode = {}
     for r in records:
-        if r["kind"] == "glue":
+        if _glue(r):
             by_opcode[r["opcode"]] = by_opcode.get(r["opcode"], 0) + r["bytes"]
     print("  glue by opcode: " + ", ".join(
         f"{op} {gb(n)}" for op, n in sorted(by_opcode.items(),
@@ -258,10 +304,12 @@ def main(argv=None) -> int:
     print(f"  largest float32 result of a glue operation: "
           f"{widest_f32_glue_result(records):,} elements")
     for r in sorted(records, key=lambda r: -r["bytes"]):
-        if r["kind"] == "glue" and r["bytes"] > args.over_mb * 1e6:
+        if _glue(r) and r["bytes"] > args.over_mb * 1e6:
             shape = re.sub(r"\{[^}]*\}", "", r["result"])
+            where = f"{r['kind']} {r['pass']}, " if r["kind"] not in (
+                "glue", "mosaic") else ""
             print(f"  {r['bytes'] / 1e6:8.1f} MB  {r['name']} "
-                  f"({r['opcode']}) -> {shape[:100]}")
+                  f"({where}{r['opcode']}) -> {shape[:100]}")
     return 0
 
 
